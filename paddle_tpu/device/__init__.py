@@ -39,13 +39,17 @@ def device_count() -> int:
 
 
 def memory_stats(device=None) -> dict:
-    """Raw PJRT allocator stats (may be {} when the runtime doesn't
-    export them — e.g. remote-tunneled backends)."""
+    """Raw PJRT allocator stats. The CPU backend exports none and gives
+    {}; on an accelerator missing stats are an error, not an empty
+    dict that reads as zero bytes."""
     d = _device(device)
-    try:
-        return d.memory_stats() or {}
-    except Exception:
-        return {}
+    stats = d.memory_stats()
+    if stats is None:
+        if d.platform == "cpu":
+            return {}
+        raise RuntimeError(
+            f"{d.device_kind} ({d.platform}) exports no allocator stats")
+    return stats
 
 
 def memory_allocated(device=None) -> int:

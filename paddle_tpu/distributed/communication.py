@@ -188,7 +188,6 @@ def _eager_collective(g: "Group", kind: str, local, **static):
     from functools import partial
 
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh, idx, dev = _group_mesh(g)
     n = len(g.ranks)
@@ -253,10 +252,10 @@ def _eager_collective(g: "Group", kind: str, local, **static):
 
         out_spec = P("w") if kind in ("reduce_scatter", "all_to_all",
                                       "scatter", "shift") else P()
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=P("w", *([None] * local.ndim)),
-            out_specs=out_spec, check_rep=False))
+            out_specs=out_spec, check_vma=False))
         _eager_jits[key] = fn
     # per-collective watchdog probe (the reference records start/end per
     # collective in comm_task_manager.cc; a hang here reports WHICH

@@ -30,6 +30,7 @@ from paddle_tpu.core import generator as gen
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.mesh import ProcessMesh, Replicate, Shard
 from paddle_tpu.jit.trace import functionalize
+from paddle_tpu.ops.pallas.common import kernel_mesh
 
 __all__ = ["current_mesh", "set_current_mesh", "shard_model_parameters",
            "ParallelTrainStep", "ParallelConfig"]
@@ -240,8 +241,15 @@ class ParallelTrainStep:
 
             trainable_params = [p for p, t in zip(param_datas,
                                                   self._trainable) if t]
-            (_, (loss, new_buffers)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(trainable_params)
+            # a Pallas kernel in the model (flash attention) cannot be
+            # partitioned by GSPMD: declare how attention operands are
+            # sharded here, so it runs per shard under shard_map
+            with kernel_mesh(
+                    mesh.jax_mesh(),
+                    heads="mp" if "mp" in mesh.dim_names else None,
+                    batch=batch_axes or None):
+                (_, (loss, new_buffers)), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(trainable_params)
 
             found_inf = None
             new_scaler_state = scaler_state
@@ -306,10 +314,16 @@ class ParallelTrainStep:
         self._jitted = None  # built lazily at first call (needs batch avals)
         # step seeds from the optimizer counter so checkpoint resume keeps
         # bias correction right (see jit/train.py _sync_step_carry)
-        self._carry = (jnp.asarray(float(optimizer._step_count),
-                                   jnp.float32),
-                       gen.default_generator.next_key(),
-                       jnp.zeros((), jnp.float32))  # nonfinite skips
+        # placed like the carry every step returns: an uncommitted
+        # first carry makes step 2 a second compile of the whole step
+        # (see jit.TrainStep._commit_state)
+        self._carry = tuple(jax.device_put(c, repl) for c in (
+            jnp.asarray(float(optimizer._step_count), jnp.float32),
+            gen.default_generator.next_key(),
+            jnp.zeros((), jnp.float32)))  # nonfinite skips
+        if self._scaler_state is not None:
+            self._scaler_state = tuple(jax.device_put(v, repl)
+                                       for v in self._scaler_state)
         self._host_step_mirror = optimizer._step_count
         if self._skip_nonfinite:
             from paddle_tpu.jit.train import install_nonfinite_observability

@@ -20,8 +20,8 @@ primitive for the serving stack:
   hold). Single-device CPU CI exercises every layout pair through the
   oracle; the device path must agree with it bit-for-bit.
 * the **device path** — :func:`redistribute` lowers a layout change to
-  ``jax.jit`` with ``NamedSharding`` in/out shardings. The container's
-  jax 0.4.37 has no usable shard_map, so the collectives are GSPMD's:
+  ``jax.jit`` with ``NamedSharding`` in/out shardings. No collective
+  is written by hand (no ``shard_map``); they are GSPMD's:
   jit of the identity function with a different out_sharding makes XLA
   insert the gather/slice/collective-permute lattice itself (the same
   s_to_r = all-gather, s_to_s = all-to-all lowering the reference
@@ -366,9 +366,9 @@ def redistribute(x, src: "Layout", dst: "Layout", devices=None):
     """Device path: move a jax array from ``src`` to ``dst`` layout.
 
     Lowers through ``jax.jit`` of the identity with ``NamedSharding``
-    in/out shardings over a common mesh (jax 0.4.37: no shard_map —
-    GSPMD inserts the all-gather/slice/permute collectives from the
-    sharding change alone). Numpy inputs are accepted and placed under
+    in/out shardings over a common mesh (GSPMD inserts the
+    all-gather/slice/permute collectives from the sharding change
+    alone). Numpy inputs are accepted and placed under
     ``src`` first, so callers can feed oracle shards straight in.
     """
     import jax

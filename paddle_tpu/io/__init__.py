@@ -509,17 +509,14 @@ class DataLoader:
             n_batches = len(batches)
         nw = self.num_workers
         # transport: native C++ shared-memory ring buffer (one memcpy per
-        # batch; the reference's LoDTensorBlockingQueue role) when
-        # available and use_shared_memory, else an mp.Queue (pickle)
-        result_q = None
+        # batch; the reference's LoDTensorBlockingQueue role) with
+        # use_shared_memory — a build failure raises, it does not
+        # quietly change the transport — else an mp.Queue (pickle)
         if self.use_shared_memory:
-            try:
-                from paddle_tpu.io.shm_queue import ShmQueue
+            from paddle_tpu.io.shm_queue import ShmQueue
 
-                result_q = ShmQueue()
-            except Exception:
-                result_q = None
-        if result_q is None:
+            result_q = ShmQueue()
+        else:
             # per-worker prefetch depth (reference prefetch_factor
             # semantics): a full queue backpressures the workers
             result_q = ctx.Queue(

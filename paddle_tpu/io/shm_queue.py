@@ -6,13 +6,17 @@ SURVEY.md §2.2 io row). Numpy batches cross the worker→trainer boundary
 as one memcpy each way (length-prefixed records with a tiny numpy
 header), instead of a pickle round-trip through an mp.Queue.
 
-The .so is built lazily with g++ the first time it's needed and cached
-under ~/.cache/paddle_tpu; if no compiler is available the DataLoader
-falls back to the mp.Queue transport.
+The .so is built lazily with g++ the first time it's needed, from
+``csrc/shm_queue.cpp`` as git holds it, into the checkout's ignored
+cache directory (``utils.build_cache``), named by a hash of the source:
+an edited source never loads a stale artifact, whatever the mtimes. A
+failed build is an error where the queue is asked for, not a silent
+change of transport.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import io as _io
 import mmap
 import os
@@ -34,19 +38,20 @@ def _build_lib():
     global _LIB, _LIB_ERR
     if _LIB is not None or _LIB_ERR is not None:
         return _LIB
+    from paddle_tpu.utils.build_cache import cache_dir
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "csrc", "shm_queue.cpp")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     with _BUILD_LOCK:
         if _LIB is not None or _LIB_ERR is not None:
             return _LIB
-        src = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "csrc", "shm_queue.cpp")
-        cache = os.environ.get(
-            "PADDLE_TPU_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu"))
+        cache = cache_dir("native")
         os.makedirs(cache, exist_ok=True)  # tpulint: disable=blocking-under-lock (one-time double-checked build: the lock exists precisely to serialize the slow compile)
-        so = os.path.join(cache, "libshm_queue.so")
+        so = os.path.join(cache, f"libshm_queue-{digest}.so")
         try:
-            if (not os.path.exists(so)
-                    or os.path.getmtime(so) < os.path.getmtime(src)):
+            if not os.path.exists(so):
                 tmp = so + f".tmp{os.getpid()}"
                 subprocess.run(  # tpulint: disable=blocking-under-lock (one-time double-checked build: the lock exists precisely to serialize the slow compile)
                     ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, src,
@@ -74,6 +79,8 @@ def _build_lib():
                                                       ctypes.c_int64]
             _LIB = lib
         except Exception as e:  # no compiler / no pthread etc.
+            # remembered so every later ask fails the same way without
+            # re-running the compiler; ShmQueue() raises it
             _LIB_ERR = e
             _LIB = None
     return _LIB

@@ -7,7 +7,7 @@ embedding.py, fused_rms_norm.py, swiglu.py).
 
 TPU-native: bf16-first, RMSNorm in f32, rope precomputed cos/sin, GQA,
 flash attention through ops.pallas_attention (Pallas kernel on TPU, XLA
-fallback elsewhere). Parallelism by construction:
+SDPA elsewhere). Parallelism by construction:
   tp  — Column/Row parallel projections + vocab-parallel embedding/head
   sp  — sequence dim constrained to the mp axis between blocks
   dp/fsdp — via ParallelTrainStep config
@@ -48,7 +48,10 @@ class LlamaConfig:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     sequence_parallel: bool = False
-    use_flash_attention: bool = True
+    # True: ops.pallas_attention's rule (Pallas kernel on a TPU, XLA SDPA
+    # elsewhere); a string names the implementation ("pallas",
+    # "interpret", "sdpa"); False: masked SDPA
+    use_flash_attention: object = True
     # ring-attention context parallelism: sequence sharded over this mesh
     # axis, KV rotated by ppermute (ops/ring_attention.py)
     context_parallel: bool = False
@@ -187,10 +190,12 @@ class LlamaAttention(nn.Layer):
             rep = self.n_heads // self.n_kv
             k = ops.repeat_interleave(k, rep, axis=2)
             v = ops.repeat_interleave(v, rep, axis=2)
-        if self.config.use_flash_attention and attn_mask is None:
+        fa = self.config.use_flash_attention
+        if fa and attn_mask is None:
             from paddle_tpu.ops import pallas_attention
 
-            out = pallas_attention.flash_attention(q, k, v, causal=True)
+            out = pallas_attention.flash_attention(
+                q, k, v, causal=True, impl=None if fa is True else fa)
         else:
             out = ops.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None)

@@ -11,9 +11,10 @@ grid order.
 Public layout convention matches paddle flash_attention: [B, S, H, D].
 Kernels operate on [B*H, S, D].
 
-On non-TPU backends the same kernels run in Pallas interpret mode, which
-is how tests/test_flash_attention.py verifies numerics against the XLA
-SDPA fallback on the CPU mesh.
+``interpret`` is never inferred: the kernels compile for the TPU unless a
+caller passes ``interpret=True`` by name, which is how
+tests/test_flash_attention.py verifies numerics against XLA SDPA on the
+CPU mesh. Lowering the compiled kernel for a CPU raises.
 """
 from __future__ import annotations
 
@@ -23,13 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu import works even on CPU; kernels then need interpret=True
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from paddle_tpu.ops.pallas.common import declared, mxu_dot
+
+_VMEM = pltpu.VMEM
 
 # Large blocks amortize Mosaic per-tile overhead: measured on v5e at
 # [4,2048,16,128] bf16 causal, 512x1024 runs ~2x faster than 128x128.
@@ -41,7 +41,7 @@ def _pick_block(seq_len, preferred):
     """Largest block <= preferred that divides seq_len, stepping down
     through MXU-friendly sizes; sequences shorter than 128 (or with no
     dividing candidate) become a single whole-sequence block, which
-    available() then gates on 8-alignment."""
+    tileable() then gates on 8-alignment."""
     for b in (preferred, 512, 256, 128):
         if b <= preferred and b <= seq_len and seq_len % b == 0:
             return b
@@ -80,7 +80,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
@@ -96,7 +96,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        acc_scr[:] = acc_scr[:] * alpha + mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -144,6 +144,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             _VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -172,7 +173,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0]
         lse = lse_ref[0, 0, :][:, None]
         delta = delta_ref[0, 0, :][:, None]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
@@ -182,11 +183,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 jnp.int32, s.shape, 1)
             s = jnp.where(row >= col, s, _NEG_INF)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        dq_scr[:] = dq_scr[:] + mxu_dot(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
@@ -217,7 +218,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0]
         lse = lse_ref[0, 0, :][:, None]
         delta = delta_ref[0, 0, :][:, None]
-        s = jax.lax.dot_general(
+        s = mxu_dot(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
@@ -227,14 +228,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, s.shape, 1)
             s = jnp.where(row >= col, s, _NEG_INF)
         p = jnp.exp(s - lse)  # [bq, bk]
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[:] = dv_scr[:] + mxu_dot(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
+        dp = mxu_dot(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dk_scr[:] = dk_scr[:] + mxu_dot(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
@@ -271,6 +272,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[_VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -299,6 +301,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             _VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -328,33 +331,25 @@ def _make_flash(scale, causal, block_q, block_k, interpret):
     return fa
 
 
-def available(seq_len=None, block_q=DEFAULT_BLOCK_Q,
-              block_k=DEFAULT_BLOCK_K):
-    """Whether the Pallas kernel path applies: native on TPU, interpret
-    elsewhere; sequence must tile evenly into blocks that satisfy TPU
-    sublane tiling (block a multiple of 8)."""
-    if pltpu is None:
-        return False
-    if seq_len is not None:
-        bq = _pick_block(seq_len, block_q)
-        bk = _pick_block(seq_len, block_k)
-        if seq_len % bq or seq_len % bk:
-            return False
-        if bq % 8 or bk % 8:
-            return False
-    return True
+def tileable(seq_len, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+    """Whether the kernel tiles this sequence length: it must divide
+    evenly into blocks that satisfy TPU sublane tiling (a multiple of
+    8). The entry point sends every other length to XLA SDPA."""
+    bq = _pick_block(seq_len, block_q)
+    bk = _pick_block(seq_len, block_k)
+    return not (seq_len % bq or seq_len % bk or bq % 8 or bk % 8)
 
 
 def flash_attention_data(q, k, v, causal=False, scale=None,
                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                         interpret=None):
-    """Raw-jnp flash attention on [B, S, H, D] inputs (differentiable)."""
-    b, s, h, d = q.shape
+                         interpret=False):
+    """Raw-jnp flash attention on [B, S, H, D] inputs (differentiable).
+    Under a declared ``kernel_mesh`` it runs per (batch, head) shard
+    inside ``jax.shard_map``: GSPMD refuses to partition the kernel."""
+    s, d = q.shape[1], q.shape[3]
     sk = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(sk, block_k)
     if s % block_q or sk % block_k:
@@ -363,24 +358,35 @@ def flash_attention_data(q, k, v, causal=False, scale=None,
             f"sizes; got q_seq={s} (block_q={block_q}), k_seq={sk} "
             f"(block_k={block_k}). Use ops.scaled_dot_product_attention "
             f"for ragged shapes.")
+    fa = _make_flash(float(scale), bool(causal), int(block_q), int(block_k),
+                     bool(interpret))
 
     def to_bh(x):
         xs = x.shape
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(
             xs[0] * xs[2], xs[1], xs[3])
 
-    fa = _make_flash(float(scale), bool(causal), int(block_q), int(block_k),
-                     bool(interpret))
-    o = fa(to_bh(q), to_bh(k), to_bh(v))
-    return jnp.transpose(o.reshape(b, h, s, d), (0, 2, 1, 3))
+    def local(q, k, v):             # shapes are the shard's own
+        o = fa(to_bh(q), to_bh(k), to_bh(v))
+        return jnp.transpose(
+            o.reshape(q.shape[0], q.shape[2], s, d), (0, 2, 1, 3))
+
+    decl = declared()
+    if decl is not None and (decl[1] is not None or decl[2] is not None):
+        mesh, heads, batch = decl
+        spec = PartitionSpec(batch, None, heads, None)
+        local = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                              out_specs=spec, check_vma=False)
+    return local(q, k, v)
 
 
-def flash_attention_op(query, key, value, causal=False):
+def flash_attention_op(query, key, value, causal=False, interpret=False):
     """Tensor-level entry used by ops/pallas_attention.py; registers on the
     autograd tape via the registry emitter below."""
     from paddle_tpu.ops.registry import API as _API
 
-    return _API["flash_attention"](query, key, value, causal=causal)
+    return _API["flash_attention"](query, key, value, causal=causal,
+                                   interpret=interpret)
 
 
 # register as a first-class op so eager autograd + AMP treat it like any
@@ -390,8 +396,9 @@ from paddle_tpu.ops.registry import register_emitter as _register  # noqa
 
 
 @_register
-def flash_attention(q, k, v, causal=False):
-    return flash_attention_data(q, k, v, causal=causal)
+def flash_attention(q, k, v, causal=False, interpret=False):
+    return flash_attention_data(q, k, v, causal=causal,
+                                interpret=interpret)
 
 
 if "flash_attention" not in _registry.OPS:

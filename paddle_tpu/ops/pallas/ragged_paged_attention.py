@@ -34,15 +34,26 @@ both are the same code path, which is what makes chunked prefill free.
 Two implementations, shape-identical:
 
 * ``_ragged_attend_ref`` — pure jnp gather/einsum. The semantics oracle
-  and the CI path (the CPU container cannot execute TPU Pallas natively).
-* ``_ragged_attend_pallas`` — Pallas TPU kernel, grid (S, q_blocks,
-  kv_blocks) with scalar-prefetched cu_seqlens/context_lens/block_tables;
-  online-softmax accumulators in VMEM scratch; out-of-range and
-  post-causal blocks are skipped entirely, so padded slots cost zero.
+  and the path every non-TPU backend takes. It materializes each token's
+  whole (MB*BS, KH, D) context, so it is for small shapes only.
+* ``_ragged_attend_pallas`` — Pallas TPU kernel, grid (q_tiles, S,
+  kv_pages) with scalar-prefetched cu_seqlens/context_lens/block_tables.
+  q and out move through (block_q, H*D) BlockSpec tiles of the packed
+  stream (a tile may hold rows of several slots; each slot's rows are
+  attended and stored on that slot's sweep), one (BS, KH, D) KV page per
+  grid step, online-softmax accumulators in VMEM scratch. Steps whose
+  slot has no row in the tile, or whose page lies past the causal bound,
+  do nothing and fetch nothing. Compiled, it needs head_dim % 128 == 0.
 
-Selection: Pallas on TPU, reference elsewhere; override with ``impl=`` or
-``PADDLE_RAGGED_ATTN_IMPL=ref|pallas|interpret`` (interpret runs the
-kernel through the Pallas interpreter — slow, test-only).
+Selection: ``impl=None`` reads ``PADDLE_RAGGED_ATTN_IMPL``, else picks
+``"pallas"`` on a TPU backend and ``"ref"`` elsewhere. ``"pallas"``
+always means the compiled kernel (lowering it for a CPU raises);
+``"interpret"`` runs the kernel through the Pallas interpreter and
+happens only when asked for by name (slow, test-only).
+
+Under a device mesh the whole op runs per head-shard inside
+``jax.shard_map`` when the tracing engine has declared the head axis
+(``kernel_mesh``): GSPMD refuses to partition a Mosaic kernel.
 """
 from __future__ import annotations
 
@@ -53,23 +64,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu import works even on CPU; kernels then need interpret=True
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from paddle_tpu.ops.pallas.common import declared, mxu_dot
 
+_VMEM = pltpu.VMEM
 _NEG_INF = -1e30
 
-__all__ = ["ragged_paged_attention", "available"]
-
-
-def available():
-    """Whether the Pallas kernel path can be built (native on TPU,
-    interpret elsewhere)."""
-    return pltpu is not None
+__all__ = ["ragged_paged_attention"]
 
 
 def _pick_block_q(t):
@@ -113,7 +116,7 @@ def _write_kv(cache, new, block_tables, seg, pos):
 
 
 # ---------------------------------------------------------------------------
-# reference implementation (semantics oracle; the CI path)
+# reference implementation (semantics oracle; the non-TPU path)
 # ---------------------------------------------------------------------------
 def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
     t_total, h, d = q.shape
@@ -143,35 +146,51 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
+def _q_block_span(cu_ref, ctx_ref, ns_ref, i, qb, block_q, block_size):
+    """For q tile ``qb`` (stream rows [qb*block_q, (qb+1)*block_q)) and
+    sequence slot ``i``: whether any of the slot's rows fall in the tile,
+    and the last KV page the tile's rows of that slot may attend to
+    (causal upper bound). Scalar math on the prefetched refs only, so the
+    index maps can call it too."""
+    lo = cu_ref[i]
+    nq = cu_ref[i + 1] - lo
+    # last stream row of slot i inside the tile (exclusive)
+    end = jnp.minimum(lo + nq, (qb + 1) * block_q)
+    live = (i < ns_ref[0]) & (end > jnp.maximum(lo, qb * block_q))
+    hi = ctx_ref[i] - nq + (end - lo) - 1      # absolute pos of that row
+    last_j = jnp.where(live, jnp.maximum(hi, 0) // block_size, 0)
+    return live, last_j
+
+
 def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
-                   scale, block_q, block_size, t_total, n_heads, kv_heads):
-    i = pl.program_id(0)          # sequence slot
-    qb = pl.program_id(1)         # q block within the slot's token window
-    j = pl.program_id(2)          # kv block (position within block table)
+                   scale, block_q, block_size, n_heads, kv_heads, head_dim):
+    qb = pl.program_id(0)         # q tile of the packed token stream
+    i = pl.program_id(1)          # sequence slot
+    j = pl.program_id(2)          # kv page (position within block table)
+    d = head_dim
+    rep = n_heads // kv_heads
 
-    nq = cu_ref[i + 1] - cu_ref[i]
-    ctx = ctx_ref[i]
-    # last absolute position covered by this q block (causal upper bound)
-    hi = ctx - nq + jnp.minimum(nq, (qb + 1) * block_q) - 1
-    last_j = jnp.maximum(hi, 0) // block_size
-    run = ((i < ns_ref[0]) & (qb * block_q < nq)
-           & (j * block_size <= hi))
+    # the out tile stays resident across the (i, j) sweep of one q tile;
+    # rows no slot owns (stream padding) keep these zeros
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j == 0)
+    live, last_j = _q_block_span(cu_ref, ctx_ref, ns_ref, i, qb, block_q,
+                                 block_size)
+
+    @pl.when(live & (j == 0))
     def _():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # q window start, clamped so the block stays in bounds; `shift` rows at
-    # the front of the loaded window belong to earlier (already-stored)
-    # tokens and are masked out of both the math and the store
-    raw_start = cu_ref[i] + qb * block_q
-    qs = jnp.minimum(raw_start, t_total - block_q)
-    shift = raw_start - qs
-    rep = n_heads // kv_heads
+    run = live & (j <= last_j)
+    lo = cu_ref[i]
+    nq = cu_ref[i + 1] - lo
+    ctx = ctx_ref[i]
 
     @pl.when(run)
     def _():
@@ -179,15 +198,19 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         col = (j * block_size
                + jax.lax.broadcasted_iota(jnp.int32,
                                           (block_q, block_size), 1))
-        local = qb * block_q + (row - shift)             # seq-local q index
+        local = qb * block_q + row - lo                  # seq-local q index
         qpos = ctx - nq + local                          # absolute position
-        mask = (row >= shift) & (local < nq) & (col <= qpos)
+        mask = (local >= 0) & (local < nq) & (col <= qpos)
         for h in range(n_heads):
-            qh = pl.load(q_ref,
-                         (pl.ds(qs, block_q), pl.ds(h, 1),
-                          slice(None)))[:, 0, :]
-            kh_blk = k_ref[0, :, h // rep, :]
-            s = jax.lax.dot_general(
+            # q/out heads live on the lane axis (the (T, H*D) view), so
+            # a head is a static 128-aligned lane slice; the KV page keeps
+            # the cache's own (BS, KH, D) layout (folding its heads onto
+            # lanes would re-tile the whole cache every call) and a head
+            # is a static index on its sublane axis
+            qh = q_ref[:, h * d:(h + 1) * d]
+            g = h // rep
+            kh_blk = k_ref[0, :, g, :]
+            s = mxu_dot(
                 qh, kh_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             s = jnp.where(mask, s, _NEG_INF)
@@ -198,68 +221,83 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
             l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            vh_blk = v_ref[0, :, h // rep, :]
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                p.astype(vh_blk.dtype), vh_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            vh_blk = v_ref[0, :, g, :]
+            acc_scr[:, h * d:(h + 1) * d] = (
+                acc_scr[:, h * d:(h + 1) * d] * alpha
+                + mxu_dot(
+                    p.astype(vh_blk.dtype), vh_blk,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
             m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
             l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(run & (j == last_j))
     def _():
         row = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-        ok = (row >= shift) & ((qb * block_q + row - shift) < nq)
+        local = qb * block_q + row - lo
+        ok = (local >= 0) & (local < nq)
         for h in range(n_heads):
             l = l_scr[h, :, :1]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            val = (acc_scr[h] / l_safe).astype(o_ref.dtype)
-            # read-modify-write: rows outside this window (clamp overlap)
-            # must keep the values earlier grid steps stored
-            idx = (pl.ds(qs, block_q), pl.ds(h, 1), slice(None))
-            cur = pl.load(o_ref, idx)[:, 0, :]
-            pl.store(o_ref, idx, jnp.where(ok, val, cur)[:, None, :])
+            val = (acc_scr[:, h * d:(h + 1) * d] / l_safe).astype(
+                o_ref.dtype)
+            # rows of this tile owned by OTHER slots keep what their own
+            # (i, last_j) step stored
+            cur = o_ref[:, h * d:(h + 1) * d]
+            o_ref[:, h * d:(h + 1) * d] = jnp.where(ok, val, cur)
 
 
-def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, valid, scale,
+def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
                           interpret):
     t_total, h, d = q.shape
-    nb, bs, kh, _ = kc.shape
+    _, bs, kh, _ = kc.shape
     s_slots, mb = bt.shape
     block_q = _pick_block_q(t_total)
     n_qb = -(-t_total // block_q)
+    t_pad = n_qb * block_q
     ns = jnp.reshape(num_seqs.astype(jnp.int32), (1,))
     bt_flat = jnp.maximum(bt, 0).reshape(-1).astype(jnp.int32)
+    q2 = q.reshape(t_total, h * d)
+    if t_pad != t_total:
+        q2 = jnp.pad(q2, ((0, t_pad - t_total), (0, 0)))
 
-    def kv_map(i, qb, j, cu_r, ctx_r, ns_r, bt_r):
-        return (bt_r[i * mb + j], 0, 0, 0)
+    def q_map(qb, i, j, cu_r, ctx_r, ns_r, bt_r):
+        return (qb, 0)
+
+    def kv_map(qb, i, j, cu_r, ctx_r, ns_r, bt_r):
+        # pages past the causal bound (and every page of a slot with no
+        # row in this q tile) re-name the last needed page: an unchanged
+        # block index is not fetched again
+        _, last_j = _q_block_span(cu_r, ctx_r, ns_r, i, qb, block_q, bs)
+        return (bt_r[i * mb + jnp.minimum(j, last_j)], 0, 0, 0)
 
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_q=block_q, block_size=bs,
-        t_total=t_total, n_heads=h, kv_heads=kh)
+        n_heads=h, kv_heads=kh, head_dim=d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(s_slots, n_qb, mb),
+        grid=(n_qb, s_slots, mb),
         in_specs=[
-            pl.BlockSpec(memory_space=_VMEM),            # q, whole array
+            pl.BlockSpec((block_q, h * d), q_map, memory_space=_VMEM),
             pl.BlockSpec((1, bs, kh, d), kv_map, memory_space=_VMEM),
             pl.BlockSpec((1, bs, kh, d), kv_map, memory_space=_VMEM),
         ],
-        out_specs=pl.BlockSpec(memory_space=_VMEM),      # out, whole array
+        out_specs=pl.BlockSpec((block_q, h * d), q_map,
+                               memory_space=_VMEM),
         scratch_shapes=[
             _VMEM((h, block_q, 128), jnp.float32),
             _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((h, block_q, d), jnp.float32),
+            _VMEM((block_q, h * d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_total, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, h * d), q.dtype),
         interpret=interpret,
-    )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q, kc, vc)
-    # padded rows were never visited by the grid and hold uninitialized
-    # garbage: force them to zero (where, not multiply — NaN * 0 == NaN)
-    return jnp.where(valid[:, None, None], out, 0)
+        name="ragged_paged_attention",
+    )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
+    return out[:t_total].reshape(t_total, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -280,27 +318,36 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
         scale = 1.0 / (d ** 0.5)
     if impl is None:
         impl = os.environ.get("PADDLE_RAGGED_ATTN_IMPL") or (
-            "pallas" if (jax.default_backend() == "tpu" and available())
-            else "ref")
+            "pallas" if jax.default_backend() == "tpu" else "ref")
+    if impl not in ("ref", "pallas", "interpret"):
+        raise ValueError(f"unknown ragged attention impl: {impl!r}")
     cu = jnp.asarray(cu_seqlens).astype(jnp.int32)
     ctx = jnp.asarray(context_lens).astype(jnp.int32)
     bt = jnp.asarray(block_tables).astype(jnp.int32)
     ns = jnp.asarray(num_seqs).astype(jnp.int32)
 
-    seg, pos, valid = _token_layout(t_total, s_slots, cu, ctx, ns)
-    kc = _write_kv(key_cache, k_new, bt, seg, pos)
-    vc = _write_kv(value_cache, v_new, bt, seg, pos)
+    def local(q, k_new, v_new, key_cache, value_cache, bt, cu, ctx, ns):
+        seg, pos, valid = _token_layout(t_total, s_slots, cu, ctx, ns)
+        kc = _write_kv(key_cache, k_new, bt, seg, pos)
+        vc = _write_kv(value_cache, v_new, bt, seg, pos)
+        if impl == "ref":
+            out = _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid,
+                                     scale)
+        else:
+            out = _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, ns, scale,
+                                        interpret=(impl == "interpret"))
+        return out, kc, vc
 
-    if impl == "ref":
-        out = _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid,
-                                 scale)
-    elif impl in ("pallas", "interpret"):
-        if pltpu is None:  # pragma: no cover
-            raise RuntimeError("Pallas TPU backend is unavailable")
-        out = _ragged_attend_pallas(
-            q, kc, vc, bt, cu, ctx, ns, valid, scale,
-            interpret=(impl == "interpret"
-                       or jax.default_backend() != "tpu"))
-    else:
-        raise ValueError(f"unknown ragged attention impl: {impl!r}")
-    return out, kc, vc
+    decl = declared()
+    if decl is not None and decl[1] is not None:
+        # heads are sharded over a mesh axis (TP serving): every head is
+        # independent, so each shard runs the same program on its own
+        # heads and its own slice of the cache's kv-head dim
+        mesh, ax, _ = decl
+        hs = PartitionSpec(None, ax, None)
+        cs = PartitionSpec(None, None, ax, None)
+        r = PartitionSpec()
+        local = jax.shard_map(
+            local, mesh=mesh, in_specs=(hs, hs, hs, cs, cs, r, r, r, r),
+            out_specs=(hs, cs, cs), check_vma=False)
+    return local(q, k_new, v_new, key_cache, value_cache, bt, cu, ctx, ns)

@@ -2,33 +2,42 @@
 
 Reference capability: paddle/phi/kernels/gpu/flash_attn_kernel.cu (CUDA
 flash-attn). TPU-native: a Pallas blockwise-softmax kernel
-(ops/pallas/flash_attention.py) used natively on TPU and in interpret
-mode on CPU; the XLA SDPA emitter remains the fallback for shapes the
-kernel doesn't tile (and for dropout).
+(ops/pallas/flash_attention.py).
+
+Which implementation runs is a stated rule, never the outcome of a
+caught error. ``impl=None``: the compiled Pallas kernel when the
+backend is a TPU, there is no dropout and both sequence lengths tile
+(``flash_attention.tileable``); the XLA SDPA emitter otherwise. A
+caller may name the implementation: ``"pallas"`` (compiled — lowering
+it for a CPU raises), ``"interpret"`` (the same kernel through the
+Pallas interpreter; slow, for tests and rehearsals) or ``"sdpa"``.
 
 Layout convention (paddle flash_attention): [batch, seq, heads, head_dim].
 """
 from __future__ import annotations
 
-from paddle_tpu.core.tensor import Tensor
+import jax
+
+from paddle_tpu.ops.pallas import flash_attention as _fa
 from paddle_tpu.ops.registry import API as _API
 
 
 def flash_attention(query, key, value, causal=False, dropout=0.0,
-                    training=True):
-    use_pallas = False
-    if dropout == 0.0:
-        try:
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-
-            seq = (query._data if isinstance(query, Tensor)
-                   else query).shape[1]
-            kseq = (key._data if isinstance(key, Tensor) else key).shape[1]
-            use_pallas = _fa.available(seq) and _fa.available(kseq)
-        except Exception:
-            use_pallas = False
-    if use_pallas:
-        return _fa.flash_attention_op(query, key, value, causal=causal)
-    return _API["scaled_dot_product_attention"](
-        query, key, value, is_causal=causal, dropout_p=dropout,
-        training=training)
+                    training=True, impl=None):
+    if impl is None:
+        impl = ("pallas" if (jax.default_backend() == "tpu"
+                             and dropout == 0.0
+                             and _fa.tileable(query.shape[1])
+                             and _fa.tileable(key.shape[1]))
+                else "sdpa")
+    if impl == "sdpa":
+        return _API["scaled_dot_product_attention"](
+            query, key, value, is_causal=causal, dropout_p=dropout,
+            training=training)
+    if impl not in ("pallas", "interpret"):
+        raise ValueError(f"unknown flash attention impl: {impl!r}")
+    if dropout != 0.0:
+        raise ValueError("the Pallas flash kernel has no dropout; "
+                         "use impl='sdpa'")
+    return _fa.flash_attention_op(query, key, value, causal=causal,
+                                  interpret=(impl == "interpret"))

@@ -73,6 +73,7 @@ preemption) — see paddle_tpu/testing/faults.py.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -131,9 +132,11 @@ class EngineConfig:
     # tensor parallelism: shard weights (attention heads, MLP hidden)
     # and the paged KV caches (kv-head dim) over a 1-D "tp" mesh of
     # the first tp_degree visible devices. The ONE compiled step stays
-    # one program — an SPMD program with NamedSharding in/outs (jax
-    # 0.4.37: no shard_map; GSPMD inserts the collectives). tp_degree=1
-    # is the existing single-device engine, bit for bit.
+    # one program — an SPMD program with NamedSharding in/outs; GSPMD
+    # inserts the collectives. Only the ragged attention op runs under
+    # jax.shard_map over "tp" (a Mosaic kernel cannot be partitioned
+    # by GSPMD). tp_degree=1 is the existing single-device engine, bit
+    # for bit.
     tp_degree: int = 1
     max_batched_tokens: int = 2048
     max_model_len: Optional[int] = None   # default: model max positions
@@ -675,6 +678,7 @@ class LLMEngine:
 
         # -- compiled prefill/decode step -------------------------------
         from paddle_tpu.jit.trace import functionalize
+        from paddle_tpu.ops.pallas.common import kernel_mesh
         from paddle_tpu.ops.sampling import sample_or_verify
 
         apply, (self._pnames, self._params), (_, self._buffers) \
@@ -752,18 +756,30 @@ class LLMEngine:
                 apply_r, _, _ = functionalize(model.forward_ragged)
                 goff = None
 
-            def raw_step_ragged(param_datas, buffer_datas, key, ids, kcs,
-                                vcs, bt, cu, ctx, nseq, skeys, stemp,
-                                stopk, stopp, sdraft, sndraft):
-                if goff is None:
-                    (logits, k2, v2), _ = apply_r(
-                        param_datas, buffer_datas, key, ids, kcs, vcs,
-                        bt, cu, ctx, nseq)
-                    lg3 = logits[:, None, :]
-                else:
+            def forward_r(param_datas, buffer_datas, key, ids, kcs, vcs,
+                          bt, cu, ctx, nseq):
+                # trace-time declaration for the attention op: heads
+                # and the caches' kv-head dim are sharded over "tp"
+                # (read at trace time, so the mesh is the live one)
+                decl = (kernel_mesh(self._cache_sharding.mesh, heads="tp")
+                        if self._cache_sharding is not None
+                        else contextlib.nullcontext())
+                with decl:
+                    if goff is None:
+                        (logits, k2, v2), _ = apply_r(
+                            param_datas, buffer_datas, key, ids, kcs,
+                            vcs, bt, cu, ctx, nseq)
+                        return logits[:, None, :], k2, v2
                     (lg3, k2, v2), _ = apply_r(
                         param_datas, buffer_datas, key, ids, kcs, vcs,
                         bt, cu, ctx, nseq, goff)
+                    return lg3, k2, v2
+
+            def raw_step_ragged(param_datas, buffer_datas, key, ids, kcs,
+                                vcs, bt, cu, ctx, nseq, skeys, stemp,
+                                stopk, stopp, sdraft, sndraft):
+                lg3, k2, v2 = forward_r(param_datas, buffer_datas, key,
+                                        ids, kcs, vcs, bt, cu, ctx, nseq)
                 packed, finite = pack_sampled(
                     lg3, sdraft, sndraft, skeys, stemp, stopk, stopp)
                 return packed, finite, k2, v2
@@ -782,15 +798,8 @@ class LLMEngine:
                 nb = kcs.shape[1]
                 kall = jnp.concatenate([kcs, hk], axis=1)
                 vall = jnp.concatenate([vcs, hv], axis=1)
-                if goff is None:
-                    (logits, k2, v2), _ = apply_r(
-                        param_datas, buffer_datas, key, ids, kall, vall,
-                        bt, cu, ctx, nseq)
-                    lg3 = logits[:, None, :]
-                else:
-                    (lg3, k2, v2), _ = apply_r(
-                        param_datas, buffer_datas, key, ids, kall, vall,
-                        bt, cu, ctx, nseq, goff)
+                lg3, k2, v2 = forward_r(param_datas, buffer_datas, key,
+                                        ids, kall, vall, bt, cu, ctx, nseq)
                 packed, finite = pack_sampled(
                     lg3, sdraft, sndraft, skeys, stemp, stopk, stopp)
                 return packed, finite, k2[:, :nb], v2[:, :nb]

@@ -259,6 +259,7 @@ class InProcessReplica(ReplicaHandle):
         self.replica_id = replica_id or f"replica-{id(self):x}"
         self.engine = LLMEngine(model, config)
         self.alive = True
+        self.last_error: Optional[EngineStepError] = None  # why it died
         self.retiring = False
         self.role = role
         self.created_at = time.monotonic()
@@ -520,6 +521,7 @@ class InProcessReplica(ReplicaHandle):
             # aborts; across the seam a dead replica returns its last
             # outputs rather than raising into the router
             self.alive = False
+            self.last_error = e
             return e.outputs
 
     def start_drain(self, reason: str = "manual") -> List[RequestOutput]:

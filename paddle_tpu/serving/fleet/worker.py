@@ -157,7 +157,11 @@ def main() -> int:
     from paddle_tpu.serving.engine import EngineConfig
     from paddle_tpu.serving.fleet.replica import InProcessReplica
     from paddle_tpu.serving.fleet.transport import ReplicaServicer
+    from paddle_tpu.utils.build_cache import enable_compile_cache
 
+    # every worker of a fleet compiles the same step: share the
+    # persistent cache (JAX_COMPILATION_CACHE_DIR, else the checkout's)
+    enable_compile_cache()
     model = build_model(spec)
     monitor = PreemptionMonitor()
     monitor.install()

@@ -91,7 +91,15 @@ def test_estimate_mfu():
     mfu = estimate_mfu(1e12, 0.01, peak_flops=197e12)
     assert abs(mfu - 1e12 / 0.01 / 197e12) < 1e-9
     assert 0.4 < mfu < 0.6
-    assert profiler.device_peak_flops() > 0
+    # an unknown device (the CPU here) is an error, not a v5e default;
+    # the chip reports "TPU v5 lite", which the table has
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        profiler.device_peak_flops()
+
+    class _V5e:
+        device_kind, platform = "TPU v5 lite", "tpu"
+
+    assert profiler.device_peak_flops(_V5e()) == 197e12
 
 
 def test_device_summary_reports_xla_ops(tmp_path):

@@ -220,3 +220,51 @@ def test_fleet_handoff_parity_ragged(tiny_model):
     for rid in ids:
         assert final[rid].generated == ref[rid], rid
     assert router.num_handoffs >= 1
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernel itself (interpret mode, asked for by name)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (8, 2)],
+                         ids=["mha", "gqa"])
+def test_ragged_kernel_interpret_matches_ref_on_mixed_batch(heads,
+                                                            kv_heads):
+    """impl="interpret" vs impl="ref" on one mixed step: a prefill from
+    position 0, a decode row, a mid-context prefill chunk that straddles
+    two q tiles, a padding slot and padding rows — the coverage whose
+    absence let the kernel rot (it called pl.load/pl.store, which the
+    installed Pallas no longer has, and nothing ran it)."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention,
+    )
+
+    d, bs, t, s, mb = 16, 4, 48, 4, 12      # q tiles of 32 rows
+    nb = s * mb
+    new = [13, 1, 25]           # tokens this step per live slot
+    ctx_live = [13, 9, 33]      # cache length after the step
+    rng = np.random.RandomState(7)
+    cu = np.zeros(s + 1, np.int32)
+    cu[1:4] = np.cumsum(new)
+    cu[4:] = cu[3]
+    ctx = np.zeros(s, np.int32)
+    ctx[:3] = ctx_live
+    bt = np.full((s, mb), -1, np.int32)
+    free = list(rng.permutation(nb))
+    for i, c in enumerate(ctx_live):
+        n = -(-c // bs)
+        bt[i, :n] = [free.pop() for _ in range(n)]
+    q = rng.randn(t, heads, d).astype(np.float32)
+    k_new = rng.randn(t, kv_heads, d).astype(np.float32)
+    v_new = rng.randn(t, kv_heads, d).astype(np.float32)
+    kc = rng.randn(nb, bs, kv_heads, d).astype(np.float32)
+    vc = rng.randn(nb, bs, kv_heads, d).astype(np.float32)
+
+    got = {impl: ragged_paged_attention(
+        q, k_new, v_new, kc, vc, bt, cu, ctx, np.int32(3), impl=impl)
+        for impl in ("ref", "interpret")}
+    for r, i in zip(got["ref"], got["interpret"]):
+        np.testing.assert_allclose(np.asarray(i), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
+    out = np.asarray(got["interpret"][0])
+    assert np.abs(out[:39]).min(axis=(1, 2)).all()   # every live row set
+    assert not out[39:].any()                        # padding rows are 0

@@ -1,0 +1,146 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler at
+real widths — for a chip that is described, not attached.
+
+No chip time: ``jax.experimental.topologies`` describes a ``v5e:2x2`` host
+and ``jit(...).lower(shapes).compile()`` raises what the chip's compiler
+would raise (unaligned slices, VMEM overflow, a kernel GSPMD cannot
+partition). Interpret-mode tests cannot see any of that, which is how the
+ragged kernel rotted unnoticed. Nothing runs, so these say nothing about
+results or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and every xdist worker imports every test
+file. All such tests live in this one file for the same reason.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas.common import kernel_calls, kernel_mesh
+from paddle_tpu.ops.pallas.flash_attention import flash_attention_data
+from paddle_tpu.ops.pallas.ragged_paged_attention import (
+    ragged_paged_attention,
+)
+
+# the engine's one compiled width at GPT-1B: token budget, sequence slots,
+# head dim, KV block size, blocks per sequence, blocks in the pool
+T, S, D, BS, MB, NB = 2048, 8, 128, 16, 128, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; the next run would warn and
+    compile again. Keep the cache off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _ragged_shapes(h, kh, sh_heads, sh_cache, sh_rep):
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def sds(shape, dt, sh):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    return (sds((T, h, D), bf16, sh_heads), sds((T, kh, D), bf16, sh_heads),
+            sds((T, kh, D), bf16, sh_heads),
+            sds((NB, BS, kh, D), bf16, sh_cache),
+            sds((NB, BS, kh, D), bf16, sh_cache),
+            sds((S, MB), i32, sh_rep), sds((S + 1,), i32, sh_rep),
+            sds((S,), i32, sh_rep), sds((), i32, sh_rep))
+
+
+def _kernel_calls(compiled, name):
+    return kernel_calls(compiled.as_text(), name)
+
+
+@pytest.mark.parametrize("h,kh", [(16, 16), (32, 8)],
+                         ids=["mha16", "gqa32x8"])
+def test_ragged_kernel_compiles_for_v5e(one_chip, h, kh):
+    """The engine's ragged step shape at GPT-1B width (16/16 heads) and
+    at the north-star GQA width (32/8)."""
+    fn = jax.jit(functools.partial(ragged_paged_attention, impl="pallas"),
+                 donate_argnums=(3, 4))
+    compiled = fn.lower(
+        *_ragged_shapes(h, kh, one_chip, one_chip, one_chip)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+    mem = compiled.memory_analysis()
+    # the caches are updated in place, and the kernel's operands need no
+    # re-tiled copy of them (a (BS, KH*D) view of the cache cost one)
+    cache_bytes = 2 * NB * BS * kh * D * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
+def test_ragged_kernel_compiles_head_sharded_over_four_chips(topo):
+    """TP serving: GSPMD refuses to partition a Mosaic kernel, so under a
+    declared kernel mesh the op runs per head-shard inside shard_map —
+    one kernel per chip, no collective around it."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("tp",))
+    heads = NamedSharding(mesh, P(None, "tp", None))
+    cache = NamedSharding(mesh, P(None, None, "tp", None))
+    rep = NamedSharding(mesh, P())
+
+    def step(*args):
+        with kernel_mesh(mesh, heads="tp"):
+            return ragged_paged_attention(*args, impl="pallas")
+
+    compiled = jax.jit(step, donate_argnums=(3, 4),
+                       out_shardings=(heads, cache, cache)).lower(
+        *_ragged_shapes(16, 16, heads, cache, rep)).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+    assert "all-gather" not in text and "all-reduce" not in text
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(functools.partial(ragged_paged_attention, impl="pallas"),
+                out_shardings=(heads, cache, cache)).lower(
+            *_ragged_shapes(16, 16, heads, cache, rep))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_kernel_compiles_for_v5e(one_chip, backward):
+    """Flash attention at the 1B train step's shape: batch 4, seq 2048,
+    16 heads of 128, bf16, causal."""
+    x = jax.ShapeDtypeStruct((4, 2048, 16, D), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention_data(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert _kernel_calls(compiled, "flash_attention_fwd") == 1
+    for name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert _kernel_calls(compiled, name) == int(backward)
