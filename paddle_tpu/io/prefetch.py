@@ -29,6 +29,7 @@ Consumed via ``DataLoader(..., use_device_prefetch=True)`` or
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Iterable
@@ -38,6 +39,7 @@ import numpy as np
 import jax
 
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.profiler import span
 
 __all__ = ["DevicePrefetcher", "prefetch_to_device"]
 
@@ -302,10 +304,16 @@ class DevicePrefetcher:
 
         def producer():
             try:
-                for batch in self._loader:
+                it = iter(self._loader)
+                for n in itertools.count():
+                    with span("prefetch.load", batch=n):
+                        batch = next(it, END)
+                    if batch is END:
+                        break
                     if stop.is_set():
                         return
-                    item = ("ok", self._transfer(batch))
+                    with span("prefetch.stage", batch=n):
+                        item = ("ok", self._transfer(batch))
                     while not stop.is_set():
                         try:
                             q.put(item, timeout=0.1)
@@ -329,7 +337,8 @@ class DevicePrefetcher:
         t.start()
         try:
             while True:
-                kind, item = q.get()
+                with span("prefetch.wait"):
+                    kind, item = q.get()
                 if kind == "end":
                     return
                 if kind == "err":

@@ -31,6 +31,7 @@ from paddle_tpu.core import generator as gen
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.jit.trace import functionalize
 from paddle_tpu.nn.clip import ClipGradByGlobalNorm
+from paddle_tpu.profiler import span
 
 __all__ = ["TrainStep"]
 
@@ -213,14 +214,15 @@ class TrainStep:
                         optimizer._decay_enabled(self._params[i])
                     optimizer._current_mask = \
                         optimizer._param_masks.get(id(self._params[i]))
-                    np_, ns = optimizer._rule_mp(param_datas[i], g,
-                                                 slot_list[i], lr, step)
+                    with jax.named_scope("optimizer"):
+                        np_, ns = optimizer._rule_mp(
+                            param_datas[i], g, slot_list[i], lr, step)
+                        if skip is not None:
+                            np_ = jnp.where(skip, param_datas[i], np_)
+                            ns = {k: jnp.where(skip, slot_list[i][k], v)
+                                  for k, v in ns.items()}
                     optimizer._current_decay_enabled = True
                     optimizer._current_mask = None
-                    if skip is not None:
-                        np_ = jnp.where(skip, param_datas[i], np_)
-                        ns = {k: jnp.where(skip, slot_list[i][k], v)
-                              for k, v in ns.items()}
                     new_params[i] = np_
                     new_slots[i] = ns
                 # a skipped/invalid run must leave carried state
@@ -401,28 +403,31 @@ class TrainStep:
     def __call__(self, *batch, n_model_inputs: Optional[int] = None):
         """batch = (model_inputs..., labels...). By default the model takes
         one input and the rest are labels."""
-        n_inputs = 1 if n_model_inputs is None else n_model_inputs
-        datas = tuple(
-            self._commit(b._data if isinstance(b, Tensor)
-                         else jnp.asarray(b)) for b in batch)
-        self._sync_step_carry()
-        self._opt._step_count += 1  # host mirror (schedulers, state_dict)
-        self._host_step_mirror = self._opt._step_count
-        lr_val = float(self._opt.get_lr())
-        if self._lr_arr is None or lr_val != self._lr_val:
-            self._lr_val = lr_val
-            self._lr_arr = jax.device_put(np.float32(lr_val))
+        # the host's time in this call; the dispatch is asynchronous, so
+        # this is not the device step
+        with span("train.step", step=self._opt._step_count):
+            n_inputs = 1 if n_model_inputs is None else n_model_inputs
+            datas = tuple(
+                self._commit(b._data if isinstance(b, Tensor)
+                             else jnp.asarray(b)) for b in batch)
+            self._sync_step_carry()
+            self._opt._step_count += 1  # host mirror (schedulers, state_dict)
+            self._host_step_mirror = self._opt._step_count
+            lr_val = float(self._opt.get_lr())
+            if self._lr_arr is None or lr_val != self._lr_val:
+                self._lr_val = lr_val
+                self._lr_arr = jax.device_put(np.float32(lr_val))
 
-        if self._sot_cache is None:
-            try:
-                return self._run(self._jitted, n_inputs, datas)
-            except jax.errors.ConcretizationTypeError:
-                # data-dependent Python control flow: switch this step to
-                # SOT guard-path specialization (jit/sot.py)
-                from paddle_tpu.jit.sot import PathCache
+            if self._sot_cache is None:
+                try:
+                    return self._run(self._jitted, n_inputs, datas)
+                except jax.errors.ConcretizationTypeError:
+                    # data-dependent Python control flow: switch this step to
+                    # SOT guard-path specialization (jit/sot.py)
+                    from paddle_tpu.jit.sot import PathCache
 
-                self._sot_cache = PathCache()
-        return self._sot_call(n_inputs, datas)
+                    self._sot_cache = PathCache()
+            return self._sot_call(n_inputs, datas)
 
     def run_steps(self, k, *batch, n_model_inputs: Optional[int] = None,
                   stacked: bool = False):
@@ -538,11 +543,12 @@ class TrainStep:
         wd_id = arm_step(f"TrainStep#{self._opt._step_count}",
                          cold=not warm)
         try:
-            loss, self._carry, new_params, new_slots, new_buffers, \
-                new_scaler_state, valid = jitted(
-                    n_inputs, self._carry, param_datas, self._slots,
-                    buffer_datas, self._lr_arr, self._scaler_state,
-                    *datas)
+            with span("train.dispatch", cold=int(not warm)):
+                loss, self._carry, new_params, new_slots, new_buffers, \
+                    new_scaler_state, valid = jitted(
+                        n_inputs, self._carry, param_datas, self._slots,
+                        buffer_datas, self._lr_arr, self._scaler_state,
+                        *datas)
         except BaseException:
             # failed dispatch must not leave an armed deadline behind
             default_watchdog().disarm(wd_id)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu import ops
@@ -441,8 +442,10 @@ class LlamaModel(nn.Layer):
                 cu, ctx, num_seqs)
             new_k.append(kc._data if isinstance(kc, Tensor) else kc)
             new_v.append(vc._data if isinstance(vc, Tensor) else vc)
-        return (self.norm(x), jnp.stack(new_k, axis=0),
-                jnp.stack(new_v, axis=0))
+        with jax.named_scope("kv_update"):    # the restack (ROADMAP S10)
+            new_kcs = jnp.stack(new_k, axis=0)
+            new_vcs = jnp.stack(new_v, axis=0)
+        return self.norm(x), new_kcs, new_vcs
 
 
 class LlamaPretrainingCriterion(nn.Layer):
@@ -453,8 +456,9 @@ class LlamaPretrainingCriterion(nn.Layer):
         self.ce = ParallelCrossEntropy(ignore_index=-100)
 
     def forward(self, logits, labels):
-        loss = self.ce(logits, labels)
-        return ops.mean(loss)
+        with jax.named_scope("lm_head_loss"):
+            loss = self.ce(logits, labels)
+            return ops.mean(loss)
 
 
 class LlamaForCausalLM(nn.Layer):
@@ -470,7 +474,8 @@ class LlamaForCausalLM(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None):
         h = self.llama(input_ids, attn_mask)
-        return self.lm_head(h)
+        with jax.named_scope("lm_head_loss"):
+            return self.lm_head(h)
 
     @staticmethod
     def criterion(config=None):

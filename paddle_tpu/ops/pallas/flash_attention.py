@@ -367,9 +367,10 @@ def flash_attention_data(q, k, v, causal=False, scale=None,
             xs[0] * xs[2], xs[1], xs[3])
 
     def local(q, k, v):             # shapes are the shard's own
-        o = fa(to_bh(q), to_bh(k), to_bh(v))
-        return jnp.transpose(
-            o.reshape(q.shape[0], q.shape[2], s, d), (0, 2, 1, 3))
+        with jax.named_scope("attention"):
+            o = fa(to_bh(q), to_bh(k), to_bh(v))
+            return jnp.transpose(
+                o.reshape(q.shape[0], q.shape[2], s, d), (0, 2, 1, 3))
 
     decl = declared()
     if decl is not None and (decl[1] is not None or decl[2] is not None):

@@ -328,14 +328,17 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
 
     def local(q, k_new, v_new, key_cache, value_cache, bt, cu, ctx, ns):
         seg, pos, valid = _token_layout(t_total, s_slots, cu, ctx, ns)
-        kc = _write_kv(key_cache, k_new, bt, seg, pos)
-        vc = _write_kv(value_cache, v_new, bt, seg, pos)
-        if impl == "ref":
-            out = _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid,
-                                     scale)
-        else:
-            out = _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, ns, scale,
-                                        interpret=(impl == "interpret"))
+        with jax.named_scope("kv_update"):      # the cache scatter
+            kc = _write_kv(key_cache, k_new, bt, seg, pos)
+            vc = _write_kv(value_cache, v_new, bt, seg, pos)
+        with jax.named_scope("attention"):
+            if impl == "ref":
+                out = _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos,
+                                         valid, scale)
+            else:
+                out = _ragged_attend_pallas(
+                    q, kc, vc, bt, cu, ctx, ns, scale,
+                    interpret=(impl == "interpret"))
         return out, kc, vc
 
     decl = declared()

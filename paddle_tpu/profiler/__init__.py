@@ -5,17 +5,23 @@ export_chrome_tracing :215), host tracer
 paddle/fluid/platform/profiler/host_tracer.cc, chrome writer
 profiler/chrometracing_logger.cc, timer profiler/timer.py.
 
-TPU mapping: the host side is a RecordEvent scope recorder threaded
-through op dispatch (ops/registry.py profiler hook) and user code; the
-device side delegates to ``jax.profiler`` trace capture (xplane), the
-TPU's native tracer. ``Profiler.summary()`` aggregates host scopes;
-``benchmark()`` is the hapi throughput timer; ``estimate_mfu`` turns
-step flops + step time into the north-star MFU number (BASELINE gate #4).
+TPU mapping: the host side is ``RecordEvent``, the program's one span:
+always a ``ptpu:`` TraceAnnotation in the profiler's own trace (one clock
+with the device's ops) and, while a Profiler records, kept in memory with
+its parent and attributes. It is opened by op dispatch (ops/registry.py
+profiler hook), the serving engine and router, the train step, the
+prefetcher and user code; the device side delegates to ``jax.profiler``
+trace capture (xplane), the TPU's native tracer. ``Profiler.summary()``
+aggregates host scopes; ``benchmark()`` is the hapi throughput timer;
+``estimate_mfu`` turns step flops + step time into the north-star MFU
+number (BASELINE gate #4).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -23,10 +29,10 @@ from typing import Callable, Dict, List, Optional
 from paddle_tpu.profiler.timer import Benchmark, benchmark  # noqa: F401
 
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
-           "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "benchmark", "estimate_mfu", "device_phases",
-           "register_counter_provider", "unregister_counter_provider",
-           "counters"]
+           "span", "make_scheduler", "export_chrome_tracing",
+           "load_profiler_result", "benchmark", "estimate_mfu",
+           "device_phases", "register_counter_provider",
+           "unregister_counter_provider", "counters"]
 
 
 class ProfilerState:
@@ -51,6 +57,11 @@ class _HostEventRecorder:
         self.events: List[dict] = []
         self.active = False
         self._lock = threading.Lock()
+        self._ids = itertools.count(1)
+        # per-thread stack of the ids of the spans open on that thread
+        # (the prefetcher's producer has its own): a span's parent is
+        # the top of its own thread's stack
+        self._open = threading.local()
 
     def start(self):
         self.events = []
@@ -59,55 +70,112 @@ class _HostEventRecorder:
     def stop(self):
         self.active = False
 
-    def add(self, name, ts_us, dur_us):
+    def push(self):
+        """Open a span on this thread: returns ``(id, parent id)``."""
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        sid = next(self._ids)
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        return sid, parent
+
+    def pop(self, sid):
+        stack = getattr(self._open, "stack", ())
+        if sid in stack:    # begin()/end() pairs need not nest
+            stack.remove(sid)
+
+    def add(self, name, ts_us, dur_us, sid, parent, args):
         if not self.active:
             return
+        event = {"name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
+                 "pid": os.getpid(), "tid": threading.get_ident() % 100000,
+                 "id": sid, "parent": parent, "args": args}
         with self._lock:
-            self.events.append({
-                "name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
-                "pid": os.getpid(), "tid": threading.get_ident() % 100000,
-            })
+            self.events.append(event)
 
 
 _recorder = _HostEventRecorder()
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _bind_annotation():
+    global _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+
+    _TraceAnnotation = TraceAnnotation
+    return TraceAnnotation
 
 
 class RecordEvent:
-    """User-facing host scope (reference profiler/event_tracing.h
-    RecordEvent). Usable as context manager or decorator; records only
-    while a Profiler is in a RECORD state."""
+    """The program's one span (reference profiler/event_tracing.h
+    RecordEvent). ``RecordEvent(name, **attrs)``, as a context manager or
+    a decorator; ``profiler.span`` is the same class.
 
-    def __init__(self, name: str, event_type=None):
+    Entering always opens ``jax.profiler.TraceAnnotation("ptpu:" + name,
+    **attrs)``: with no profiler session that is a sub-microsecond no-op
+    (the attributes are not formatted); with one (``jax.profiler`` or a
+    :class:`Profiler` with the TPU target) the span lands in the same
+    ``.xplane.pb`` as the device's ``XLA Ops``, on the same clock, and
+    the attributes come back as the event's typed stats. While a
+    :class:`Profiler` records, the span is also kept in memory with its
+    ``id``, its ``parent`` (the span open around it on the same thread)
+    and its ``args``, for the chrome export and ``summary()``.
+
+    Attributes are plain ints, floats and short strings the caller
+    already holds; nothing is fetched from a device to fill one."""
+
+    __slots__ = ("name", "attrs", "_ann", "_t0", "_id", "_parent")
+
+    def __init__(self, name: str, event_type=None, **attrs):
         self.name = name
-        self._t0 = None
-
-    def begin(self):
-        self._t0 = time.perf_counter_ns()
-
-    def end(self):
-        if self._t0 is None:
-            return
-        t1 = time.perf_counter_ns()
-        _recorder.add(self.name, self._t0 / 1e3, (t1 - self._t0) / 1e3)
-        self._t0 = None
+        self.attrs = attrs
+        self._ann = self._t0 = None
 
     def __enter__(self):
-        self.begin()
+        ann = self._ann = (_TraceAnnotation or _bind_annotation())(
+            "ptpu:" + self.name, **self.attrs)
+        ann.__enter__()
+        if _recorder.active:
+            self._id, self._parent = _recorder.push()
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self.end()
+        if self._t0 is not None:
+            t1 = time.perf_counter_ns()
+            _recorder.pop(self._id)
+            _recorder.add(self.name, self._t0 / 1e3, (t1 - self._t0) / 1e3,
+                          self._id, self._parent, self.attrs)
+            self._t0 = None
+        self._ann.__exit__(None, None, None)
         return False
+
+    def set(self, **attrs):
+        """Attributes known only once the span is open (what a step
+        emitted): joined to the open span's."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    begin = __enter__
+
+    def end(self):
+        if self._ann is not None:
+            self.__exit__()
+            self._ann = None
 
     def __call__(self, fn):
         import functools
 
         @functools.wraps(fn)
         def wrapped(*a, **k):
-            with RecordEvent(self.name):
+            with RecordEvent(self.name, **self.attrs):
                 return fn(*a, **k)
 
         return wrapped
+
+
+span = RecordEvent
 
 
 # ---------------------------------------------------------------------------
@@ -213,10 +281,15 @@ class Profiler:
             try:
                 import jax
 
-                import time as _time
-
-                self._trace_token = _time.time()
-                jax.profiler.start_trace(self._trace_dir)
+                # the Python tracer floods the trace and slows the host
+                # it measures; host annotations (the ``ptpu:`` spans)
+                # stay on
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 2
+                self._trace_token = time.time()
+                jax.profiler.start_trace(self._trace_dir,
+                                         profiler_options=opts)
                 self._device_tracing = True
             except Exception:
                 self._device_tracing = False
@@ -279,26 +352,36 @@ class Profiler:
 
     def summary(self, sorted_by="total", print_table: bool = True,
                 pipeline_step=None):
-        """Aggregate host events by name -> calls/total/avg/max ms; when
-        a device trace was captured, append the per-phase breakdown
+        """Aggregate host events by name -> calls/total/self/avg/max ms
+        (self = a span's duration minus the part its child spans cover);
+        when a device trace was captured, append the per-phase breakdown
         (phase_summary); when a PipelineTrainStep is passed, report its
         schedule + bubble fraction (reference profiler_statistic.py
         step-category report, VERDICT r4 #9)."""
+        covered: Dict[int, float] = {}      # span id -> its children's us
+        for e in self.host_events:
+            if e.get("parent") is not None:
+                covered[e["parent"]] = covered.get(e["parent"], 0.0) \
+                    + e["dur"]
         agg: Dict[str, List[float]] = {}
+        self_ms: Dict[str, float] = {}
         for e in self.host_events:
             agg.setdefault(e["name"], []).append(e["dur"] / 1e3)  # ms
-        rows = [(k, len(v), sum(v), sum(v) / len(v), max(v))
+            self_ms[e["name"]] = self_ms.get(e["name"], 0.0) + (
+                e["dur"] - covered.get(e.get("id"), 0.0)) / 1e3
+        rows = [(k, len(v), sum(v), sum(v) / len(v), max(v), self_ms[k])
                 for k, v in agg.items()]
         rows.sort(key=lambda r: -r[2])
         if print_table:
             hdr = (f"{'Event':<44}{'Calls':>8}{'Total(ms)':>12}"
-                   f"{'Avg(ms)':>10}{'Max(ms)':>10}")
+                   f"{'Self(ms)':>12}{'Avg(ms)':>10}{'Max(ms)':>10}")
             print(hdr)
             print("-" * len(hdr))
-            for nm, c, tot, avg, mx in rows[:40]:
-                print(f"{nm:<44}{c:>8}{tot:>12.3f}{avg:>10.3f}{mx:>10.3f}")
+            for nm, c, tot, avg, mx, own in rows[:40]:
+                print(f"{nm:<44}{c:>8}{tot:>12.3f}{own:>12.3f}"
+                      f"{avg:>10.3f}{mx:>10.3f}")
         out = {r[0]: {"calls": r[1], "total_ms": r[2], "avg_ms": r[3],
-                      "max_ms": r[4]} for r in rows}
+                      "max_ms": r[4], "self_ms": r[5]} for r in rows}
         try:
             phases = self.phase_summary(print_table=print_table)
         except Exception:
@@ -367,11 +450,23 @@ class Profiler:
 
     @classmethod
     def classify_phase(cls, op_name: str) -> str:
-        """XLA op name -> phase bucket (compute | collective | copy)."""
-        nm = op_name.lower()
-        if any(t in nm for t in cls._PHASE_COLLECTIVE):
+        """XLA op name -> phase bucket (compute | collective | copy), by
+        the op's FAMILY: the instruction's own name without its number
+        (``copy.4``, ``copy-start.1``, ``all-gather-done``). The chip
+        names an op by its whole instruction (``%fusion.6 = f32[..]
+        fusion(.. %copy.3), kind=kLoop``), so a substring rule files
+        every fusion that reads a copy under copies (BENCH_r05's
+        ``copy_frac`` 0.545)."""
+        family = re.sub(r"[.\d]+$", "",
+                        op_name.split(" = ")[0].strip().lstrip("%")).lower()
+
+        def among(tokens):
+            return any(family == t or family.startswith(t + "-")
+                       for t in tokens)
+
+        if among(cls._PHASE_COLLECTIVE):
             return "collective"
-        if any(t in nm for t in cls._PHASE_COPY):
+        if among(cls._PHASE_COPY):
             return "copy"
         return "compute"
 

@@ -83,6 +83,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from paddle_tpu.profiler import span
 from paddle_tpu.serving.block_manager import (
     BlockManager, NoFreeBlocksError, cdiv,
 )
@@ -706,12 +707,14 @@ class LLMEngine:
             # argmax, keeping the greedy path token-identical to
             # np.argmax (pinned by tests/test_serving_engine.py); the
             # per-slot finite bit is the nonfinite guard's observable.
-            finite = jnp.isfinite(lg3).all(axis=-1).all(axis=-1)
-            toks, n_emit, nkeys = sample_or_verify(
-                lg3, sdraft, sndraft, skeys, stemp, stopk, stopp)
-            packed = jnp.concatenate([
-                toks, n_emit[:, None],
-                jax.lax.bitcast_convert_type(nkeys, jnp.int32)], axis=1)
+            with jax.named_scope("sampler"):
+                finite = jnp.isfinite(lg3).all(axis=-1).all(axis=-1)
+                toks, n_emit, nkeys = sample_or_verify(
+                    lg3, sdraft, sndraft, skeys, stemp, stopk, stopp)
+                packed = jnp.concatenate([
+                    toks, n_emit[:, None],
+                    jax.lax.bitcast_convert_type(nkeys, jnp.int32)],
+                    axis=1)
             return packed, finite
 
         def raw_step(param_datas, buffer_datas, key, ids, kcs, vcs, bt,
@@ -933,6 +936,7 @@ class LLMEngine:
                       sampling=sampling, callback=callback)
         self._apply_rng_state(req, rng_state)
         self._requests[request_id] = req
+        self._note_arrival(req)
         # admission control: a draining engine admits nothing; a live
         # one consults the controller. Rejection is a first-class
         # structured output (finish_reason='rejected'), NOT an
@@ -968,7 +972,7 @@ class LLMEngine:
     def abort_request(self, request_id: str) -> bool:
         found = self.scheduler.abort(request_id, "aborted:user")
         if found:
-            self._count_finish("aborted:user")
+            self._note_finish(self._requests[request_id])
         return found
 
     # -- TP layout surface ------------------------------------------------
@@ -1158,6 +1162,7 @@ class LLMEngine:
             self.num_kv_reshards += 1
         req.num_cached = covered
         self._requests[request_id] = req
+        self._note_arrival(req)
         self.scheduler.add_continuation(req)
         if self.cfg.prefix_cache:
             # shipped prompt blocks are fully written now — register
@@ -1351,6 +1356,7 @@ class LLMEngine:
                       sampling=sampling, callback=callback)
         self._apply_rng_state(req, rng_state)
         self._requests[request_id] = req
+        self._note_arrival(req)
         if hit > 0:
             req.num_cached = hit
             self.scheduler.add_continuation(req)
@@ -1396,6 +1402,34 @@ class LLMEngine:
         if reason is not None:
             self.finish_counts[reason] = \
                 self.finish_counts.get(reason, 0) + 1
+
+    # -- a request's four marks in the trace (instant spans sharing its
+    # id: arrive, scheduled, first_token, finish) ------------------------
+    def _note_arrival(self, req: Request):
+        req.arrival_step = self.metrics.engine_steps
+        with span("request.arrive", request_id=req.request_id,
+                  prompt_tokens=len(req.prompt_ids)):
+            pass
+
+    def _note_first_scheduled(self, reqs):
+        for r in reqs:
+            if r.first_scheduled_time is not None:
+                continue
+            r.first_scheduled_time = time.monotonic()
+            waited = r.first_scheduled_time - r.arrival_time
+            self.metrics.queue_waits_s.append(waited)
+            with span("request.scheduled", request_id=r.request_id,
+                      waited_ms=round(waited * 1e3, 3),
+                      waited_steps=max(
+                          self.metrics.engine_steps - r.arrival_step, 0)):
+                pass
+
+    def _note_finish(self, req: Request):
+        self._count_finish(req.finish_reason)
+        with span("request.finish", request_id=req.request_id,
+                  reason=str(req.finish_reason),
+                  generated=req.num_generated):
+            pass
 
     # -- graceful drain --------------------------------------------------
     def install_preemption_handler(self, monitor=None):
@@ -1484,7 +1518,7 @@ class LLMEngine:
     def _terminal_output(self, req: Request) -> RequestOutput:
         """Structured tokenless emission for an aborted/expired/rejected
         request; streams through its callback like a sampled token."""
-        self._count_finish(req.finish_reason)
+        self._note_finish(req)
         out = RequestOutput(request_id=req.request_id, token=None,
                             finished=True, generated=list(req.generated),
                             finish_reason=req.finish_reason)
@@ -1553,41 +1587,166 @@ class LLMEngine:
         sampled tokens plus any structured terminal emissions (expired,
         rejected, drain-aborted, poisoned) produced at this iteration
         boundary."""
-        outputs: List[RequestOutput] = self._flush_pending()
+        with span("engine.step", step=self.metrics.engine_steps):
+            outputs: List[RequestOutput] = self._flush_pending()
 
-        # preemption notice (SIGTERM / programmatic) -> drain
-        if self._preempt is not None and not self._draining \
-                and self._preempt.requested():
-            outputs.extend(self.start_drain("preemption"))
-        if self._draining:
-            if not self.scheduler.has_unfinished():
-                self._finish_drain()
+            # preemption notice (SIGTERM / programmatic) -> drain
+            if self._preempt is not None and not self._draining \
+                    and self._preempt.requested():
+                outputs.extend(self.start_drain("preemption"))
+            if self._draining:
+                if not self.scheduler.has_unfinished():
+                    self._finish_drain()
+                    return outputs
+                if time.monotonic() > self._drain_deadline:
+                    # grace budget spent: the stragglers abort, structured
+                    outputs.extend(self._abort_running("aborted:drain"))
+                    self._finish_drain()
+                    return outputs
+
+            if self._spec is not None:
+                self._propose_drafts()
+            if self._kvtier is not None:
+                # pressure-driven rebalancing BEFORE scheduling, so the
+                # scheduler sees the post-demotion free list
+                self._kvtier.balance()
+            t0 = time.perf_counter()
+            with span("engine.schedule"):
+                batch = self.scheduler.schedule()
+                outputs.extend(self._terminal_output(r) for r in batch.expired)
+                self.num_expired += len(batch.expired)
+                self._note_first_scheduled(batch.requests)
+            if batch.is_empty:
+                if self.scheduler.has_unfinished() and not (
+                        batch.preempted or batch.swapped_in
+                        or self.scheduler.num_swapped):
+                    raise RuntimeError(
+                        "scheduler produced an empty batch with unfinished "
+                        "requests — KV cache too small for any waiting "
+                        "request (admission validation should prevent this)")
                 return outputs
-            if time.monotonic() > self._drain_deadline:
-                # grace budget spent: the stragglers abort, structured
-                outputs.extend(self._abort_running("aborted:drain"))
-                self._finish_drain()
+            with span("engine.fill"):
+                (reqs, n_run, arrays, B, S, R, sampling_arrays, padded,
+                 prompt_toks, composition) = self._fill(batch)
+            try:
+                out_np, finite_np = self._dispatch(
+                    reqs, batch.kind, arrays, B, S, sampling_arrays,
+                    composition)
+            except EngineStepError as e:
+                # this step's already-produced structured outputs (flushed
+                # rejections, expiries) must not vanish with the failure —
+                # they ride the exception ahead of the abort sweep
+                e.outputs = outputs + e.outputs
+                raise
+
+            with span("engine.post") as post_span:
+                # non-finite-logits guard: abort ONLY the poisoned row(s); the
+                # rest of the batch continues untouched (their KV blocks and
+                # logits are independent of the poisoned row)
+                poisoned = self._poisoned_rows(reqs, finite_np)
+                n_before = len(outputs)
+                self.metrics.record_step(
+                    batch.kind, len(reqs), composition["q_tokens"],
+                    self.cfg.max_num_seqs, time.perf_counter() - t0,
+                    padded_tokens=padded, prompt_tokens=prompt_toks,
+                    decode_rows=composition["decode_rows"])
+                # unpack the step's single host fetch: per row [tokens(R),
+                # n_emit, key_hi, key_lo]
+                tokens_mat = out_np[:, :R]
+                n_emit_np = out_np[:, R]
+                keys_np = np.ascontiguousarray(
+                    out_np[:, R + 1:]).view(np.uint32)
+                for i, r in enumerate(reqs):
+                    if i in poisoned:
+                        self.scheduler.abort(r.request_id, "aborted:nonfinite")
+                        self.num_poisoned_aborts += 1
+                        outputs.append(self._terminal_output(r))
+                        continue
+                    d = len(r.draft_tokens)
+                    r.draft_tokens = []
+                    # committed cache coverage: drafts are NOT tokens until
+                    # accepted below
+                    r.num_cached += n_run[i] - d
+                    if self.cfg.prefix_cache:
+                        # register fully-written prompt blocks AFTER the step
+                        # that wrote them (never discoverable before their K/V
+                        # bytes exist on device)
+                        self.block_manager.commit_prefix(
+                            r.request_id, r.prompt_ids, r.num_cached)
+                    if r.num_cached < len(r.tokens):
+                        continue  # mid-prefill chunk: its row logit is a
+                        # prompt position — never sampled, no output this step
+                    pre_len = len(r.tokens)
+                    emit = [int(t) for t in tokens_mat[i, :int(n_emit_np[i])]]
+                    accepted = max(int(n_emit_np[i]) - 1, 0)
+                    if d:
+                        self.num_spec_proposed += d
+                        self.num_spec_accepted += accepted
+                    finished = False
+                    appended = 0
+                    for token in emit:
+                        first = r.first_token_time is None
+                        finished = r.append_token(token)
+                        if first:
+                            with span("request.first_token",
+                                      request_id=r.request_id,
+                                      ttft_ms=round((r.first_token_time
+                                                     - r.arrival_time) * 1e3,
+                                                    3)):
+                                pass
+                        self.metrics.record_token()
+                        appended += 1
+                        out = RequestOutput(
+                            request_id=r.request_id, token=token,
+                            finished=finished, generated=list(r.generated),
+                            finish_reason=r.finish_reason)
+                        outputs.append(out)
+                        if r.callback is not None:
+                            r.callback(r.request_id, token, finished)
+                        if finished:
+                            break  # EOS inside an accepted draft prefix: the
+                            # tokens behind it are never emitted
+                    # the accepted prefix's K/V (written this step at draft
+                    # positions) is valid and stays committed; the corrected/
+                    # bonus token recomputes next step
+                    r.num_cached = pre_len + min(appended, accepted)
+                    # the in-graph sampler advanced this row's stream by a
+                    # fixed split count; persist it only for emitting rows, so
+                    # a request's key position is a pure function of its
+                    # emitted-step count (chunking- and hand-off-invariant)
+                    r.device_key = keys_np[i].copy()
+                    if finished:
+                        if self._kvtier is not None:
+                            # session capture BEFORE the table frees: the full
+                            # chain commits to the trie and the partial tail's
+                            # bytes stash host-side, so a multi-turn follow-up
+                            # resumes with zero prompt recompute
+                            self._kvtier.on_finish(r)
+                        self.scheduler.finish(r)
+                        self.metrics.record_finish(r)
+                        self._note_finish(r)
+                    elif d:
+                        # speculative rollback: free the slots claimed for
+                        # rejected (or post-EOS) draft tokens
+                        self.block_manager.trim(r.request_id, len(r.tokens))
+                post_span.set(
+                    emitted=sum(1 for o in outputs[n_before:]
+                                if o.token is not None),
+                    finished=sum(1 for o in outputs[n_before:] if o.finished))
+                if self._draining and not self.scheduler.has_unfinished():
+                    self._finish_drain()  # this step emptied the engine
                 return outputs
 
-        if self._spec is not None:
-            self._propose_drafts()
-        if self._kvtier is not None:
-            # pressure-driven rebalancing BEFORE scheduling, so the
-            # scheduler sees the post-demotion free list
-            self._kvtier.balance()
-        t0 = time.perf_counter()
-        batch = self.scheduler.schedule()
-        outputs.extend(self._terminal_output(r) for r in batch.expired)
-        self.num_expired += len(batch.expired)
-        if batch.is_empty:
-            if self.scheduler.has_unfinished() and not (
-                    batch.preempted or batch.swapped_in
-                    or self.scheduler.num_swapped):
-                raise RuntimeError(
-                    "scheduler produced an empty batch with unfinished "
-                    "requests — KV cache too small for any waiting "
-                    "request (admission validation should prevent this)")
-            return outputs
+    def _fill(self, batch: ScheduledBatch):
+        """The step's host inputs from a scheduled batch: the packed (or
+        bucketed) arrays, the per-slot sampling rows, pending tier moves
+        and copy-on-write copies applied, and the batch's composition
+        counted once. A method of its own so that its ~25 locals are off
+        the stack before the dispatch: the first dispatch traces the whole
+        step, and CPython's frame stack grows in 16 KiB chunks; a deeper
+        stack above the jit call moves JAX's hot tracing calls across a
+        chunk boundary (an mmap/munmap each), which cost the serve cell
+        6-10 s of set-up on the v5e host (PERF.md section 6, PR 27)."""
         reqs = batch.requests
         n_run = (list(batch.num_scheduled) if batch.num_scheduled
                  else [len(r.tokens_to_run()) for r in reqs])
@@ -1617,6 +1776,19 @@ class LLMEngine:
             cu[len(reqs) + 1:] = off
             arrays = (ids, bt, cu, ctx, np.int32(len(reqs)))
             padded = 0
+            # the mixed batch's split: prompt tokens prefilled this
+            # step vs decode rows (feeds occupancy + prompt
+            # throughput the same way the classic prefill/decode
+            # kinds did; a verify row costs 1 + its draft count but
+            # is still one decode row)
+            prompt_toks = sum(
+                min(n, max(len(r.prompt_ids) - r.num_cached, 0))
+                for r, n in zip(reqs, n_run))
+            decode_rows = sum(
+                1 for r, n in zip(reqs, n_run)
+                if n - len(r.draft_tokens) == 1
+                and r.num_generated > 0)
+            ctx_tokens = int(ctx.sum())
         else:
             is_prefill = batch.kind == "prefill"
             S = self._seq_bucket(max(n_run)) if is_prefill else 1
@@ -1638,10 +1810,14 @@ class LLMEngine:
                 bt[i, :len(table)] = table
             arrays = (ids, bt, enc, dec, now)
             padded = B * S - int(sum(n_run))
+            # record_step infers the classic kinds' split from the kind
+            prompt_toks = None
+            decode_rows = 0 if is_prefill else len(reqs)
+            ctx_tokens = int(dec.sum() + now.sum())
 
-        # pending tier moves land FIRST (a COW source may be a block a
-        # promote just filled), then copy-on-write block copies — both
-        # before the step writes the destination blocks
+        # pending tier moves land FIRST (a COW source may be a block
+        # a promote just filled), then copy-on-write block copies —
+        # both before the step writes the destination blocks
         if self._kvtier is not None:
             self._kvtier.apply_moves()
         self._apply_cow()
@@ -1673,116 +1849,15 @@ class LLMEngine:
             sampling_arrays = (skeys, stemp, stopk, stopp)
         if any(r.sampling.temperature > 0.0 for r in reqs):
             self.num_sampled_steps += 1
-        try:
-            out_np, finite_np = self._dispatch(
-                reqs, batch.kind, arrays, B, S, sampling_arrays)
-        except EngineStepError as e:
-            # this step's already-produced structured outputs (flushed
-            # rejections, expiries) must not vanish with the failure —
-            # they ride the exception ahead of the abort sweep
-            e.outputs = outputs + e.outputs
-            raise
-
-        # non-finite-logits guard: abort ONLY the poisoned row(s); the
-        # rest of the batch continues untouched (their KV blocks and
-        # logits are independent of the poisoned row)
-        poisoned = self._poisoned_rows(reqs, finite_np)
-
-        if self._ragged:
-            # the mixed batch's split: prompt tokens prefilled this step
-            # vs decode rows (feeds occupancy + prompt throughput the
-            # same way the classic prefill/decode kinds did; a verify
-            # row costs 1 + its draft count but is still one decode row)
-            prompt_toks = sum(
-                min(n, max(len(r.prompt_ids) - r.num_cached, 0))
-                for r, n in zip(reqs, n_run))
-            decode_rows = sum(
-                1 for r, n in zip(reqs, n_run)
-                if n - len(r.draft_tokens) == 1 and r.num_generated > 0)
-            self.metrics.record_step(
-                batch.kind, len(reqs), int(sum(n_run)),
-                self.cfg.max_num_seqs, time.perf_counter() - t0,
-                padded_tokens=0, prompt_tokens=prompt_toks,
-                decode_rows=decode_rows)
-        else:
-            self.metrics.record_step(batch.kind, len(reqs),
-                                     int(sum(n_run)),
-                                     self.cfg.max_num_seqs,
-                                     time.perf_counter() - t0,
-                                     padded_tokens=padded)
-        # unpack the step's single host fetch: per row [tokens(R),
-        # n_emit, key_hi, key_lo]
-        tokens_mat = out_np[:, :R]
-        n_emit_np = out_np[:, R]
-        keys_np = np.ascontiguousarray(out_np[:, R + 1:]).view(np.uint32)
-        for i, r in enumerate(reqs):
-            if i in poisoned:
-                self.scheduler.abort(r.request_id, "aborted:nonfinite")
-                self.num_poisoned_aborts += 1
-                outputs.append(self._terminal_output(r))
-                continue
-            d = len(r.draft_tokens)
-            r.draft_tokens = []
-            # committed cache coverage: drafts are NOT tokens until
-            # accepted below
-            r.num_cached += n_run[i] - d
-            if self.cfg.prefix_cache:
-                # register fully-written prompt blocks AFTER the step
-                # that wrote them (never discoverable before their K/V
-                # bytes exist on device)
-                self.block_manager.commit_prefix(
-                    r.request_id, r.prompt_ids, r.num_cached)
-            if r.num_cached < len(r.tokens):
-                continue  # mid-prefill chunk: its row logit is a prompt
-                # position — never sampled, no output this step
-            pre_len = len(r.tokens)
-            emit = [int(t) for t in tokens_mat[i, :int(n_emit_np[i])]]
-            accepted = max(int(n_emit_np[i]) - 1, 0)
-            if d:
-                self.num_spec_proposed += d
-                self.num_spec_accepted += accepted
-            finished = False
-            appended = 0
-            for token in emit:
-                finished = r.append_token(token)
-                self.metrics.record_token()
-                appended += 1
-                out = RequestOutput(request_id=r.request_id, token=token,
-                                    finished=finished,
-                                    generated=list(r.generated),
-                                    finish_reason=r.finish_reason)
-                outputs.append(out)
-                if r.callback is not None:
-                    r.callback(r.request_id, token, finished)
-                if finished:
-                    break  # EOS inside an accepted draft prefix: the
-                    # tokens behind it are never emitted
-            # the accepted prefix's K/V (written this step at draft
-            # positions) is valid and stays committed; the corrected/
-            # bonus token recomputes next step
-            r.num_cached = pre_len + min(appended, accepted)
-            # the in-graph sampler advanced this row's stream by a
-            # fixed split count; persist it only for emitting rows, so
-            # a request's key position is a pure function of its
-            # emitted-step count (chunking- and hand-off-invariant)
-            r.device_key = keys_np[i].copy()
-            if finished:
-                if self._kvtier is not None:
-                    # session capture BEFORE the table frees: the full
-                    # chain commits to the trie and the partial tail's
-                    # bytes stash host-side, so a multi-turn follow-up
-                    # resumes with zero prompt recompute
-                    self._kvtier.on_finish(r)
-                self.scheduler.finish(r)
-                self.metrics.record_finish(r)
-                self._count_finish(r.finish_reason)
-            elif d:
-                # speculative rollback: free the slots claimed for
-                # rejected (or post-EOS) draft tokens
-                self.block_manager.trim(r.request_id, len(r.tokens))
-        if self._draining and not self.scheduler.has_unfinished():
-            self._finish_drain()  # this step emptied the engine
-        return outputs
+        # the step's composition, counted once: the dispatch span's
+        # attributes and record_step get the same values
+        composition = dict(
+            step=self.metrics.engine_steps, kind=batch.kind,
+            rows=len(reqs), q_tokens=int(sum(n_run)),
+            ctx_tokens=ctx_tokens, prefill_rows=len(reqs) - decode_rows,
+            decode_rows=decode_rows)
+        return (reqs, n_run, arrays, B, S, R, sampling_arrays, padded,
+                prompt_toks, composition)
 
     def _propose_drafts(self):
         """One draft-model pass proposing ``num_spec_tokens`` greedy
@@ -1833,7 +1908,8 @@ class LLMEngine:
             self._vcs = jax.device_put(self._vcs, self._cache_sharding)
 
     # -- the guarded compiled dispatch ----------------------------------
-    def _dispatch(self, reqs, kind, arrays, B, S, sampling_arrays):
+    def _dispatch(self, reqs, kind, arrays, B, S, sampling_arrays,
+                  composition):
         """Run the compiled step under the fault-isolation envelope:
         watchdog-armed dispatch (hung-step detection), bounded
         retry-with-backoff on transient failures, and the fetch of this
@@ -1847,7 +1923,9 @@ class LLMEngine:
         invalidated — the engine aborts EVERY live request with
         ``finish_reason='aborted:error'`` structured outputs and raises
         :class:`EngineStepError` carrying them (drain semantics: no
-        request just vanishes)."""
+        request just vanishes). ``composition`` is what the batch holds
+        (rows, query and context tokens, the prefill/decode split): the
+        attributes of each attempt's ``engine.dispatch`` span."""
         if self._ragged:
             ids, bt, cu, ctx, nseq = arrays
             tag = f"serving.ragged[T={B},S={S}]"
@@ -1872,34 +1950,37 @@ class LLMEngine:
                     eid = self._watchdog.arm(
                         tag, factor=COMPILE_ALLOWANCE if cold else 1.0)
                 faults.fire(faults.SERVING_STEP)  # slow/raise/sigterm point
-                if self._ragged and self._kvtier is not None:
-                    packed, finite, kcs, vcs = self._jstep_ragged(
-                        [p._data for p in self._params],
-                        [b._data for b in self._buffers],
-                        self._key, ids, self._kcs, self._vcs,
-                        self._htk, self._htv, bt, cu, ctx, nseq,
-                        *sampling_arrays)
-                elif self._ragged:
-                    packed, finite, kcs, vcs = self._jstep_ragged(
-                        [p._data for p in self._params],
-                        [b._data for b in self._buffers],
-                        self._key, ids, self._kcs, self._vcs, bt, cu,
-                        ctx, nseq, *sampling_arrays)
-                else:
-                    packed, finite, kcs, vcs = self._jstep(
-                        [p._data for p in self._params],
-                        [b._data for b in self._buffers],
-                        self._key, ids, self._kcs, self._vcs, bt, enc,
-                        dec, now, *sampling_arrays)
+                with span("engine.dispatch", cold=int(cold),
+                          attempt=attempt, **composition):
+                    if self._ragged and self._kvtier is not None:
+                        packed, finite, kcs, vcs = self._jstep_ragged(
+                            [p._data for p in self._params],
+                            [b._data for b in self._buffers],
+                            self._key, ids, self._kcs, self._vcs,
+                            self._htk, self._htv, bt, cu, ctx, nseq,
+                            *sampling_arrays)
+                    elif self._ragged:
+                        packed, finite, kcs, vcs = self._jstep_ragged(
+                            [p._data for p in self._params],
+                            [b._data for b in self._buffers],
+                            self._key, ids, self._kcs, self._vcs, bt, cu,
+                            ctx, nseq, *sampling_arrays)
+                    else:
+                        packed, finite, kcs, vcs = self._jstep(
+                            [p._data for p in self._params],
+                            [b._data for b in self._buffers],
+                            self._key, ids, self._kcs, self._vcs, bt, enc,
+                            dec, now, *sampling_arrays)
                 if self._watchdog is not None:
                     self._watchdog.attach(eid, (packed,))
                 # sampling (greedy AND temperature/top-k/top-p, plus
                 # speculative verify) ran in-graph — the step's whole
                 # host boundary is this one packed int32 row per slot
-                out_np = np.asarray(packed)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized int fetch IS the engine's host boundary — tokens, emit counts, and advanced RNG keys in one packed row)
-                finite_np = None
-                if self.cfg.nonfinite_guard:
-                    finite_np = np.asarray(finite)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized bool fetch: the nonfinite guard's observable)
+                with span("engine.fetch"):
+                    out_np = np.asarray(packed)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized int fetch IS the engine's host boundary — tokens, emit counts, and advanced RNG keys in one packed row)
+                    finite_np = None
+                    if self.cfg.nonfinite_guard:
+                        finite_np = np.asarray(finite)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized bool fetch: the nonfinite guard's observable)
             except Exception as e:
                 if self._watchdog is not None:
                     self._watchdog.disarm(eid)

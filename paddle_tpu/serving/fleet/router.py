@@ -114,6 +114,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from paddle_tpu.distributed.replica_registry import ReplicaRegistry
+from paddle_tpu.profiler import span
 from paddle_tpu.serving.block_manager import prefix_chain_hashes
 from paddle_tpu.serving.fleet.lease import LeaseStore, rendezvous_owner
 from paddle_tpu.serving.fleet.metrics import FleetMetrics
@@ -353,6 +354,7 @@ class FleetRouter:
         self.num_relay_bytes = 0
         self.num_ship_skipped_expired = 0
         self._ticket_seq = itertools.count()
+        self._steps = 0     # step() calls so far: the router.step span's index
         # fleet-global prefix cache: eventually-consistent adverts
         # (replica_id -> last heartbeat digest), per-prefix dispatch
         # hit counts, and the recent-ship cooldown table
@@ -655,54 +657,62 @@ class FleetRouter:
         """Pump faults, heartbeats, health, dispatch, then one engine
         iteration per live replica. Returns this step's client-visible
         outputs (hand-offs emit nothing — the request continues)."""
-        if self.lease_store is not None:
-            if not self.router_dead and faults.check(
-                    faults.FLEET_ROUTER_KILL, key=self.router_id):
-                # in-process SIGKILL: this router goes silent NOW — no
-                # farewell beat, no lease release, nothing emitted again
-                self.router_dead = True
-            if self.router_dead or self.partitioned:
-                # dead: silent forever. partitioned: FROZEN — no beats,
-                # no renewals, no dispatch; pending terminals wait for
-                # the heal (their positions are <= the lease's committed
-                # progress, so a late emission cannot duplicate)
-                return []
-            self._router_sync()
-        outputs, self._pending_outputs = self._pending_outputs, []
-        self._fire_fault_points(outputs)
-        self._heartbeat()
-        self._health_sweep(outputs)
-        self._dispatch_queue(outputs)
-        self._ship_hot_prefixes()
-        self._offload_pressured_sessions()
-        for h in list(self.replicas):
-            if not h.alive:
-                continue
-            if not self._steps_replica(h):
-                continue  # a peer router owns this engine
-            to_ship: List[str] = []
-            for out in h.step():
-                self._handle_output(h, out, outputs, to_ship)
-            # ship AFTER the whole output list folded into progress —
-            # shipping inside the loop would migrate a request while
-            # later outputs from the same step still reference it
-            for rid in to_ship:
-                fr = self._open.get(rid)
-                if (fr is not None and not fr.finished
-                        and fr.replica_id == h.replica_id):
-                    self._ship_from(h, fr)
-            if not h.alive and not h.retiring:
-                # the engine died mid-step (EngineStepError absorbed at
-                # the handle): outputs above carried its structured
-                # aborts; anything still assigned re-enqueues now.
-                # Retiring handles are exempt — a drained-out worker
-                # exits right after its last reply (retiring set from
-                # that reply) and is reaped, not counted dead; if one
-                # truly crashes mid-drain with work assigned, the next
-                # health sweep recovers it
-                self.kill_replica(h.replica_id, "step failure", outputs)
-        self._reap_retired()
-        return outputs
+        with span("router.step", step=self._steps):
+            self._steps += 1
+            if self.lease_store is not None:
+                if not self.router_dead and faults.check(
+                        faults.FLEET_ROUTER_KILL, key=self.router_id):
+                    # in-process SIGKILL: this router goes silent NOW — no
+                    # farewell beat, no lease release, nothing emitted again
+                    self.router_dead = True
+                if self.router_dead or self.partitioned:
+                    # dead: silent forever. partitioned: FROZEN — no beats,
+                    # no renewals, no dispatch; pending terminals wait for
+                    # the heal (their positions are <= the lease's committed
+                    # progress, so a late emission cannot duplicate)
+                    return []
+                self._router_sync()
+            outputs, self._pending_outputs = self._pending_outputs, []
+            with span("router.control"):
+                self._fire_fault_points(outputs)
+                self._heartbeat()
+                self._health_sweep(outputs)
+                self._dispatch_queue(outputs)
+                self._ship_hot_prefixes()
+                self._offload_pressured_sessions()
+            for h in list(self.replicas):
+                if not h.alive:
+                    continue
+                if not self._steps_replica(h):
+                    continue  # a peer router owns this engine
+                with span("replica.step", replica=h.replica_id):
+                    outs = h.step()
+                with span("router.collect", replica=h.replica_id,
+                          outputs=len(outs)):
+                    to_ship: List[str] = []
+                    for out in outs:
+                        self._handle_output(h, out, outputs, to_ship)
+                    # ship AFTER the whole output list folded into progress
+                    # — shipping inside the loop would migrate a request
+                    # while later outputs from the same step still
+                    # reference it
+                    for rid in to_ship:
+                        fr = self._open.get(rid)
+                        if (fr is not None and not fr.finished
+                                and fr.replica_id == h.replica_id):
+                            self._ship_from(h, fr)
+                if not h.alive and not h.retiring:
+                    # the engine died mid-step (EngineStepError absorbed at
+                    # the handle): outputs above carried its structured
+                    # aborts; anything still assigned re-enqueues now.
+                    # Retiring handles are exempt — a drained-out worker
+                    # exits right after its last reply (retiring set from
+                    # that reply) and is reaped, not counted dead; if one
+                    # truly crashes mid-drain with work assigned, the next
+                    # health sweep recovers it
+                    self.kill_replica(h.replica_id, "step failure", outputs)
+            self._reap_retired()
+            return outputs
 
     def run(self, max_steps: Optional[int] = None) -> List[RequestOutput]:
         outs: List[RequestOutput] = []

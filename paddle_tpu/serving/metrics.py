@@ -139,6 +139,9 @@ class ServingMetrics:
         self.num_slot_tokens = 0          # real + padded
         self.ttfts_s: List[float] = []
         self.tpots_s: List[float] = []
+        # arrival -> first scheduled, appended by the engine where the
+        # wait ends: the operator's view of queueing
+        self.queue_waits_s: List[float] = []
         # batch occupancy: scheduled seqs / max_num_seqs per decode step
         self._occupancy_sum = 0.0
         self._occupancy_n = 0
@@ -249,6 +252,8 @@ class ServingMetrics:
             "ttft_ms_avg": round(_mean(self.ttfts_s) * 1e3, 3),
             "ttft_ms_p90": round(
                 _percentile(self.ttfts_s, 0.9) * 1e3, 3),
+            "queue_ms_p90": round(
+                _percentile(self.queue_waits_s, 0.9) * 1e3, 3),
             "tpot_ms_avg": round(_mean(self.tpots_s) * 1e3, 3),
             "batch_occupancy": round(self.batch_occupancy, 4),
         }

@@ -98,6 +98,12 @@ class Request:
     status: RequestStatus = RequestStatus.WAITING
     tokens: List[int] = field(default_factory=list)
     num_cached: int = 0
+    # first time the scheduler put it in a batch (the clock of
+    # ``arrival_time``; set once, kept across preemption): arrival ->
+    # here is the queue wait
+    first_scheduled_time: Optional[float] = None
+    # the engine's step count when it arrived (queue wait in steps)
+    arrival_step: int = 0
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     num_preemptions: int = 0

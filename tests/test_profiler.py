@@ -122,20 +122,38 @@ def test_device_summary_reports_xla_ops(tmp_path):
         assert {"calls", "total_ms", "avg_ms"} <= set(row)
 
 
-def test_phase_classifier():
-    """XLA op name -> phase bucket (the profiler_statistic.py
-    kernel/communication/memcpy categories, VERDICT r4 #9)."""
-    from paddle_tpu.profiler import Profiler
-
-    assert Profiler.classify_phase("fusion.123") == "compute"
-    assert Profiler.classify_phase("dot_general.7") == "compute"
-    assert Profiler.classify_phase("all-reduce.1") == "collective"
-    assert Profiler.classify_phase("all-gather-start") == "collective"
-    assert Profiler.classify_phase("reduce-scatter.2") == "collective"
-    assert Profiler.classify_phase("collective-permute.5") == "collective"
-    assert Profiler.classify_phase("copy.4") == "copy"
-    assert Profiler.classify_phase("copy-start.1") == "copy"
-    assert Profiler.classify_phase("infeed") == "copy"
+@pytest.mark.parametrize("op,phase", [
+    ("fusion.123", "compute"),
+    ("dot_general.7", "compute"),
+    ("all-reduce.1", "collective"),
+    ("all-gather-start", "collective"),
+    ("reduce-scatter.2", "collective"),
+    ("collective-permute.5", "collective"),
+    ("copy.4", "copy"),
+    ("copy-start.1", "copy"),
+    ("copy-done", "copy"),
+    ("infeed", "copy"),
+    # the chip names an op by its whole instruction: the family is the
+    # instruction's own name, not whatever its operands are called (the
+    # substring rule behind BENCH_r05's copy_frac 0.545 beside MFU 0.698)
+    ("%fusion.6 = f32[16]{0} fusion(f32[16]{0} %copy.3), kind=kLoop",
+     "compute"),
+    ("%copy.3 = bf16[24,2048]{1,0} copy(bf16[24,2048]{0,1} %fusion.9)",
+     "copy"),
+    ("%copy-done.2 = bf16[8]{0} copy-done((bf16[8]{0}, u32[]) %copy-start.2)",
+     "copy"),
+    ("%convolution_add_fusion.1 = bf16[4]{0} fusion(%all-reduce.7), "
+     "kind=kOutput", "compute"),
+    ("%all-reduce-start.3 = f32[4]{0} all-reduce-start(f32[4]{0} %copy.1)",
+     "collective"),
+    ("copy_fusion.2", "compute"),
+    ("multiply_reduce_fusion", "compute"),
+])
+def test_phase_classifier(op, phase):
+    """XLA op name -> phase bucket by the op's family (the
+    profiler_statistic.py kernel/communication/memcpy categories,
+    VERDICT r4 #9)."""
+    assert Profiler.classify_phase(op) == phase
 
 
 def test_phase_summary_graceful_without_device_trace(tmp_path):
