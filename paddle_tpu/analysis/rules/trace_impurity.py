@@ -9,6 +9,12 @@ later changes. Mutating a closed-over list/dict is the dual failure:
 the append runs once per TRACE, not once per step, so counters and
 caches go quietly wrong the moment XLA stops retracing.
 
+A Pallas kernel's ``Ref`` parameters are the exception: ``ref[i] = v``
+is a traced store, also from a loop body nested in the kernel, so
+stores into the parameters of a function handed to ``pallas_call``
+(directly, through ``functools.partial``, or through a name bound to
+one) are not findings.
+
 In-graph alternatives: thread RNG keys (``jax.random.split``), pass
 timestamps/config in as arguments, return accumulated values instead of
 appending to closures.
@@ -88,6 +94,38 @@ def _local_bindings(fdef: ast.AST) -> Set[str]:
     return out
 
 
+def _pallas_kernel_refs(tree: ast.AST) -> dict:
+    """id(nested function def) -> the Ref parameter names of the Pallas
+    kernel it is nested in. A kernel is a function of this module whose
+    name is the first argument of a ``pallas_call(...)``, bare, wrapped
+    in ``partial(...)``, or bound to such a ``partial`` by assignment."""
+    def target(node):
+        if isinstance(node, ast.Call) and node.args and getattr(
+                node.func, "attr", getattr(node.func, "id", "")) == "partial":
+            node = node.args[0]
+        return node.id if isinstance(node, ast.Name) else None
+
+    bound = {t.id: target(node.value) for node in ast.walk(tree)
+             if isinstance(node, ast.Assign) for t in node.targets
+             if isinstance(t, ast.Name) and isinstance(node.value, ast.Call)}
+    kernels = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and node.args and getattr(
+                node.func, "attr", getattr(node.func, "id", "")) \
+                == "pallas_call":
+            name = target(node.args[0])
+            kernels.add(bound.get(name) or name)
+    out = {}
+    for fdef in ast.walk(tree):
+        if isinstance(fdef, ast.FunctionDef) and fdef.name in kernels:
+            a = fdef.args
+            refs = {x.arg for x in a.posonlyargs + a.args + a.kwonlyargs}
+            for inner in ast.walk(fdef):
+                if inner is not fdef and isinstance(inner, ast.FunctionDef):
+                    out[id(inner)] = refs
+    return out
+
+
 def _impure_call(module, call: ast.Call):
     canon = module.canonical(call.func)
     if canon is None:
@@ -140,10 +178,12 @@ def check(module) -> List[Finding]:
 
     # closure mutation: per traced function, mutating method calls /
     # subscript stores on names NOT bound in the function's own scope
+    kernel_refs = _pallas_kernel_refs(module.tree)
     for fdef in module.traces.traced_functions():
         if isinstance(fdef, ast.Lambda):
             continue
         local = _local_bindings(fdef)
+        refs = kernel_refs.get(id(fdef), ())
         # shallow walk: a nested helper's statements are judged against
         # ITS locals by its own pass, not against this scope's
         for node in walk_own(fdef):
@@ -171,6 +211,7 @@ def check(module) -> List[Finding]:
                             isinstance(t.value, ast.Name) and \
                             t.value.id not in local and \
                             t.value.id not in imported and \
+                            t.value.id not in refs and \
                             id(t) not in seen:
                         seen.add(id(t))
                         out.append(module.finding(

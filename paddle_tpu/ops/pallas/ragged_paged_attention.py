@@ -36,14 +36,24 @@ Two implementations, shape-identical:
 * ``_ragged_attend_ref`` — pure jnp gather/einsum. The semantics oracle
   and the path every non-TPU backend takes. It materializes each token's
   whole (MB*BS, KH, D) context, so it is for small shapes only.
-* ``_ragged_attend_pallas`` — Pallas TPU kernel, grid (q_tiles, S,
-  kv_pages) with scalar-prefetched cu_seqlens/context_lens/block_tables.
-  q and out move through (block_q, H*D) BlockSpec tiles of the packed
-  stream (a tile may hold rows of several slots; each slot's rows are
-  attended and stored on that slot's sweep), one (BS, KH, D) KV page per
-  grid step, online-softmax accumulators in VMEM scratch. Steps whose
-  slot has no row in the tile, or whose page lies past the causal bound,
-  do nothing and fetch nothing. Compiled, it needs head_dim % 128 == 0.
+* ``_ragged_attend_pallas`` — Pallas TPU kernel whose work follows the
+  live (q tile, slot, page) triples, not the batch's capacity. The grid
+  is the q tiles of the packed stream alone: q and out move through
+  (block_q, H*D) BlockSpec tiles, the caches stay in HBM. Inside a tile
+  the kernel walks, from the scalar-prefetched cu_seqlens/context_lens/
+  block_tables, the contiguous range of slots that have rows in it and,
+  per slot, its pages up to the causal bound of its last row there, in
+  groups of ``pages`` pages (128 tokens): each group is ``pages`` async
+  copies into a double-buffered VMEM scratch, in the cache's own
+  (BS, KH, D) layout, the next group (or the next slot's first) in
+  flight while the present one is computed. Per group and KV head one
+  matmul of the head's ``rep`` query heads, stacked on the row axis,
+  against the group's keys, online-softmax state in VMEM scratch. A slot
+  whose rows fit one aligned slab of 8/16 rows (a decode row, a chunk's
+  tail) computes on that slab only; any other on the whole tile, the
+  other slots' rows masked. Tiles past the last token do nothing but
+  zero their output. Compiled, it needs head_dim % 128 == 0 and
+  kv_heads * itemsize >= 4 per shard.
 
 Selection: ``impl=None`` reads ``PADDLE_RAGGED_ATTN_IMPL``, else picks
 ``"pallas"`` on a TPU backend and ``"ref"`` elsewhere. ``"pallas"``
@@ -146,145 +156,258 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _q_block_span(cu_ref, ctx_ref, ns_ref, i, qb, block_q, block_size):
-    """For q tile ``qb`` (stream rows [qb*block_q, (qb+1)*block_q)) and
-    sequence slot ``i``: whether any of the slot's rows fall in the tile,
-    and the last KV page the tile's rows of that slot may attend to
-    (causal upper bound). Scalar math on the prefetched refs only, so the
-    index maps can call it too."""
-    lo = cu_ref[i]
-    nq = cu_ref[i + 1] - lo
-    # last stream row of slot i inside the tile (exclusive)
-    end = jnp.minimum(lo + nq, (qb + 1) * block_q)
-    live = (i < ns_ref[0]) & (end > jnp.maximum(lo, qb * block_q))
-    hi = ctx_ref[i] - nq + (end - lo) - 1      # absolute pos of that row
-    last_j = jnp.where(live, jnp.maximum(hi, 0) // block_size, 0)
-    return live, last_j
+def _head_reader(buf):
+    """``read(g)``: KV head ``g`` of a fetched page group ``buf``
+    (P, BS, KH, D) as a (P*BS, D) matrix. The pages keep the cache's own
+    layout (folding heads onto lanes would re-tile the whole cache every
+    call), so a head is a static index on the sublane axis. Rows of 16
+    bits share a 32-bit sublane word with the next head's: there the
+    head is one half of every KH/2-th word of the (P*BS*KH/2, D) view,
+    one strided load instead of a row-by-row gather."""
+    p, bs, kh, d = buf.shape
+    n = p * bs
+    if buf.dtype.itemsize != 2 or kh % 2:
+        return lambda g: buf[:, :, g, :].reshape(n, d)
+    words = buf.reshape(n * kh, d).bitcast(jnp.uint32)
+
+    def read(g):
+        w = words[pl.ds(g // 2, n, stride=kh // 2), :]
+        w = w & jnp.uint32(0xFFFF0000) if g % 2 else w << 16
+        return pltpu.bitcast(w, jnp.float32).astype(buf.dtype)
+    return read
 
 
 def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
-                   q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *,
-                   scale, block_q, block_size, n_heads, kv_heads, head_dim):
-    qb = pl.program_id(0)         # q tile of the packed token stream
-    i = pl.program_id(1)          # sequence slot
-    j = pl.program_id(2)          # kv page (position within block table)
+                   q_ref, kc_ref, vc_ref, o_ref,
+                   kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
+                   scale, block_q, slab, block_size, pages, n_heads,
+                   kv_heads, head_dim):
     d = head_dim
     rep = n_heads // kv_heads
+    width = pages * block_size            # KV tokens per page group
+    s_slots = ctx_ref.shape[0]
+    mb = bt_ref.shape[0] // s_slots
+    t_lo = pl.program_id(0) * block_q     # this q tile's stream rows
+    t_hi = t_lo + block_q
+    ns = ns_ref[0]
 
-    # the out tile stays resident across the (i, j) sweep of one q tile;
-    # rows no slot owns (stream padding) keep these zeros
-    @pl.when((i == 0) & (j == 0))
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    # rows no slot owns (stream padding) keep these zeros; a page group's
+    # unfetched tail is multiplied by exact-zero probabilities, so what
+    # the V buffer starts with must be finite
+    o_ref[...] = jnp.zeros_like(o_ref)
+    vbuf[...] = jnp.zeros_like(vbuf)
 
-    live, last_j = _q_block_span(cu_ref, ctx_ref, ns_ref, i, qb, block_q,
-                                 block_size)
+    def span(s):
+        """Slot ``s`` in this tile: its stream rows [r0, r1) and how many
+        KV pages they may attend to (the causal bound of the last row;
+        0 if the slot has no row here)."""
+        c = jnp.minimum(s, s_slots - 1)
+        lo = cu_ref[c]
+        nq = cu_ref[c + 1] - lo
+        r0 = jnp.maximum(lo, t_lo)
+        r1 = jnp.minimum(lo + nq, t_hi)
+        live = (s < ns) & (r1 > r0)
+        hi = ctx_ref[c] - nq + (r1 - lo) - 1   # absolute pos of row r1-1
+        n_pg = jnp.where(live, jnp.clip(hi // block_size + 1, 1, mb), 0)
+        return lo, nq, ctx_ref[c], r0, r1, live, n_pg
 
-    @pl.when(live & (j == 0))
-    def _():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def copies(p, b, page):
+        return (pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
+                                      sem.at[0, b]),
+                pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
+                                      sem.at[1, b]))
 
-    run = live & (j <= last_j)
-    lo = cu_ref[i]
-    nq = cu_ref[i + 1] - lo
-    ctx = ctx_ref[i]
+    def fetch(s, grp, n_pg, b):
+        """Start the copies of slot ``s``'s page group ``grp`` (the
+        first ``pages`` of its remaining ``n_pg`` pages) into buffer
+        ``b``."""
+        def one(p, _):
+            for c in copies(p, b, bt_ref[s * mb + grp * pages + p]):
+                c.start()
+            return 0
+        jax.lax.fori_loop(0, jnp.minimum(n_pg, pages), one, 0)
 
-    @pl.when(run)
-    def _():
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_size), 0)
-        col = (j * block_size
-               + jax.lax.broadcasted_iota(jnp.int32,
-                                          (block_q, block_size), 1))
-        local = qb * block_q + row - lo                  # seq-local q index
-        qpos = ctx - nq + local                          # absolute position
-        mask = (local >= 0) & (local < nq) & (col <= qpos)
-        for h in range(n_heads):
-            # q/out heads live on the lane axis (the (T, H*D) view), so
-            # a head is a static 128-aligned lane slice; the KV page keeps
-            # the cache's own (BS, KH, D) layout (folding its heads onto
-            # lanes would re-tile the whole cache every call) and a head
-            # is a static index on its sublane axis
-            qh = q_ref[:, h * d:(h + 1) * d]
-            g = h // rep
-            kh_blk = k_ref[0, :, g, :]
-            s = mxu_dot(
-                qh, kh_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_scr[h, :, :1]
-            l_prev = l_scr[h, :, :1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            vh_blk = v_ref[0, :, g, :]
-            acc_scr[:, h * d:(h + 1) * d] = (
-                acc_scr[:, h * d:(h + 1) * d] * alpha
-                + mxu_dot(
-                    p.astype(vh_blk.dtype), vh_blk,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+    def wait(n_pg, b):
+        def one(p, _):
+            for c in copies(p, b, 0):
+                c.wait()
+            return 0
+        jax.lax.fori_loop(0, jnp.minimum(n_pg, pages), one, 0)
 
-    @pl.when(run & (j == last_j))
-    def _():
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-        local = qb * block_q + row - lo
-        ok = (local >= 0) & (local < nq)
-        for h in range(n_heads):
-            l = l_scr[h, :, :1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            val = (acc_scr[:, h * d:(h + 1) * d] / l_safe).astype(
-                o_ref.dtype)
-            # rows of this tile owned by OTHER slots keep what their own
-            # (i, last_j) step stored
-            cur = o_ref[:, h * d:(h + 1) * d]
-            o_ref[:, h * d:(h + 1) * d] = jnp.where(ok, val, cur)
+    def slot_body(carry):
+        s, b, fetched = carry
+        lo, nq, ctx, r0, r1, live, n_pg = span(s)
+        n_grp = (n_pg + pages - 1) // pages
+        nxt_live, nxt_pg = span(s + 1)[-2:]
+
+        @pl.when(live & (fetched == 0))
+        def _():
+            fetch(s, 0, n_pg, b)
+
+        # the slot's rows in the tile: one aligned slab of ``slab`` rows
+        # when they fit in one (a decode row, a chunk's tail), else the
+        # whole tile with the other slots' rows masked
+        first = (r0 - t_lo) // slab * slab
+        small = r1 - t_lo <= first + slab
+
+        def on_rows(fn):
+            if slab < block_q:
+                pl.when(live & small)(
+                    lambda: fn(pl.multiple_of(first, slab), slab))
+            pl.when(live & ~small if slab < block_q else live)(
+                lambda: fn(0, block_q))
+
+        def init(row0, n):
+            rows = pl.ds(row0, n)
+            m_scr[:, rows, :] = jnp.full((n_heads, n, 128), _NEG_INF,
+                                         jnp.float32)
+            l_scr[:, rows, :] = jnp.zeros((n_heads, n, 128), jnp.float32)
+            acc_scr[rows, :] = jnp.zeros((n, n_heads * d), jnp.float32)
+
+        def attend(grp, b, row0, n):
+            rows = pl.ds(row0, n)
+            row = row0 + jax.lax.broadcasted_iota(jnp.int32, (n, width), 0)
+            col = grp * width + jax.lax.broadcasted_iota(
+                jnp.int32, (n, width), 1)
+            local = t_lo + row - lo                      # seq-local q index
+            qpos = ctx - nq + local                      # absolute position
+            mask = (local >= 0) & (local < nq) & (col <= qpos)
+            mask = jnp.concatenate([mask] * rep, axis=0)
+            k_head, v_head = _head_reader(kbuf.at[b]), _head_reader(
+                vbuf.at[b])
+            for g in range(kv_heads):
+                # q/out heads live on the lane axis (the (T, H*D) view),
+                # so a head is a static 128-aligned lane slice, and the
+                # ``rep`` query heads of a KV head stack on the row axis:
+                # one matmul per KV head over the whole page group
+                hs = [slice(h * d, (h + 1) * d)
+                      for h in range(g * rep, (g + 1) * rep)]
+                qg = jnp.concatenate([q_ref[rows, h] for h in hs], axis=0)
+                kg, vg = k_head(g), v_head(g)
+                sc = mxu_dot(
+                    qg, kg, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(mask, sc, _NEG_INF)
+                m_prev = jnp.concatenate(
+                    [m_scr[g * rep + r, rows, :1] for r in range(rep)],
+                    axis=0)
+                l_prev = jnp.concatenate(
+                    [l_scr[g * rep + r, rows, :1] for r in range(rep)],
+                    axis=0)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(sc, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(sc - m_new)
+                l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+                pv = mxu_dot(
+                    p.astype(vg.dtype), vg, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                for r, h in enumerate(hs):
+                    part = slice(r * n, (r + 1) * n)
+                    acc_scr[rows, h] = (acc_scr[rows, h] * alpha[part]
+                                        + pv[part])
+                    m_scr[g * rep + r, rows, :] = jnp.broadcast_to(
+                        m_new[part], (n, 128))
+                    l_scr[g * rep + r, rows, :] = jnp.broadcast_to(
+                        l_new[part], (n, 128))
+
+        def store(row0, n):
+            rows = pl.ds(row0, n)
+            local = (t_lo + row0 - lo
+                     + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0))
+            ok = (local >= 0) & (local < nq)
+            for h in range(n_heads):
+                hd = slice(h * d, (h + 1) * d)
+                l = l_scr[h, rows, :1]
+                val = (acc_scr[rows, hd]
+                       / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+                # rows of other slots keep what their own sweep stored
+                o_ref[rows, hd] = jnp.where(ok, val, o_ref[rows, hd])
+
+        on_rows(init)
+
+        def group_body(grp, b):
+            # next in flight while this one is computed: the slot's next
+            # group, or after its last the next slot's first
+            last = grp + 1 == n_grp
+
+            @pl.when(~last | nxt_live)
+            def _():
+                fetch(jnp.where(last, s + 1, s),
+                      jnp.where(last, 0, grp + 1),
+                      jnp.where(last, nxt_pg, n_pg - (grp + 1) * pages),
+                      1 - b)
+
+            wait(n_pg - grp * pages, b)
+            on_rows(functools.partial(attend, grp, b))
+            return 1 - b
+
+        b = jax.lax.fori_loop(0, n_grp, group_body, b)
+        on_rows(store)
+        return s + 1, b, (live & nxt_live).astype(jnp.int32)
+
+    # slots are contiguous in the stream, so a tile holds a contiguous
+    # slot range: find its first, walk until one starts past the tile
+    s0 = jax.lax.while_loop(
+        lambda s: (s + 1 < ns) & (cu_ref[s + 1] <= t_lo),
+        lambda s: s + 1, jnp.int32(0))
+    jax.lax.while_loop(
+        lambda c: (c[0] < ns) & (cu_ref[c[0]] < t_hi), slot_body,
+        (s0, jnp.int32(0), jnp.int32(0)))
 
 
+# page groups of this many KV tokens: one lane width of scores
+_GROUP_TOKENS = 128
+
+
+# jitted on its own so that the layers of a model, which call it with one
+# set of shapes, share one trace and one lowering of the kernel body: the
+# body is the slow part of tracing a serving step (PERF.md, PR 28)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
                           interpret):
     t_total, h, d = q.shape
     _, bs, kh, _ = kc.shape
-    s_slots, mb = bt.shape
+    _, mb = bt.shape
+    if not interpret and kh * kc.dtype.itemsize < 4:
+        # Mosaic pads a ref's second-minor dim to one 32-bit sublane
+        # word and then refuses every slice of it ("must be aligned to
+        # tiling"), so no page of such a cache can be copied
+        raise NotImplementedError(
+            f"the compiled ragged kernel cannot fetch pages of a "
+            f"{kc.dtype} cache with {kh} KV head(s) per shard: keep "
+            f"kv_heads * itemsize >= 4 (fewer head shards)")
     block_q = _pick_block_q(t_total)
     n_qb = -(-t_total // block_q)
     t_pad = n_qb * block_q
+    pages = max(1, min(mb, _GROUP_TOKENS // bs))
     ns = jnp.reshape(num_seqs.astype(jnp.int32), (1,))
     bt_flat = jnp.maximum(bt, 0).reshape(-1).astype(jnp.int32)
     q2 = q.reshape(t_total, h * d)
     if t_pad != t_total:
         q2 = jnp.pad(q2, ((0, t_pad - t_total), (0, 0)))
 
-    def q_map(qb, i, j, cu_r, ctx_r, ns_r, bt_r):
+    def q_map(qb, cu_r, ctx_r, ns_r, bt_r):
         return (qb, 0)
 
-    def kv_map(qb, i, j, cu_r, ctx_r, ns_r, bt_r):
-        # pages past the causal bound (and every page of a slot with no
-        # row in this q tile) re-name the last needed page: an unchanged
-        # block index is not fetched again
-        _, last_j = _q_block_span(cu_r, ctx_r, ns_r, i, qb, block_q, bs)
-        return (bt_r[i * mb + jnp.minimum(j, last_j)], 0, 0, 0)
-
     kernel = functools.partial(
-        _ragged_kernel, scale=scale, block_q=block_q, block_size=bs,
+        _ragged_kernel, scale=scale, block_q=block_q,
+        slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
         n_heads=h, kv_heads=kh, head_dim=d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_qb, s_slots, mb),
+        grid=(n_qb,),
         in_specs=[
             pl.BlockSpec((block_q, h * d), q_map, memory_space=_VMEM),
-            pl.BlockSpec((1, bs, kh, d), kv_map, memory_space=_VMEM),
-            pl.BlockSpec((1, bs, kh, d), kv_map, memory_space=_VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_q, h * d), q_map,
                                memory_space=_VMEM),
         scratch_shapes=[
+            _VMEM((2, pages, bs, kh, d), kc.dtype),
+            _VMEM((2, pages, bs, kh, d), vc.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             _VMEM((h, block_q, 128), jnp.float32),
             _VMEM((h, block_q, 128), jnp.float32),
             _VMEM((block_q, h * d), jnp.float32),

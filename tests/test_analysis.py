@@ -539,6 +539,33 @@ class TestTraceImpurity:
         """)
         assert fs == []
 
+    def test_pallas_ref_store_in_kernel_loop_body_is_clean(self):
+        # a store into a kernel's Ref parameter is a traced store, also
+        # from a loop body nested in the kernel; the list is still a
+        # closure mutation
+        fs = run("""
+            import functools
+
+            import jax
+            from jax.experimental import pallas as pl
+
+            seen = []
+
+            def kernel(x_ref, o_ref, acc_ref, *, n):
+                def body(i, carry):
+                    acc_ref[i] = x_ref[i] + carry
+                    seen.append(i)
+                    return carry
+                jax.lax.fori_loop(0, n, body, 0)
+                o_ref[...] = acc_ref[...]
+
+            def call(x):
+                k = functools.partial(kernel, n=4)
+                return pl.pallas_call(k, out_shape=x)(x)
+        """)
+        assert rules_of(fs) == ["trace-time-impurity"]
+        assert "seen.append" in fs[0].snippet
+
     def test_nested_helper_local_does_not_mask_closure_mutation(self):
         # `hits` is bound only inside the nested helper: the OUTER
         # body's append is still a closure mutation

@@ -6,6 +6,7 @@ replaces — greedy and sampled, through chunking, preemption and fleet
 hand-off — while compiling exactly ONE step function for a whole mixed
 prefill/decode workload (the bucket lattice it collapses compiles one
 function per (batch, seq) bucket)."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -225,46 +226,105 @@ def test_fleet_handoff_parity_ragged(tiny_model):
 # ---------------------------------------------------------------------------
 # the Pallas kernel itself (interpret mode, asked for by name)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (8, 2)],
-                         ids=["mha", "gqa"])
-def test_ragged_kernel_interpret_matches_ref_on_mixed_batch(heads,
-                                                            kv_heads):
-    """impl="interpret" vs impl="ref" on one mixed step: a prefill from
-    position 0, a decode row, a mid-context prefill chunk that straddles
-    two q tiles, a padding slot and padding rows — the coverage whose
-    absence let the kernel rot (it called pl.load/pl.store, which the
-    installed Pallas no longer has, and nothing ran it)."""
-    from paddle_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_paged_attention,
-    )
-
-    d, bs, t, s, mb = 16, 4, 48, 4, 12      # q tiles of 32 rows
+def _ragged_batch(heads, kv_heads, new, ctx_live, t, s, mb, bs, d=16,
+                  seed=7, dtype=np.float32):
+    """One packed step for the op: ``new[i]`` query rows of live slot i
+    end a context of ``ctx_live[i]`` tokens; slots past them, rows past
+    them and every block-table tail are padding."""
+    n = len(new)
     nb = s * mb
-    new = [13, 1, 25]           # tokens this step per live slot
-    ctx_live = [13, 9, 33]      # cache length after the step
-    rng = np.random.RandomState(7)
+    rng = np.random.RandomState(seed)
     cu = np.zeros(s + 1, np.int32)
-    cu[1:4] = np.cumsum(new)
-    cu[4:] = cu[3]
+    cu[1:n + 1] = np.cumsum(new)
+    cu[n + 1:] = cu[n]
     ctx = np.zeros(s, np.int32)
-    ctx[:3] = ctx_live
+    ctx[:n] = ctx_live
     bt = np.full((s, mb), -1, np.int32)
     free = list(rng.permutation(nb))
     for i, c in enumerate(ctx_live):
-        n = -(-c // bs)
-        bt[i, :n] = [free.pop() for _ in range(n)]
-    q = rng.randn(t, heads, d).astype(np.float32)
-    k_new = rng.randn(t, kv_heads, d).astype(np.float32)
-    v_new = rng.randn(t, kv_heads, d).astype(np.float32)
-    kc = rng.randn(nb, bs, kv_heads, d).astype(np.float32)
-    vc = rng.randn(nb, bs, kv_heads, d).astype(np.float32)
+        n_blocks = -(-c // bs)
+        bt[i, :n_blocks] = [free.pop() for _ in range(n_blocks)]
+    return tuple(
+        jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+        for shape in [(t, heads, d), (t, kv_heads, d), (t, kv_heads, d),
+                      (nb, bs, kv_heads, d), (nb, bs, kv_heads, d)]
+    ) + (bt, cu, ctx, np.int32(n))
 
-    got = {impl: ragged_paged_attention(
-        q, k_new, v_new, kc, vc, bt, cu, ctx, np.int32(3), impl=impl)
-        for impl in ("ref", "interpret")}
-    for r, i in zip(got["ref"], got["interpret"]):
-        np.testing.assert_allclose(np.asarray(i), np.asarray(r),
-                                   rtol=1e-5, atol=1e-5)
-    out = np.asarray(got["interpret"][0])
-    assert np.abs(out[:39]).min(axis=(1, 2)).all()   # every live row set
-    assert not out[39:].any()                        # padding rows are 0
+
+# q tiles of 32 rows, one page group holds a slot's whole table: a prefill
+# from position 0, a decode row, a mid-context prefill chunk that
+# straddles two q tiles, a padding slot and padding rows
+_MIXED = dict(new=[13, 1, 25], ctx_live=[13, 9, 33], t=48, s=4, mb=12, bs=4)
+# the next three: page groups of 16 pages (128 tokens) under longer tables.
+# Decode rows only, over contexts that end exactly on a page boundary, one
+# token past it, inside the first page, and at page counts (25, 26, 33)
+# that are no multiple of the group
+_DECODE = dict(new=[1] * 7, ctx_live=[128, 129, 200, 201, 7, 1, 264],
+               t=16, s=8, mb=64, bs=8)
+# one chunk over four 128-row q tiles behind a cached prefix of 150 tokens
+# (more than one group), a decode row on either side
+_LONG_CHUNK = dict(new=[1, 390, 1], ctx_live=[40, 540, 9],
+                   t=512, s=4, mb=72, bs=8)
+# 2 of 6 slots live, 71 of 384 rows: the last two q tiles hold nothing
+_TRAILING_PADDING = dict(new=[70, 1], ctx_live=[131, 17],
+                         t=384, s=6, mb=64, bs=8)
+
+
+@pytest.mark.parametrize("heads,kv_heads,batch,strict", [
+    (4, 4, _MIXED, False), (8, 2, _MIXED, False), (4, 2, _MIXED, False),
+    (4, 2, _DECODE, False), (8, 2, _DECODE, False),
+    (4, 2, _LONG_CHUNK, False), (4, 4, _LONG_CHUNK, False),
+    (4, 2, _TRAILING_PADDING, False), (4, 2, _MIXED, True),
+    (4, 2, _DECODE, True),
+    # 16-bit caches: a KV head is half of a sublane word (_head_reader)
+    (4, 2, dict(_MIXED, dtype=jnp.bfloat16), False),
+    (8, 4, dict(_DECODE, dtype=jnp.bfloat16), False),
+], ids=["mha", "gqa", "rep2", "decode-rep2", "decode-rep4", "chunk-rep2",
+        "chunk-mha", "padding-rep2", "strict-mixed", "strict-decode",
+        "bf16-mixed", "bf16-decode"])
+def test_ragged_kernel_interpret_matches_ref_on_mixed_batch(
+        heads, kv_heads, batch, strict):
+    """impl="interpret" vs impl="ref" on one step — the coverage whose
+    absence let the kernel rot (it called pl.load/pl.store, which the
+    installed Pallas no longer has, and nothing ran it). ``strict`` runs
+    the same kernel under the TPU interpreter (``InterpretParams``:
+    buffers start as NaN, DMAs and their semaphores are simulated), which
+    impl="interpret" (``interpret=True``) is not."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    args = _ragged_batch(heads, kv_heads, **batch)
+    ref = rpa.ragged_paged_attention(*args, impl="ref")
+    if strict:
+        q = args[0]
+        got = (rpa._ragged_attend_pallas(
+            q, ref[1], ref[2], *args[5:], 1.0 / q.shape[-1] ** 0.5,
+            pltpu.InterpretParams()),)
+    else:
+        got = rpa.ragged_paged_attention(*args, impl="interpret")
+    tol = 1e-5 if args[0].dtype == np.float32 else 3e-2
+    for r, i in zip(ref, got):
+        np.testing.assert_allclose(np.asarray(i, np.float32),
+                                   np.asarray(r, np.float32),
+                                   rtol=tol, atol=tol)
+    out = np.asarray(got[0], np.float32)
+    live = sum(batch["new"])
+    assert np.abs(out[:live]).min(axis=(1, 2)).all()  # every live row set
+    assert not out[live:].any()                       # padding rows are 0
+
+
+def test_compiled_kernel_refuses_a_cache_it_cannot_page():
+    """One bfloat16 KV head per shard is half a 32-bit sublane word:
+    Mosaic cannot slice a page out of such a cache, and the entry point
+    says so before the compiler's own message does."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    args = _ragged_batch(4, 1, **dict(_MIXED, dtype=jnp.bfloat16))
+    with pytest.raises(NotImplementedError, match="KV head"):
+        rpa.ragged_paged_attention(*args, impl="pallas")
+    out = rpa.ragged_paged_attention(*args, impl="interpret")[0]
+    ref = rpa.ragged_paged_attention(*args, impl="ref")[0]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=3e-2, atol=3e-2)

@@ -65,38 +65,41 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _ragged_shapes(h, kh, sh_heads, sh_cache, sh_rep):
+def _ragged_shapes(h, kh, sh_heads, sh_cache, sh_rep, t=T, s=S, nb=NB):
     bf16, i32 = jnp.bfloat16, jnp.int32
 
     def sds(shape, dt, sh):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
-    return (sds((T, h, D), bf16, sh_heads), sds((T, kh, D), bf16, sh_heads),
-            sds((T, kh, D), bf16, sh_heads),
-            sds((NB, BS, kh, D), bf16, sh_cache),
-            sds((NB, BS, kh, D), bf16, sh_cache),
-            sds((S, MB), i32, sh_rep), sds((S + 1,), i32, sh_rep),
-            sds((S,), i32, sh_rep), sds((), i32, sh_rep))
+    return (sds((t, h, D), bf16, sh_heads), sds((t, kh, D), bf16, sh_heads),
+            sds((t, kh, D), bf16, sh_heads),
+            sds((nb, BS, kh, D), bf16, sh_cache),
+            sds((nb, BS, kh, D), bf16, sh_cache),
+            sds((s, MB), i32, sh_rep), sds((s + 1,), i32, sh_rep),
+            sds((s,), i32, sh_rep), sds((), i32, sh_rep))
 
 
 def _kernel_calls(compiled, name):
     return kernel_calls(compiled.as_text(), name)
 
 
-@pytest.mark.parametrize("h,kh", [(16, 16), (32, 8)],
-                         ids=["mha16", "gqa32x8"])
-def test_ragged_kernel_compiles_for_v5e(one_chip, h, kh):
-    """The engine's ragged step shape at GPT-1B width (16/16 heads) and
-    at the north-star GQA width (32/8)."""
+@pytest.mark.parametrize("h,kh,t,s,nb", [
+    (16, 16, T, S, NB), (32, 8, T, S, NB), (16, 8, 512, 16, 2048),
+    (8, 2, T, S, NB)], ids=["mha16", "gqa32x8", "chat-c16", "gqa8x2"])
+def test_ragged_kernel_compiles_for_v5e(one_chip, h, kh, t, s, nb):
+    """The engine's ragged step shape at GPT-1B width (16/16 heads), at
+    the north-star GQA width (32/8), at the benchmark's serve cell
+    (`internlm2-1.8b.chat-c16`: 16/8 heads, 512-token budget, 16 slots,
+    2,048 blocks) and at one of four head shards of the GQA width."""
     fn = jax.jit(functools.partial(ragged_paged_attention, impl="pallas"),
                  donate_argnums=(3, 4))
-    compiled = fn.lower(
-        *_ragged_shapes(h, kh, one_chip, one_chip, one_chip)).compile()
+    compiled = fn.lower(*_ragged_shapes(
+        h, kh, one_chip, one_chip, one_chip, t, s, nb)).compile()
     assert _kernel_calls(compiled, "ragged_paged_attention") == 1
     mem = compiled.memory_analysis()
     # the caches are updated in place, and the kernel's operands need no
     # re-tiled copy of them (a (BS, KH*D) view of the cache cost one)
-    cache_bytes = 2 * NB * BS * kh * D * 2
+    cache_bytes = 2 * nb * BS * kh * D * 2
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
