@@ -15,7 +15,14 @@ seq 2048), random weights from ``--seed``:
   with the default ``EngineConfig`` (``max_model_len=2048``): 8 requests,
   prompts 30-1500 tokens, 32 new tokens, half greedy and half
   seeded-sampled, two arriving mid-flight; a warm-up wave, the served
-  wave, and the served wave again from the same seed.
+  wave, and the served wave again from the same seed;
+* hybrid phase — ``Phi4FlashForCausalLM`` at its published widths (32
+  layers, vocabulary 200,064: state-space, window and full-attention
+  layers, a cross-decoder) behind the same router and engine, whose cache
+  is then a full pool, a window pool and state slots: the same 8 requests
+  served twice from the same seed, streams identical run to run, window
+  blocks released, every state slot returned. ``--only hybrid`` runs it
+  alone.
 
 ``--chips 4`` runs only ``LLMEngine(tp_degree=4)`` against ``tp_degree=1``
 and ``ParallelTrainStep`` on a dp2 x tp2 mesh against the one-chip
@@ -380,6 +387,55 @@ def serve_phase(dev, cfg_kw, seed, compiles, on_chip, new_tokens):
         "not reported by the cpu backend")
 
 
+def hybrid_phase(dev, seed, on_chip, new_tokens):
+    """The model that says what it caches, through the normal path."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                             Phi4FlashForCausalLM)
+    from paddle_tpu.serving import EngineConfig
+    from paddle_tpu.serving.fleet import FleetRouter, InProcessReplica
+
+    t0 = time.perf_counter()
+    paddle.seed(seed)
+    paddle.set_default_dtype("bfloat16")
+    try:
+        cfg = (Phi4FlashConfig() if on_chip else Phi4FlashConfig.tiny(
+            vocab_size=512, hidden_size=128, sliding_window=16))
+        model = Phi4FlashForCausalLM(cfg)
+    finally:
+        paddle.set_default_dtype("float32")
+    model.eval()
+    max_len = 2048 if on_chip else 128
+    replica = InProcessReplica(model, EngineConfig(max_model_len=max_len),
+                               replica_id="h0")
+    router = FleetRouter([replica])
+    engine = replica.engine
+    bm = engine.block_manager
+    say("hybrid", dev, layers=cfg.num_hidden_layers, vocab=cfg.vocab_size,
+        kv_blocks=engine.cfg.num_blocks,
+        window_blocks=engine.cfg.num_window_blocks,
+        state_slots=bm.state_slots, donated_cache=engine._donated,
+        built_s=round(time.perf_counter() - t0, 1))
+    assert engine._cache is not None and not engine.cfg.prefix_cache
+    shape = dict(max_position_embeddings=max_len,
+                 vocab_size=cfg.vocab_size)
+    waves = []
+    for _ in range(2):
+        t1 = time.perf_counter()
+        waves.append(serve_wave(router, engine,
+                                make_requests(shape, seed, new_tokens),
+                                new_tokens, replica=replica))
+        say("hybrid", dev, wave_s=round(time.perf_counter() - t1, 2),
+            engine_steps=engine.metrics.engine_steps,
+            window_blocks_released=bm.num_window_blocks_released)
+        assert bm.state_slots_in_use == 0 and bm.num_used_blocks == 0
+        assert bm.num_used_window_blocks == 0
+    assert waves[0] == waves[1], "streams differ run to run"
+    assert bm.num_window_blocks_released > 0
+    assert engine.num_logits_fetches == 0
+    say("hybrid", dev, streams_identical=True, requests=len(waves[0]))
+
+
 def engine_step_text(engine, ids, bt, cu, ctx, nseq):
     """Compiled text of the engine's one ragged step, lowered again from
     the shapes of a real dispatch (a persistent-cache hit)."""
@@ -514,6 +570,8 @@ def main():
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny widths on the CPU, kernels interpreted")
+    ap.add_argument("--only", choices=("hybrid",), default=None,
+                    help="one chip: run this phase alone")
     args = ap.parse_args()
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -547,16 +605,21 @@ def main():
     if args.chips == 4:
         tp_phase(devs, cfg_kw, args.seed, new_tokens, on_chip)
         parallel_train_phase(devs, cfg_kw, args.seed, compiles, on_chip)
+    elif args.only == "hybrid":
+        hybrid_phase(dev, args.seed, on_chip, new_tokens)
     else:
         train_phase(dev, cfg_kw, args.seed, compiles, on_chip)
-        # the two phases never share HBM: the trainer's state is gone
-        # (and shown gone) before the engine is built
+        # the phases never share HBM: the trainer's state is gone (and
+        # shown gone) before the engine is built, and so is that engine
+        # before the next model
         gc.collect()
         if on_chip:
             left = bytes_in_use(dev)
             say("train", dev, bytes_in_use_after_free=left)
             assert left < 0.25 * GIB, left
         serve_phase(dev, cfg_kw, args.seed, compiles, on_chip, new_tokens)
+        gc.collect()
+        hybrid_phase(dev, args.seed, on_chip, new_tokens)
     say("cache", dev, dir=cache, programs=compiles.programs,
         cache_requests=compiles.requests, cache_hits=compiles.hits)
     print(json.dumps({"ok": True, "device": {

@@ -15,7 +15,13 @@ Contract (all data-level jnp arrays):
                                 sequence slot i; rows >= cu_seqlens[num_seqs]
                                 are padding.
 * ``k_new/v_new``:  (T, KH, D)  new K/V for the same rows (GQA: KH <= H).
-* ``key_cache/value_cache``: (num_blocks, block_size, KH, D) paged cache.
+* ``key_cache/value_cache``: (num_blocks, block_size, KH, D) paged cache,
+                    or FOLDED, (num_blocks, block_size, KH*D): a token's
+                    heads side by side on the lane axis. Mosaic tiles the
+                    last two dims, so it cannot slice a page of a 4-D
+                    cache whose KH is no multiple of 8 (and XLA pads such
+                    a cache in HBM); folded, any KH with D % 128 == 0
+                    compiles and a head is a static lane slice.
 * ``block_tables``: (S, MB) int32 physical block ids per slot (-1 pads).
 * ``cu_seqlens``:   (S+1,) int32 exclusive prefix sum of per-slot new-token
                     counts (cu_seqlens[0] == 0).
@@ -120,6 +126,8 @@ def _write_kv(cache, new, block_tables, seg, pos):
     flat = jnp.maximum(entry, 0) * bs + off
     cache_flat = cache.reshape(-1, *cache.shape[2:])
     fi = jnp.where(valid, flat, cache_flat.shape[0])
+    if cache.ndim == 3:                   # folded: (T, KH, D) -> (T, KH*D)
+        new = new.reshape(new.shape[0], -1)
     cache_flat = cache_flat.at[fi].set(new.astype(cache.dtype),
                                        mode="drop")
     return cache_flat.reshape(cache.shape)
@@ -128,8 +136,12 @@ def _write_kv(cache, new, block_tables, seg, pos):
 # ---------------------------------------------------------------------------
 # reference implementation (semantics oracle; the non-TPU path)
 # ---------------------------------------------------------------------------
-def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
+def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
+                       window=None):
     t_total, h, d = q.shape
+    if kc.ndim == 3:                                     # folded heads
+        kc = kc.reshape(*kc.shape[:2], -1, d)
+        vc = vc.reshape(*vc.shape[:2], -1, d)
     nb, bs, kh, _ = kc.shape
     mb = bt.shape[1]
     bt_tok = bt[seg]                                     # (T, MB)
@@ -145,6 +157,8 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
     att = ((lpos <= pos[:, None])
            & (bt_tok >= 0).repeat(bs, axis=1)
            & valid[:, None])                             # (T, L)
+    if window is not None:
+        att = att & (lpos > pos[:, None] - window)
     neg = jnp.asarray(jnp.finfo(jnp.float32).min, logits.dtype)
     logits = jnp.where(att[:, None, :], logits, neg)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -156,14 +170,19 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _head_reader(buf):
-    """``read(g)``: KV head ``g`` of a fetched page group ``buf``
-    (P, BS, KH, D) as a (P*BS, D) matrix. The pages keep the cache's own
+def _head_reader(buf, d=None):
+    """``read(g)``: KV head ``g`` of a fetched page group ``buf`` as a
+    (P*BS, D) matrix. A folded group (P, BS, KH*D) keeps its heads side
+    by side on lanes: a head is a static ``d``-wide lane slice. Else
+    ``buf`` is (P, BS, KH, D). The pages keep the cache's own
     layout (folding heads onto lanes would re-tile the whole cache every
     call), so a head is a static index on the sublane axis. Rows of 16
     bits share a 32-bit sublane word with the next head's: there the
     head is one half of every KH/2-th word of the (P*BS*KH/2, D) view,
     one strided load instead of a row-by-row gather."""
+    if len(buf.shape) == 3:
+        p, bs, _ = buf.shape
+        return lambda g: buf[:, :, g * d:(g + 1) * d].reshape(p * bs, d)
     p, bs, kh, d = buf.shape
     n = p * bs
     if buf.dtype.itemsize != 2 or kh % 2:
@@ -181,7 +200,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
                    kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
                    scale, block_q, slab, block_size, pages, n_heads,
-                   kv_heads, head_dim):
+                   kv_heads, head_dim, window=None):
     d = head_dim
     rep = n_heads // kv_heads
     width = pages * block_size            # KV tokens per page group
@@ -198,9 +217,11 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
     vbuf[...] = jnp.zeros_like(vbuf)
 
     def span(s):
-        """Slot ``s`` in this tile: its stream rows [r0, r1) and how many
-        KV pages they may attend to (the causal bound of the last row;
-        0 if the slot has no row here)."""
+        """Slot ``s`` in this tile: its stream rows [r0, r1), the first
+        KV page they may attend to (0 without a window; under one, the
+        page of the first row's oldest visible key: the pages before it
+        may be gone) and how many pages from there on (up to the causal
+        bound of the last row; 0 if the slot has no row here)."""
         c = jnp.minimum(s, s_slots - 1)
         lo = cu_ref[c]
         nq = cu_ref[c + 1] - lo
@@ -209,7 +230,12 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         live = (s < ns) & (r1 > r0)
         hi = ctx_ref[c] - nq + (r1 - lo) - 1   # absolute pos of row r1-1
         n_pg = jnp.where(live, jnp.clip(hi // block_size + 1, 1, mb), 0)
-        return lo, nq, ctx_ref[c], r0, r1, live, n_pg
+        if window is None:
+            return lo, nq, ctx_ref[c], r0, r1, 0, live, n_pg
+        first = ctx_ref[c] - nq + (r0 - lo)    # absolute pos of row r0
+        pg0 = jnp.where(
+            live, jnp.maximum(first - window + 1, 0) // block_size, 0)
+        return lo, nq, ctx_ref[c], r0, r1, pg0, live, n_pg - pg0
 
     def copies(p, b, page):
         return (pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
@@ -217,12 +243,15 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                 pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
                                       sem.at[1, b]))
 
-    def fetch(s, grp, n_pg, b):
-        """Start the copies of slot ``s``'s page group ``grp`` (the
-        first ``pages`` of its remaining ``n_pg`` pages) into buffer
-        ``b``."""
+    def fetch(s, pg0, grp, n_pg, b):
+        """Start the copies of slot ``s``'s page group ``grp`` counted
+        from page ``pg0`` (the first ``pages`` of its remaining ``n_pg``
+        pages) into buffer ``b``."""
         def one(p, _):
-            for c in copies(p, b, bt_ref[s * mb + grp * pages + p]):
+            entry = s * mb + grp * pages + p
+            if window is not None:
+                entry = entry + pg0
+            for c in copies(p, b, bt_ref[entry]):
                 c.start()
             return 0
         jax.lax.fori_loop(0, jnp.minimum(n_pg, pages), one, 0)
@@ -236,13 +265,13 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
 
     def slot_body(carry):
         s, b, fetched = carry
-        lo, nq, ctx, r0, r1, live, n_pg = span(s)
+        lo, nq, ctx, r0, r1, pg0, live, n_pg = span(s)
         n_grp = (n_pg + pages - 1) // pages
-        nxt_live, nxt_pg = span(s + 1)[-2:]
+        nxt_pg0, nxt_live, nxt_pg = span(s + 1)[-3:]
 
         @pl.when(live & (fetched == 0))
         def _():
-            fetch(s, 0, n_pg, b)
+            fetch(s, pg0, 0, n_pg, b)
 
         # the slot's rows in the tile: one aligned slab of ``slab`` rows
         # when they fit in one (a decode row, a chunk's tail), else the
@@ -269,12 +298,20 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             row = row0 + jax.lax.broadcasted_iota(jnp.int32, (n, width), 0)
             col = grp * width + jax.lax.broadcasted_iota(
                 jnp.int32, (n, width), 1)
+            if window is not None:
+                col = col + pg0 * block_size
             local = t_lo + row - lo                      # seq-local q index
             qpos = ctx - nq + local                      # absolute position
             mask = (local >= 0) & (local < nq) & (col <= qpos)
+            if window is not None:
+                mask = mask & (col > qpos - window)
             mask = jnp.concatenate([mask] * rep, axis=0)
-            k_head, v_head = _head_reader(kbuf.at[b]), _head_reader(
-                vbuf.at[b])
+            if len(kbuf.shape) == 4:                     # folded pages
+                k_head, v_head = (_head_reader(kbuf.at[b], d),
+                                  _head_reader(vbuf.at[b], d))
+            else:
+                k_head, v_head = _head_reader(kbuf.at[b]), _head_reader(
+                    vbuf.at[b])
             for g in range(kv_heads):
                 # q/out heads live on the lane axis (the (T, H*D) view),
                 # so a head is a static 128-aligned lane slice, and the
@@ -334,6 +371,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             @pl.when(~last | nxt_live)
             def _():
                 fetch(jnp.where(last, s + 1, s),
+                      jnp.where(last, nxt_pg0, pg0) if window else 0,
                       jnp.where(last, 0, grp + 1),
                       jnp.where(last, nxt_pg, n_pg - (grp + 1) * pages),
                       1 - b)
@@ -363,13 +401,20 @@ _GROUP_TOKENS = 128
 # jitted on its own so that the layers of a model, which call it with one
 # set of shapes, share one trace and one lowering of the kernel body: the
 # body is the slow part of tracing a serving step (PERF.md, PR 28)
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
-                          interpret):
+                          interpret, window=None):
     t_total, h, d = q.shape
-    _, bs, kh, _ = kc.shape
+    folded = kc.ndim == 3
+    if folded:
+        _, bs, lanes = kc.shape
+        kh, page = lanes // d, (bs, lanes)
+    else:
+        _, bs, kh, _ = kc.shape
+        page = (bs, kh, d)
     _, mb = bt.shape
-    if not interpret and kh * kc.dtype.itemsize < 4:
+    if not interpret and not folded and kh * kc.dtype.itemsize < 4:
         # Mosaic pads a ref's second-minor dim to one 32-bit sublane
         # word and then refuses every slice of it ("must be aligned to
         # tiling"), so no page of such a cache can be copied
@@ -393,7 +438,8 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_q=block_q,
         slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
-        n_heads=h, kv_heads=kh, head_dim=d)
+        n_heads=h, kv_heads=kh, head_dim=d,
+        **({} if window is None else {"window": window}))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_qb,),
@@ -405,8 +451,8 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
         out_specs=pl.BlockSpec((block_q, h * d), q_map,
                                memory_space=_VMEM),
         scratch_shapes=[
-            _VMEM((2, pages, bs, kh, d), kc.dtype),
-            _VMEM((2, pages, bs, kh, d), vc.dtype),
+            _VMEM((2, pages) + page, kc.dtype),
+            _VMEM((2, pages) + page, vc.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             _VMEM((h, block_q, 128), jnp.float32),
             _VMEM((h, block_q, 128), jnp.float32),
@@ -428,9 +474,19 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
 # ---------------------------------------------------------------------------
 def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
                            block_tables, cu_seqlens, context_lens,
-                           num_seqs, *, scale=None, impl=None):
-    """See module docstring for the contract. Returns (out, kc', vc')."""
+                           num_seqs, *, scale=None, impl=None, window=None):
+    """See module docstring for the contract. Returns (out, kc', vc').
+    ``window`` w (None = full): a query at position p attends keys
+    p-w+1..p, and the page walk starts at the page of the first row's
+    oldest visible key, so the cost does not grow with the context and
+    block-table entries behind the window may be gone (-1).
+    ``k_new``/``v_new`` None is the read-only call: nothing is written
+    and the caches come back as they were (a layer that attends another
+    layer's pages)."""
     q = jnp.asarray(q)
+    read_only = k_new is None
+    if read_only:
+        k_new = v_new = jnp.zeros((0,), q.dtype)    # placeholders, unread
     k_new = jnp.asarray(k_new)
     v_new = jnp.asarray(v_new)
     key_cache = jnp.asarray(key_cache)
@@ -448,23 +504,33 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
     ctx = jnp.asarray(context_lens).astype(jnp.int32)
     bt = jnp.asarray(block_tables).astype(jnp.int32)
     ns = jnp.asarray(num_seqs).astype(jnp.int32)
+    # a full-attention call passes no window at all: its trace, and the
+    # jit cache entry of the kernel body, are the ones it had before
+    win = {} if window is None else {"window": int(window)}
 
     def local(q, k_new, v_new, key_cache, value_cache, bt, cu, ctx, ns):
         seg, pos, valid = _token_layout(t_total, s_slots, cu, ctx, ns)
-        with jax.named_scope("kv_update"):      # the cache scatter
-            kc = _write_kv(key_cache, k_new, bt, seg, pos)
-            vc = _write_kv(value_cache, v_new, bt, seg, pos)
+        if read_only:
+            kc, vc = key_cache, value_cache
+        else:
+            with jax.named_scope("kv_update"):      # the cache scatter
+                kc = _write_kv(key_cache, k_new, bt, seg, pos)
+                vc = _write_kv(value_cache, v_new, bt, seg, pos)
         with jax.named_scope("attention"):
             if impl == "ref":
                 out = _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos,
-                                         valid, scale)
+                                         valid, scale, **win)
             else:
                 out = _ragged_attend_pallas(
                     q, kc, vc, bt, cu, ctx, ns, scale,
-                    interpret=(impl == "interpret"))
+                    interpret=(impl == "interpret"), **win)
         return out, kc, vc
 
     decl = declared()
+    if decl is not None and decl[1] is not None and key_cache.ndim == 3:
+        raise NotImplementedError(
+            "a folded (blocks, block_size, KH*D) cache has no head axis "
+            "to shard over a mesh")
     if decl is not None and decl[1] is not None:
         # heads are sharded over a mesh axis (TP serving): every head is
         # independent, so each shard runs the same program on its own
@@ -473,7 +539,8 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
         hs = PartitionSpec(None, ax, None)
         cs = PartitionSpec(None, None, ax, None)
         r = PartitionSpec()
+        new = r if read_only else hs
         local = jax.shard_map(
-            local, mesh=mesh, in_specs=(hs, hs, hs, cs, cs, r, r, r, r),
+            local, mesh=mesh, in_specs=(hs, new, new, cs, cs, r, r, r, r),
             out_specs=(hs, cs, cs), check_vma=False)
     return local(q, k_new, v_new, key_cache, value_cache, bt, cu, ctx, ns)

@@ -54,7 +54,22 @@ later one in the same scheduling round. Writes never target the host
 region: only fully-committed blocks strictly below a request's write
 frontier are demote-eligible, and the capped-write block of a prefix
 hit that lands on a virtual entry is promote-copied first (the
-cross-tier analogue of COW)."""
+cross-tier analogue of COW).
+
+Window pool and state slots (``window_blocks > 0`` / ``state_slots > 0``,
+for a model whose ``cache_spec`` has window layers or recurrent state): a
+SECOND table per request indexes a separate pool that the model's window
+layers share. It grows in step with the main table (``allocate`` /
+``append_slot`` claim from both pools or from neither), but
+``release_behind_window`` hands back, after each step, every block that
+lies wholly behind ``context - window``: the table keeps its logical
+indexing and a released entry reads -1 (the kernel's window walk never
+reaches it), so a sequence's live window blocks stay bounded however long
+it grows. A state slot is the index of the request's recurrent state in
+the engine's per-layer state arrays: taken at ``allocate``, returned by
+``free`` (finish, abort, preemption), never shared. Neither pool is
+prefix-cached, swapped or tiered (the engine refuses those for such a
+model)."""
 from __future__ import annotations
 
 import hashlib
@@ -108,9 +123,21 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  num_host_blocks: int = 0,
                  enable_prefix_cache: bool = False,
-                 kv_layout=None, tiered: bool = False):
+                 kv_layout=None, tiered: bool = False,
+                 window_blocks: int = 0, window: int = 0,
+                 state_slots: int = 0):
         if num_blocks < 1 or block_size < 1:
             raise ValueError("num_blocks and block_size must be >= 1")
+        if window_blocks < 0 or state_slots < 0:
+            raise ValueError("window_blocks and state_slots must be >= 0")
+        if window_blocks and window < 1:
+            raise ValueError("a window pool needs its window (tokens)")
+        if (window_blocks or state_slots) and (
+                enable_prefix_cache or tiered or num_host_blocks):
+            raise ValueError(
+                "a window pool or state slots cannot be combined with "
+                "prefix caching, a host pool or tiers: a shared or "
+                "spilled block has no recurrent state to go with it")
         if num_host_blocks < 0:
             raise ValueError("num_host_blocks must be >= 0")
         if tiered and num_host_blocks < 1:
@@ -172,6 +199,16 @@ class BlockManager:
         self._tier_moves: List[Tuple[str, int, int]] = []
         self.num_demotes = 0
         self.num_promotes = 0
+        # window pool + state slots (module docstring)
+        self.window_blocks = window_blocks
+        self.window = window
+        self._wfree = deque(range(window_blocks - 1, -1, -1))
+        self._wtables: Dict[str, List[int]] = {}   # logical, -1 released
+        self._wfirst: Dict[str, int] = {}          # first live index
+        self.num_window_blocks_released = 0
+        self.state_slots = state_slots
+        self._slot_free: List[int] = list(range(state_slots - 1, -1, -1))
+        self._slots: Dict[str, int] = {}
 
     # -- tier addressing --------------------------------------------------
     def is_host_entry(self, entry: int) -> bool:
@@ -202,7 +239,75 @@ class BlockManager:
 
     def can_allocate(self, num_tokens: int) -> bool:
         """Conservative (prefix hits can only reduce the real need)."""
-        return self.blocks_needed(num_tokens) <= len(self._free)
+        need = self.blocks_needed(num_tokens)
+        return (need <= len(self._free)
+                and (not self.window_blocks or need <= len(self._wfree))
+                and (not self.state_slots or bool(self._slot_free)))
+
+    # -- window pool + state slots ---------------------------------------
+    @property
+    def num_used_window_blocks(self) -> int:
+        return self.window_blocks - len(self._wfree)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return len(self._slots)
+
+    def window_table(self, request_id: str) -> List[int]:
+        """The request's window-pool table, logically indexed like its
+        main table; entries behind the window are -1."""
+        return list(self._wtables[request_id])
+
+    def num_window_blocks(self, request_id: str) -> int:
+        """Live window-pool blocks the request holds."""
+        return len(self._wtables[request_id]) - self._wfirst[request_id]
+
+    def state_slot(self, request_id: str) -> int:
+        return self._slots[request_id]
+
+    def _window_need(self, request_id: Optional[str],
+                     num_tokens: int) -> int:
+        """Window-pool blocks a table must gain to cover
+        ``num_tokens`` (0 with no window pool)."""
+        if not self.window_blocks:
+            return 0
+        have = len(self._wtables.get(request_id, ()))
+        return max(self.blocks_needed(num_tokens) - have, 0)
+
+    def _grow_window(self, request_id: str, need: int):
+        table = self._wtables.setdefault(request_id, [])
+        self._wfirst.setdefault(request_id, 0)
+        table.extend(self._wfree.pop() for _ in range(need))
+
+    def release_behind_window(self, request_id: str,
+                              context_len: int) -> int:
+        """After a step that left the request at ``context_len`` tokens:
+        every window-pool block that lies wholly behind
+        ``context_len - window`` goes back to the pool (no later query
+        can see a key in it). Returns the blocks released."""
+        table = self._wtables.get(request_id)
+        if table is None:
+            return 0
+        first = self._wfirst[request_id]
+        dead = min(max(context_len - self.window, 0) // self.block_size,
+                   len(table))
+        for i in range(first, dead):
+            self._wfree.append(table[i])
+            table[i] = -1
+        if dead > first:
+            self._wfirst[request_id] = dead
+            self.num_window_blocks_released += dead - first
+            return dead - first
+        return 0
+
+    def _free_window_and_slot(self, request_id: str):
+        table = self._wtables.pop(request_id, None)
+        if table is not None:
+            self._wfree.extend(b for b in table[self._wfirst.pop(
+                request_id):] if b >= 0)
+        slot = self._slots.pop(request_id, None)
+        if slot is not None:
+            self._slot_free.append(slot)
 
     def has_table(self, request_id: str) -> bool:
         return request_id in self._tables
@@ -637,6 +742,19 @@ class BlockManager:
                 f"need {fresh_need + cow_need} fresh block(s) for "
                 f"{num_tokens} tokens ({hit_tok} prefix-cached), "
                 f"{len(self._free) - shared_free} free")
+        wneed = self._window_need(None, num_tokens)
+        if wneed > len(self._wfree):
+            raise NoFreeBlocksError(
+                f"need {wneed} window-pool block(s) for {num_tokens} "
+                f"tokens, {len(self._wfree)} free")
+        if self.state_slots:
+            if not self._slot_free:
+                raise NoFreeBlocksError(
+                    f"all {self.state_slots} state slots are in use "
+                    f"(one per running sequence, max_num_seqs)")
+            self._slots[request_id] = self._slot_free.pop()
+        if self.window_blocks:
+            self._grow_window(request_id, wneed)
         table: List[int] = []
         for b in shared:
             table.append(self._share_entry(b))
@@ -719,7 +837,8 @@ class BlockManager:
         """Would growing this request's sequence to ``new_len`` tokens
         fit (either inside its last block or with one free block)?"""
         need = self.blocks_needed(new_len) - len(self._tables[request_id])
-        return need <= len(self._free)
+        return (need <= len(self._free) and
+                self._window_need(request_id, new_len) <= len(self._wfree))
 
     def append_slot(self, request_id: str, new_len: int,
                     write_from: Optional[int] = None) -> List[int]:
@@ -742,7 +861,8 @@ class BlockManager:
         # (defensive: demotion never covers the write frontier, but a
         # resumed chain hitting host-tier blocks can reach here)
         promo_idxs = [i for i in span if self.is_host_entry(table[i])]
-        if need <= 0 and not cow_idxs and not promo_idxs:
+        wneed = self._window_need(request_id, new_len)
+        if need <= 0 and not cow_idxs and not promo_idxs and not wneed:
             return list(table)
         # deterministic forced-OOM injection points: a `flag` fault at
         # the global point (any request) or the per-request one
@@ -760,6 +880,13 @@ class BlockManager:
                 f"request {request_id!r}: {want} "
                 f"more block(s) needed for length {new_len}, "
                 f"{len(self._free)} free")
+        if wneed > len(self._wfree):
+            raise NoFreeBlocksError(
+                f"request {request_id!r}: {wneed} more window-pool "
+                f"block(s) needed for length {new_len}, "
+                f"{len(self._wfree)} free")
+        if wneed:
+            self._grow_window(request_id, wneed)
         for i in promo_idxs:
             self._promote_entry(request_id, i, False)
         for i in cow_idxs:
@@ -851,6 +978,7 @@ class BlockManager:
         block references released; idempotent for unknown ids (a request
         preempted before admission owns none)."""
         self.free_host(request_id)
+        self._free_window_and_slot(request_id)
         table = self._tables.pop(request_id, None)
         if table is None:
             return 0
@@ -991,6 +1119,20 @@ class BlockManager:
     def check_invariants(self):
         """Exact free-block accounting; raises AssertionError on any
         violation (used by the randomized-sequence tests every step)."""
+        wlive = [b for rid, t in self._wtables.items()
+                 for b in t[self._wfirst[rid]:]]
+        assert all(b >= 0 for b in wlive) and all(
+            b == -1 for rid, t in self._wtables.items()
+            for b in t[:self._wfirst[rid]]), \
+            "window table: a live entry released or a released one live"
+        assert sorted(wlive + list(self._wfree)) == list(
+            range(self.window_blocks)), "window-pool block leak or double"
+        assert sorted(list(self._slots.values()) + self._slot_free) == \
+            list(range(self.state_slots)), "state slot leak or double"
+        assert set(self._slots) <= set(self._tables) and (
+            not self.window_blocks
+            or set(self._wtables) == set(self._tables)), \
+            "window table or state slot without a main table"
         owned = [b for t in self._tables.values() for b in t]
         virt_owned = [self.host_slot_of(b) for b in owned
                       if self.is_host_entry(b)]

@@ -129,6 +129,10 @@ class EngineConfig:
 
     block_size: int = 16
     num_blocks: Optional[int] = None
+    # the window layers' pool of a model whose ``cache_spec`` has any
+    # (ignored otherwise). None sizes it so every sequence can hold its
+    # window plus one step's chunk: release keeps it there.
+    num_window_blocks: Optional[int] = None
     max_num_seqs: int = 8
     # tensor parallelism: shard weights (attention heads, MLP hidden)
     # and the paged KV caches (kv-head dim) over a 1-D "tp" mesh of
@@ -484,6 +488,15 @@ class LLMEngine:
             self.cfg.num_host_blocks = max(self.cfg.num_host_blocks,
                                            want_host)
 
+        # -- what the model says it caches. A model with ``cache_spec``
+        # (models/phi4flash.py) gets exactly that: separate pools and
+        # per-sequence state slots, one pytree through the step. A
+        # model that says nothing gets the stacked K/V pair below.
+        spec = model.cache_spec() if hasattr(model, "cache_spec") else None
+        self._cache_spec = spec
+        if spec is not None:
+            self._refuse_for_cache_spec()
+
         # -- ragged-path resolution (model-dependent, so not in
         # EngineConfig.__post_init__): ragged auto-enables on models
         # exposing forward_ragged; chunked prefill is inseparable from
@@ -515,7 +528,7 @@ class LLMEngine:
         if self.cfg.chunked_prefill is None:
             self.cfg.chunked_prefill = self.cfg.ragged
         if self.cfg.prefix_cache is None:
-            self.cfg.prefix_cache = self.cfg.ragged
+            self.cfg.prefix_cache = self.cfg.ragged and spec is None
         if self._tiered and not self.cfg.prefix_cache:
             raise ValueError(
                 "kv_tiers needs prefix_cache (the trie is what spans "
@@ -600,11 +613,29 @@ class LLMEngine:
         # cache layout: (L, NB, BS, KH, D) with the kv-head dim split
         self.kv_layout = Layout.tp_sharded(5, 3, tp)
 
+        pools = {}
+        if spec is not None:
+            kinds = [lay["kind"] for lay in spec["layers"]]
+            windows = {lay["window"] for lay in spec["layers"]
+                       if lay["kind"] == "window"}
+            if len(windows) > 1:
+                raise ValueError(f"one window pool, one window: the "
+                                 f"model's layers state {sorted(windows)}")
+            if windows:
+                w = windows.pop()
+                if self.cfg.num_window_blocks is None:
+                    self.cfg.num_window_blocks = self.cfg.max_num_seqs * min(
+                        cdiv(w + self._ragged_T, self.cfg.block_size) + 2,
+                        self.max_blocks_per_seq)
+                pools.update(window_blocks=self.cfg.num_window_blocks,
+                             window=w)
+            if "state" in kinds:
+                pools.update(state_slots=self.cfg.max_num_seqs)
         self.block_manager = BlockManager(
             self.cfg.num_blocks, self.cfg.block_size,
             num_host_blocks=self.cfg.num_host_blocks,
             enable_prefix_cache=self.cfg.prefix_cache,
-            kv_layout=self.kv_layout, tiered=self._tiered)
+            kv_layout=self.kv_layout, tiered=self._tiered, **pools)
         self._swapper = _KVSwapper(self)
         self._kvtier = (TieredKVStore(self, self._tiers_cfg)
                         if self._tiered else None)
@@ -632,12 +663,21 @@ class LLMEngine:
             from paddle_tpu.core.dtype import to_jax
 
             cache_dtype = to_jax(self.cfg.dtype)
-        else:
+        elif spec is None:
             cache_dtype = model.lm_head.weight._data.dtype
-        shape = (mcfg.num_hidden_layers, self.cfg.num_blocks,
-                 self.cfg.block_size, kh, hd)
-        self._kcs = jnp.zeros(shape, cache_dtype)
-        self._vcs = jnp.zeros(shape, cache_dtype)
+        else:
+            cache_dtype = next(iter(model.parameters()))._data.dtype
+        if spec is None:
+            shape = (mcfg.num_hidden_layers, self.cfg.num_blocks,
+                     self.cfg.block_size, kh, hd)
+            self._kcs = jnp.zeros(shape, cache_dtype)
+            self._vcs = jnp.zeros(shape, cache_dtype)
+            self._cache = None
+        else:
+            # separate arrays, updated in place by the donated step and
+            # never restacked; the stacked pair does not exist
+            self._kcs = self._vcs = None
+            self._cache = self._build_cache(spec, cache_dtype)
         if tp > 1:
             self._cache_sharding = self.kv_layout.named_sharding(
                 self._tp_devices)
@@ -684,7 +724,7 @@ class LLMEngine:
 
         apply, (self._pnames, self._params), (_, self._buffers) \
             = functionalize(
-            model.forward_paged)
+            model.forward_paged if spec is None else model.forward_ragged)
         if tp > 1:
             # commit every weight to its TP placement IN PLACE on the
             # model (the engine owns serving weights): column-parallel
@@ -746,9 +786,28 @@ class LLMEngine:
             step_outs = None
         self._jstep = jax.jit(
             raw_step, donate_argnums=(4, 5) if donate else (),
-            out_shardings=step_outs)
+            out_shardings=step_outs) if spec is None else None
 
-        if self._ragged:
+        if spec is not None:
+            def raw_step_ragged_spec(param_datas, buffer_datas, key, ids,
+                                     cache, tables, bt, cu, ctx, nseq,
+                                     skeys, stemp, stopk, stopp, sdraft,
+                                     sndraft):
+                # the Llama step's argument positions (ids 3, bt 6, cu 7,
+                # ctx 8, nseq 9); 4 is the whole cache, donated, 5 the
+                # step's other tables (window block table, state slots)
+                (logits, cache2), _ = apply(
+                    param_datas, buffer_datas, key, ids, cache, tables,
+                    bt, cu, ctx, nseq)
+                packed, finite = pack_sampled(
+                    logits[:, None, :], sdraft, sndraft, skeys, stemp,
+                    stopk, stopp)
+                return packed, finite, cache2
+
+            self._jstep_ragged = jax.jit(
+                raw_step_ragged_spec,
+                donate_argnums=(4,) if donate else ())
+        elif self._ragged:
             spec_r = self._spec_R
             if spec_r > 1:
                 apply_r, _, _ = functionalize(model.forward_ragged_multi)
@@ -887,6 +946,75 @@ class LLMEngine:
             self._watchdog = None
 
         self.metrics = ServingMetrics(self)
+
+    # -- a model that says what it caches ---------------------------------
+    _SPEC_REFUSED = (
+        ("kv_tiers", lambda c: c.kv_tiers not in (None, False),
+         "a demoted block has no recurrent state to come back to"),
+        ("swap_mode='host'", lambda c: c.swap_mode == "host",
+         "the host pool holds K/V blocks, not state slots or a window "
+         "table; preemption is by recompute from zero state"),
+        ("draft_model", lambda c: c.draft_model is not None,
+         "a rejected draft token cannot be taken back out of a "
+         "recurrent state"),
+        ("tp_degree > 1", lambda c: c.tp_degree > 1,
+         "the state slots and the window pool have no TP layout yet"),
+        ("prefix_cache=True", lambda c: bool(c.prefix_cache),
+         "a shared K/V block does not carry the recurrent state at its "
+         "boundary (it needs snapshots: ROADMAP.md)"),
+        ("ragged=False", lambda c: c.ragged is False,
+         "such a model has only the ragged step"),
+    )
+
+    def _refuse_for_cache_spec(self, method: Optional[str] = None):
+        """A model with ``cache_spec`` (window pools, recurrent state)
+        cannot honour these; each is refused by name, the knobs at
+        construction and the methods when called."""
+        if method is not None:
+            if self._cache_spec is not None:
+                raise ValueError(
+                    f"{method} is refused for a model with cache_spec(): "
+                    f"its K/V blocks mean nothing without the recurrent "
+                    f"state and the window table that go with them, and "
+                    f"those have no wire or host format yet")
+            return
+        for name, on, why in self._SPEC_REFUSED:
+            if on(self.cfg):
+                raise ValueError(
+                    f"{name} is refused for a model with cache_spec() "
+                    f"({type(self.model).__name__}): {why}")
+
+    def _build_cache(self, spec, dtype):
+        """The cache a model's ``cache_spec()`` describes, one entry per
+        layer: a (K, V) pair of pools for ``full`` (``num_blocks``) and
+        ``window`` (``num_window_blocks``) layers, a dict of
+        ``(max_num_seqs + 1, *shape)`` state arrays (the last slot is
+        scratch, for padding rows) for ``state`` layers, None for layers
+        that cache nothing of their own."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.core.dtype import to_jax
+
+        def pool(blocks):
+            shape = (blocks, self.cfg.block_size, *spec["kv_shape"])
+            return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+        cache = []
+        for lay in spec["layers"]:
+            if lay["kind"] == "full":
+                cache.append(pool(self.cfg.num_blocks))
+            elif lay["kind"] == "window":
+                cache.append(pool(self.cfg.num_window_blocks))
+            elif lay["kind"] == "state":
+                cache.append({
+                    name: jnp.zeros((self.cfg.max_num_seqs + 1, *shape),
+                                    to_jax(dt) if dt else dtype)
+                    for name, (shape, dt) in lay["shapes"].items()})
+            elif lay["kind"] in ("none", "reads"):
+                cache.append(None)
+            else:
+                raise ValueError(f"unknown cache kind {lay['kind']!r}")
+        return cache
 
     # -- request lifecycle ----------------------------------------------
     def add_request(self, request_id, prompt_ids: Sequence[int] = None,
@@ -1037,6 +1165,7 @@ class LLMEngine:
         tokens, no device table). Sources either a live request's table
         or the drain-parked snapshot of one a drain sweep already
         aborted. Read-only and idempotent — safe under RPC retry."""
+        self._refuse_for_cache_spec("export_kv")
         covered, table = 0, None
         req = self._requests.get(request_id)
         if req is not None and req.num_cached > 0 \
@@ -1089,6 +1218,7 @@ class LLMEngine:
             raise ValueError(
                 "KV import needs chunked prefill (the imported request "
                 "resumes as a mid-context continuation row)")
+        self._refuse_for_cache_spec("import_kv")
         if self._draining:
             raise ValueError("engine is draining")
         if request_id in self._requests:
@@ -1190,6 +1320,7 @@ class LLMEngine:
         corrupt). Returns ``None`` when the hash is unknown or its
         chain was partially evicted since advertisement — staleness is
         a miss, not an error. Read-only and idempotent (RPC-retryable)."""
+        self._refuse_for_cache_spec("export_prefix")
         if not self.cfg.prefix_cache:
             return None
         resolved = self.block_manager.prefix_blocks_by_hash(chain_hash)
@@ -1227,6 +1358,7 @@ class LLMEngine:
         death): geometry/checksum mismatch, draining, or a pool whose
         free headroom is all REGISTERED content — a proactive ship must
         never evict resident cache to make room for speculative bytes."""
+        self._refuse_for_cache_spec("import_prefix")
         if not self.cfg.prefix_cache:
             raise ValueError("prefix import needs prefix caching on")
         if self._draining:
@@ -1303,6 +1435,7 @@ class LLMEngine:
 
     # -- tiered sessions (park / resume) ----------------------------------
     def _require_tiers(self):
+        self._refuse_for_cache_spec("sessions (park/resume/adopt)")
         if self._kvtier is None:
             raise ValueError(
                 "kv_tiers is off — build the engine with "
@@ -1733,6 +1866,12 @@ class LLMEngine:
                     emitted=sum(1 for o in outputs[n_before:]
                                 if o.token is not None),
                     finished=sum(1 for o in outputs[n_before:] if o.finished))
+                if self.block_manager.window_blocks:
+                    # blocks wholly behind a row's window go back to the
+                    # window pool (a finished row's went with its table)
+                    post_span.set(window_blocks_released=sum(
+                        self.block_manager.release_behind_window(
+                            r.request_id, r.num_cached) for r in reqs))
                 if self._draining and not self.scheduler.has_unfinished():
                     self._finish_drain()  # this step emptied the engine
                 return outputs
@@ -1775,6 +1914,8 @@ class LLMEngine:
                 bt[i, :len(table)] = table
             cu[len(reqs) + 1:] = off
             arrays = (ids, bt, cu, ctx, np.int32(len(reqs)))
+            if self._cache is not None:
+                arrays += (self._spec_tables(reqs, S),)
             padded = 0
             # the mixed batch's split: prompt tokens prefilled this
             # step vs decode rows (feeds occupancy + prompt
@@ -1856,8 +1997,35 @@ class LLMEngine:
             rows=len(reqs), q_tokens=int(sum(n_run)),
             ctx_tokens=ctx_tokens, prefill_rows=len(reqs) - decode_rows,
             decode_rows=decode_rows)
+        if self._cache is not None:
+            first = sum(1 for r in reqs if r.num_cached == 0)
+            composition.update(
+                state_rows=len(reqs) - first, first_rows=first,
+                win_blocks=self.block_manager.num_used_window_blocks,
+                full_blocks=self.block_manager.num_used_blocks,
+                cross_rows=len(reqs))
         return (reqs, n_run, arrays, B, S, R, sampling_arrays, padded,
                 prompt_toks, composition)
+
+    def _spec_tables(self, reqs, S):
+        """The step's tables beside the main block table, for a model
+        with ``cache_spec``: each row's window-pool table (logical
+        indexing, -1 behind the window) and its state slot (padding rows
+        get the scratch slot)."""
+        bm = self.block_manager
+        tables = {}
+        if bm.window_blocks:
+            wbt = np.full((S, self.max_blocks_per_seq), -1, np.int32)
+            for i, r in enumerate(reqs):
+                table = bm.window_table(r.request_id)
+                wbt[i, :len(table)] = table
+            tables["window"] = wbt
+        if bm.state_slots:
+            slots = np.full((S,), self.cfg.max_num_seqs, np.int32)
+            for i, r in enumerate(reqs):
+                slots[i] = bm.state_slot(r.request_id)
+            tables["slots"] = slots
+        return tables
 
     def _propose_drafts(self):
         """One draft-model pass proposing ``num_spec_tokens`` greedy
@@ -1927,7 +2095,7 @@ class LLMEngine:
         (rows, query and context tokens, the prefill/decode split): the
         attributes of each attempt's ``engine.dispatch`` span."""
         if self._ragged:
-            ids, bt, cu, ctx, nseq = arrays
+            ids, bt, cu, ctx, nseq, *tables = arrays
             tag = f"serving.ragged[T={B},S={S}]"
             shape_key = ("ragged", B, S)
         else:
@@ -1935,6 +2103,7 @@ class LLMEngine:
             tag = f"serving.{kind}[B={B},S={S}]"
             shape_key = (kind, B, S)
         cold = shape_key not in self._seen_shapes
+        spec_cache = self._cache is not None
         attempt = 0
         while True:
             eid = 0
@@ -1952,7 +2121,13 @@ class LLMEngine:
                 faults.fire(faults.SERVING_STEP)  # slow/raise/sigterm point
                 with span("engine.dispatch", cold=int(cold),
                           attempt=attempt, **composition):
-                    if self._ragged and self._kvtier is not None:
+                    if spec_cache:
+                        packed, finite, cache = self._jstep_ragged(
+                            [p._data for p in self._params],
+                            [b._data for b in self._buffers],
+                            self._key, ids, self._cache, *tables, bt, cu,
+                            ctx, nseq, *sampling_arrays)
+                    elif self._ragged and self._kvtier is not None:
                         packed, finite, kcs, vcs = self._jstep_ragged(
                             [p._data for p in self._params],
                             [b._data for b in self._buffers],
@@ -2008,7 +2183,10 @@ class LLMEngine:
             break
         # commit only after a fully-successful dispatch+fetch, so a
         # retried attempt re-reads the PRE-failure cache state
-        self._kcs, self._vcs = kcs, vcs
+        if spec_cache:
+            self._cache = cache
+        else:
+            self._kcs, self._vcs = kcs, vcs
         self._seen_shapes.add(shape_key)
         with self._hung_lock:
             tags, self._hung_tags = self._hung_tags, None

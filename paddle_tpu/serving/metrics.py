@@ -266,6 +266,16 @@ class ServingMetrics:
                     eng.block_manager.utilization(), 4),
                 "kv_blocks_total": eng.block_manager.num_blocks,
                 "kv_host_blocks_total": eng.block_manager.num_host_blocks,
+                # live blocks of the full pool and of the window pool,
+                # window blocks handed back behind the window, state
+                # slots held (the last three are 0 for a model without
+                # cache_spec)
+                "kv_blocks_full": eng.block_manager.num_used_blocks,
+                "kv_blocks_window":
+                    eng.block_manager.num_used_window_blocks,
+                "window_blocks_released":
+                    eng.block_manager.num_window_blocks_released,
+                "state_slots_in_use": eng.block_manager.state_slots_in_use,
             })
             # resilience counters (what BENCH_serving trends): swap
             # traffic, TTL expiry, admission rejects, step retries,

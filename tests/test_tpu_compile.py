@@ -104,6 +104,36 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, h, kh, t, s, nb):
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
+@pytest.mark.parametrize("t,window,write", [
+    (512, 512, True), (512, None, True), (32, None, False)],
+    ids=["window", "full", "cross_read_only"])
+def test_ragged_kernel_compiles_folded_for_v5e(one_chip, t, window, write):
+    """The three attention calls of the `phi4-mini-flash.reason-c32` step:
+    40 padded query heads of 128 lanes over 10 K/V pairs in a FOLDED cache
+    (blocks, 16, 1280) — a 4-D cache with 10 heads is refused by Mosaic
+    (the second-minor dim must be a multiple of 8) — with the 512-token
+    window, without, and the read-only call of 32 rows."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    h, lanes, s, mb, nb = 40, 1280, 32, 256, 2112
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    new = (sds((t, 10, D), bf16),) * 2 if write else ()
+
+    def call(q, *rest):
+        k_new, v_new = rest[:2] if write else (None, None)
+        return ragged_paged_attention(
+            q, k_new, v_new, *rest[len(new):], impl="pallas", window=window,
+            scale=0.125)
+
+    compiled = jax.jit(call).lower(
+        sds((t, h, D), bf16), *new, sds((nb, BS, lanes), bf16),
+        sds((nb, BS, lanes), bf16), sds((s, mb), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+
+
 def test_ragged_kernel_compiles_head_sharded_over_four_chips(topo):
     """TP serving: GSPMD refuses to partition a Mosaic kernel, so under a
     declared kernel mesh the op runs per head-shard inside shard_map —
