@@ -9,7 +9,18 @@ host — never the B×vocab logits. Three layers:
   (``temperature <= 0``) become an EXACT one-hot at ``argmax(logits)``
   (first-occurrence tie-breaking, matching ``np.argmax``), which keeps
   the greedy path bit-identical to the host oracle and lets one code
-  path serve mixed greedy/sampled batches.
+  path serve mixed greedy/sampled batches. The cut-offs are VALUES
+  found by threshold selection (:func:`_select`), not positions in a
+  sorted row: the k-th largest probability is the largest bit pattern
+  that k entries reach, the nucleus boundary the largest one whose
+  entries-at-or-above hold ``top_p`` of the mass, and of the entries
+  equal to the boundary the first by index stay. Nothing sorts,
+  argsorts or permutes the vocabulary (a sort of 32 x 200,064 floats
+  was three quarters of a decode step); the rule, ties included, and
+  so the token streams are the sort-based filter's, which lives on as
+  the tests' reference (``tests/refs/sampling_sort_ref.py``). What may
+  differ is float32 summation order: an entry of the boundary's tie
+  run whose preceding mass lies within a few 1e-6 of ``top_p``.
 * :func:`sample_tokens` — one categorical draw per slot from its own
   PRNG key (the per-request stream the engine persists), returning the
   advanced keys alongside the tokens.
@@ -44,15 +55,38 @@ import jax.numpy as jnp
 __all__ = ["filtered_probs", "sample_tokens", "sample_or_verify"]
 
 
+def _select(bits, weights, target, width=31):
+    """Per row, the largest ``t`` below ``2**width`` with ``sum(weights
+    where bits >= t) >= target`` (0 where no ``t`` reaches it). ``t`` is
+    built from its top bit down, one compare-and-reduce pass over (S, V)
+    a bit: a fixed trip count whatever the data (NaNs included), no
+    ordering of the row. Non-negative floats order as their uint32 bit
+    patterns do, so over a row of probabilities ``t`` is one of them."""
+    def one_bit(i, t):
+        cand = t | (jnp.uint32(1 << (width - 1)) >> i.astype(jnp.uint32))
+        reached = jnp.sum(jnp.where(bits >= cand[:, None], weights, 0),
+                          axis=-1)
+        return jnp.where(reached >= target, cand, t)
+
+    return jax.lax.fori_loop(
+        0, width, one_bit, jnp.zeros(bits.shape[:1], jnp.uint32))
+
+
+def _bits(p):
+    return jax.lax.bitcast_convert_type(p, jnp.uint32)
+
+
 def filtered_probs(logits, temperature, top_k, top_p):
     """Per-row sampling distributions: ``logits`` (S, V); ``temperature``
     (S,) float (``<= 0`` = greedy one-hot); ``top_k`` (S,) int (0 = off);
     ``top_p`` (S,) float (1.0 = off). Returns (S, V) probabilities.
 
-    Mirrors the engine's host oracle (``LLMEngine._sample``) transform
-    order — temperature softmax, then top-k renormalized, then the
-    smallest nucleus with cumulative mass >= top_p — in f32 (the oracle
-    runs f64; parity is distributional, pinned statistically)."""
+    The host oracle's (``LLMEngine._sample``) rule in f32 — temperature
+    softmax; top-k keeps every ``p >= kth`` (all ties at the k-th
+    largest), renormalized; top-p keeps the smallest stable
+    descending-order prefix whose mass reaches ``top_p`` (of equal
+    probabilities the lower index first), renormalized — with both
+    cut-offs found by :func:`_select`, never by ordering the row."""
     lg = logits.astype(jnp.float32)
     v = lg.shape[-1]
     greedy = temperature <= 0.0
@@ -61,22 +95,30 @@ def filtered_probs(logits, temperature, top_k, top_p):
     x = x - jnp.max(x, axis=-1, keepdims=True)
     p = jnp.exp(x)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
-    # top-k: zero everything below the k-th largest probability
-    desc = jnp.sort(p, axis=-1)[:, ::-1]
+    # top-k: kth = the largest value that k entries reach (k = V, the
+    # row's minimum, when top-k is off)
     k_eff = jnp.where((top_k > 0) & (top_k < v), top_k, v)
-    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
-    p = jnp.where(p >= kth, p, 0.0)
+    bits = _bits(p)
+    kth = _select(bits, 1, k_eff)
+    p = jnp.where(bits >= kth[:, None], p, 0.0)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
-    # top-p: keep the smallest descending-order prefix whose cumulative
-    # mass reaches top_p (same keep_n = searchsorted(csum, top_p) + 1
-    # rule as the host oracle)
-    order = jnp.argsort(-p, axis=-1)
-    sp = jnp.take_along_axis(p, order, axis=-1)
-    csum = jnp.cumsum(sp, axis=-1)
-    keep_n = jnp.sum((csum < top_p[:, None]).astype(jnp.int32),
-                     axis=-1) + 1
-    rank = jnp.argsort(order, axis=-1)
-    p = jnp.where(rank < keep_n[:, None], p, 0.0)
+    # top-p: the boundary value t* is the largest whose entries-at-or-
+    # above hold top_p of the mass; everything above it stays, and of
+    # the run equal to it the first m by index, m = what the mass above
+    # still lacks, in entries of t*
+    bits = _bits(p)
+    cut = _select(bits, p, top_p)
+    above = bits > cut[:, None]
+    tie = bits == cut[:, None]
+    lacks = top_p - jnp.sum(jnp.where(above, p, 0.0), axis=-1)
+    n_tie = jnp.sum(tie, axis=-1)
+    m = jnp.ceil(lacks / jax.lax.bitcast_convert_type(cut, jnp.float32))
+    m = jnp.clip(m, 1.0, n_tie.astype(jnp.float32)).astype(jnp.int32)
+    # the m-th tie by index = the (n_tie - m + 1)-th from the top
+    pos = jnp.where(tie, jnp.arange(1, v + 1, dtype=jnp.uint32), 0)
+    last = _select(pos, 1, n_tie - m + 1, width=v.bit_length())
+    keep = above | (tie & (pos <= last[:, None])) | (top_p >= 1.0)[:, None]
+    p = jnp.where(keep, p, 0.0)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     onehot = jax.nn.one_hot(jnp.argmax(lg, axis=-1), v, dtype=p.dtype)
     return jnp.where(greedy[:, None], onehot, p)

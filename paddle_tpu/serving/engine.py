@@ -1988,7 +1988,9 @@ class LLMEngine:
         else:
             R = 1
             sampling_arrays = (skeys, stemp, stopk, stopp)
-        if any(r.sampling.temperature > 0.0 for r in reqs):
+        # rows the sampler's filter acts on; greedy rows are a one-hot
+        sampled_rows = sum(1 for r in reqs if r.sampling.temperature > 0.0)
+        if sampled_rows:
             self.num_sampled_steps += 1
         # the step's composition, counted once: the dispatch span's
         # attributes and record_step get the same values
@@ -1996,7 +1998,7 @@ class LLMEngine:
             step=self.metrics.engine_steps, kind=batch.kind,
             rows=len(reqs), q_tokens=int(sum(n_run)),
             ctx_tokens=ctx_tokens, prefill_rows=len(reqs) - decode_rows,
-            decode_rows=decode_rows)
+            decode_rows=decode_rows, sampled_rows=sampled_rows)
         if self._cache is not None:
             first = sum(1 for r in reqs if r.num_cached == 0)
             composition.update(

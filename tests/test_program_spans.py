@@ -123,6 +123,31 @@ def engine_run(tiny_model, tmp_path_factory):
     return eng, tr, counted
 
 
+def test_dispatch_counts_the_rows_the_filter_acts_on(tiny_model):
+    """``sampled_rows`` = the rows of the dispatch with a temperature
+    above zero, as the compiled step was handed them."""
+    eng = _engine(tiny_model)
+    handed = []
+    real = eng._jstep_ragged
+
+    def spy(*args):
+        handed.append(int((np.asarray(args[11]) > 0).sum()))   # stemp
+        return real(*args)
+
+    eng._jstep_ragged = spy
+    prof = Profiler(record_op_events=False).start()
+    try:
+        for rid, prompt, sp in _requests(tiny_model.config.vocab_size):
+            eng.add_request(rid, prompt, sampling=sp)
+        _drain(eng)
+    finally:
+        prof.stop()
+    got = [(e["args"]["sampled_rows"], e["args"]["rows"])
+           for e in prof.host_events if e["name"] == "engine.dispatch"]
+    assert [s for s, _ in got] == handed
+    assert any(0 < s < rows for s, rows in got)     # mixed with greedy rows
+
+
 # -- (a) the primitive ------------------------------------------------------
 def test_nested_spans_keep_parent_id_and_args_on_two_threads(tmp_path):
     def worker():
@@ -422,6 +447,16 @@ def _engine_step_text(model):
     return real.lower(*seen[0]).as_text(debug_info=True)
 
 
+def _hybrid_engine_step_text(_):
+    from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                             Phi4FlashForCausalLM)
+
+    paddle.seed(0)
+    model = Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
+    model.eval()
+    return _engine_step_text(model)
+
+
 def _train_step_text(_):
     cfg = LlamaConfig.tiny(use_flash_attention="interpret")
     paddle.seed(0)
@@ -439,7 +474,8 @@ def _train_step_text(_):
 @pytest.mark.parametrize("text_of,scopes", [
     (_engine_step_text, ("attention", "kv_update", "sampler")),
     (_train_step_text, ("attention", "lm_head_loss", "optimizer")),
-], ids=["engine_ragged_step", "train_step"])
+    (_hybrid_engine_step_text, ("attention", "kv_update", "sampler")),
+], ids=["engine_ragged_step", "train_step", "engine_hybrid_step"])
 def test_device_regions_are_named_in_the_lowered_step(tiny_model, text_of,
                                                       scopes):
     text = text_of(tiny_model)
