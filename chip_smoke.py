@@ -4,9 +4,9 @@
     python chip_smoke.py --chips 4             the two cross-chip paths only
     python chip_smoke.py --rehearse [...]      tiny widths on the CPU, no chip
 
-Default run, one chip, GPT "1B" widths as bench.py builds them (vocab 32000,
-hidden 2048, intermediate 5632, 16 layers, 16/16 heads, head dim 128, bf16,
-seq 2048), random weights from ``--seed``:
+Default run, one chip, GPT "1B" widths (vocab 32000, hidden 2048,
+intermediate 5632, 16 layers, 16/16 heads, head dim 128, bf16, seq 2048),
+random weights from ``--seed``:
 
 * train phase — ``DataLoader(use_device_prefetch=True)`` ->
   ``paddle.jit.TrainStep`` (AdamW, default donation), batch 4 x seq 2048, a

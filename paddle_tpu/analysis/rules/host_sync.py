@@ -18,7 +18,7 @@ and ships a buffer to host. Two placements are flagged:
   B-sized token vector) — suppress with a reason when they are.
 
 The dispatch-result placement tracks results ACROSS methods of a class:
-``self._last = self._jstep(...)`` (directly, or via a local name still
+``self._last = self._step_fn(...)`` (directly, or via a local name still
 carrying the dispatch result) marks ``self._last`` dispatch-carrying
 class-wide, so ``np.asarray(self._last)`` in a different method is
 flagged too. An attribute REASSIGNED from anything non-dispatch
@@ -109,7 +109,7 @@ def _dispatch_result_events(module, fdef):
                 for name in target_names(tgt):
                     book.setdefault(name, []).append(node.lineno)
         elif isinstance(node, (ast.AugAssign, ast.AnnAssign)):
-            # `out: jax.Array = self._jstep(...)` binds like an Assign
+            # `out: jax.Array = self._step_fn(...)` binds like an Assign
             value = getattr(node, "value", None)
             is_dispatch = isinstance(value, ast.Call) and \
                 module.jit_bindings.lookup(value.func) is not None
@@ -293,7 +293,7 @@ def check(module) -> List[Finding]:
                     f"bug class); keep it on device or fold the "
                     f"consumer into the compiled step"))
     # placement 2b: dispatch results parked on self attributes and
-    # fetched from a DIFFERENT method (`self._last = self._jstep(...)`
+    # fetched from a DIFFERENT method (`self._last = self._step_fn(...)`
     # in step(), `np.asarray(self._last)` in result()). Method call
     # order is unknowable statically, so an attribute reassigned from
     # anything non-dispatch anywhere in the class clears the bind.

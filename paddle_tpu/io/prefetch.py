@@ -1,6 +1,6 @@
 """Device-resident async input pipeline.
 
-The profiler's phase breakdown on the 1B-GPT config (BENCH_r05) showed
+The profiler's phase breakdown on the 1B-GPT config (July-2026 chip run) showed
 more device time in copies than in compute (copy_frac 0.545): the
 compiled step was waiting on host->device transfers that could have
 overlapped the previous step, and each batch array paid its own

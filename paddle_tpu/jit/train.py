@@ -441,7 +441,7 @@ class TrainStep:
         must not silently change semantics. This is the standard TPU pattern
         for host-latency-bound steps: a small model's ~1 ms step pays the
         host's dispatch cost every call, so k steps per dispatch raises
-        throughput by up to k× with identical numerics (BENCH_r05,
+        throughput by up to k× with identical numerics (July-2026 chip run,
         ResNet-50: 9,268 img/s at k=1 vs 36,314 at k=32). The reference's analog is
         the static-graph executor running the whole Program without
         returning to Python each op (SURVEY.md §3.3).

@@ -61,11 +61,6 @@ class LlamaConfig:
     recompute: bool = False
     tie_word_embeddings: bool = False
     dtype: str = "float32"
-    # serving tensor parallelism (LLMEngine tp_degree): the GQA
-    # head-packing in forward_paged groups heads per TP shard so the
-    # packed qkv stack stays shard-local under a tp-sharded head dim.
-    # Exact at any value — tp_degree=1 is the flat legacy packing.
-    tp_degree: int = 1
 
     @staticmethod
     def llama3_8b(**kw):
@@ -203,47 +198,6 @@ class LlamaAttention(nn.Layer):
         out = ops.reshape(out, [b, s, self.n_heads * self.head_dim])
         return self.o_proj(out)
 
-    def forward_paged(self, x, cos, sin, key_cache, value_cache,
-                      block_tables, seq_lens_encoder, seq_lens_decoder,
-                      seq_lens_this_time):
-        """Serving attention over the paged KV cache. ``x`` (B,S,h);
-        ``cos``/``sin`` (B,S,D) gathered at absolute token positions;
-        caches (num_blocks, block_size, KH, D). Returns
-        (out (B,S,h), key_cache', value_cache') — caches are returned
-        functionally (donated at the engine's jit boundary)."""
-        from paddle_tpu.incubate.nn import functional as F
-
-        b, s, _ = x.shape
-        q = ops.reshape(self.q_proj(x),
-                        [b, s, self.n_heads, self.head_dim])._data
-        k = ops.reshape(self.k_proj(x),
-                        [b, s, self.n_kv, self.head_dim])._data
-        v = ops.reshape(self.v_proj(x),
-                        [b, s, self.n_kv, self.head_dim])._data
-        q, k = _rope_apply_at(q, k, cos, sin)
-        tp = max(1, int(getattr(self.config, "tp_degree", 1)))
-        if self.n_kv != self.n_heads:
-            # pack K/V into the leading n_kv/tp slots of EACH TP head
-            # group's H/tp-wide stripe (the fused-projection layout
-            # block_multihead_attention unpacks with the same
-            # tp_degree) — per-group so the (B,S,3,H,D) stack never
-            # mixes head-dim shards; tp=1 is the flat legacy packing
-            hg, kg = self.n_heads // tp, self.n_kv // tp
-            pad = [(0, 0), (0, 0), (0, 0), (0, hg - kg), (0, 0)]
-            k = jnp.pad(k.reshape(b, s, tp, kg, self.head_dim), pad)
-            k = k.reshape(b, s, self.n_heads, self.head_dim)
-            v = jnp.pad(v.reshape(b, s, tp, kg, self.head_dim), pad)
-            v = v.reshape(b, s, self.n_heads, self.head_dim)
-        qkv = jnp.stack([q, k, v], axis=2)  # (B, S, 3, H, D)
-        out, kc, vc = F.block_multihead_attention(
-            qkv, key_cache, value_cache,
-            seq_lens_encoder=seq_lens_encoder,
-            seq_lens_decoder=seq_lens_decoder,
-            seq_lens_this_time=seq_lens_this_time,
-            block_tables=block_tables, tp_degree=tp)
-        out = ops.reshape(out, [b, s, self.n_heads * self.head_dim])
-        return self.o_proj(out), kc, vc
-
     def forward_ragged(self, x, cos, sin, key_cache, value_cache,
                        block_tables, cu_seqlens, context_lens, num_seqs):
         """Serving attention over a ragged-packed token stream. ``x``
@@ -314,23 +268,6 @@ class LlamaDecoderLayer(nn.Layer):
             out = sharding_constraint(out, {1: "mp"})
         return out
 
-    def forward_paged(self, x, positions, key_cache, value_cache,
-                      block_tables, seq_lens_encoder, seq_lens_decoder,
-                      seq_lens_this_time):
-        """One decoder block over the paged cache. ``positions`` (B,S)
-        absolute token positions (pad rows may hold anything in range —
-        the attention op masks them by ``seq_lens_this_time``)."""
-        pos = jnp.clip(positions, 0, self.rope_cos.shape[0] - 1)
-        cos = self.rope_cos._data[pos]   # (B, S, D)
-        sin = self.rope_sin._data[pos]
-        attn_out, kc, vc = self.self_attn.forward_paged(
-            self.input_layernorm(x), cos, sin, key_cache, value_cache,
-            block_tables, seq_lens_encoder, seq_lens_decoder,
-            seq_lens_this_time)
-        h = x + attn_out
-        out = h + self.mlp(self.post_attention_layernorm(h))
-        return out, kc, vc
-
     def forward_ragged(self, x, positions, key_cache, value_cache,
                        block_tables, cu_seqlens, context_lens, num_seqs):
         """One decoder block over the ragged stream. ``positions`` (T,)
@@ -369,40 +306,6 @@ class LlamaModel(nn.Layer):
             else:
                 x = layer(x, attn_mask)
         return self.norm(x)
-
-    def forward_paged(self, input_ids, key_caches, value_caches,
-                      block_tables, seq_lens_encoder, seq_lens_decoder,
-                      seq_lens_this_time):
-        """KV-cache forward over stacked per-layer paged caches
-        (L, num_blocks, block_size, KH, D). Per-sequence mode comes from
-        the length tensors (block_attention.py): ``seq_lens_decoder[b]>0``
-        = decode continuing a cached prefix, else prefill from 0.
-        Returns (hidden (B,S,h), key_caches', value_caches')."""
-        kcs = key_caches._data if isinstance(key_caches, Tensor) \
-            else jnp.asarray(key_caches)
-        vcs = value_caches._data if isinstance(value_caches, Tensor) \
-            else jnp.asarray(value_caches)
-        dec = (seq_lens_decoder._data if isinstance(seq_lens_decoder,
-                                                    Tensor)
-               else jnp.asarray(seq_lens_decoder)).reshape(-1)
-        if not isinstance(input_ids, Tensor):
-            input_ids = Tensor(input_ids)
-        s = input_ids.shape[1]
-        # absolute position of each new token: after the cached prefix
-        # (decode) or from 0 (prefill); pad rows land in-range and are
-        # masked out downstream by seq_lens_this_time
-        positions = (jnp.where(dec > 0, dec, 0)[:, None]
-                     + jnp.arange(s, dtype=jnp.int32)[None, :])
-        x = self.embed_tokens(input_ids)
-        new_k, new_v = [], []
-        for i, layer in enumerate(self.layers):
-            x, kc, vc = layer.forward_paged(
-                x, positions, kcs[i], vcs[i], block_tables,
-                seq_lens_encoder, seq_lens_decoder, seq_lens_this_time)
-            new_k.append(kc._data if isinstance(kc, Tensor) else kc)
-            new_v.append(vc._data if isinstance(vc, Tensor) else vc)
-        return (self.norm(x), jnp.stack(new_k, axis=0),
-                jnp.stack(new_v, axis=0))
 
     def forward_ragged(self, input_ids, key_caches, value_caches,
                        block_tables, cu_seqlens, context_lens, num_seqs):
@@ -481,27 +384,6 @@ class LlamaForCausalLM(nn.Layer):
     def criterion(config=None):
         return LlamaPretrainingCriterion(config)
 
-    def forward_paged(self, input_ids, key_caches, value_caches,
-                      block_tables, seq_lens_encoder, seq_lens_decoder,
-                      seq_lens_this_time):
-        """Serving step: paged forward + lm_head on each sequence's LAST
-        valid token (the sampling position). Returns
-        (logits (B, vocab), key_caches', value_caches'). This is the
-        function ``paddle_tpu.serving.LLMEngine`` compiles as its
-        prefill/decode step."""
-        h, kcs, vcs = self.llama.forward_paged(
-            input_ids, key_caches, value_caches, block_tables,
-            seq_lens_encoder, seq_lens_decoder, seq_lens_this_time)
-        now = (seq_lens_this_time._data
-               if isinstance(seq_lens_this_time, Tensor)
-               else jnp.asarray(seq_lens_this_time)).reshape(-1)
-        hd = h._data if isinstance(h, Tensor) else h
-        b = hd.shape[0]
-        last = jnp.clip(now - 1, 0, hd.shape[1] - 1)
-        h_last = hd[jnp.arange(b), last]              # (B, hidden)
-        logits = self.lm_head(Tensor._from_data(h_last))
-        return logits, kcs, vcs
-
     def forward_ragged(self, input_ids, key_caches, value_caches,
                        block_tables, cu_seqlens, context_lens, num_seqs):
         """Ragged serving step: one unpadded forward over the packed
@@ -510,7 +392,7 @@ class LlamaForCausalLM(nn.Layer):
         discards the row). Returns (logits (S, vocab), key_caches',
         value_caches') — S is the fixed number of sequence slots, so a
         mixed prefill/decode continuous batch has exactly ONE compiled
-        shape (the bucket lattice collapses to this function)."""
+        shape."""
         h, kcs, vcs = self.llama.forward_ragged(
             input_ids, key_caches, value_caches, block_tables,
             cu_seqlens, context_lens, num_seqs)
@@ -558,8 +440,8 @@ class LlamaForCausalLM(nn.Layer):
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
                  top_k=0, use_cache=None):
         """Decode ``max_new_tokens`` continuations. ``use_cache`` routes
-        through the paged KV-cache serving engine (compiled prefill +
-        per-token decode; token-identical to the naive loop for greedy,
+        through the paged KV-cache serving engine (one compiled ragged
+        step per iteration; token-identical to the naive loop for greedy,
         pinned by tests/test_serving_engine.py). Default: the paged path
         for greedy decoding, the naive full-recompute loop otherwise
         (sampled decoding draws from the eager RNG stream, which the

@@ -14,7 +14,7 @@ prefetcher and user code; the device side delegates to ``jax.profiler``
 trace capture (xplane), the TPU's native tracer. ``Profiler.summary()``
 aggregates host scopes; ``benchmark()`` is the hapi throughput timer;
 ``estimate_mfu`` turns step flops + step time into the north-star MFU
-number (BASELINE gate #4).
+number.
 """
 from __future__ import annotations
 
@@ -455,8 +455,8 @@ class Profiler:
         (``copy.4``, ``copy-start.1``, ``all-gather-done``). The chip
         names an op by its whole instruction (``%fusion.6 = f32[..]
         fusion(.. %copy.3), kind=kLoop``), so a substring rule files
-        every fusion that reads a copy under copies (BENCH_r05's
-        ``copy_frac`` 0.545)."""
+        every fusion that reads a copy under copies (the July-2026 chip
+        run's ``copy_frac`` 0.545)."""
         family = re.sub(r"[.\d]+$", "",
                         op_name.split(" = ")[0].strip().lstrip("%")).lower()
 
@@ -632,8 +632,7 @@ def device_phases(step_fn: Optional[Callable] = None, *, steps: int = 3,
     * ``device_phases(fn, steps=3)`` — call ``fn()`` ``warmup`` times
       un-traced (compile outside the measured window), then ``steps``
       times under a fresh device trace, sync the last result, and return
-      the breakdown. This is what ``bench.py`` reports per config: the
-      ``copy_frac`` it returns is the number the input-pipeline work
+      the breakdown. The ``copy_frac`` it returns is the number the input-pipeline work
       (donated train-step buffers, ``io.DevicePrefetcher``) is driving
       down.
     * ``device_phases(trace_dir=...)`` — parse the newest xplane trace
@@ -679,7 +678,7 @@ def device_phases(step_fn: Optional[Callable] = None, *, steps: int = 3,
 
 
 # ---------------------------------------------------------------------------
-# MFU (BASELINE gate #4: >=45% at 8B)
+# MFU (the north star's gate: >=45% at 8B)
 # ---------------------------------------------------------------------------
 _PEAK_BF16_FLOPS = {
     # per-chip peak dense bf16 FLOP/s (public spec sheets), matched as a

@@ -9,14 +9,12 @@ the TPU-native execution model:
   per-request block tables ("Ragged Paged Attention", arxiv 2604.15464:
   paged attention is the right TPU kernel shape), allocated by
   :class:`BlockManager` and attended through
-  ``incubate.nn.functional.block_multihead_attention``;
-* prefill and decode are the SAME compiled function. On models exposing
-  ``forward_ragged`` (the default path) every iteration is ONE unpadded
-  ragged step — a packed (T,) token stream over S sequence slots, so a
-  mixed chunked-prefill/decode continuous batch has exactly one
-  compiled shape and zero attention-path padding; the legacy bucketed
-  path (``ragged=False``) jits over a bounded set of padded shapes
-  (O(log max_len * log max_batch) compiles);
+  ``incubate.nn.functional.ragged_paged_attention``;
+* prefill and decode are the SAME compiled function: every iteration is
+  ONE unpadded ragged step of the model's ``forward_ragged`` — a packed
+  (T,) token stream over S sequence slots, so a mixed
+  chunked-prefill/decode continuous batch has exactly one compiled
+  shape and zero attention-path padding;
 * prompt prefixes are cached: full prompt blocks register in the
   BlockManager's content-keyed trie after the step that writes them,
   later requests share them by refcount, and the first divergent write
@@ -34,9 +32,8 @@ emit count, and the advanced per-request RNG key — never the B×vocab
 logits. Per-request RNG streams are threefry keys held on
 :class:`~paddle_tpu.serving.request.Request` and advanced a fixed
 number of splits per emitting step, so they stay reproducible across
-preemptions AND across fleet drain hand-off; the numpy sampler
-(``LLMEngine._sample``) survives as the CPU oracle the device path is
-pinned against. Speculative decoding rides the same machinery:
+preemptions AND across fleet drain hand-off. Speculative decoding rides
+the same machinery:
 ``EngineConfig(draft_model=, num_spec_tokens=k)`` proposes k greedy
 draft tokens per decode row (:class:`paddle_tpu.serving.spec.
 SpecDecoder`), the target verifies them in the SAME ragged step as
@@ -147,7 +144,6 @@ class EngineConfig:
     max_model_len: Optional[int] = None   # default: model max positions
     dtype: Optional[str] = None           # default: model param dtype
     donate_cache: Optional[bool] = None   # default: True off-CPU
-    min_prefill_bucket: int = 8
     # -- resilience -----------------------------------------------------
     # preemption: 'recompute' re-prefills an OOM victim from scratch;
     # 'host' spills its KV blocks to a host pool of num_host_blocks
@@ -159,20 +155,12 @@ class EngineConfig:
     # prefixes and parked sessions demote there instead of evicting,
     # admission counts reachable blocks across tiers, and
     # park_session/resume_session serve multi-turn traffic with zero
-    # re-prefill. Rides the ragged step (forces chunked prefill +
-    # prefix caching).
+    # re-prefill. Needs prefix caching (the trie is what spans tiers).
     kv_tiers: Optional[object] = None
-    # -- ragged serving hot path ----------------------------------------
-    # ragged=None auto-enables the unpadded single-shape step when the
-    # model exposes ``forward_ragged``: every iteration dispatches ONE
-    # compiled shape (token budget T x seq slots S), whatever mix of
-    # prefill chunks and decode rows fills it. chunked_prefill rides
-    # with it (a lone over-budget prompt must chunk to fit the fixed
-    # stream), as does prefix_cache (COW block sharing) unless
-    # explicitly disabled.
-    ragged: Optional[bool] = None
+    # prefix caching (COW block sharing over the content-keyed trie).
+    # None resolves to on, except for a model with ``cache_spec()``,
+    # which cannot share blocks (see ``_SPEC_REFUSED``).
     prefix_cache: Optional[bool] = None
-    chunked_prefill: Optional[bool] = None
     # admission control: reject (first-class 'rejected' output) when the
     # waiting queue is this deep, or when the estimated TTFT for a new
     # arrival exceeds the SLO (None = unbounded / no SLO)
@@ -181,7 +169,7 @@ class EngineConfig:
     # speculative decoding: a small draft model proposes num_spec_tokens
     # greedy continuations per decode row each iteration; the target
     # verifies them inside its one ragged step with fused rejection
-    # sampling. Both knobs or neither; requires the ragged path.
+    # sampling. Both knobs or neither.
     draft_model: Optional[object] = None
     num_spec_tokens: int = 0
     # drain: running requests get this long to finish after a drain
@@ -203,8 +191,6 @@ class EngineConfig:
             raise ValueError("tp_degree must be >= 1")
         if self.num_blocks is not None and self.num_blocks < 1:
             raise ValueError("num_blocks must be >= 1")
-        if self.min_prefill_bucket < 1:
-            raise ValueError("min_prefill_bucket must be >= 1")
         if self.max_model_len is not None and self.max_model_len < 1:
             raise ValueError("max_model_len must be >= 1")
         if self.swap_mode not in ("recompute", "host"):
@@ -434,14 +420,14 @@ class _KVSwapper:
 
 class LLMEngine:
     """Drive a :class:`~paddle_tpu.models.llama.LlamaForCausalLM` (or
-    any model exposing the same ``forward_paged`` contract) as a
+    any model exposing the same ``forward_ragged`` contract) as a
     continuously-batched token server::
 
         eng = LLMEngine(model, EngineConfig(max_num_seqs=8))
         eng.add_request("r0", prompt_ids, SamplingParams(max_new_tokens=16),
                         callback=lambda rid, tok, done: ...)
         while eng.has_unfinished():
-            for out in eng.step():   # one prefill OR decode iteration
+            for out in eng.step():   # one mixed prefill/decode iteration
                 if out.finished:
                     eng.release_request(out.request_id)
 
@@ -497,53 +483,17 @@ class LLMEngine:
         if spec is not None:
             self._refuse_for_cache_spec()
 
-        # -- ragged-path resolution (model-dependent, so not in
-        # EngineConfig.__post_init__): ragged auto-enables on models
-        # exposing forward_ragged; chunked prefill is inseparable from
-        # it (the fixed token stream cannot hold an over-budget prompt
-        # whole), prefix caching defaults on with it but may be opted
-        # out
-        if self.cfg.ragged is None:
-            self.cfg.ragged = hasattr(model, "forward_ragged")
-        elif self.cfg.ragged and not hasattr(model, "forward_ragged"):
+        if not hasattr(model, "forward_ragged"):
             raise ValueError(
-                "ragged=True needs a model exposing forward_ragged "
-                "(fall back to the bucketed path with ragged=False)")
-        # the bucketed forward_paged fallback is a degree-1, single-tier
-        # path; configurations that can only fail LATE (shape drift at
-        # the first sharded dispatch, a host-tier block table the padded
-        # op cannot index) are refused here instead
-        if not self.cfg.ragged:
-            if self.cfg.tp_degree > 1:
-                raise ValueError(
-                    f"tp_degree={self.cfg.tp_degree} needs the ragged "
-                    f"step — the bucketed forward_paged fallback "
-                    f"(ragged=False) is degree-1-only; use a model "
-                    f"exposing forward_ragged")
-            if self._tiered:
-                raise ValueError(
-                    "kv_tiers rides the ragged step (host-tier blocks "
-                    "are attended through the single-shape concat) — "
-                    "it cannot run with ragged=False")
-        if self.cfg.chunked_prefill is None:
-            self.cfg.chunked_prefill = self.cfg.ragged
+                f"{type(model).__name__} has no forward_ragged: the "
+                f"engine's one compiled step is the model's ragged "
+                f"forward over a packed token stream")
         if self.cfg.prefix_cache is None:
-            self.cfg.prefix_cache = self.cfg.ragged and spec is None
+            self.cfg.prefix_cache = spec is None
         if self._tiered and not self.cfg.prefix_cache:
             raise ValueError(
                 "kv_tiers needs prefix_cache (the trie is what spans "
                 "tiers) — do not disable it with tiering on")
-        if self.cfg.chunked_prefill != self.cfg.ragged:
-            raise ValueError(
-                "chunked_prefill rides the ragged step: a lone "
-                "over-budget prompt must chunk to fit the fixed token "
-                "stream, and the bucketed op cannot run a mid-prefill "
-                "continuation — set both or neither")
-        if self.cfg.prefix_cache and not self.cfg.ragged:
-            raise ValueError(
-                "prefix_cache needs the ragged path (the classic "
-                "scheduler never passes prompt tokens to allocate)")
-        self._ragged = bool(self.cfg.ragged)
         # the ONE compiled token-stream width: the configured budget,
         # clamped to the most tokens a full batch could ever schedule
         self._ragged_T = min(self.cfg.max_batched_tokens,
@@ -551,11 +501,6 @@ class LLMEngine:
 
         # -- speculative-decoding resolution ----------------------------
         if self.cfg.draft_model is not None:
-            if not self._ragged:
-                raise ValueError(
-                    "speculative decoding rides the ragged step (verify "
-                    "rows are mid-context multi-token rows) — it cannot "
-                    "run with ragged=False")
             dcfg = getattr(self.cfg.draft_model, "config", None)
             dv = getattr(dcfg, "vocab_size", None)
             if dv != mcfg.vocab_size:
@@ -604,10 +549,6 @@ class LLMEngine:
                     f"tp_degree {tp} needs {tp} devices, "
                     f"{len(devs)} visible")
             self._tp_devices: Optional[tuple] = tuple(devs[:tp])
-            # the model's GQA head-packing must group heads per TP
-            # shard so the packed qkv stack stays shard-local
-            if getattr(mcfg, "tp_degree", 1) != tp:
-                mcfg.tp_degree = tp
         else:
             self._tp_devices = None
         # cache layout: (L, NB, BS, KH, D) with the kv-head dim split
@@ -642,10 +583,7 @@ class LLMEngine:
         self.scheduler = Scheduler(
             self.block_manager,
             SchedulerConfig(max_num_seqs=self.cfg.max_num_seqs,
-                            max_batched_tokens=(
-                                self._ragged_T if self._ragged
-                                else self.cfg.max_batched_tokens),
-                            chunked_prefill=self.cfg.chunked_prefill),
+                            max_batched_tokens=self._ragged_T),
             swap_mode=self.cfg.swap_mode, kv_swapper=self._swapper)
         if self._kvtier is not None:
             # demote-before-preempt: every scheduler OOM path tries
@@ -717,14 +655,19 @@ class LLMEngine:
         else:
             self._htk = self._htv = None
 
-        # -- compiled prefill/decode step -------------------------------
+        # -- the compiled step -------------------------------------------
         from paddle_tpu.jit.trace import functionalize
         from paddle_tpu.ops.pallas.common import kernel_mesh
         from paddle_tpu.ops.sampling import sample_or_verify
 
+        # R > 1 gathers R logit rows per slot; only gather_offsets'
+        # STATIC shape matters — baked in as a jit constant, it sets the
+        # per-row gather width
+        spec_r = self._spec_R
+        goff = np.arange(spec_r, dtype=np.int32) if spec_r > 1 else None
         apply, (self._pnames, self._params), (_, self._buffers) \
-            = functionalize(
-            model.forward_paged if spec is None else model.forward_ragged)
+            = functionalize(model.forward_ragged_multi if spec_r > 1
+                            else model.forward_ragged)
         if tp > 1:
             # commit every weight to its TP placement IN PLACE on the
             # model (the engine owns serving weights): column-parallel
@@ -757,16 +700,6 @@ class LLMEngine:
                     axis=1)
             return packed, finite
 
-        def raw_step(param_datas, buffer_datas, key, ids, kcs, vcs, bt,
-                     enc, dec, now, skeys, stemp, stopk, stopp):
-            (logits, k2, v2), _ = apply(param_datas, buffer_datas, key,
-                                        ids, kcs, vcs, bt, enc, dec, now)
-            b = logits.shape[0]
-            packed, finite = pack_sampled(
-                logits[:, None, :], jnp.zeros((b, 0), jnp.int32),
-                jnp.zeros((b,), jnp.int32), skeys, stemp, stopk, stopp)
-            return packed, finite, k2, v2
-
         donate = self.cfg.donate_cache
         if donate is None:
             donate = jax.default_backend() not in ("cpu",)
@@ -784,9 +717,6 @@ class LLMEngine:
                          self._cache_sharding)
         else:
             step_outs = None
-        self._jstep = jax.jit(
-            raw_step, donate_argnums=(4, 5) if donate else (),
-            out_shardings=step_outs) if spec is None else None
 
         if spec is not None:
             def raw_step_ragged_spec(param_datas, buffer_datas, key, ids,
@@ -807,17 +737,7 @@ class LLMEngine:
             self._jstep_ragged = jax.jit(
                 raw_step_ragged_spec,
                 donate_argnums=(4,) if donate else ())
-        elif self._ragged:
-            spec_r = self._spec_R
-            if spec_r > 1:
-                apply_r, _, _ = functionalize(model.forward_ragged_multi)
-                # only gather_offsets' STATIC shape matters — baked in
-                # as a jit constant, it sets the per-row gather width
-                goff = np.arange(spec_r, dtype=np.int32)
-            else:
-                apply_r, _, _ = functionalize(model.forward_ragged)
-                goff = None
-
+        else:
             def forward_r(param_datas, buffer_datas, key, ids, kcs, vcs,
                           bt, cu, ctx, nseq):
                 # trace-time declaration for the attention op: heads
@@ -828,11 +748,11 @@ class LLMEngine:
                         else contextlib.nullcontext())
                 with decl:
                     if goff is None:
-                        (logits, k2, v2), _ = apply_r(
+                        (logits, k2, v2), _ = apply(
                             param_datas, buffer_datas, key, ids, kcs,
                             vcs, bt, cu, ctx, nseq)
                         return logits[:, None, :], k2, v2
-                    (lg3, k2, v2), _ = apply_r(
+                    (lg3, k2, v2), _ = apply(
                         param_datas, buffer_datas, key, ids, kcs, vcs,
                         bt, cu, ctx, nseq, goff)
                     return lg3, k2, v2
@@ -871,8 +791,6 @@ class LLMEngine:
                 else raw_step_ragged,
                 donate_argnums=(4, 5) if donate else (),
                 out_shardings=step_outs)
-        else:
-            self._jstep_ragged = None
         self._key = jax.random.key(0)
 
         self._requests: Dict[str, Request] = {}
@@ -962,8 +880,6 @@ class LLMEngine:
         ("prefix_cache=True", lambda c: bool(c.prefix_cache),
          "a shared K/V block does not carry the recurrent state at its "
          "boundary (it needs snapshots: ROADMAP.md)"),
-        ("ragged=False", lambda c: c.ragged is False,
-         "such a model has only the ragged step"),
     )
 
     def _refuse_for_cache_spec(self, method: Optional[str] = None):
@@ -1214,10 +1130,6 @@ class LLMEngine:
         the transport layer never mistakes it for replica death and the
         router can fall back to recompute; nothing is allocated unless
         admission fully succeeds."""
-        if not self.cfg.chunked_prefill:
-            raise ValueError(
-                "KV import needs chunked prefill (the imported request "
-                "resumes as a mid-context continuation row)")
         self._refuse_for_cache_spec("import_kv")
         if self._draining:
             raise ValueError("engine is draining")
@@ -1698,26 +1610,12 @@ class LLMEngine:
     def has_unfinished(self) -> bool:
         return self.scheduler.has_unfinished()
 
-    # -- bucketed padding -----------------------------------------------
-    def _batch_bucket(self, n: int) -> int:
-        b = 1
-        while b < n:
-            b *= 2
-        return min(b, self.cfg.max_num_seqs)
-
-    def _seq_bucket(self, n: int) -> int:
-        s = self.cfg.min_prefill_bucket
-        while s < n:
-            s *= 2
-        cap = cdiv(self.cfg.max_model_len, 8) * 8
-        return min(s, cap)
-
     # -- one engine iteration -------------------------------------------
     def step(self) -> List[RequestOutput]:
-        """Schedule + run ONE model iteration (a prefill batch or a
-        decode batch), sample one token per scheduled request, retire
-        finished requests. Returns this step's per-request outputs —
-        sampled tokens plus any structured terminal emissions (expired,
+        """Schedule + run ONE model iteration (a mixed batch of decode
+        rows and prefill chunks), sample for every row that reached its
+        last token, retire finished requests. Returns this step's
+        per-request outputs — sampled tokens plus any structured terminal emissions (expired,
         rejected, drain-aborted, poisoned) produced at this iteration
         boundary."""
         with span("engine.step", step=self.metrics.engine_steps):
@@ -1759,12 +1657,11 @@ class LLMEngine:
                         "request (admission validation should prevent this)")
                 return outputs
             with span("engine.fill"):
-                (reqs, n_run, arrays, B, S, R, sampling_arrays, padded,
-                 prompt_toks, composition) = self._fill(batch)
+                (reqs, n_run, arrays, sampling_arrays, prompt_toks,
+                 composition) = self._fill(batch)
             try:
                 out_np, finite_np = self._dispatch(
-                    reqs, batch.kind, arrays, B, S, sampling_arrays,
-                    composition)
+                    reqs, arrays, sampling_arrays, composition)
             except EngineStepError as e:
                 # this step's already-produced structured outputs (flushed
                 # rejections, expiries) must not vanish with the failure —
@@ -1779,12 +1676,12 @@ class LLMEngine:
                 poisoned = self._poisoned_rows(reqs, finite_np)
                 n_before = len(outputs)
                 self.metrics.record_step(
-                    batch.kind, len(reqs), composition["q_tokens"],
-                    self.cfg.max_num_seqs, time.perf_counter() - t0,
-                    padded_tokens=padded, prompt_tokens=prompt_toks,
+                    batch.kind, self.cfg.max_num_seqs,
+                    time.perf_counter() - t0, prompt_tokens=prompt_toks,
                     decode_rows=composition["decode_rows"])
                 # unpack the step's single host fetch: per row [tokens(R),
                 # n_emit, key_hi, key_lo]
+                R = self._spec_R
                 tokens_mat = out_np[:, :R]
                 n_emit_np = out_np[:, R]
                 keys_np = np.ascontiguousarray(
@@ -1877,9 +1774,9 @@ class LLMEngine:
                 return outputs
 
     def _fill(self, batch: ScheduledBatch):
-        """The step's host inputs from a scheduled batch: the packed (or
-        bucketed) arrays, the per-slot sampling rows, pending tier moves
-        and copy-on-write copies applied, and the batch's composition
+        """The step's host inputs from a scheduled batch: the packed
+        arrays, the per-slot sampling rows, pending tier moves and
+        copy-on-write copies applied, and the batch's composition
         counted once. A method of its own so that its ~25 locals are off
         the stack before the dispatch: the first dispatch traces the whole
         step, and CPython's frame stack grows in 16 KiB chunks; a deeper
@@ -1887,74 +1784,42 @@ class LLMEngine:
         chunk boundary (an mmap/munmap each), which cost the serve cell
         6-10 s of set-up on the v5e host (PERF.md section 6, PR 27)."""
         reqs = batch.requests
-        n_run = (list(batch.num_scheduled) if batch.num_scheduled
-                 else [len(r.tokens_to_run()) for r in reqs])
-        if self._ragged:
-            # ONE shape for every batch kind: the packed token stream
-            # (T,) plus S sequence slots — prefill chunks and decode
-            # rows differ only in their cu_seqlens deltas
-            B, S = self._ragged_T, self.cfg.max_num_seqs
-            ids = np.zeros((B,), np.int32)
-            cu = np.zeros((S + 1,), np.int32)
-            ctx = np.zeros((S,), np.int32)
-            bt = np.full((S, self.max_blocks_per_seq), -1, np.int32)
-            off = 0
-            for i, r in enumerate(reqs):
-                n = n_run[i]
-                # a verify row's stream is its newest committed token
-                # followed by the draft proposals (scheduled as one
-                # 1+d mid-context row)
-                src = (r.tokens + r.draft_tokens if r.draft_tokens
-                       else r.tokens)
-                ids[off:off + n] = src[r.num_cached:r.num_cached + n]
-                off += n
-                cu[i + 1] = off
-                ctx[i] = r.num_cached + n
-                table = self.block_manager.block_table(r.request_id)
-                bt[i, :len(table)] = table
-            cu[len(reqs) + 1:] = off
-            arrays = (ids, bt, cu, ctx, np.int32(len(reqs)))
-            if self._cache is not None:
-                arrays += (self._spec_tables(reqs, S),)
-            padded = 0
-            # the mixed batch's split: prompt tokens prefilled this
-            # step vs decode rows (feeds occupancy + prompt
-            # throughput the same way the classic prefill/decode
-            # kinds did; a verify row costs 1 + its draft count but
-            # is still one decode row)
-            prompt_toks = sum(
-                min(n, max(len(r.prompt_ids) - r.num_cached, 0))
-                for r, n in zip(reqs, n_run))
-            decode_rows = sum(
-                1 for r, n in zip(reqs, n_run)
-                if n - len(r.draft_tokens) == 1
-                and r.num_generated > 0)
-            ctx_tokens = int(ctx.sum())
-        else:
-            is_prefill = batch.kind == "prefill"
-            S = self._seq_bucket(max(n_run)) if is_prefill else 1
-            B = self._batch_bucket(len(reqs))
-
-            ids = np.zeros((B, S), np.int32)
-            enc = np.zeros((B,), np.int32)
-            dec = np.zeros((B,), np.int32)
-            now = np.zeros((B,), np.int32)
-            bt = np.full((B, self.max_blocks_per_seq), -1, np.int32)
-            for i, r in enumerate(reqs):
-                run = r.tokens_to_run()
-                ids[i, :len(run)] = run
-                now[i] = len(run)
-                if is_prefill:
-                    enc[i] = len(run)
-                dec[i] = r.num_cached
-                table = self.block_manager.block_table(r.request_id)
-                bt[i, :len(table)] = table
-            arrays = (ids, bt, enc, dec, now)
-            padded = B * S - int(sum(n_run))
-            # record_step infers the classic kinds' split from the kind
-            prompt_toks = None
-            decode_rows = 0 if is_prefill else len(reqs)
-            ctx_tokens = int(dec.sum() + now.sum())
+        n_run = batch.num_scheduled
+        # ONE shape for every batch kind: the packed token stream (T,)
+        # plus S sequence slots — prefill chunks and decode rows differ
+        # only in their cu_seqlens deltas
+        T, S = self._ragged_T, self.cfg.max_num_seqs
+        ids = np.zeros((T,), np.int32)
+        cu = np.zeros((S + 1,), np.int32)
+        ctx = np.zeros((S,), np.int32)
+        bt = np.full((S, self.max_blocks_per_seq), -1, np.int32)
+        off = 0
+        for i, r in enumerate(reqs):
+            n = n_run[i]
+            # a verify row's stream is its newest committed token
+            # followed by the draft proposals (scheduled as one 1+d
+            # mid-context row)
+            src = (r.tokens + r.draft_tokens if r.draft_tokens
+                   else r.tokens)
+            ids[off:off + n] = src[r.num_cached:r.num_cached + n]
+            off += n
+            cu[i + 1] = off
+            ctx[i] = r.num_cached + n
+            table = self.block_manager.block_table(r.request_id)
+            bt[i, :len(table)] = table
+        cu[len(reqs) + 1:] = off
+        arrays = (ids, bt, cu, ctx, np.int32(len(reqs)))
+        if self._cache is not None:
+            arrays += (self._spec_tables(reqs, S),)
+        # the mixed batch's split: prompt tokens prefilled this step vs
+        # decode rows (feeds occupancy + prompt throughput; a verify row
+        # costs 1 + its draft count but is still one decode row)
+        prompt_toks = sum(
+            min(n, max(len(r.prompt_ids) - r.num_cached, 0))
+            for r, n in zip(reqs, n_run))
+        decode_rows = sum(
+            1 for r, n in zip(reqs, n_run)
+            if n - len(r.draft_tokens) == 1 and r.num_generated > 0)
 
         # pending tier moves land FIRST (a COW source may be a block
         # a promote just filled), then copy-on-write block copies —
@@ -1963,31 +1828,23 @@ class LLMEngine:
             self._kvtier.apply_moves()
         self._apply_cow()
         # per-slot sampling state for the in-graph sampler: RNG keys,
-        # params, and (ragged only) the draft rows under verification
-        rows_dim = S if self._ragged else B
-        skeys = np.zeros((rows_dim, 2), np.uint32)
-        stemp = np.zeros((rows_dim,), np.float32)
-        stopk = np.zeros((rows_dim,), np.int32)
-        stopp = np.ones((rows_dim,), np.float32)
+        # params, and the draft rows under verification
+        skeys = np.zeros((S, 2), np.uint32)
+        stemp = np.zeros((S,), np.float32)
+        stopk = np.zeros((S,), np.int32)
+        stopp = np.ones((S,), np.float32)
+        sdraft = np.zeros((S, self._spec_R - 1), np.int32)
+        sndraft = np.zeros((S,), np.int32)
         for i, r in enumerate(reqs):
             skeys[i] = r.device_key
             stemp[i] = r.sampling.temperature
             stopk[i] = r.sampling.top_k
             stopp[i] = r.sampling.top_p
-        if self._ragged:
-            R = self._spec_R
-            sdraft = np.zeros((rows_dim, R - 1), np.int32)
-            sndraft = np.zeros((rows_dim,), np.int32)
-            for i, r in enumerate(reqs):
-                d = len(r.draft_tokens)
-                if d:
-                    sdraft[i, :d] = r.draft_tokens
-                    sndraft[i] = d
-            sampling_arrays = (skeys, stemp, stopk, stopp, sdraft,
-                               sndraft)
-        else:
-            R = 1
-            sampling_arrays = (skeys, stemp, stopk, stopp)
+            d = len(r.draft_tokens)
+            if d:
+                sdraft[i, :d] = r.draft_tokens
+                sndraft[i] = d
+        sampling_arrays = (skeys, stemp, stopk, stopp, sdraft, sndraft)
         # rows the sampler's filter acts on; greedy rows are a one-hot
         sampled_rows = sum(1 for r in reqs if r.sampling.temperature > 0.0)
         if sampled_rows:
@@ -1997,7 +1854,7 @@ class LLMEngine:
         composition = dict(
             step=self.metrics.engine_steps, kind=batch.kind,
             rows=len(reqs), q_tokens=int(sum(n_run)),
-            ctx_tokens=ctx_tokens, prefill_rows=len(reqs) - decode_rows,
+            ctx_tokens=int(ctx.sum()), prefill_rows=len(reqs) - decode_rows,
             decode_rows=decode_rows, sampled_rows=sampled_rows)
         if self._cache is not None:
             first = sum(1 for r in reqs if r.num_cached == 0)
@@ -2006,8 +1863,8 @@ class LLMEngine:
                 win_blocks=self.block_manager.num_used_window_blocks,
                 full_blocks=self.block_manager.num_used_blocks,
                 cross_rows=len(reqs))
-        return (reqs, n_run, arrays, B, S, R, sampling_arrays, padded,
-                prompt_toks, composition)
+        return (reqs, n_run, arrays, sampling_arrays, prompt_toks,
+                composition)
 
     def _spec_tables(self, reqs, S):
         """The step's tables beside the main block table, for a model
@@ -2078,13 +1935,12 @@ class LLMEngine:
             self._vcs = jax.device_put(self._vcs, self._cache_sharding)
 
     # -- the guarded compiled dispatch ----------------------------------
-    def _dispatch(self, reqs, kind, arrays, B, S, sampling_arrays,
-                  composition):
+    def _dispatch(self, reqs, arrays, sampling_arrays, composition):
         """Run the compiled step under the fault-isolation envelope:
         watchdog-armed dispatch (hung-step detection), bounded
         retry-with-backoff on transient failures, and the fetch of this
         step's host-side views. Returns ``(out_np, finite_np)`` —
-        ``out_np`` is the packed (B, R+3) int32 sampler output
+        ``out_np`` is the packed (rows, R+3) int32 sampler output
         ([tokens(R), n_emit, key_hi, key_lo] per row); ``finite_np`` is
         the per-row nonfinite-guard bit (None with the guard off).
 
@@ -2096,14 +1952,10 @@ class LLMEngine:
         request just vanishes). ``composition`` is what the batch holds
         (rows, query and context tokens, the prefill/decode split): the
         attributes of each attempt's ``engine.dispatch`` span."""
-        if self._ragged:
-            ids, bt, cu, ctx, nseq, *tables = arrays
-            tag = f"serving.ragged[T={B},S={S}]"
-            shape_key = ("ragged", B, S)
-        else:
-            ids, bt, enc, dec, now = arrays
-            tag = f"serving.{kind}[B={B},S={S}]"
-            shape_key = (kind, B, S)
+        ids, bt, cu, ctx, nseq, *tables = arrays
+        T, S = self._ragged_T, self.cfg.max_num_seqs
+        tag = f"serving.ragged[T={T},S={S}]"
+        shape_key = ("ragged", T, S)
         cold = shape_key not in self._seen_shapes
         spec_cache = self._cache is not None
         attempt = 0
@@ -2129,25 +1981,19 @@ class LLMEngine:
                             [b._data for b in self._buffers],
                             self._key, ids, self._cache, *tables, bt, cu,
                             ctx, nseq, *sampling_arrays)
-                    elif self._ragged and self._kvtier is not None:
+                    elif self._kvtier is not None:
                         packed, finite, kcs, vcs = self._jstep_ragged(
                             [p._data for p in self._params],
                             [b._data for b in self._buffers],
                             self._key, ids, self._kcs, self._vcs,
                             self._htk, self._htv, bt, cu, ctx, nseq,
                             *sampling_arrays)
-                    elif self._ragged:
+                    else:
                         packed, finite, kcs, vcs = self._jstep_ragged(
                             [p._data for p in self._params],
                             [b._data for b in self._buffers],
                             self._key, ids, self._kcs, self._vcs, bt, cu,
                             ctx, nseq, *sampling_arrays)
-                    else:
-                        packed, finite, kcs, vcs = self._jstep(
-                            [p._data for p in self._params],
-                            [b._data for b in self._buffers],
-                            self._key, ids, self._kcs, self._vcs, bt, enc,
-                            dec, now, *sampling_arrays)
                 if self._watchdog is not None:
                     self._watchdog.attach(eid, (packed,))
                 # sampling (greedy AND temperature/top-k/top-p, plus
@@ -2243,34 +2089,6 @@ class LLMEngine:
         if self.num_spec_proposed == 0:
             return 0.0
         return self.num_spec_accepted / self.num_spec_proposed
-
-    # -- sampling CPU oracle --------------------------------------------
-    @staticmethod
-    def _sample(req: Request, logits: np.ndarray) -> int:
-        """Host-side reference sampler. The serving hot path no longer
-        calls this — sampling is fused into the compiled step
-        (:mod:`paddle_tpu.ops.sampling`) — but it REMAINS the oracle the
-        device sampler is pinned against: greedy bit-identity and
-        sampled distribution-parity in tests/test_spec_decode.py."""
-        sp = req.sampling
-        if sp.temperature <= 0.0:
-            return int(np.argmax(logits))
-        x = logits.astype(np.float64) / sp.temperature
-        x -= x.max()
-        p = np.exp(x)
-        p /= p.sum()
-        if sp.top_k > 0 and sp.top_k < p.size:
-            kth = np.partition(p, -sp.top_k)[-sp.top_k]
-            p = np.where(p >= kth, p, 0.0)
-            p /= p.sum()
-        if sp.top_p < 1.0:
-            order = np.argsort(-p)
-            csum = np.cumsum(p[order])
-            keep_n = int(np.searchsorted(csum, sp.top_p) + 1)
-            mask = np.zeros_like(p)
-            mask[order[:keep_n]] = p[order[:keep_n]]
-            p = mask / mask.sum()
-        return int(req._rng.choice(p.size, p=p))
 
     # -- run-to-completion convenience ----------------------------------
     def run(self, max_steps: Optional[int] = None) -> List[RequestOutput]:

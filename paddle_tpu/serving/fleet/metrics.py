@@ -3,8 +3,7 @@
 Mirrors :class:`~paddle_tpu.serving.metrics.ServingMetrics` one level
 up: every gauge registers a ``fleet/<name>#<id>`` profiler counter
 provider (weakref'd — a dropped router unregisters itself), and
-:meth:`FleetMetrics.snapshot` returns the one dict
-``bench.py --serving --replicas N`` emits as BENCH_serving JSON.
+:meth:`FleetMetrics.snapshot` returns them as one dict.
 
 The ``fleet_finish`` histogram is the CLIENT-visible aggregate (one
 bucket per request, from the router's bookkeeping); the nested

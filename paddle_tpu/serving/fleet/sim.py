@@ -20,9 +20,8 @@ and the replica:
   state it hands the router is ``{"pos": <absolute position>}``, which
   rides the lease like the real composite RNG dict and makes adopted
   continuations resume at exactly the right position;
-* :class:`LatencyModel` — per-tick virtual costs sampled from the
-  repo's measured serving benches (BENCH_serving_r05–r08), with
-  documented fallback constants when the files are absent;
+* :class:`LatencyModel` — per-tick virtual costs, constants taken from
+  the repo's July-2026 serving runs on the CPU;
 * traffic generators (:func:`diurnal_trace`, :func:`spike_trace`) —
   bursty multi-tenant arrival schedules, deterministic per seed;
 * :class:`FleetSim` — wires shared :class:`MemStore` registries, a
@@ -37,9 +36,7 @@ idle floor so arrival schedules always make progress.
 """
 from __future__ import annotations
 
-import json
 import math
-import os
 import random
 import zlib
 from dataclasses import dataclass, field
@@ -86,22 +83,19 @@ class VirtualClock:
 
 @dataclass
 class LatencyModel:
-    """Virtual step costs, sampled from the repo's measured benches.
+    """Virtual step costs: constants from the repo's July-2026 serving
+    runs on the CPU (their records are in git history).
 
-    Fallback constants are the r05–r08 measurements baked in, so the
-    simulator behaves identically whether or not the JSON files are
-    present:
-
-    * ``decode_step_s`` — BENCH_serving_r05: 213.03 fleet tokens/s over
-      2 replicas → ~9.4 ms per replica decode step;
-    * ``prefill_s_per_token`` — BENCH_serving_r07: 8.76 ms cold TTFT
-      over a 104-token prompt → ~0.084 ms/token;
+    * ``decode_step_s`` — 213.03 fleet tokens/s over 2 replicas → ~9.4
+      ms per replica decode step;
+    * ``prefill_s_per_token`` — 8.76 ms cold TTFT over a 104-token
+      prompt → ~0.084 ms/token;
     * ``rpc_s`` — per-step control-plane overhead (~2.2 ms measured
       RPC round-trip);
-    * ``kv_ship_s`` / ``peer_ship_s`` — BENCH_serving_r06 (17.786 ms
-      relay ship) and r08 (6.996 ms peer ship); unused by
-      :class:`SimReplica` (no KV capability) but kept so a future
-      disaggregated sim prices transfers consistently.
+    * ``kv_ship_s`` / ``peer_ship_s`` — 17.786 ms relay ship and 6.996
+      ms peer ship; unused by :class:`SimReplica` (no KV capability)
+      but kept so a future disaggregated sim prices transfers
+      consistently.
     """
 
     decode_step_s: float = 2.0 / 213.03
@@ -109,41 +103,6 @@ class LatencyModel:
     rpc_s: float = 2.181e-3
     kv_ship_s: float = 17.786e-3
     peer_ship_s: float = 6.996e-3
-
-    @classmethod
-    def from_bench(cls, bench_dir: str = ".") -> "LatencyModel":
-        def load(name):
-            try:
-                with open(os.path.join(bench_dir, name)) as f:
-                    return json.load(f)
-            except (OSError, ValueError):
-                return None
-
-        kw = {}
-        r05 = load("BENCH_serving_r05.json")
-        if r05 and float(r05.get("value") or 0) > 0:
-            kw["decode_step_s"] = 2.0 / float(r05["value"])
-        r07 = load("BENCH_serving_r07.json")
-        if r07:
-            extra = r07.get("extra") or {}
-            cold = float((extra.get("affine") or {}).get(
-                "ttft_cold_ms") or 0)
-            plen = float(extra.get("prompt_len") or 0)
-            if cold > 0 and plen > 0:
-                kw["prefill_s_per_token"] = cold * 1e-3 / plen
-        r06 = load("BENCH_serving_r06.json")
-        if r06:
-            ship = float((r06.get("extra") or {}).get(
-                "fleet_kv_ship_ms_avg") or 0)
-            if ship > 0:
-                kw["kv_ship_s"] = ship * 1e-3
-        r08 = load("BENCH_serving_r08.json")
-        if r08:
-            ship = float(((r08.get("extra") or {}).get("peer") or {})
-                         .get("ship_ms_avg") or 0)
-            if ship > 0:
-                kw["peer_ship_s"] = ship * 1e-3
-        return cls(**kw)
 
 
 class SimReplica(ReplicaHandle):

@@ -7,7 +7,7 @@ Exposed two ways:
   machinery), so ``profiler.counters()`` reports ``serving/<name>``
   alongside training counters like ``train_step/nonfinite_skipped``;
 * snapshot — :meth:`ServingMetrics.snapshot` returns one dict (what
-  ``bench.py --serving`` emits as the BENCH_serving JSON).
+  the fleet heartbeat and the smoke scripts read).
 
 TTFT (time-to-first-token) and TPOT (time-per-output-token, a.k.a.
 inter-token latency) follow the standard serving definitions: TTFT is
@@ -47,10 +47,9 @@ class ServingMetrics:
               "num_swapped", "swapped_out", "swapped_in", "expired",
               "rejected", "step_retries", "poisoned_aborts",
               "drain_started", "drain_aborted", "drain_completed",
-              # ragged hot path (ISSUE 9): attention-path padding waste
-              # plus prefix-cache, copy-on-write, and chunked-prefill
-              # traffic
-              "padded_token_frac", "prefix_cache_hits",
+              # ragged hot path (ISSUE 9): prefix-cache, copy-on-write,
+              # and chunked-prefill traffic
+              "prefix_cache_hits",
               "prefix_cache_hit_tokens", "cow_copies", "prefill_chunks",
               # in-graph sampling + speculative decoding (ISSUE 11):
               # draft proposal/acceptance traffic and sampled-step count
@@ -130,13 +129,6 @@ class ServingMetrics:
         self.prefill_steps = 0
         self.decode_steps = 0
         self.mixed_steps = 0
-        # attention-path padding: slots the compiled step attended that
-        # held no real token (bucketed rows x longest-row padding; the
-        # ragged step packs, so it contributes zero — its fixed token
-        # budget is dense-MLP headroom, not attention work, and is NOT
-        # counted here)
-        self.num_padded_tokens = 0
-        self.num_slot_tokens = 0          # real + padded
         self.ttfts_s: List[float] = []
         self.tpots_s: List[float] = []
         # arrival -> first scheduled, appended by the engine where the
@@ -152,24 +144,14 @@ class ServingMetrics:
         self._register(engine)
 
     # -- recording (called by the engine) --------------------------------
-    def record_step(self, kind: str, n_seqs: int, n_tokens: int,
-                    max_num_seqs: int, dt_s: Optional[float] = None,
-                    padded_tokens: int = 0,
-                    prompt_tokens: Optional[int] = None,
-                    decode_rows: Optional[int] = None):
-        """``prompt_tokens``/``decode_rows`` split a MIXED (ragged) batch
-        explicitly; None infers them from ``kind`` (the classic
-        prefill-xor-decode accounting). ``padded_tokens`` counts
-        attention-path pad slots the step attended (0 for ragged)."""
+    def record_step(self, kind: str, max_num_seqs: int,
+                    dt_s: Optional[float] = None, *,
+                    prompt_tokens: int, decode_rows: int):
+        """``prompt_tokens``/``decode_rows`` are the batch's split: the
+        prompt tokens prefilled this step and the rows that decoded."""
         self.engine_steps += 1
         if dt_s is not None:
             self._step_times_s.append(dt_s)
-        self.num_slot_tokens += n_tokens + padded_tokens
-        self.num_padded_tokens += padded_tokens
-        if prompt_tokens is None:
-            prompt_tokens = n_tokens if kind == "prefill" else 0
-        if decode_rows is None:
-            decode_rows = n_seqs if kind == "decode" else 0
         self.num_prompt_tokens += prompt_tokens
         if kind == "prefill":
             self.prefill_steps += 1
@@ -230,13 +212,6 @@ class ServingMetrics:
         return (self._occupancy_sum / self._occupancy_n
                 if self._occupancy_n else 0.0)
 
-    @property
-    def padded_token_frac(self) -> float:
-        """Fraction of attended token slots that were padding — the
-        waste the ragged step eliminates by construction."""
-        return (self.num_padded_tokens / self.num_slot_tokens
-                if self.num_slot_tokens else 0.0)
-
     def snapshot(self) -> Dict[str, float]:
         eng = self._engine()
         out = {
@@ -247,7 +222,6 @@ class ServingMetrics:
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
             "mixed_steps": self.mixed_steps,
-            "padded_token_frac": round(self.padded_token_frac, 4),
             "tokens_per_sec": round(self.tokens_per_sec, 2),
             "ttft_ms_avg": round(_mean(self.ttfts_s) * 1e3, 3),
             "ttft_ms_p90": round(
@@ -277,9 +251,8 @@ class ServingMetrics:
                     eng.block_manager.num_window_blocks_released,
                 "state_slots_in_use": eng.block_manager.state_slots_in_use,
             })
-            # resilience counters (what BENCH_serving trends): swap
-            # traffic, TTL expiry, admission rejects, step retries,
-            # poisoned-row aborts, drain lifecycle
+            # resilience counters: swap traffic, TTL expiry, admission
+            # rejects, step retries, poisoned-row aborts, drain lifecycle
             out.update({f"serving_{name}": int(get(eng))
                         for name, get in self._ENGINE_GAUGES.items()})
             # the one float engine gauge (kept out of the int() wrap)
@@ -326,8 +299,6 @@ class ServingMetrics:
                     return eng.scheduler.num_preemptions
                 if name == "batch_occupancy":
                     return m.batch_occupancy
-                if name == "padded_token_frac":
-                    return m.padded_token_frac
                 return None
             return get
 

@@ -2,12 +2,14 @@
 
 Orca's insight, as shipped by vLLM: scheduling decisions happen every
 model iteration, not per request. Each call to :meth:`schedule` emits
-either a PREFILL batch (admitting waiting requests under a token budget
-and the free-block supply) or a DECODE batch (one token for every
-running request), so late-arriving requests join the running batch at
-the next iteration boundary instead of waiting for a full drain.
+ONE mixed batch under a raw token budget — a token for every running
+request that has caught up, then chunks of the prompts still being
+prefilled, then new admissions, their prompts chunked too — so
+late-arriving requests join the running batch at the next iteration
+boundary instead of waiting for a full drain, and a long prompt never
+stalls the decode rows beside it.
 
-Preemption: when a decode step needs a block and none are free, the
+Preemption: when a row needs a block and none are free, the
 lowest-priority running request (largest ``(priority, arrival)`` key)
 is evicted — never a higher-priority one — until the victim set frees
 enough. Priority-then-FCFS admission plus eviction-from-the-back gives
@@ -44,34 +46,24 @@ __all__ = ["SchedulerConfig", "ScheduledBatch", "Scheduler"]
 class SchedulerConfig:
     """Admission/batching knobs.
 
-    ``max_num_seqs``   — max concurrently RUNNING requests (decode batch
-                         width; also caps a prefill batch).
-    ``max_batched_tokens`` — per-iteration PADDED-token budget for
-                         prefill batches: rows × longest row admitted,
-                         since the engine pads every row to the batch's
-                         longest request. (Bucket rounding can still
-                         exceed this by up to 2× — pow2 seq buckets.)
+    ``max_num_seqs``   — max concurrently RUNNING requests (the rows of
+                         a batch).
+    ``max_batched_tokens`` — per-iteration RAW token budget: the tokens
+                         of all rows together. The ragged step pads
+                         nothing, so the raw count is the compiled work;
+                         a prompt over what is left of it runs as chunks.
     """
 
     max_num_seqs: int = 8
     max_batched_tokens: int = 2048
-    # chunked prefill (the ragged engine path): schedule MIXED batches —
-    # decode rows first, then long prompts as budget-sized chunks — under
-    # a RAW token budget (the ragged step pads nothing, so raw token
-    # count is the compiled work). Off: the classic padded-budget
-    # prefill-xor-decode policy above.
-    chunked_prefill: bool = False
 
     def __post_init__(self):
         if self.max_num_seqs < 1:
             raise ValueError("max_num_seqs must be >= 1")
-        if self.max_batched_tokens < 1:
-            raise ValueError("max_batched_tokens must be >= 1")
-        if self.chunked_prefill and \
-                self.max_batched_tokens < self.max_num_seqs:
+        if self.max_batched_tokens < self.max_num_seqs:
             raise ValueError(
-                "chunked_prefill needs max_batched_tokens >= max_num_seqs "
-                "(every running row must afford its decode token)")
+                "max_batched_tokens must be >= max_num_seqs (every "
+                "running row must afford its decode token)")
 
 
 @dataclass
@@ -88,9 +80,7 @@ class ScheduledBatch:
     preempted: List[Request] = field(default_factory=list)
     swapped_in: List[Request] = field(default_factory=list)
     expired: List[Request] = field(default_factory=list)
-    # chunked-prefill mode: tokens scheduled per row (parallel to
-    # ``requests``); empty for the classic path (each row runs its whole
-    # ``tokens_to_run()``)
+    # tokens scheduled per row (parallel to ``requests``)
     num_scheduled: List[int] = field(default_factory=list)
 
     @property
@@ -270,8 +260,8 @@ class Scheduler:
 
     def _swap_in_ready(self) -> List[Request]:
         """Restore swapped requests (most important first) while device
-        blocks allow; they rejoin ``running`` and decode this very
-        iteration if no prefill batch forms."""
+        blocks allow; they rejoin ``running`` and take a row of this
+        very iteration's batch."""
         restored: List[Request] = []
         for r in sorted(self.swapped, key=lambda r: r.sort_key):
             if len(self.running) + len(restored) >= self.config.max_num_seqs:
@@ -289,99 +279,13 @@ class Scheduler:
 
     # -- the per-iteration decision --------------------------------------
     def schedule(self) -> ScheduledBatch:
-        # Phase 0 — TTL sweep, then restore swapped requests while
-        # blocks allow (they already consumed compute; finishing them
-        # frees host AND device memory fastest, and their sort keys
-        # predate anything still waiting).
+        # TTL sweep, then restore swapped requests while blocks allow
+        # (they already consumed compute; finishing them frees host AND
+        # device memory fastest, and their sort keys predate anything
+        # still waiting), then the batch.
         expired = self.expire_deadlines()
         swapped_in = self._swap_in_ready()
-
-        if self.config.chunked_prefill:
-            return self._schedule_mixed(expired, swapped_in)
-
-        # Phase 1 — admit waiting requests (priority, then FCFS) when
-        # capacity allows. A request is admitted only when its FULL
-        # uncached prefix fits the token budget and the free-block
-        # supply; admission claims the blocks immediately so the batch
-        # can't oversubscribe. Head-of-line: the first blocked
-        # candidate ends admission, so a starved high-priority request
-        # is never overtaken.
-        prefills: List[Request] = []
-        batch_max = 0  # longest row admitted -> the padded row width
-        # one sort per iteration (timsort is O(n) on the common case —
-        # all-default priorities arrive already FCFS-ordered), and ONE
-        # deque rebuild below instead of an O(n) remove per admit
-        for req in sorted(self.waiting, key=lambda r: r.sort_key):
-            need = len(req.tokens_to_run())
-            if len(self.running) + len(prefills) >= self.config.max_num_seqs:
-                break
-            # budget the PADDED batch (rows x longest row): the engine
-            # pads every row to the longest, so raw token counts would
-            # under-bound the compiled work by the padding factor
-            padded = (len(prefills) + 1) * max(batch_max, need)
-            if prefills and padded > self.config.max_batched_tokens:
-                break  # batch full; this request leads the next one
-            # (a lone over-budget prompt is still admitted, alone —
-            # rejecting it forever would starve it)
-            if not self.block_manager.can_allocate(need):
-                break  # blocks free up as running requests finish
-            self.block_manager.allocate(req.request_id, need)
-            req.status = RequestStatus.RUNNING
-            prefills.append(req)
-            batch_max = max(batch_max, need)
-        if prefills:
-            admitted = set(id(r) for r in prefills)
-            self.waiting = deque(r for r in self.waiting
-                                 if id(r) not in admitted)
-            self.running.extend(prefills)
-            return ScheduledBatch(kind="prefill", requests=prefills,
-                                  swapped_in=swapped_in, expired=expired)
-
-        # Phase 2 — decode: one token for every running request. Each
-        # needs a slot for its new K/V; an OOM on slot growth evicts
-        # the least-important running request (possibly the request
-        # itself, when it IS the least important) — to the host swap
-        # pool when enabled, else back to WAITING for recompute.
-        preempted: List[Request] = []
-        decodes: List[Request] = []
-        for req in sorted(self.running, key=lambda r: r.sort_key):
-            if req not in self.running:
-                continue  # evicted while a less important one ran
-            # this step computes K/V for tokens[-1] at position
-            # len(tokens)-1, so coverage of len(tokens) slots is exact —
-            # +1 would claim each next block one step early (and a
-            # never-written block on the final decode step)
-            got_slot = False
-            while True:
-                try:
-                    self.block_manager.append_slot(req.request_id,
-                                                   len(req.tokens))
-                    got_slot = True
-                    break
-                except NoFreeBlocksError:
-                    if self.tier_relief is not None \
-                            and self.tier_relief(req):
-                        continue  # demoted cold content freed room
-                    victim = self._preempt_one(req)
-                    if victim is None:
-                        break  # nothing left to evict but req itself
-                    preempted.append(victim)
-                    if victim in decodes:
-                        # a more important request lost its slot too
-                        decodes.remove(victim)
-            if got_slot:
-                decodes.append(req)
-            else:
-                # req could not be saved even after evicting every other
-                # candidate: evict req itself
-                self._evict(req)
-                preempted.append(req)
-        if decodes:
-            return ScheduledBatch(kind="decode", requests=decodes,
-                                  preempted=preempted,
-                                  swapped_in=swapped_in, expired=expired)
-        return ScheduledBatch(kind="idle", preempted=preempted,
-                              swapped_in=swapped_in, expired=expired)
+        return self._schedule_mixed(expired, swapped_in)
 
     def _claim_with_relief(self, req: Request, claim):
         """Run a block claim, retrying after each successful tier-relief
@@ -413,7 +317,7 @@ class Scheduler:
                     return None
                 n = max(1, n // 2)
 
-    # -- chunked-prefill mixed scheduling ---------------------------------
+    # -- the mixed batch ---------------------------------------------------
     def _schedule_mixed(self, expired: List[Request],
                         swapped_in: List[Request]) -> ScheduledBatch:
         """One MIXED batch under a raw token budget: (A) decode rows
@@ -421,8 +325,8 @@ class Scheduler:
         continue with whatever budget remains, chunked; (C) new
         admissions fill the rest, their prompts chunked too (and served
         from the prefix cache where full prompt blocks match). Each pass
-        runs the same evict-lowest-priority OOM loop as classic decode,
-        so the starvation guard carries over unchanged."""
+        runs the same evict-lowest-priority OOM loop, which is what the
+        starvation guard rests on."""
         bm = self.block_manager
         budget = self.config.max_batched_tokens
         rows: List[Request] = []
@@ -441,8 +345,8 @@ class Scheduler:
 
         def claim_slots(req: Request, new_len: int,
                         write_from: int) -> bool:
-            """append_slot with the classic preempt-or-self-evict loop;
-            False means req itself was evicted."""
+            """append_slot with the preempt-or-self-evict loop; False
+            means req itself was evicted."""
             while True:
                 try:
                     bm.append_slot(req.request_id, new_len,

@@ -24,8 +24,8 @@ def enable_compile_cache(min_compile_time_secs: float = 0.0) -> str:
     """Switch on JAX's persistent compilation cache for this process and
     return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
     already uses it and no directory is set in code; otherwise the cache
-    is ``cache_dir()``. Every entry point (chip_smoke.py, bench.py, the
-    fleet worker, the test suite) calls this and sets no other."""
+    is ``cache_dir()``. Every entry point (chip_smoke.py, benchmark/run.py,
+    the fleet worker, the test suite) calls this and sets no other."""
     import jax
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")

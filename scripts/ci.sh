@@ -8,11 +8,6 @@
 #                            replicas + a mid-run replica kill, then 2
 #                            subprocess workers with a real SIGKILL
 #                            mid-decode and token parity; ~2 min)
-#   scripts/ci.sh --ragged   ragged hot-path smoke only (mixed long/
-#                            short prompts with shared prefixes;
-#                            asserts ONE compiled step shape, zero
-#                            padding, prefix-cache hits, chunked
-#                            prefill, bucketed token parity; ~1 min)
 #   scripts/ci.sh --spec     speculative-decoding smoke only (self-
 #                            draft k=3; asserts acceptance > 0, greedy
 #                            token parity vs the non-spec engine, and
@@ -102,19 +97,8 @@ if [[ "${1:-}" == "--lint" ]]; then
     exit 0
 fi
 
-run_ragged() {
-    echo "== ragged smoke =="
-    timeout -k 10 300 env JAX_PLATFORMS=cpu PYTHONPATH=. \
-        python scripts/ragged_smoke.py
-}
-
 if [[ "${1:-}" == "--fleet" ]]; then
     run_fleet
-    exit 0
-fi
-
-if [[ "${1:-}" == "--ragged" ]]; then
-    run_ragged
     exit 0
 fi
 

@@ -42,7 +42,8 @@ def test_ttft_estimate_survives_concurrent_step_records():
     def writer():
         i = 0
         while not stop.is_set():
-            m.record_step("decode", 1, 1, 8, dt_s=0.01 + (i % 7) * 1e-4)
+            m.record_step("decode", 8, dt_s=0.01 + (i % 7) * 1e-4,
+                          prompt_tokens=0, decode_rows=1)
             i += 1
 
     def reader():

@@ -3,7 +3,7 @@
 The regression this tripwires: someone drops ``donate_argnums`` (or
 breaks the aliasing contract) and every step silently goes back to
 allocate-and-copy for the whole parameter/optimizer state — exactly the
-copy_frac=0.545 regime BENCH_r05 measured. Runs entirely on CPU: XLA:CPU
+copy_frac=0.545 regime the July-2026 chip run measured. Runs entirely on CPU: XLA:CPU
 honors input/output aliasing, a frozen (stop_gradient) parameter is a
 pass-through output that MUST be copied without donation and aliased
 with it, so the donated executable provably contains and executes fewer
@@ -85,7 +85,7 @@ def test_donated_step_issues_fewer_copy_ops():
 
 def test_phase_api_reports_copy_fraction():
     """device_phases exposes copy_frac as a first-class metric for any
-    step fn (what bench.py records per config)."""
+    step fn."""
     X, Y = _batch()
     _, step = _fresh(donate=True)
     ph = profiler.device_phases(lambda: step(X, Y), steps=2)

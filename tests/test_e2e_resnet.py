@@ -1,4 +1,4 @@
-"""Minimum end-to-end slice (SURVEY.md §7 step 5 / BASELINE.json config #1):
+"""Minimum end-to-end slice (SURVEY.md §7 step 5):
 ResNet on CIFAR-10-like data, eager + compiled, loss must descend."""
 import numpy as np
 import pytest

@@ -3,8 +3,7 @@
 Layers, cheapest first:
 
 * :class:`KVTiersConfig` parsing/validation and the engine-side config
-  guards (the bucketed ``ragged=False`` fallback is degree-1-only and
-  untierable; tiering without the trie is a contradiction);
+  guard (tiering without the trie is a contradiction);
 * BlockManager tier mechanics — virtual host entries, the ordered
   demote/promote move ledger, chain demote (slots park cached-free and
   UNOWNED), chain evict, exact invariants throughout;
@@ -128,14 +127,6 @@ class TestTiersConfig:
             KVTiersConfig(num_host_blocks=0)
         with pytest.raises(ValueError):
             KVTiersConfig(max_sessions=0)
-
-    def test_bucketed_fallback_rejects_tiers(self, tiny_model):
-        with pytest.raises(ValueError, match="ragged"):
-            LLMEngine(tiny_model, _ecfg(ragged=False, kv_tiers=True))
-
-    def test_bucketed_fallback_rejects_tp(self, tiny_model):
-        with pytest.raises(ValueError, match="degree-1"):
-            LLMEngine(tiny_model, _ecfg(ragged=False, tp_degree=2))
 
     def test_tiers_require_prefix_cache(self, tiny_model):
         with pytest.raises(ValueError, match="prefix"):

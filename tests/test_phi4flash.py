@@ -552,7 +552,6 @@ def test_requests_swapping_rows_keep_their_own_state(model):
     (dict(kv_tiers=True), "kv_tiers"),
     (dict(swap_mode="host"), "swap_mode='host'"),
     (dict(tp_degree=2), "tp_degree > 1"),
-    (dict(ragged=False, chunked_prefill=False), "ragged=False"),
     (dict(draft_model=object(), num_spec_tokens=2), "draft_model"),
 ])
 def test_engine_refuses_by_name_at_construction(model, knobs, name):

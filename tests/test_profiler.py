@@ -135,7 +135,8 @@ def test_device_summary_reports_xla_ops(tmp_path):
     ("infeed", "copy"),
     # the chip names an op by its whole instruction: the family is the
     # instruction's own name, not whatever its operands are called (the
-    # substring rule behind BENCH_r05's copy_frac 0.545 beside MFU 0.698)
+    # substring rule behind a July-2026 chip run's copy_frac 0.545 beside
+    # MFU 0.698)
     ("%fusion.6 = f32[16]{0} fusion(f32[16]{0} %copy.3), kind=kLoop",
      "compute"),
     ("%copy.3 = bf16[24,2048]{1,0} copy(bf16[24,2048]{0,1} %fusion.9)",

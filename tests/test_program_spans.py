@@ -107,13 +107,12 @@ def engine_run(tiny_model, tmp_path_factory):
     over four slots and a 16-token budget, so prompts are chunked, two
     requests queue, and steps mix prefill chunks with decode rows."""
     eng = _engine(tiny_model)
-    assert eng._ragged
     counted = []                      # what record_step was handed
     real = eng.metrics.record_step
 
-    def record_step(kind, n_seqs, n_tokens, *a, **kw):
-        counted.append((kind, n_seqs, n_tokens, kw.get("decode_rows")))
-        return real(kind, n_seqs, n_tokens, *a, **kw)
+    def record_step(kind, *a, **kw):
+        counted.append((kind, kw["prompt_tokens"], kw["decode_rows"]))
+        return real(kind, *a, **kw)
 
     eng.metrics.record_step = record_step
     with Traced(tmp_path_factory.mktemp("engine_trace")) as tr:
@@ -258,10 +257,12 @@ def test_engine_step_has_its_five_children_nested_and_in_order(engine_run):
 def test_dispatch_attributes_are_the_batch_the_metrics_counted(engine_run):
     eng, tr, counted = engine_run
     got = [d["stats"] for d in tr.named("engine.dispatch")]
-    assert [(d["kind"], d["rows"], d["q_tokens"], d["decode_rows"])
+    # no drafts and no recompute in this run: a step's tokens are its
+    # prompt tokens and one per decode row
+    assert [(d["kind"], d["q_tokens"] - d["decode_rows"], d["decode_rows"])
             for d in got] == counted
     assert [d["step"] for d in got] == list(range(len(got)))
-    assert sum(d["q_tokens"] for d in got) == eng.metrics.num_slot_tokens
+    assert sum(n for _, n, _ in counted) == eng.metrics.num_prompt_tokens
     assert all(d["prefill_rows"] + d["decode_rows"] == d["rows"]
                and 0 < d["q_tokens"] <= 16 and d["ctx_tokens"] >= d["q_tokens"]
                and d["attempt"] == 0 for d in got)

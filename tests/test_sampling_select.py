@@ -115,7 +115,7 @@ def test_kept_set_and_probabilities_equal_both_references(name):
             # the one place the sort-based filter leaves the rule: with
             # top-p off its float32 cumsum can reach 1.0 before the row
             # ends, and the tail behind that is dropped. The rule (and
-            # LLMEngine._sample) keeps everything.
+            # the float64 ``oracle_probs``) keeps everything.
             assert row[2] >= 1.0 and (old[i] > 0).sum() < (ref > 0).sum()
         if row[0] <= 0.0:
             assert set(np.unique(new[i])) == {0.0, 1.0}

@@ -1,9 +1,10 @@
 """serving.BlockManager / Scheduler invariants (model-free fast tests).
 
-Pins the tentpole's allocator + scheduler contracts: exact free-block
-accounting under randomized admit/decode/free/preempt sequences, no
-double allocation, preempted requests re-admit and finish, and the
-FCFS starvation guard (waiting requests eventually run)."""
+Pins the allocator + scheduler contracts: exact free-block accounting
+under randomized admit/decode/free/preempt sequences, no double
+allocation, the mixed batch's order and raw budget, preempted requests
+re-admit and finish, and the FCFS starvation guard (waiting requests
+eventually run)."""
 import numpy as np
 import pytest
 
@@ -97,57 +98,113 @@ def test_block_manager_randomized_invariants():
 # ---------------------------------------------------------------------------
 # Scheduler
 # ---------------------------------------------------------------------------
+def _is_decode_row(r):
+    return r.num_generated > 0 and len(r.tokens) - r.num_cached == 1
+
+
+def _run_batch(sched, batch, token=lambda: 7):
+    """What the engine does with a scheduled batch, model-free: every
+    row advances by the tokens it was scheduled, and a row that reached
+    its last token gets one sampled. Holds every batch to the mixed
+    policy's contract on the way: one count per row, the raw budget and
+    the seats never exceeded, decode rows ahead of prefill chunks, the
+    kind naming what the batch holds."""
+    cfg = sched.config
+    assert len(batch.num_scheduled) == len(batch.requests)
+    assert sum(batch.num_scheduled) <= cfg.max_batched_tokens
+    assert len(batch.requests) <= cfg.max_num_seqs
+    assert len(sched.running) <= cfg.max_num_seqs
+    decode = [_is_decode_row(r) for r in batch.requests]
+    assert decode == sorted(decode, reverse=True), "decode rows go first"
+    want = ("idle" if not decode else "decode" if all(decode)
+            else "mixed" if any(decode) else "prefill")
+    assert batch.kind == want
+    for r, n in zip(batch.requests, batch.num_scheduled):
+        d = len(r.draft_tokens)
+        assert 1 <= n <= len(r.tokens) + d - r.num_cached
+        r.draft_tokens = []
+        r.num_cached += n - d
+        if r.num_cached == len(r.tokens) and r.append_token(token()):
+            sched.finish(r)
+    sched.block_manager.check_invariants()
+
+
 def _drive(sched, max_iters=200):
-    """Minimal engine loop: run scheduled batches, append one token per
-    scheduled request per iteration, retire finished requests. Returns
-    the per-iteration batch kinds."""
-    kinds = []
+    """Minimal engine loop: schedule, run, retire, until nothing is
+    left. Returns the per-iteration batches' (kind, num_scheduled)."""
+    seen = []
     for _ in range(max_iters):
         if not sched.has_unfinished():
             break
         batch = sched.schedule()
-        kinds.append(batch.kind)
-        assert not (batch.is_empty and batch.kind != "idle")
-        for r in batch.requests:
-            r.num_cached += len(r.tokens_to_run())
-            if r.append_token(7):
-                sched.finish(r)
-        sched.block_manager.check_invariants()
+        seen.append((batch.kind, list(batch.num_scheduled)))
+        _run_batch(sched, batch)
     assert not sched.has_unfinished(), "starved requests remain"
-    return kinds
+    return seen
 
 
 def test_scheduler_interleaves_prefill_and_decode():
+    """Prompts that fit the budget prefill whole in one batch, then
+    decode; a late arrival joins the decoding rows in a MIXED batch,
+    behind them."""
     bm = BlockManager(num_blocks=64, block_size=4)
     s = Scheduler(bm, SchedulerConfig(max_num_seqs=4,
                                       max_batched_tokens=64))
     for i in range(3):
         s.add(_req(i, n_prompt=5, max_new=3, arrival=float(i)))
-    kinds = _drive(s)
-    assert kinds[0] == "prefill"
-    assert "decode" in kinds
+    b = s.schedule()
+    assert b.kind == "prefill" and b.num_scheduled == [5, 5, 5]
+    _run_batch(s, b)
+    b = s.schedule()
+    assert b.kind == "decode" and b.num_scheduled == [1, 1, 1]
+    _run_batch(s, b)
+    s.add(_req("late", n_prompt=6, max_new=2, arrival=9.0))
+    b = s.schedule()
+    assert b.kind == "mixed"
+    assert [r.request_id for r in b.requests] == ["0", "1", "2", "late"]
+    assert b.num_scheduled == [1, 1, 1, 6]
+    _run_batch(s, b)
+    _drive(s)
     assert bm.num_free_blocks == 64
 
 
 def test_scheduler_token_budget_splits_prefill_batches():
+    """The raw budget is filled to the token: the prompt that crosses it
+    is cut there, and goes on next iteration behind the decode row."""
     bm = BlockManager(num_blocks=64, block_size=4)
     s = Scheduler(bm, SchedulerConfig(max_num_seqs=8,
                                       max_batched_tokens=10))
-    for i in range(4):
-        s.add(_req(i, n_prompt=6, max_new=1, arrival=float(i)))
+    reqs = [_req(i, n_prompt=6, max_new=2, arrival=float(i))
+            for i in range(4)]
+    for r in reqs:
+        s.add(r)
     b1 = s.schedule()
-    assert b1.kind == "prefill" and len(b1.requests) == 1  # 6+6 > 10
+    assert b1.kind == "prefill"
+    assert [r.request_id for r in b1.requests] == ["0", "1"]
+    assert b1.num_scheduled == [6, 4]           # 6+6 > 10: cut at 10
+    assert reqs[1].was_chunked and not reqs[0].was_chunked
+    _run_batch(s, b1)
     b2 = s.schedule()
-    assert b2.kind == "prefill" and len(b2.requests) == 1
+    # decode row, then the split prompt's rest, then new admissions
+    assert [r.request_id for r in b2.requests] == ["0", "1", "2", "3"]
+    assert b2.num_scheduled == [1, 2, 6, 1] and b2.kind == "mixed"
+    assert s.num_prefill_chunks == 3    # 1's two pieces and 3's first
+    _run_batch(s, b2)
+    _drive(s)
+    assert all(r.is_finished for r in reqs)
 
 
 def test_scheduler_overbudget_prompt_admitted_alone():
+    """A prompt over the whole budget is not refused and not starved: it
+    arrives as budget-sized chunks, and samples only after the last."""
     bm = BlockManager(num_blocks=64, block_size=4)
     s = Scheduler(bm, SchedulerConfig(max_num_seqs=8,
                                       max_batched_tokens=8))
-    s.add(_req("big", n_prompt=20, max_new=1))
-    b = s.schedule()
-    assert b.kind == "prefill" and len(b.requests) == 1
+    big = _req("big", n_prompt=20, max_new=1)
+    s.add(big)
+    seen = _drive(s)
+    assert seen == [("prefill", [8]), ("prefill", [8]), ("prefill", [4])]
+    assert s.num_prefill_chunks == 3 and big.generated == [7]
 
 
 def test_scheduler_preempts_latest_arrival_on_oom():
@@ -162,11 +219,9 @@ def test_scheduler_preempts_latest_arrival_on_oom():
         s.add(r)
     batch = s.schedule()       # both prefill: 2 blocks each, cache full
     assert [r.request_id for r in batch.requests] == ["a", "b"]
-    for r in batch.requests:
-        r.num_cached += len(r.tokens_to_run())
-        r.append_token(7)
+    _run_batch(s, batch)
     batch = s.schedule()       # both need a slot; only b's eviction frees one
-    assert batch.kind == "decode"
+    assert batch.kind == "decode" and batch.num_scheduled == [1]
     assert [r.request_id for r in batch.requests] == ["a"]
     assert [r.request_id for r in batch.preempted] == ["b"]
     assert b.status == RequestStatus.WAITING
@@ -193,12 +248,13 @@ def test_scheduler_starvation_guard_all_requests_finish():
 
 
 def test_scheduler_randomized_storm():
-    """Random arrivals + tight memory: preempted requests re-admit and
-    finish; block accounting stays exact throughout."""
+    """Random arrivals + tight memory + a budget that cuts prompts:
+    preempted requests re-admit and finish; block accounting and the
+    batch contract (``_run_batch``) hold at every iteration."""
     rng = np.random.default_rng(1)
-    bm = BlockManager(num_blocks=10, block_size=2)
+    bm = BlockManager(num_blocks=7, block_size=2)
     s = Scheduler(bm, SchedulerConfig(max_num_seqs=3,
-                                      max_batched_tokens=32))
+                                      max_batched_tokens=5))
     reqs = []
     for it in range(400):
         if len(reqs) < 20 and rng.random() < 0.2:
@@ -208,15 +264,11 @@ def test_scheduler_randomized_storm():
             s.add(r)
         if not s.has_unfinished():
             continue
-        batch = s.schedule()
-        for r in batch.requests:
-            r.num_cached += len(r.tokens_to_run())
-            if r.append_token(int(rng.integers(0, 100))):
-                s.finish(r)
-        bm.check_invariants()
+        _run_batch(s, s.schedule(), lambda: int(rng.integers(0, 100)))
     _drive(s, max_iters=500)
     assert len(reqs) == 20 and all(r.is_finished for r in reqs)
-    assert bm.num_free_blocks == 10
+    assert bm.num_free_blocks == 7
+    assert s.num_preemptions > 0 and s.num_prefill_chunks > 0
 
 
 def test_scheduler_abort():
@@ -229,6 +281,162 @@ def test_scheduler_abort():
     assert a.status == RequestStatus.FINISHED
     assert "a" not in [r.request_id for r in s.running]
     bm.check_invariants()
+
+
+# -- the mixed policy, pass by pass -----------------------------------------
+def _mixed_decode_continuation_admission():
+    """Pass A before B before C: the decode row, then the split prompt's
+    next piece, then the newcomer with what is left of the budget."""
+    bm = BlockManager(num_blocks=64, block_size=4)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=4, max_batched_tokens=8))
+    s.add(_req("dec", n_prompt=2, max_new=8, arrival=1.0))
+    _run_batch(s, s.schedule())
+    s.add(_req("mid", n_prompt=12, max_new=8, arrival=2.0))
+    b = s.schedule()
+    assert [r.request_id for r in b.requests] == ["dec", "mid"]
+    assert b.num_scheduled == [1, 7]
+    _run_batch(s, b)
+    # the newcomer outranks both and still queues behind their rows
+    new = Request(request_id="new", prompt_ids=[1, 2, 3],
+                  sampling=SamplingParams(max_new_tokens=8, priority=-1))
+    s.add(new)
+    b = s.schedule()
+    assert b.kind == "mixed"
+    assert [r.request_id for r in b.requests] == ["dec", "mid", "new"]
+    assert b.num_scheduled == [1, 5, 2]
+    assert new.was_chunked and new.status == RequestStatus.RUNNING
+    _run_batch(s, b)
+    b = s.schedule()                 # new's last token, no sample before
+    assert [r.request_id for r in b.requests] == ["dec", "mid", "new"]
+    assert b.num_scheduled == [1, 1, 1] and new.num_generated == 0
+    _run_batch(s, b)
+    assert new.num_generated == 1
+
+
+def _mixed_continuation_keeps_its_seat():
+    """A prompt in the middle of its chunks is RUNNING: a more important
+    arrival does not take the seat, and the chunks do not restart."""
+    bm = BlockManager(num_blocks=64, block_size=4)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=1, max_batched_tokens=4))
+    big = _req("big", n_prompt=10, max_new=2, arrival=1.0)
+    s.add(big)
+    _run_batch(s, s.schedule())
+    assert big.num_cached == 4 and big.was_chunked
+    vip = Request(request_id="vip", prompt_ids=[1, 2],
+                  sampling=SamplingParams(max_new_tokens=1, priority=-1))
+    s.add(vip)
+    seen = []
+    while not big.is_finished:
+        b = s.schedule()
+        assert [r.request_id for r in b.requests] == ["big"]
+        assert vip.status == RequestStatus.WAITING
+        seen.append(b.num_scheduled[0])
+        _run_batch(s, b)
+    assert seen == [4, 2, 1]         # the rest of the prompt, one decode
+    assert s.num_prefill_chunks == 3 and s.num_preemptions == 0
+    b = s.schedule()
+    assert [r.request_id for r in b.requests] == ["vip"]
+
+
+def _mixed_verify_row_all_or_nothing():
+    """A verify row costs 1 + its drafts, whole or not at all: the row
+    the budget cannot verify sheds its drafts and decodes plainly."""
+    bm = BlockManager(num_blocks=64, block_size=2)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=2, max_batched_tokens=4))
+    a = _req("a", n_prompt=3, max_new=8, arrival=1.0)
+    b = _req("b", n_prompt=1, max_new=8, arrival=2.0)
+    s.add(a), s.add(b)
+    _run_batch(s, s.schedule())
+    a.draft_tokens, b.draft_tokens = [11, 12], [21, 22]
+    batch = s.schedule()
+    assert [r.request_id for r in batch.requests] == ["a", "b"]
+    assert batch.num_scheduled == [3, 1] and batch.kind == "decode"
+    assert a.draft_tokens == [11, 12] and b.draft_tokens == []
+    # a's table covers its drafts' positions, b's only its own token
+    assert len(bm.block_table("a")) == bm.blocks_needed(len(a.tokens) + 2)
+    assert len(bm.block_table("b")) == bm.blocks_needed(len(b.tokens))
+    _run_batch(s, batch)
+
+
+def _mixed_table_holding_continuation():
+    """A request that arrives with its table filled (a KV ship) waits
+    for a seat like any other, then resumes mid-context on the blocks it
+    holds: no fresh allocation, nothing recomputed."""
+    bm = BlockManager(num_blocks=16, block_size=2)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=1, max_batched_tokens=8))
+    run = _req("run", n_prompt=3, max_new=2, arrival=1.0)
+    s.add(run)
+    _run_batch(s, s.schedule())
+    cont = _req("cont", n_prompt=7, max_new=2, arrival=2.0)
+    shipped = list(bm.import_blocks("cont", 4))
+    cont.num_cached = 4
+    s.add_continuation(cont)
+    b = s.schedule()
+    assert [r.request_id for r in b.requests] == ["run"]   # no seat yet
+    assert cont.status == RequestStatus.WAITING and bm.has_table("cont")
+    _run_batch(s, b)
+    assert run.is_finished
+    b = s.schedule()
+    assert [r.request_id for r in b.requests] == ["cont"]
+    assert b.num_scheduled == [3] and b.kind == "prefill"
+    assert s.num_continuation_resumes == 1
+    assert bm.block_table("cont")[:2] == shipped
+    _run_batch(s, b)
+    assert cont.num_generated == 1 and cont.num_cached == 7
+
+
+def _mixed_budget_equal_to_seats():
+    """The smallest budget the config admits: every seat affords its
+    decode token and nothing is left to admit with."""
+    with pytest.raises(ValueError, match="max_batched_tokens"):
+        SchedulerConfig(max_num_seqs=4, max_batched_tokens=3)
+    bm = BlockManager(num_blocks=64, block_size=2)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=3, max_batched_tokens=3))
+    reqs = [_req(i, n_prompt=1, max_new=3, arrival=float(i))
+            for i in range(3)]
+    late = _req("late", n_prompt=2, max_new=1, arrival=9.0)
+    for r in reqs:
+        s.add(r)
+    b = s.schedule()
+    assert b.num_scheduled == [1, 1, 1] and b.kind == "prefill"
+    _run_batch(s, b)
+    s.add(late)
+    while not reqs[0].is_finished:
+        b = s.schedule()
+        assert b.num_scheduled == [1, 1, 1] and b.kind == "decode"
+        assert late.status == RequestStatus.WAITING
+        _run_batch(s, b)
+    _drive(s)
+    assert late.is_finished
+
+
+def _mixed_idle():
+    """Nothing to run is an idle batch, empty in every list; so is a
+    lone arrival the pool cannot hold yet, which stays queued."""
+    bm = BlockManager(num_blocks=2, block_size=2)
+    s = Scheduler(bm, SchedulerConfig(max_num_seqs=2, max_batched_tokens=8))
+    b = s.schedule()
+    assert b.kind == "idle" and b.is_empty and b.num_scheduled == []
+    assert not (b.preempted or b.swapped_in or b.expired)
+    bm.allocate("squatter", 4)
+    s.add(_req("w", n_prompt=3))
+    b = s.schedule()
+    assert b.kind == "idle" and b.is_empty and s.num_waiting == 1
+    bm.free("squatter")
+    b = s.schedule()
+    assert b.kind == "prefill" and b.num_scheduled == [3]
+
+
+@pytest.mark.parametrize("case", [
+    _mixed_decode_continuation_admission,
+    _mixed_continuation_keeps_its_seat,
+    _mixed_verify_row_all_or_nothing,
+    _mixed_table_holding_continuation,
+    _mixed_budget_equal_to_seats,
+    _mixed_idle,
+], ids=lambda f: f.__name__.removeprefix("_mixed_"))
+def test_schedule_mixed(case):
+    case()
 
 
 def test_request_and_sampling_validation():
@@ -324,10 +532,7 @@ def test_scheduler_swap_preempts_and_restores():
     b = _req("b", n_prompt=4, max_new=8, arrival=2.0)
     for r in (a, b):
         s.add(r)
-    s.schedule()                             # both prefill, cache full
-    for r in (a, b):
-        r.num_cached += len(r.tokens_to_run())
-        r.append_token(7)
+    _run_batch(s, s.schedule())              # both prefill, cache full
     batch = s.schedule()                     # OOM -> b swaps out
     assert [r.request_id for r in batch.requests] == ["a"]
     assert [r.request_id for r in batch.preempted] == ["b"]
@@ -343,7 +548,7 @@ def test_scheduler_swap_preempts_and_restores():
     s.finish(a)
     batch = s.schedule()
     assert [r.request_id for r in batch.swapped_in] == ["b"]
-    assert batch.kind == "decode"
+    assert batch.kind == "decode" and batch.num_scheduled == [1]
     assert [r.request_id for r in batch.requests] == ["b"]
     assert b.status == RequestStatus.RUNNING
     assert s.num_swap_ins == 1 and len(sw.in_calls) == 1
@@ -363,10 +568,7 @@ def test_scheduler_host_pool_exhaustion_falls_back_to_recompute():
     b = _req("b", n_prompt=4, max_new=8, arrival=2.0)
     for r in (a, b):
         s.add(r)
-    s.schedule()
-    for r in (a, b):
-        r.num_cached += len(r.tokens_to_run())
-        r.append_token(7)
+    _run_batch(s, s.schedule())
     batch = s.schedule()                     # b evicted; pool too small
     assert [r.request_id for r in batch.preempted] == ["b"]
     assert b.status == RequestStatus.WAITING
@@ -390,10 +592,7 @@ def test_scheduler_torn_spill_copy_frees_host_slots():
     b = _req("b", n_prompt=4, max_new=8, arrival=2.0)
     for r in (a, b):
         s.add(r)
-    s.schedule()
-    for r in (a, b):
-        r.num_cached += len(r.tokens_to_run())
-        r.append_token(7)
+    _run_batch(s, s.schedule())
     batch = s.schedule()                     # OOM -> spill of b tears
     assert [r.request_id for r in batch.preempted] == ["b"]
     assert b.status == RequestStatus.WAITING  # recompute, not SWAPPED
@@ -418,9 +617,7 @@ def test_scheduler_priority_orders_admission_and_eviction():
     s.add(lo), s.add(vip)
     batch = s.schedule()
     assert [r.request_id for r in batch.requests] == ["vip", "lo"]
-    for r in batch.requests:
-        r.num_cached += len(r.tokens_to_run())
-        r.append_token(7)
+    _run_batch(s, batch)
     batch = s.schedule()                     # OOM: LO is the victim
     assert [r.request_id for r in batch.requests] == ["vip"]
     assert [r.request_id for r in batch.preempted] == ["lo"]
@@ -468,7 +665,7 @@ def test_randomized_abort_interleaving_never_leaks_blocks():
     bm = BlockManager(num_blocks=10, block_size=2, num_host_blocks=4)
     sw = _StubSwapper()
     s = Scheduler(bm, SchedulerConfig(max_num_seqs=3,
-                                      max_batched_tokens=32),
+                                      max_batched_tokens=6),
                   swap_mode="host", kv_swapper=sw)
     reqs = []
     n_aborted = 0
@@ -497,12 +694,7 @@ def test_randomized_abort_interleaving_never_leaks_blocks():
                 n_aborted += 1
         if not s.has_unfinished():
             continue
-        batch = s.schedule()
-        for r in batch.requests:
-            r.num_cached += len(r.tokens_to_run())
-            if r.append_token(int(rng.integers(0, 100))):
-                s.finish(r)
-        bm.check_invariants()
+        _run_batch(s, s.schedule(), lambda: int(rng.integers(0, 100)))
     # drain the stragglers (aborting a random half on the way out)
     guard = 0
     while s.has_unfinished():
@@ -512,12 +704,7 @@ def test_randomized_abort_interleaving_never_leaks_blocks():
         if live and rng.random() < 0.3:
             s.abort(live[0].request_id)
             n_aborted += 1
-        batch = s.schedule()
-        for r in batch.requests:
-            r.num_cached += len(r.tokens_to_run())
-            if r.append_token(int(rng.integers(0, 100))):
-                s.finish(r)
-        bm.check_invariants()
+        _run_batch(s, s.schedule(), lambda: int(rng.integers(0, 100)))
     assert len(reqs) == 24 and all(r.is_finished for r in reqs)
     assert n_aborted > 0, "storm never exercised abort"
     # the satellite's pin: NOTHING leaks, device or host side

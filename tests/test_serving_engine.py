@@ -34,28 +34,77 @@ def _prompts(rng, vocab, lens):
     return [list(map(int, rng.integers(0, vocab, size=n))) for n in lens]
 
 
-def test_prefill_logits_match_naive_forward(tiny_model):
-    """One paged prefill == the dense causal forward's last-token
-    logits (the compiled serving step computes the same math)."""
+def _ragged_step(m, rows, kcs, vcs, T=16, S=2, max_blocks=4):
+    """One ``forward_ragged`` call the way the engine packs it: ``rows``
+    is ``[(new_tokens, num_cached, block_table)]``; the stream and the
+    slots are padded to the fixed (T, S). Returns each row's logits and
+    the caches."""
+    ids = np.zeros((T,), np.int32)
+    cu = np.zeros((S + 1,), np.int32)
+    ctx = np.zeros((S,), np.int32)
+    bt = np.full((S, max_blocks), -1, np.int32)
+    off = 0
+    for i, (toks, cached, table) in enumerate(rows):
+        ids[off:off + len(toks)] = toks
+        off += len(toks)
+        cu[i + 1] = off
+        ctx[i] = cached + len(toks)
+        bt[i, :len(table)] = table
+    cu[len(rows) + 1:] = off
+    logits, kcs, vcs = m.forward_ragged(ids, kcs, vcs, bt, cu, ctx,
+                                        np.int32(len(rows)))
+    return logits.numpy()[:len(rows)], kcs, vcs
+
+
+def _dense_last(m, tokens):
+    return m(paddle.to_tensor(np.asarray([tokens], np.int32))).numpy()[0, -1]
+
+
+def _whole_prompt(m, a, b, step):
+    (la,), kcs, _ = step([(a, 0, [0, 1, 2])])
+    # prefill wrote the cache: the first layer's block 0 is nonzero
+    assert float(np.abs(np.asarray(kcs)[0, 0]).sum()) > 0
+    return [(la, a)]
+
+
+def _two_chunks(m, a, b, step):
+    step([(a[:4], 0, [0, 1, 2])])         # a mid-prompt row: no sample
+    (la,), _, _ = step([(a[4:], 4, [0, 1, 2])])
+    return [(la, a)]
+
+
+def _continuation_beside_decode(m, a, b, step):
+    (_, lb), _, _ = step([(a[:5], 0, [0, 1, 2]), (b, 0, [3, 4])])
+    nxt = int(np.argmax(lb))
+    # decode rows go first, as the scheduler orders them
+    (lb2, la), _, _ = step([([nxt], len(b), [3, 4]),
+                            (a[5:], 5, [0, 1, 2])])
+    return [(lb, b), (lb2, b + [nxt]), (la, a)]
+
+
+@pytest.mark.parametrize("case", [_whole_prompt, _two_chunks,
+                                  _continuation_beside_decode],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_prefill_logits_match_naive_forward(tiny_model, case):
+    """The compiled serving step's forward == the dense causal forward's
+    last-token logits, for a prompt prefilled whole, one fed as two
+    chunks through the paged cache, and a chunk continuation sharing a
+    step with a decode row."""
     m = tiny_model
     cfg = m.config
     rng = np.random.default_rng(0)
-    s, bs, nb = 6, 4, 8
-    ids = rng.integers(0, cfg.vocab_size, size=(1, s)).astype(np.int32)
-    L = cfg.num_hidden_layers
+    a, b = _prompts(rng, cfg.vocab_size, [9, 6])
     kh = cfg.num_key_value_heads
     hd = cfg.hidden_size // cfg.num_attention_heads
-    kcs = np.zeros((L, nb, bs, kh, hd), np.float32)
-    vcs = np.zeros_like(kcs)
-    bt = np.asarray([[0, 1]], np.int32)
-    logits, kcs2, vcs2 = m.forward_paged(
-        ids, kcs, vcs, bt,
-        np.asarray([s], np.int32), np.asarray([0], np.int32),
-        np.asarray([s], np.int32))
-    ref = m(paddle.to_tensor(ids)).numpy()[:, -1]
-    np.testing.assert_allclose(logits.numpy(), ref, rtol=2e-4, atol=2e-4)
-    # prefill wrote the cache: the first layer's block 0 is nonzero
-    assert float(np.abs(np.asarray(kcs2)[0, 0]).sum()) > 0
+    cache = [np.zeros((cfg.num_hidden_layers, 8, 4, kh, hd), np.float32)] * 2
+
+    def step(rows):
+        logits, cache[0], cache[1] = _ragged_step(m, rows, *cache)
+        return logits, cache[0], cache[1]
+
+    for got, tokens in case(m, a, b, step):
+        np.testing.assert_allclose(got, _dense_last(m, tokens),
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_e2e_concurrent_unequal_lengths_with_late_arrival(tiny_model):
@@ -271,56 +320,3 @@ def test_mixed_greedy_and_sampled_batch_parity(tiny_model):
     assert eng.get_request(rg).generated == _naive(m, pg, 4)
     assert len(eng.get_request(rs).generated) == 4
     assert eng.num_logits_fetches == 0
-
-
-@pytest.mark.slow
-def test_bench_serving_smoke():
-    """The bench.py --serving --tiny smoke: BENCH_serving JSON fields
-    present and every request completes within the tier budget."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    out = bench.bench_serving(tiny=True)
-    assert out["metric"] == "serving_tokens_per_sec"
-    assert out["value"] > 0
-    ex = out["extra"]
-    assert ex["num_finished"] == 10
-    for key in ("ttft_ms_avg", "tpot_ms_avg", "batch_occupancy",
-                "kv_block_utilization", "preemptions"):
-        assert key in ex
-    assert ex["batch_occupancy"] > 0
-    # the ISSUE-6 resilience counters ride the JSON, with real traffic
-    # from the swap+drain smoke phase
-    for key in ("serving_swapped_out", "serving_rejected",
-                "serving_expired", "serving_drain_completed"):
-        assert key in ex
-    smoke = ex["resilience_smoke"]
-    assert smoke["serving_swapped_out"] > 0
-    assert smoke["serving_swapped_in"] == smoke["serving_swapped_out"]
-    assert smoke["serving_drain_completed"] == 1
-    # ISSUE-9 ragged-vs-bucketed comparison phase: padding gone, one
-    # compiled step, the shared prefix actually hit the COW cache
-    cmp = ex["ragged_comparison"]
-    assert cmp["ragged_padded_token_frac"] == 0.0
-    assert cmp["bucketed_padded_token_frac"] > 0.0
-    assert cmp["ragged_compiled_step_shapes"] == 1
-    assert cmp["bucketed_compiled_step_shapes"] > 1
-    assert cmp["prefix_cache_hits"] > 0
-    assert cmp["prefill_chunks"] > 0
-    # ISSUE-11 in-graph sampling + speculative phases: both fetchless,
-    # the self-draft spec run actually proposed and accepted tokens
-    smp = ex["sampled_decode"]
-    assert smp["tokens_per_sec"] > 0
-    assert smp["sampled_steps"] > 0
-    assert smp["logits_fetches"] == 0
-    spc = ex["speculative"]
-    assert spc["tokens_per_sec"] > 0
-    assert spc["spec_proposed"] > 0
-    assert spc["spec_accepted"] > 0
-    assert 0.0 < spc["spec_acceptance_rate"] <= 1.0
-    assert spc["logits_fetches"] == 0

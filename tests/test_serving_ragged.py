@@ -1,11 +1,12 @@
-"""Ragged serving hot path (ISSUE 9): parity + compile-count pins.
+"""The serving step: parity + compile-count pins.
 
-The ragged engine (single-shape packed step + chunked prefill + COW
-prefix caching) must be TOKEN-IDENTICAL to the bucketed engine it
-replaces — greedy and sampled, through chunking, preemption and fleet
-hand-off — while compiling exactly ONE step function for a whole mixed
-prefill/decode workload (the bucket lattice it collapses compiles one
-function per (batch, seq) bucket)."""
+The engine (single-shape packed step + chunked prefill + COW prefix
+caching) must be TOKEN-IDENTICAL to oracles that share none of it —
+greedy rows to the dense full-recompute forward, sampled rows to that
+forward's logits put through the sampler with the request's own key —
+and a sampled stream must not depend on chunking, batch mix, preemption
+or fleet hand-off, while the engine compiles exactly ONE step function
+for a whole mixed prefill/decode workload."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,7 +14,9 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.distributed.watchdog import PreemptionMonitor
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import EngineConfig, LLMEngine, SamplingParams
+from paddle_tpu.serving import (
+    EngineConfig, LLMEngine, Request, SamplingParams,
+)
 from paddle_tpu.serving.fleet import FleetRouter, InProcessReplica
 
 
@@ -36,16 +39,15 @@ def _prompts(seed, vocab, lens):
     return [list(map(int, rng.integers(0, vocab, size=n))) for n in lens]
 
 
-def _cfg(ragged, **kw):
+def _cfg(**kw):
     kw.setdefault("block_size", 4)
     kw.setdefault("max_num_seqs", 4)
     kw.setdefault("max_model_len", 64)
-    return EngineConfig(ragged=ragged, chunked_prefill=ragged,
-                        prefix_cache=ragged, **kw)
+    return EngineConfig(**kw)
 
 
-def _serve(model, prompts, samplings, ragged, **cfg_kw):
-    eng = LLMEngine(model, _cfg(ragged, **cfg_kw))
+def _serve(model, prompts, samplings, **cfg_kw):
+    eng = LLMEngine(model, _cfg(**cfg_kw))
     rids = [eng.add_request(f"r{i}", p, sampling=sp)
             for i, (p, sp) in enumerate(zip(prompts, samplings))]
     steps = 0
@@ -56,30 +58,35 @@ def _serve(model, prompts, samplings, ragged, **cfg_kw):
     return eng, [eng.get_request(r).generated for r in rids]
 
 
+def _alone(model, rid, prompt, sampling):
+    """The same request served alone by a fresh engine whose budget takes
+    the prompt whole: what a sampled row's stream must equal, since it is
+    a function of the request's key and emitted-step count only."""
+    eng = LLMEngine(model, _cfg())
+    eng.add_request(rid, prompt, sampling=sampling)
+    eng.run()
+    assert eng.scheduler.num_prefill_chunks == 0
+    assert eng.scheduler.num_preemptions == 0
+    return eng.get_request(rid).generated
+
+
 # ---------------------------------------------------------------------------
 # config resolution
 # ---------------------------------------------------------------------------
 def test_ragged_is_the_default_for_ragged_capable_models(tiny_model):
-    eng = LLMEngine(tiny_model, EngineConfig(
-        block_size=4, max_num_seqs=2, max_model_len=32))
-    assert eng._ragged
-    assert eng.cfg.chunked_prefill and eng.cfg.prefix_cache
-    # explicit opt-out restores the bucketed lattice wholesale
-    eng_b = LLMEngine(tiny_model, EngineConfig(
-        block_size=4, max_num_seqs=2, max_model_len=32, ragged=False))
-    assert not eng_b._ragged
-    assert not eng_b.cfg.chunked_prefill and not eng_b.cfg.prefix_cache
+    """No option picks a step: the defaults are the chunking scheduler on
+    the step's raw budget and the prefix cache, and a model that cannot
+    run that step is refused by name."""
+    ecfg = EngineConfig(block_size=4, max_num_seqs=2, max_model_len=32)
+    eng = LLMEngine(tiny_model, ecfg)
+    assert eng.cfg.prefix_cache
+    assert eng.scheduler.config.max_batched_tokens == eng._ragged_T == 64
 
+    class DenseOnly:
+        config = tiny_model.config
 
-def test_invalid_knob_combinations_raise(tiny_model):
-    with pytest.raises(ValueError, match="chunked_prefill"):
-        LLMEngine(tiny_model, EngineConfig(
-            block_size=4, max_num_seqs=2, max_model_len=32,
-            ragged=True, chunked_prefill=False))
-    with pytest.raises(ValueError, match="prefix_cache"):
-        LLMEngine(tiny_model, EngineConfig(
-            block_size=4, max_num_seqs=2, max_model_len=32,
-            ragged=False, prefix_cache=True))
+    with pytest.raises(ValueError, match="DenseOnly has no forward_ragged"):
+        LLMEngine(DenseOnly(), ecfg)
 
 
 # ---------------------------------------------------------------------------
@@ -87,53 +94,87 @@ def test_invalid_knob_combinations_raise(tiny_model):
 # ---------------------------------------------------------------------------
 def test_mixed_workload_parity_and_single_compiled_shape(tiny_model):
     """Long prompts over the token budget (forced chunks), short
-    prompts, a sampled row: ragged == bucketed for every request, the
-    greedy rows == naive generate, and the WHOLE ragged run (chunked
-    prefills, mixed batches, shrinking decode tails) dispatched ONE
-    compiled step shape while the bucketed run walked its lattice."""
+    prompts, two sampled rows (one of them chunked): the greedy rows ==
+    naive generate, the sampled rows == the same requests served alone
+    and whole, and the WHOLE run (chunked prefills, mixed batches,
+    shrinking decode tails) dispatched ONE compiled step shape."""
     m = tiny_model
     prompts = _prompts(21, m.config.vocab_size, [29, 3, 22, 6])
     sps = [SamplingParams(max_new_tokens=6),
            SamplingParams(max_new_tokens=5, temperature=0.8, seed=3),
-           SamplingParams(max_new_tokens=6),
+           SamplingParams(max_new_tokens=6, temperature=0.9, top_k=12,
+                          top_p=0.95, seed=5),
            SamplingParams(max_new_tokens=4)]
-    # budget 16 < the 29/22-token prompts: the ragged engine must chunk
-    eng_r, outs_r = _serve(m, prompts, sps, True, max_batched_tokens=16)
-    eng_b, outs_b = _serve(m, prompts, sps, False, max_batched_tokens=16)
-    assert outs_r == outs_b
-    for i in (0, 2, 3):          # greedy rows vs the full-recompute oracle
-        assert outs_r[i] == _naive(m, prompts[i], sps[i].max_new_tokens)
-    assert len(eng_r._seen_shapes) == 1, eng_r._seen_shapes
-    assert len(eng_b._seen_shapes) > 1
-    snap = eng_r.metrics.snapshot()
+    # budget 16 < the 29/22-token prompts: the engine must chunk
+    eng, outs = _serve(m, prompts, sps, max_batched_tokens=16)
+    for i in (0, 3):             # greedy rows vs the full-recompute oracle
+        assert outs[i] == _naive(m, prompts[i], sps[i].max_new_tokens)
+    assert eng.get_request("r2").was_chunked
+    for i in (1, 2):             # sampled rows: chunking and mix invariant
+        assert outs[i] == _alone(m, f"r{i}", prompts[i], sps[i])
+    assert len(eng._seen_shapes) == 1, eng._seen_shapes
+    snap = eng.metrics.snapshot()
     assert snap["serving_prefill_chunks"] > 0
     assert snap["mixed_steps"] > 0, \
         "chunk continuations never shared a step with decode rows"
-    assert snap["padded_token_frac"] == 0.0
-    assert eng_r.metrics.num_generated_tokens == \
-        eng_b.metrics.num_generated_tokens
+    assert eng.metrics.num_generated_tokens == sum(map(len, outs))
+
+
+def test_sampled_stream_matches_dense_forward_through_the_sampler(
+        tiny_model):
+    """The oracle that shares neither paging nor scheduling with the
+    engine: each token of a sampled request is the draw that
+    ``sample_or_verify`` makes from the dense float32 forward's last-row
+    logits over the request's history, with the request's own key,
+    advanced as the sampler advances it. The prompt is chunked and
+    shares its steps with another request."""
+    from paddle_tpu.ops.sampling import sample_or_verify
+
+    m = tiny_model
+    prompt, other = _prompts(25, m.config.vocab_size, [21, 7])
+    sp = SamplingParams(max_new_tokens=8, temperature=0.9, top_k=20,
+                        top_p=0.9, seed=17)
+    eng, (got, _) = _serve(
+        m, [prompt, other], [sp, SamplingParams(max_new_tokens=8)],
+        max_batched_tokens=8)
+    assert eng.get_request("r0").was_chunked
+
+    key = Request(request_id="r0", prompt_ids=prompt,
+                  sampling=sp).device_key[None]
+    history = list(prompt)
+    for step, tok in enumerate(got):
+        logits = m(paddle.to_tensor(np.asarray([history], np.int32)))
+        toks, n_emit, key = sample_or_verify(
+            logits._data[:, -1:].astype(jnp.float32),
+            jnp.zeros((1, 0), jnp.int32), jnp.zeros((1,), jnp.int32), key,
+            jnp.asarray([sp.temperature], jnp.float32),
+            jnp.asarray([sp.top_k], jnp.int32),
+            jnp.asarray([sp.top_p], jnp.float32))
+        assert int(n_emit[0]) == 1
+        assert int(toks[0, 0]) == tok, f"token {step} of {got}"
+        history.append(tok)
+    np.testing.assert_array_equal(
+        np.asarray(key[0]), eng.get_request("r0").device_key)
 
 
 def test_parity_through_preemption(tiny_model):
-    """Cache sized so the batch cannot all reach full length on either
-    engine: both preempt, both still produce identical streams."""
+    """Cache sized so the batch cannot all reach full length: the engine
+    preempts (the sampled row among the victims) and recomputes, and
+    every stream is what it would have been undisturbed."""
     m = tiny_model
     prompts = _prompts(22, m.config.vocab_size, [6, 8, 5, 7])
     sps = [SamplingParams(max_new_tokens=8),
            SamplingParams(max_new_tokens=8),
-           SamplingParams(max_new_tokens=8, temperature=0.7, seed=11),
-           SamplingParams(max_new_tokens=8)]
-    kw = dict(num_blocks=10, max_model_len=32)
-    eng_r, outs_r = _serve(m, prompts, sps, True, **kw)
-    eng_b, outs_b = _serve(m, prompts, sps, False, **kw)
-    assert eng_r.scheduler.num_preemptions > 0
-    assert eng_b.scheduler.num_preemptions > 0
-    assert outs_r == outs_b
-    for i in (0, 1, 3):
-        assert outs_r[i] == _naive(m, prompts[i], 8)
-    for eng in (eng_r, eng_b):
-        assert eng.block_manager.num_free_blocks == eng.cfg.num_blocks
-        eng.block_manager.check_invariants()
+           SamplingParams(max_new_tokens=8),
+           SamplingParams(max_new_tokens=8, temperature=0.7, seed=11)]
+    eng, outs = _serve(m, prompts, sps, num_blocks=10, max_model_len=32)
+    assert eng.scheduler.num_preemptions > 0
+    assert eng.get_request("r3").num_preemptions > 0
+    for i in (0, 1, 2):
+        assert outs[i] == _naive(m, prompts[i], 8)
+    assert outs[3] == _alone(m, "r3", prompts[3], sps[3])
+    assert eng.block_manager.num_free_blocks == eng.cfg.num_blocks
+    eng.block_manager.check_invariants()
 
 
 def test_prefix_cache_hit_cap_and_cow_keep_parity(tiny_model):
@@ -148,7 +189,7 @@ def test_prefix_cache_hit_cap_and_cow_keep_parity(tiny_model):
     # and never exercise COW)
     prompt = _prompts(23, m.config.vocab_size, [12])[0]
     sp = SamplingParams(max_new_tokens=6)
-    eng = LLMEngine(m, _cfg(True))
+    eng = LLMEngine(m, _cfg())
     waves = []
     for wave in range(2):
         # two concurrent identical prompts per wave: wave 2 shares
@@ -177,37 +218,38 @@ def test_prefix_cache_hit_cap_and_cow_keep_parity(tiny_model):
 
 
 def test_fleet_handoff_parity_ragged(tiny_model):
-    """Drain one ragged replica of two mid-run: every request finishes
-    with generations identical to an uninterrupted BUCKETED single
-    engine — hand-off resume-by-recompute and the ragged step compose
-    without disturbing token streams."""
+    """Drain one replica of two mid-run: every request finishes with the
+    generations of an undisturbed run — greedy rows the dense forward's,
+    sampled rows those of the request served alone — so hand-off
+    resume-by-recompute and the step compose without disturbing token
+    streams."""
     m = tiny_model
     prompts = _prompts(24, m.config.vocab_size, [3, 5, 4, 6, 2, 5])
-    sp = SamplingParams(max_new_tokens=8)
+    sps = [SamplingParams(max_new_tokens=8, temperature=0.8, seed=40 + i)
+           if i < 2 else SamplingParams(max_new_tokens=8)
+           for i in range(len(prompts))]
     ids = [f"h{i}" for i in range(len(prompts))]
-    ref_eng = LLMEngine(m, _cfg(False))
-    for rid, p in zip(ids, prompts):
-        ref_eng.add_request(rid, p, sampling=sp)
-    steps = 0
-    while ref_eng.has_unfinished():
-        ref_eng.step()
-        steps += 1
-        assert steps < 500
-    ref = {rid: list(ref_eng.get_request(rid).generated) for rid in ids}
+    ref = {rid: (_alone(m, rid, p, sp) if sp.temperature > 0
+                 else _naive(m, p, 8))
+           for rid, p, sp in zip(ids, prompts, sps)}
 
     mon = PreemptionMonitor()
     router = FleetRouter([
-        InProcessReplica(m, _cfg(True, drain_grace_s=0.0),
+        InProcessReplica(m, _cfg(drain_grace_s=0.0),
                          replica_id="r0", monitor=mon),
-        InProcessReplica(m, _cfg(True, drain_grace_s=0.0),
+        InProcessReplica(m, _cfg(drain_grace_s=0.0),
                          replica_id="r1")])
     try:
-        for rid, p in zip(ids, prompts):
+        for rid, p, sp in zip(ids, prompts, sps):
             router.add_request(rid, p, sampling=sp)
         outs = []
         for _ in range(3):
             outs.extend(router.step())
-        assert router._by_id("r0").engine.scheduler.num_running > 0
+        r0 = router._by_id("r0").engine
+        assert r0.scheduler.num_running > 0
+        # a sampled request is on the replica about to drain, mid-stream
+        assert any(r.sampling.temperature > 0 and r.num_generated > 0
+                   for r in r0.scheduler.running)
         mon.request()            # r0 drains -> hand-off to r1
         for _ in range(500):
             if not router.has_unfinished():

@@ -224,7 +224,8 @@ def test_fully_accepted_verify_emits_prefix_plus_bonus():
 # -- distributional parity vs the host oracle -----------------------------
 
 def _oracle_probs(logits, temperature, top_k, top_p):
-    """The LLMEngine._sample transform, probabilities only (f64)."""
+    """The host sampler's transform (temperature, top-k, then top-p on
+    the sorted row), probabilities only (f64)."""
     x = logits.astype(np.float64) / temperature
     x -= x.max()
     p = np.exp(x)
