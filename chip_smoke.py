@@ -505,15 +505,19 @@ def tp_placement(engine, devs, on_chip):
     device 0 holding no more than the others."""
     tp = len(devs)
     assert engine.kv_layout.size == tp
-    shards = engine._kcs.addressable_shards
-    assert {s.device for s in shards} == set(devs)
-    assert all(s.data.nbytes * tp == engine._kcs.nbytes for s in shards)
+    k_bytes = {d: 0 for d in devs}      # summed over the per-layer arrays
+    for layer in engine._kcs:
+        shards = layer.addressable_shards
+        assert {s.device for s in shards} == set(devs)
+        assert all(s.data.nbytes * tp == layer.nbytes for s in shards)
+        for s in shards:
+            k_bytes[s.device] += s.data.nbytes
     facts = {"k_cache_bytes_per_device": {
-        s.device.id: s.data.nbytes for s in shards}}
+        d.id: k_bytes[d] for d in devs}}
     if on_chip:
         placed = {d: 0 for d in devs}
         for arr in ([p._data for p in engine._params]
-                    + [engine._kcs, engine._vcs]):
+                    + [*engine._kcs, *engine._vcs]):
             for s in arr.addressable_shards:
                 placed[s.device] += s.data.nbytes
         used = {d: bytes_in_use(d) for d in devs}

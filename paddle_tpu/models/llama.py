@@ -315,11 +315,13 @@ class LlamaModel(nn.Layer):
         ``context_lens`` (S,) is each slot's post-step cache length.
         Prefill, chunked prefill and decode rows are all the same shape
         here — ONE compiled step covers a whole continuous batch.
-        Returns (hidden (1,T,h), key_caches', value_caches')."""
-        kcs = key_caches._data if isinstance(key_caches, Tensor) \
-            else jnp.asarray(key_caches)
-        vcs = value_caches._data if isinstance(value_caches, Tensor) \
-            else jnp.asarray(value_caches)
+        ``key_caches`` / ``value_caches`` are one ``(NB, BS, KH, D)``
+        array PER LAYER (any sequence of them): a layer scatters its new
+        rows into its own array and hands it on, so a step that donates
+        the two pytrees updates the cache in place — no stacked
+        ``(L, NB, BS, KH, D)`` array exists to slice or to restack.
+        Returns (hidden (1,T,h), key_caches', value_caches'), the caches
+        as tuples of per-layer arrays."""
         cu = (cu_seqlens._data if isinstance(cu_seqlens, Tensor)
               else jnp.asarray(cu_seqlens)).astype(jnp.int32)
         ctx = (context_lens._data if isinstance(context_lens, Tensor)
@@ -339,16 +341,12 @@ class LlamaModel(nn.Layer):
             ctx[seg] - (cu[seg + 1] - cu[seg]) + (tok - cu[seg]), 0)
         x = self.embed_tokens(ids2)
         new_k, new_v = [], []
-        for i, layer in enumerate(self.layers):
+        for layer, kc, vc in zip(self.layers, key_caches, value_caches):
             x, kc, vc = layer.forward_ragged(
-                x, positions, kcs[i], vcs[i], block_tables,
-                cu, ctx, num_seqs)
+                x, positions, kc, vc, block_tables, cu, ctx, num_seqs)
             new_k.append(kc._data if isinstance(kc, Tensor) else kc)
             new_v.append(vc._data if isinstance(vc, Tensor) else vc)
-        with jax.named_scope("kv_update"):    # the restack (ROADMAP S10)
-            new_kcs = jnp.stack(new_k, axis=0)
-            new_vcs = jnp.stack(new_v, axis=0)
-        return self.norm(x), new_kcs, new_vcs
+        return self.norm(x), tuple(new_k), tuple(new_v)
 
 
 class LlamaPretrainingCriterion(nn.Layer):
@@ -392,7 +390,8 @@ class LlamaForCausalLM(nn.Layer):
         discards the row). Returns (logits (S, vocab), key_caches',
         value_caches') — S is the fixed number of sequence slots, so a
         mixed prefill/decode continuous batch has exactly ONE compiled
-        shape."""
+        shape. The caches are one array per layer, in and out
+        (:meth:`LlamaModel.forward_ragged`)."""
         h, kcs, vcs = self.llama.forward_ragged(
             input_ids, key_caches, value_caches, block_tables,
             cu_seqlens, context_lens, num_seqs)

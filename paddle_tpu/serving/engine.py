@@ -4,12 +4,16 @@ The serving analog of the reference's AnalysisPredictor
 (paddle/fluid/inference/api/analysis_predictor.h:100), rebuilt around
 the TPU-native execution model:
 
-* the KV cache is ONE stacked device array per K and V —
-  ``(layers, num_blocks, block_size, kv_heads, head_dim)`` — indexed by
-  per-request block tables ("Ragged Paged Attention", arxiv 2604.15464:
-  paged attention is the right TPU kernel shape), allocated by
+* the KV cache is one device array PER LAYER for K and for V —
+  ``(num_blocks, block_size, kv_heads, head_dim)``, two tuples of L
+  arrays handed to the step as two pytrees — indexed by per-request
+  block tables ("Ragged Paged Attention", arxiv 2604.15464: paged
+  attention is the right TPU kernel shape), allocated by
   :class:`BlockManager` and attended through
-  ``incubate.nn.functional.ragged_paged_attention``;
+  ``incubate.nn.functional.ragged_paged_attention``. No stacked
+  ``(L, NB, BS, KH, D)`` array lives on the device: that shape is the
+  FRAME of a handful of blocks in the host pool, the tiers and on the
+  wire, made and consumed by :mod:`paddle_tpu.serving.kv_blocks`;
 * prefill and decode are the SAME compiled function: every iteration is
   ONE unpadded ragged step of the model's ``forward_ragged`` — a packed
   (T,) token stream over S sequence slots, so a mixed
@@ -19,8 +23,11 @@ the TPU-native execution model:
   BlockManager's content-keyed trie after the step that writes them,
   later requests share them by refcount, and the first divergent write
   copy-on-writes (``_apply_cow`` lands the block copies pre-step);
-* cache buffers are donated at the jit boundary on TPU (the functional
-  update aliases in place — the divergence note in block_attention.py);
+* cache buffers are donated at the jit boundary on TPU: each layer
+  scatters its new rows into its own array and the step returns the
+  arrays as they are, so the update aliases in place and nothing is
+  sliced out of, or stacked back into, a whole-cache array (the
+  divergence note in block_attention.py);
 * scheduling is iteration-level (:class:`Scheduler`): late arrivals
   join the running batch at the next step, and KV OOM preempts the
   lowest-priority request back to the waiting queue (recompute).
@@ -84,6 +91,7 @@ from paddle_tpu.profiler import span
 from paddle_tpu.serving.block_manager import (
     BlockManager, NoFreeBlocksError, cdiv,
 )
+from paddle_tpu.serving.kv_blocks import gather_blocks, scatter_blocks
 from paddle_tpu.serving.metrics import ServingMetrics
 from paddle_tpu.serving.request import (
     Request, RequestOutput, RequestStatus, SamplingParams,
@@ -296,9 +304,10 @@ def _tp_param_layout(name: str, ndim: int, tp: int):
 
 
 class _KVSwapper:
-    """Engine-side block mover for swap-based preemption: copies the
-    stacked (L, nblocks, BS, KH, D) device cache slices to/from the
-    host pool, framed per TP shard (a single frame when unsharded).
+    """Engine-side block mover for swap-based preemption: copies
+    (L, nblocks, BS, KH, D) frames of the per-layer device caches
+    to/from the host pool, framed per TP shard (a single frame when
+    unsharded).
 
     ``copy_out`` is ASYNC: it enqueues a device gather of the victim's
     blocks (a fresh buffer, so the freed blocks may be rewritten by the
@@ -322,8 +331,7 @@ class _KVSwapper:
         # the blocks the host table covers
         dev = np.asarray(dev_table[:len(host_table)], np.int32)
         host = np.asarray(host_table, np.int32)
-        k_slice = eng._kcs[:, dev]   # functional gather: its own buffer
-        v_slice = eng._vcs[:, dev]
+        k_slice, v_slice = eng._gather_blocks(dev)   # their own buffers
         for buf in (k_slice, v_slice):
             start = getattr(buf, "copy_to_host_async", None)
             if start is not None:
@@ -356,9 +364,7 @@ class _KVSwapper:
         dev = np.asarray(dev_table, np.int32)
         k_np = eng.kv_layout.unshard_frames(eng._host_k[:, :, host])
         v_np = eng.kv_layout.unshard_frames(eng._host_v[:, :, host])
-        eng._kcs = eng._kcs.at[:, dev].set(k_np)
-        eng._vcs = eng._vcs.at[:, dev].set(v_np)
-        eng._pin_caches()
+        eng._scatter_blocks(dev, k_np, v_np)
 
     def gather(self, dev_table: List[int]):
         """Device->host gather of arbitrary blocks — the fleet KV-ship
@@ -379,26 +385,23 @@ class _KVSwapper:
                     for i, b in enumerate(dev_table)
                     if bm.is_host_entry(b)]
         if not host_pos:
-            dev = np.asarray(dev_table, np.int32)
-            k_slice = eng._kcs[:, dev]  # functional gather: own buffer
-            v_slice = eng._vcs[:, dev]
+            k_slice, v_slice = eng._gather_blocks(dev_table)
             for buf in (k_slice, v_slice):
                 start = getattr(buf, "copy_to_host_async", None)
                 if start is not None:
                     start()         # overlap D2H across the two slices
             return np.asarray(k_slice), np.asarray(v_slice)
         self.fence()
-        L, _, BS, KH, D = eng._kcs.shape
-        dt = np.dtype(eng._kcs.dtype)
-        k_out = np.empty((L, len(dev_table), BS, KH, D), dt)
-        v_out = np.empty((L, len(dev_table), BS, KH, D), dt)
+        shape = eng._frame_shape(len(dev_table))
+        dt = np.dtype(eng._kcs[0].dtype)
+        k_out, v_out = np.empty(shape, dt), np.empty(shape, dt)
         dev_pos = [(i, b) for i, b in enumerate(dev_table)
                    if not bm.is_host_entry(b)]
         if dev_pos:
             idxs = [i for i, _ in dev_pos]
-            ids = np.asarray([b for _, b in dev_pos], np.int32)
-            k_out[:, idxs] = np.asarray(eng._kcs[:, ids])  # tpulint: disable=host-sync-in-traced (mixed-tier gather: the export path's one device read, off the step's critical path)
-            v_out[:, idxs] = np.asarray(eng._vcs[:, ids])
+            k_dev, v_dev = eng._gather_blocks([b for _, b in dev_pos])
+            k_out[:, idxs] = np.asarray(k_dev)  # tpulint: disable=host-sync-in-traced (mixed-tier gather: the export path's one device read, off the step's critical path)
+            v_out[:, idxs] = np.asarray(v_dev)
         idxs = [i for i, _ in host_pos]
         slots = [s for _, s in host_pos]
         k_out[:, idxs] = eng.kv_layout.unshard_frames(
@@ -411,11 +414,7 @@ class _KVSwapper:
         """Write shipped KV bytes into freshly claimed device blocks
         (fleet KV-ship import path) — the ``copy_in`` write, sourced
         from wire bytes instead of the host pool."""
-        eng = self._eng
-        dev = np.asarray(dev_table, np.int32)
-        eng._kcs = eng._kcs.at[:, dev].set(k_np)
-        eng._vcs = eng._vcs.at[:, dev].set(v_np)
-        eng._pin_caches()
+        self._eng._scatter_blocks(dev_table, k_np, v_np)
 
 
 class LLMEngine:
@@ -477,7 +476,7 @@ class LLMEngine:
         # -- what the model says it caches. A model with ``cache_spec``
         # (models/phi4flash.py) gets exactly that: separate pools and
         # per-sequence state slots, one pytree through the step. A
-        # model that says nothing gets the stacked K/V pair below.
+        # model that says nothing gets a K and a V pool per layer, below.
         spec = model.cache_spec() if hasattr(model, "cache_spec") else None
         self._cache_spec = spec
         if spec is not None:
@@ -551,7 +550,10 @@ class LLMEngine:
             self._tp_devices: Optional[tuple] = tuple(devs[:tp])
         else:
             self._tp_devices = None
-        # cache layout: (L, NB, BS, KH, D) with the kv-head dim split
+        # the WIRE layout: an (L, n, BS, KH, D) frame of exported, host-
+        # pool or tier blocks with the kv-head dim split. The device
+        # arrays are one (NB, BS, KH, D) per layer, split the same way
+        # (``_cache_sharding`` below).
         self.kv_layout = Layout.tp_sharded(5, 3, tp)
 
         pools = {}
@@ -593,7 +595,7 @@ class LLMEngine:
             max_queue_depth=self.cfg.max_queue_depth,
             ttft_slo_ms=self.cfg.ttft_slo_ms)
 
-        # -- device caches: (L, NB, BS, KH, D) stacked per layer --------
+        # -- device caches: L arrays (NB, BS, KH, D) for K, L for V -----
         import jax.numpy as jnp
 
         hd = mcfg.hidden_size // mcfg.num_attention_heads
@@ -605,24 +607,25 @@ class LLMEngine:
             cache_dtype = model.lm_head.weight._data.dtype
         else:
             cache_dtype = next(iter(model.parameters()))._data.dtype
+        self._cache_sharding = (
+            Layout.tp_sharded(4, 2, tp).named_sharding(self._tp_devices)
+            if tp > 1 else None)
+
+        def layer_pools(blocks):
+            # one array per layer, updated in place by the donated step
+            # and never stacked; a tuple, as the step hands them back
+            return tuple(
+                jnp.zeros((blocks, self.cfg.block_size, kh, hd),
+                          cache_dtype, device=self._cache_sharding)
+                for _ in range(mcfg.num_hidden_layers))
+
         if spec is None:
-            shape = (mcfg.num_hidden_layers, self.cfg.num_blocks,
-                     self.cfg.block_size, kh, hd)
-            self._kcs = jnp.zeros(shape, cache_dtype)
-            self._vcs = jnp.zeros(shape, cache_dtype)
+            self._kcs = layer_pools(self.cfg.num_blocks)
+            self._vcs = layer_pools(self.cfg.num_blocks)
             self._cache = None
         else:
-            # separate arrays, updated in place by the donated step and
-            # never restacked; the stacked pair does not exist
             self._kcs = self._vcs = None
             self._cache = self._build_cache(spec, cache_dtype)
-        if tp > 1:
-            self._cache_sharding = self.kv_layout.named_sharding(
-                self._tp_devices)
-            self._kcs = jax.device_put(self._kcs, self._cache_sharding)
-            self._vcs = jax.device_put(self._vcs, self._cache_sharding)
-        else:
-            self._cache_sharding = None
         # host swap pool: plain numpy per-shard frames, the
         # restore-on-readmit side of swap-based preemption. Leading
         # axis = TP shard (size 1 when unsharded), so a spilled block
@@ -636,22 +639,15 @@ class LLMEngine:
             self._host_v = np.zeros(hshape, np.dtype(cache_dtype))
         else:
             self._host_k = self._host_v = None
-        # tiered mode keeps a DEVICE mirror of the host tier — (L, NHB,
-        # BS, KH, D), same sharding as the caches — updated
+        # tiered mode keeps a DEVICE mirror of the host tier — per layer
+        # (NHB, BS, KH, D), same sharding as the caches — updated
         # incrementally at each demote, so the compiled step attends
-        # host-tier blocks through one in-graph concat without a
-        # per-step full-pool upload. The numpy pool above stays the
+        # host-tier blocks through one in-graph concat per layer without
+        # a per-step full-pool upload. The numpy pool above stays the
         # swap/wire source of truth.
         if self._tiered:
-            tshape = (mcfg.num_hidden_layers, self.cfg.num_host_blocks,
-                      self.cfg.block_size, kh, hd)
-            self._htk = jnp.zeros(tshape, cache_dtype)
-            self._htv = jnp.zeros(tshape, cache_dtype)
-            if tp > 1:
-                self._htk = jax.device_put(self._htk,
-                                           self._cache_sharding)
-                self._htv = jax.device_put(self._htv,
-                                           self._cache_sharding)
+            self._htk = layer_pools(self.cfg.num_host_blocks)
+            self._htv = layer_pools(self.cfg.num_host_blocks)
         else:
             self._htk = self._htv = None
 
@@ -706,7 +702,8 @@ class LLMEngine:
         self._donated = bool(donate)
         if tp > 1:
             # pin the step's outputs: sampled rows replicate (tiny),
-            # cache outputs KEEP the cache layout — without the pin,
+            # every layer's cache output KEEPS the cache layout (one
+            # sharding stands for a whole tuple) — without the pin,
             # GSPMD may pick a different output sharding and the next
             # step would silently recompile against drifted caches
             from jax.sharding import NamedSharding, PartitionSpec
@@ -770,21 +767,28 @@ class LLMEngine:
                                        ids, kcs, vcs, hk, hv, bt, cu,
                                        ctx, nseq, skeys, stemp, stopk,
                                        stopp, sdraft, sndraft):
-                # tiered attention: concat the host-tier mirror onto
-                # the blocks axis INSIDE the jit, so a VIRTUAL table
-                # entry (>= num_blocks) indexes straight into host-tier
-                # content. Writes all land below the demotion frontier
-                # guard, so slicing the cache outputs back to the
-                # device region is bit-exact — host-tier blocks are
-                # read-only to the step.
-                nb = kcs.shape[1]
-                kall = jnp.concatenate([kcs, hk], axis=1)
-                vall = jnp.concatenate([vcs, hv], axis=1)
-                lg3, k2, v2 = forward_r(param_datas, buffer_datas, key,
-                                        ids, kall, vall, bt, cu, ctx, nseq)
+                # tiered attention: concat each layer's host-tier
+                # mirror onto its blocks axis INSIDE the jit, so a
+                # VIRTUAL table entry (>= num_blocks) indexes straight
+                # into host-tier content. Writes all land below the
+                # demotion frontier guard, so slicing the cache outputs
+                # back to the device region is bit-exact — host-tier
+                # blocks are read-only to the step. (The concat copies
+                # every layer's pool each step: ROADMAP S4.)
+                nb = kcs[0].shape[0]
+
+                def with_mirror(caches, mirror):
+                    return tuple(jnp.concatenate([c, m], axis=0)
+                                 for c, m in zip(caches, mirror))
+
+                lg3, k2, v2 = forward_r(
+                    param_datas, buffer_datas, key, ids,
+                    with_mirror(kcs, hk), with_mirror(vcs, hv), bt, cu,
+                    ctx, nseq)
                 packed, finite = pack_sampled(
                     lg3, sdraft, sndraft, skeys, stemp, stopk, stopp)
-                return packed, finite, k2[:, :nb], v2[:, :nb]
+                return (packed, finite, tuple(k[:nb] for k in k2),
+                        tuple(v[:nb] for v in v2))
 
             self._jstep_ragged = jax.jit(
                 raw_step_ragged_tiered if self._tiered
@@ -1154,17 +1158,16 @@ class LLMEngine:
                 f"request {request_id!r}: shipped block_size "
                 f"{meta.get('block_size')} != {self.cfg.block_size}")
         nblocks = cdiv(covered, self.cfg.block_size)
-        L, _, BS, KH, D = self._kcs.shape
-        want_shape = [L, nblocks, BS, KH, D]
+        want_shape = list(self._frame_shape(nblocks))
         if list(meta.get("shape", ())) != want_shape or \
                 int(meta.get("blocks", -1)) != nblocks:
             raise ValueError(
                 f"request {request_id!r}: shipped KV shape "
                 f"{meta.get('shape')} != expected {want_shape}")
-        if str(meta.get("dtype")) != str(self._kcs.dtype):
+        if str(meta.get("dtype")) != str(self._kcs[0].dtype):
             raise ValueError(
                 f"request {request_id!r}: shipped dtype "
-                f"{meta.get('dtype')} != cache dtype {self._kcs.dtype}")
+                f"{meta.get('dtype')} != cache dtype {self._kcs[0].dtype}")
         dtype = np.dtype(str(meta["dtype"]))
         k_bytes = int(meta.get("k_bytes", -1))
         want_bytes = int(np.prod(want_shape)) * dtype.itemsize
@@ -1287,17 +1290,16 @@ class LLMEngine:
                 f"shipped prefix covers {covered} tokens — must be a "
                 f"positive multiple of block_size {bs}")
         nblocks = covered // bs
-        L, _, BS, KH, D = self._kcs.shape
-        want_shape = [L, nblocks, BS, KH, D]
+        want_shape = list(self._frame_shape(nblocks))
         if list(meta.get("shape", ())) != want_shape or \
                 int(meta.get("blocks", -1)) != nblocks:
             raise ValueError(
                 f"shipped prefix KV shape {meta.get('shape')} != "
                 f"expected {want_shape}")
-        if str(meta.get("dtype")) != str(self._kcs.dtype):
+        if str(meta.get("dtype")) != str(self._kcs[0].dtype):
             raise ValueError(
                 f"shipped prefix dtype {meta.get('dtype')} != cache "
-                f"dtype {self._kcs.dtype}")
+                f"dtype {self._kcs[0].dtype}")
         dtype = np.dtype(str(meta["dtype"]))
         k_bytes = int(meta.get("k_bytes", -1))
         want_bytes = int(np.prod(want_shape)) * dtype.itemsize
@@ -1917,22 +1919,40 @@ class LLMEngine:
         pairs = self.block_manager.take_cow_pairs()
         if not pairs:
             return
-        src = np.asarray([p[0] for p in pairs], np.int32)
-        dst = np.asarray([p[1] for p in pairs], np.int32)
-        self._kcs = self._kcs.at[:, dst].set(self._kcs[:, src])
-        self._vcs = self._vcs.at[:, dst].set(self._vcs[:, src])
+        self._scatter_blocks([dst for _, dst in pairs],
+                             *self._gather_blocks([src for src, _ in pairs]))
+
+    def _frame_shape(self, n: int) -> tuple:
+        """``(L, n, BS, KH, D)``: the frame of ``n`` blocks of every
+        layer, as the host pool, the tiers and the wire hold them."""
+        return (len(self._kcs), n, *self._kcs[0].shape[1:])
+
+    def _gather_blocks(self, ids):
+        """The K and V frames ``(L, n, BS, KH, D)`` of device blocks
+        ``ids`` (swap-out, export, tier demote, COW), each in a device
+        buffer of its own."""
+        ids = np.asarray(ids, np.int32)
+        return gather_blocks(self._kcs, ids), gather_blocks(self._vcs, ids)
+
+    def _scatter_blocks(self, ids, k_frame, v_frame):
+        """Write ``(L, n, BS, KH, D)`` frames into device blocks ``ids``
+        of every layer (swap-in, import, tier promote, COW): the caches
+        are donated to the write, so only the n blocks move."""
+        ids = np.asarray(ids, np.int32)
+        self._kcs = scatter_blocks(self._kcs, ids, k_frame)
+        self._vcs = scatter_blocks(self._vcs, ids, v_frame)
         self._pin_caches()
 
     def _pin_caches(self):
-        """Re-commit both caches to the TP cache sharding after an
-        eager update: eager ops may hand back a differently-sharded
-        result, and a drifted cache layout would silently recompile
-        the ONE step the engine promises. No-op unsharded."""
+        """Re-commit every layer's cache to the TP cache sharding after
+        an update outside the step: it may hand back a differently-
+        sharded result, and a drifted cache layout would silently
+        recompile the ONE step the engine promises. No-op unsharded."""
         if self._cache_sharding is not None:
             import jax
 
-            self._kcs = jax.device_put(self._kcs, self._cache_sharding)
-            self._vcs = jax.device_put(self._vcs, self._cache_sharding)
+            self._kcs, self._vcs = jax.device_put(
+                (self._kcs, self._vcs), self._cache_sharding)
 
     # -- the guarded compiled dispatch ----------------------------------
     def _dispatch(self, reqs, arrays, sampling_arrays, composition):

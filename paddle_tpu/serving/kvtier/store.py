@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from paddle_tpu.serving.block_manager import prefix_chain_hashes
+from paddle_tpu.serving.kv_blocks import scatter_blocks
 
 __all__ = ["KVTiersConfig", "SessionRecord", "TieredKVStore"]
 
@@ -162,7 +163,6 @@ class TieredKVStore:
             else:
                 self._promote_bytes(run)
             i = j
-        eng._pin_caches()
         return len(moves)
 
     @staticmethod
@@ -177,16 +177,16 @@ class TieredKVStore:
     def _demote_bytes(self, run: List[tuple]) -> None:
         eng = self._eng
         pairs = self._dedupe_last([(dev, slot) for _, dev, slot in run])
-        devs = [d for d, _ in pairs]
-        slots = [s for _, s in pairs]
-        k_np = np.asarray(eng._kcs[:, devs])  # tpulint: disable=host-sync-in-traced (tier demotion: a handful of cold blocks leave the device, off the step's critical path)
-        v_np = np.asarray(eng._vcs[:, devs])
+        slots = np.asarray([s for _, s in pairs], np.int32)
+        k_dev, v_dev = eng._gather_blocks([d for d, _ in pairs])
+        k_np = np.asarray(k_dev)  # tpulint: disable=host-sync-in-traced (tier demotion: a handful of cold blocks leave the device, off the step's critical path)
+        v_np = np.asarray(v_dev)
         eng._host_k[:, :, slots] = eng.kv_layout.shard_frames(k_np)
         eng._host_v[:, :, slots] = eng.kv_layout.shard_frames(v_np)
         # the device-side mirror the tiered step concatenates with the
         # cache — updated incrementally, never re-uploaded wholesale
-        eng._htk = eng._htk.at[:, slots].set(k_np)
-        eng._htv = eng._htv.at[:, slots].set(v_np)
+        eng._htk = scatter_blocks(eng._htk, slots, k_np)
+        eng._htv = scatter_blocks(eng._htv, slots, v_np)
 
     def _promote_bytes(self, run: List[tuple]) -> None:
         eng = self._eng
@@ -195,8 +195,7 @@ class TieredKVStore:
         devs = [d for _, d in pairs]
         k_np = eng.kv_layout.unshard_frames(eng._host_k[:, :, slots])
         v_np = eng.kv_layout.unshard_frames(eng._host_v[:, :, slots])
-        eng._kcs = eng._kcs.at[:, devs].set(k_np)
-        eng._vcs = eng._vcs.at[:, devs].set(v_np)
+        eng._scatter_blocks(devs, k_np, v_np)
 
     # -- per-iteration policy ---------------------------------------------
     def balance(self) -> None:
@@ -330,11 +329,9 @@ class TieredKVStore:
             self.num_resume_recomputes += 1
         elif tail_block is not None:
             try:
-                eng._kcs = eng._kcs.at[:, [tail_block]].set(
-                    eng.kv_layout.unshard_frames(rec.tail_k))
-                eng._vcs = eng._vcs.at[:, [tail_block]].set(
+                eng._scatter_blocks(
+                    [tail_block], eng.kv_layout.unshard_frames(rec.tail_k),
                     eng.kv_layout.unshard_frames(rec.tail_v))
-                eng._pin_caches()
             except Exception:
                 # a failed tail restore must not strand the resumed
                 # claim: free the whole chain before the error

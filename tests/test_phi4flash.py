@@ -623,14 +623,17 @@ def test_window_pool_accounting_through_growth_and_release():
     bm.check_invariants()
 
 
-def test_a_model_without_cache_spec_gets_the_stacked_pair():
+def test_a_model_without_cache_spec_gets_the_per_layer_pair():
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
     m = LlamaForCausalLM(LlamaConfig.tiny())
     m.eval()
     eng = LLMEngine(m, EngineConfig(block_size=BS, max_num_seqs=2,
                                     max_model_len=32))
-    assert eng._cache is None and eng._kcs.ndim == 5
+    assert eng._cache is None
+    for caches in (eng._kcs, eng._vcs):
+        assert len(caches) == m.config.num_hidden_layers
+        assert all(c.ndim == 4 for c in caches)
     assert eng.block_manager.window_blocks == 0
     assert eng.block_manager.state_slots == 0
     assert eng.cfg.prefix_cache is True

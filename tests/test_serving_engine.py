@@ -63,7 +63,7 @@ def _dense_last(m, tokens):
 def _whole_prompt(m, a, b, step):
     (la,), kcs, _ = step([(a, 0, [0, 1, 2])])
     # prefill wrote the cache: the first layer's block 0 is nonzero
-    assert float(np.abs(np.asarray(kcs)[0, 0]).sum()) > 0
+    assert float(np.abs(np.asarray(kcs[0])[0]).sum()) > 0
     return [(la, a)]
 
 
@@ -96,7 +96,9 @@ def test_prefill_logits_match_naive_forward(tiny_model, case):
     a, b = _prompts(rng, cfg.vocab_size, [9, 6])
     kh = cfg.num_key_value_heads
     hd = cfg.hidden_size // cfg.num_attention_heads
-    cache = [np.zeros((cfg.num_hidden_layers, 8, 4, kh, hd), np.float32)] * 2
+    # one (NB, BS, KH, D) array per layer, for K and for V
+    cache = [(np.zeros((8, 4, kh, hd), np.float32),)
+             * cfg.num_hidden_layers] * 2
 
     def step(rows):
         logits, cache[0], cache[1] = _ragged_step(m, rows, *cache)

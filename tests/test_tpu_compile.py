@@ -177,3 +177,55 @@ def test_flash_kernel_compiles_for_v5e(one_chip, backward):
     assert _kernel_calls(compiled, "flash_attention_fwd") == 1
     for name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
         assert _kernel_calls(compiled, name) == int(backward)
+
+
+def test_llama_ragged_step_updates_its_cache_in_place_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's whole compiled step at the `internlm2-1.8b.chat-c16`
+    widths (16/8 heads of 128, 512-token budget, 16 slots, 2,048 blocks; 4
+    layers and a small vocabulary): the KV cache is one array per layer,
+    donated as two pytrees, and every byte of it is aliased onto the
+    step's outputs — no array of the stacked cache's shape is sliced or
+    restacked, and the step's temporaries stay far under the cache."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import EngineConfig, LLMEngine
+
+    # the entry point's rule asks jax.default_backend(), the CPU here
+    monkeypatch.setenv("PADDLE_RAGGED_ATTN_IMPL", "pallas")
+    layers, kh, t, s, nb = 4, 8, 512, 16, 2048
+    paddle.set_default_dtype("bfloat16")
+    try:
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=1024, hidden_size=16 * D, intermediate_size=8192,
+            num_hidden_layers=layers, num_attention_heads=16,
+            num_key_value_heads=kh, max_position_embeddings=2048))
+    finally:
+        paddle.set_default_dtype("float32")
+    model.eval()
+    eng = LLMEngine(model, EngineConfig(
+        block_size=BS, num_blocks=nb, max_num_seqs=s, max_model_len=2048,
+        max_batched_tokens=t, donate_cache=True))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def like(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = eng._jstep_ragged.lower(
+        *like(([p._data for p in eng._params],
+               [b._data for b in eng._buffers], eng._key)),
+        sds((t,), i32), *like((eng._kcs, eng._vcs)),
+        sds((s, eng.max_blocks_per_seq), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32),
+        sds((s, 2), jnp.uint32), sds((s,), f32), sds((s,), i32),
+        sds((s,), f32), sds((s, 0), i32), sds((s,), i32)).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == layers
+    assert f"[{layers},{nb},{BS},{kh},{D}]" not in text
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * layers * nb * BS * kh * D * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 8
