@@ -1,0 +1,79 @@
+"""Serving's expert layer: a router that picks ``top_k`` of E experts a
+row, and a DROPLESS expert FFN over the ragged ``(T,)`` token stream.
+
+No capacity and no dropped token: the live rows' ``T x top_k``
+assignments are sorted by expert, each expert's rows then lie together,
+and two grouped matrix products (gate/up, down) run over the E groups of
+uneven size; the rows go back to their tokens weighted. Rows that are
+padding of the stream (``live`` false) are routed nowhere and counted
+nowhere. (``incubate/moe/moe_layer.py`` is the training-side GShard layer
+with capacity drops; it is not on the serving path.)
+
+The grouped product is ``ops/pallas/grouped_matmul.py``: the repo's own
+Pallas kernel on a TPU, ``jax.lax.ragged_dot`` (plain XLA) elsewhere;
+``impl=`` is the only selector, as the attention op has one.
+
+Router (DeepSeek-V3's ``noaux_tc`` with one group): scores
+``s = sigmoid(W_g u)`` in float32; SELECTION by ``s + bias``; WEIGHTS from
+the scores without the bias, normalised over the chosen set and scaled.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+__all__ = ["route_sigmoid_topk", "grouped_matmul", "dropless_expert_ffn"]
+
+
+def route_sigmoid_topk(u, w_router, select_bias, *, top_k, scale,
+                       normalize=True):
+    """``u`` (T, d), ``w_router`` (d, E) and ``select_bias`` (E,) in
+    float32. Returns (chosen experts (T, top_k) int32, weights (T, top_k)
+    float32, scores + bias (T, E) float32)."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.dot(u.astype(f32), w_router.astype(f32),
+                               precision=jax.lax.Precision.HIGHEST))
+    biased = s + select_bias.astype(f32)
+    _, chosen = jax.lax.top_k(biased, top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * scale, biased
+
+
+def dropless_expert_ffn(u, chosen, weights, gate_up, down, live, *,
+                        impl=None):
+    """``sum_k weights[t, k] * E_{chosen[t, k]}(u[t])`` with
+    ``E_e(u) = down[e](SiLU(g) * v)``, ``[g | v] = gate_up[e] u``.
+
+    ``u`` (T, d); ``chosen``/``weights`` (T, K); ``gate_up`` (E, d, 2f);
+    ``down`` (E, f, d); ``live`` (T,) bool, false on the stream's padding
+    rows. Returns (out (T, d) in ``u``'s dtype, rows_per_expert (E,)
+    int32: the live rows' assignments by expert). ``impl``: the grouped
+    product's (None: Pallas on a TPU, XLA elsewhere)."""
+    t, k = chosen.shape
+    e = gate_up.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("moe_dispatch"):
+        # a padding row's assignments sort behind every expert's
+        flat = jnp.where(live[:, None], chosen, e).reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        rows_per_expert = jnp.zeros((e,), jnp.int32).at[flat].add(
+            1, mode="drop")
+        xs = u[order // k]                                    # (A, d)
+    with jax.named_scope("moe_experts"):
+        gv = grouped_matmul(xs, gate_up, rows_per_expert, impl=impl)
+        g, v = jnp.split(gv, 2, axis=-1)
+        act = (jax.nn.silu(g) * v).astype(u.dtype)
+        y = grouped_matmul(act, down, rows_per_expert, impl=impl)  # f32
+    with jax.named_scope("moe_dispatch"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        y = y[inverse].reshape(t, k, -1)
+        w = jnp.where(live[:, None], weights.astype(f32), 0.0)
+        # where, not multiply: a padding row's y was never computed
+        y = jnp.where(live[:, None, None], y, 0.0)
+        out = jnp.einsum("tk,tkd->td", w, y)
+    return out.astype(u.dtype), rows_per_expert

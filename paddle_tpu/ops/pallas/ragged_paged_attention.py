@@ -29,6 +29,14 @@ Contract (all data-level jnp arrays):
                     step's new tokens are written (prefix + new).
 * ``num_seqs``:     int32 scalar — live slots; trailing slots are padding.
 
+Latent mode (``value_cache=None, v_lanes=n``; multi-head latent
+attention in its absorbed form): ONE cache ``(num_blocks, block_size, W)``
+whose W-lane entry is every query head's key and whose first ``n`` lanes
+are its value. ``q`` is (T, H, W), ``k_new`` (T, W) and ``v_new`` None;
+one K/V head is read by all H query heads, each page group is fetched
+once and serves as keys (all lanes) and values (the first ``n``), and the
+output is (T, H, n). Compiled, W % 128 == 0 and n % 128 == 0.
+
 Returns ``(out (T, H, D), key_cache', value_cache')``: new K/V scattered
 into their paged slots (functional update — in-place on TPU is buffer
 donation at the jit boundary), and each query row attends causally to its
@@ -137,9 +145,12 @@ def _write_kv(cache, new, block_tables, seg, pos):
 # reference implementation (semantics oracle; the non-TPU path)
 # ---------------------------------------------------------------------------
 def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
-                       window=None):
+                       window=None, v_lanes=None):
     t_total, h, d = q.shape
-    if kc.ndim == 3:                                     # folded heads
+    if v_lanes is not None:           # latent: the entry is key and value
+        kc = kc[:, :, None, :]
+        vc = kc[..., :v_lanes]
+    elif kc.ndim == 3:                                   # folded heads
         kc = kc.reshape(*kc.shape[:2], -1, d)
         vc = vc.reshape(*vc.shape[:2], -1, d)
     nb, bs, kh, _ = kc.shape
@@ -147,7 +158,7 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
     bt_tok = bt[seg]                                     # (T, MB)
     safe = jnp.maximum(bt_tok, 0)
     k_seq = kc[safe].reshape(t_total, mb * bs, kh, d)
-    v_seq = vc[safe].reshape(t_total, mb * bs, kh, d)
+    v_seq = vc[safe].reshape(t_total, mb * bs, kh, vc.shape[-1])
     if kh != h:
         rep = h // kh
         k_seq = jnp.repeat(k_seq, rep, axis=2)
@@ -162,7 +173,7 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
     neg = jnp.asarray(jnp.finfo(jnp.float32).min, logits.dtype)
     logits = jnp.where(att[:, None, :], logits, neg)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("thl,tlhd->thd", probs.astype(v_seq.dtype), v_seq)
+    out = jnp.einsum("thl,tlhe->the", probs.astype(v_seq.dtype), v_seq)
     # where, not multiply: padded q rows may be NaN and NaN * 0 == NaN
     return jnp.where(valid[:, None, None], out, 0)
 
@@ -200,8 +211,12 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
                    kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
                    scale, block_q, slab, block_size, pages, n_heads,
-                   kv_heads, head_dim, window=None):
+                   kv_heads, head_dim, window=None, v_lanes=None):
+    # latent mode (``v_lanes``): no value cache; a fetched page group is
+    # the keys (all ``d`` lanes) and the values (its first ``dv`` lanes)
+    latent = v_lanes is not None
     d = head_dim
+    dv = v_lanes if latent else d
     rep = n_heads // kv_heads
     width = pages * block_size            # KV tokens per page group
     s_slots = ctx_ref.shape[0]
@@ -214,7 +229,10 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
     # unfetched tail is multiplied by exact-zero probabilities, so what
     # the V buffer starts with must be finite
     o_ref[...] = jnp.zeros_like(o_ref)
-    vbuf[...] = jnp.zeros_like(vbuf)
+    if latent:
+        kbuf[...] = jnp.zeros_like(kbuf)
+    else:
+        vbuf[...] = jnp.zeros_like(vbuf)
 
     def span(s):
         """Slot ``s`` in this tile: its stream rows [r0, r1), the first
@@ -238,8 +256,11 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         return lo, nq, ctx_ref[c], r0, r1, pg0, live, n_pg - pg0
 
     def copies(p, b, page):
-        return (pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
-                                      sem.at[0, b]),
+        k_copy = pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
+                                       sem.at[0, b])
+        if latent:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
                                       sem.at[1, b]))
 
@@ -291,7 +312,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             m_scr[:, rows, :] = jnp.full((n_heads, n, 128), _NEG_INF,
                                          jnp.float32)
             l_scr[:, rows, :] = jnp.zeros((n_heads, n, 128), jnp.float32)
-            acc_scr[rows, :] = jnp.zeros((n, n_heads * d), jnp.float32)
+            acc_scr[rows, :] = jnp.zeros((n, n_heads * dv), jnp.float32)
 
         def attend(grp, b, row0, n):
             rows = pl.ds(row0, n)
@@ -306,7 +327,9 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             if window is not None:
                 mask = mask & (col > qpos - window)
             mask = jnp.concatenate([mask] * rep, axis=0)
-            if len(kbuf.shape) == 4:                     # folded pages
+            if latent:                   # the value is a slice of the key
+                k_head = _head_reader(kbuf.at[b], d)
+            elif len(kbuf.shape) == 4:                   # folded pages
                 k_head, v_head = (_head_reader(kbuf.at[b], d),
                                   _head_reader(vbuf.at[b], d))
             else:
@@ -317,10 +340,12 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                 # so a head is a static 128-aligned lane slice, and the
                 # ``rep`` query heads of a KV head stack on the row axis:
                 # one matmul per KV head over the whole page group
-                hs = [slice(h * d, (h + 1) * d)
-                      for h in range(g * rep, (g + 1) * rep)]
-                qg = jnp.concatenate([q_ref[rows, h] for h in hs], axis=0)
-                kg, vg = k_head(g), v_head(g)
+                heads = range(g * rep, (g + 1) * rep)
+                qg = jnp.concatenate(
+                    [q_ref[rows, h * d:(h + 1) * d] for h in heads], axis=0)
+                hs = [slice(h * dv, (h + 1) * dv) for h in heads]
+                kg = k_head(g)
+                vg = kg[:, :dv] if latent else v_head(g)
                 sc = mxu_dot(
                     qg, kg, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -354,7 +379,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                      + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0))
             ok = (local >= 0) & (local < nq)
             for h in range(n_heads):
-                hd = slice(h * d, (h + 1) * d)
+                hd = slice(h * dv, (h + 1) * dv)
                 l = l_scr[h, rows, :1]
                 val = (acc_scr[rows, hd]
                        / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
@@ -401,11 +426,13 @@ _GROUP_TOKENS = 128
 # jitted on its own so that the layers of a model, which call it with one
 # set of shapes, share one trace and one lowering of the kernel body: the
 # body is the slow part of tracing a serving step (PERF.md, PR 28)
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
+                                             "v_lanes"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
-                          interpret, window=None):
+                          interpret, window=None, v_lanes=None):
     t_total, h, d = q.shape
+    latent = v_lanes is not None
+    dv = v_lanes if latent else d
     folded = kc.ndim == 3
     if folded:
         _, bs, lanes = kc.shape
@@ -423,6 +450,11 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
             f"{kc.dtype} cache with {kh} KV head(s) per shard: keep "
             f"kv_heads * itemsize >= 4 (fewer head shards)")
     block_q = _pick_block_q(t_total)
+    if latent:
+        # a row's q tile is h * d lanes and its float32 accumulator
+        # h * dv: 128 rows of 16 x 640 / 16 x 512 would take ~14 MB of
+        # VMEM beside the page buffers
+        block_q = min(block_q, 64)
     n_qb = -(-t_total // block_q)
     t_pad = n_qb * block_q
     pages = max(1, min(mb, _GROUP_TOKENS // bs))
@@ -439,7 +471,13 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
         _ragged_kernel, scale=scale, block_q=block_q,
         slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
         n_heads=h, kv_heads=kh, head_dim=d,
-        **({} if window is None else {"window": window}))
+        **({} if window is None else {"window": window}),
+        **({"v_lanes": v_lanes} if latent else {}))
+    page_buf = _VMEM((2, pages) + page, kc.dtype)
+    # latent: the kernel never touches its value operands; the one
+    # cache and a token scratch stand in their places
+    vc, v_buf = (kc, _VMEM((8, 128), kc.dtype)) if latent else (vc,
+                                                                page_buf)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_qb,),
@@ -448,34 +486,74 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_q, h * d), q_map,
+        out_specs=pl.BlockSpec((block_q, h * dv), q_map,
                                memory_space=_VMEM),
         scratch_shapes=[
-            _VMEM((2, pages) + page, kc.dtype),
-            _VMEM((2, pages) + page, vc.dtype),
+            page_buf,
+            v_buf,
             pltpu.SemaphoreType.DMA((2, 2)),
             _VMEM((h, block_q, 128), jnp.float32),
             _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((block_q, h * d), jnp.float32),
+            _VMEM((block_q, h * dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_pad, h * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, h * dv), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
     )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
-    return out[:t_total].reshape(t_total, h, d)
+    return out[:t_total].reshape(t_total, h, dv)
 
 
 # ---------------------------------------------------------------------------
 # dispatcher
 # ---------------------------------------------------------------------------
+def _resolve_impl(impl):
+    if impl is None:
+        impl = os.environ.get("PADDLE_RAGGED_ATTN_IMPL") or (
+            "pallas" if jax.default_backend() == "tpu" else "ref")
+    if impl not in ("ref", "pallas", "interpret"):
+        raise ValueError(f"unknown ragged attention impl: {impl!r}")
+    return impl
+
+
+def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes):
+    """The latent call: one cache, written and read as the entry it
+    holds (module docstring). Returns (out (T, H, v_lanes), cache',
+    None)."""
+    decl = declared()
+    if decl is not None and decl[1] is not None:
+        raise NotImplementedError(
+            "a latent cache has no head axis to shard over a mesh")
+    t_total, _, width = q.shape
+    if cache.shape[-1] != width or not 0 < v_lanes <= width:
+        raise ValueError(
+            f"latent mode: q is {width} lanes wide, the cache's entry "
+            f"{cache.shape[-1]}, the value its first {v_lanes}")
+    seg, pos, valid = _token_layout(t_total, bt.shape[0], cu, ctx, ns)
+    if new is not None:
+        with jax.named_scope("kv_update"):          # the cache scatter
+            cache = _write_kv(cache, jnp.asarray(new), bt, seg, pos)
+    with jax.named_scope("attention"):
+        if impl == "ref":
+            out = _ragged_attend_ref(q, cache, None, bt, ctx, seg, pos,
+                                     valid, scale, v_lanes=v_lanes)
+        else:
+            out = _ragged_attend_pallas(
+                q, cache, None, bt, cu, ctx, ns, scale,
+                interpret=(impl == "interpret"), v_lanes=v_lanes)
+    return out, cache, None
+
+
 def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
                            block_tables, cu_seqlens, context_lens,
-                           num_seqs, *, scale=None, impl=None, window=None):
+                           num_seqs, *, scale=None, impl=None, window=None,
+                           v_lanes=None):
     """See module docstring for the contract. Returns (out, kc', vc').
+    ``v_lanes`` n with ``value_cache`` None is the latent call: one
+    cache whose entry is the key and, in its first n lanes, the value.
     ``window`` w (None = full): a query at position p attends keys
     p-w+1..p, and the page walk starts at the page of the first row's
     oldest visible key, so the cost does not grow with the context and
@@ -484,6 +562,18 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
     and the caches come back as they were (a layer that attends another
     layer's pages)."""
     q = jnp.asarray(q)
+    if v_lanes is not None:
+        if value_cache is not None or v_new is not None or window:
+            raise ValueError("the latent call takes one cache, one new "
+                             "entry a row and no window")
+        return _latent_attention(
+            q, k_new, jnp.asarray(key_cache),
+            jnp.asarray(block_tables).astype(jnp.int32),
+            jnp.asarray(cu_seqlens).astype(jnp.int32),
+            jnp.asarray(context_lens).astype(jnp.int32),
+            jnp.asarray(num_seqs).astype(jnp.int32),
+            1.0 / (q.shape[-1] ** 0.5) if scale is None else scale,
+            _resolve_impl(impl), int(v_lanes))
     read_only = k_new is None
     if read_only:
         k_new = v_new = jnp.zeros((0,), q.dtype)    # placeholders, unread
@@ -495,11 +585,7 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
     s_slots, _ = block_tables.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if impl is None:
-        impl = os.environ.get("PADDLE_RAGGED_ATTN_IMPL") or (
-            "pallas" if jax.default_backend() == "tpu" else "ref")
-    if impl not in ("ref", "pallas", "interpret"):
-        raise ValueError(f"unknown ragged attention impl: {impl!r}")
+    impl = _resolve_impl(impl)
     cu = jnp.asarray(cu_seqlens).astype(jnp.int32)
     ctx = jnp.asarray(context_lens).astype(jnp.int32)
     bt = jnp.asarray(block_tables).astype(jnp.int32)

@@ -125,7 +125,7 @@ class BlockManager:
                  enable_prefix_cache: bool = False,
                  kv_layout=None, tiered: bool = False,
                  window_blocks: int = 0, window: int = 0,
-                 state_slots: int = 0):
+                 state_slots: int = 0, latent: bool = False):
         if num_blocks < 1 or block_size < 1:
             raise ValueError("num_blocks and block_size must be >= 1")
         if window_blocks < 0 or state_slots < 0:
@@ -209,6 +209,10 @@ class BlockManager:
         self.state_slots = state_slots
         self._slot_free: List[int] = list(range(state_slots - 1, -1, -1))
         self._slots: Dict[str, int] = {}
+        # the main pool holds latent entries (one array a layer, not a K
+        # and a V): accounted exactly as a ``full`` layer's pool is, the
+        # same table and the same block ids across layers
+        self.latent = latent
 
     # -- tier addressing --------------------------------------------------
     def is_host_entry(self, entry: int) -> bool:
@@ -243,6 +247,11 @@ class BlockManager:
         return (need <= len(self._free)
                 and (not self.window_blocks or need <= len(self._wfree))
                 and (not self.state_slots or bool(self._slot_free)))
+
+    @property
+    def num_used_latent_blocks(self) -> int:
+        """Live blocks of the main pool where it holds latent entries."""
+        return self.num_used_blocks if self.latent else 0
 
     # -- window pool + state slots ---------------------------------------
     @property

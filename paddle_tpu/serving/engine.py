@@ -479,6 +479,13 @@ class LLMEngine:
         # model that says nothing gets a K and a V pool per layer, below.
         spec = model.cache_spec() if hasattr(model, "cache_spec") else None
         self._cache_spec = spec
+        self._spec_kinds = (frozenset(lay["kind"] for lay in spec["layers"])
+                            if spec is not None else frozenset())
+        # (expert layers, experts) of the rows-per-expert histogram a
+        # model with expert layers hands back each step, else None
+        self._expert_rows_shape = (tuple(spec["expert_rows"])
+                                   if spec and spec.get("expert_rows")
+                                   else None)
         if spec is not None:
             self._refuse_for_cache_spec()
 
@@ -574,6 +581,8 @@ class LLMEngine:
                              window=w)
             if "state" in kinds:
                 pools.update(state_slots=self.cfg.max_num_seqs)
+            if "latent" in kinds:
+                pools.update(latent=True)
         self.block_manager = BlockManager(
             self.cfg.num_blocks, self.cfg.block_size,
             num_host_blocks=self.cfg.num_host_blocks,
@@ -723,12 +732,18 @@ class LLMEngine:
                 # the Llama step's argument positions (ids 3, bt 6, cu 7,
                 # ctx 8, nseq 9); 4 is the whole cache, donated, 5 the
                 # step's other tables (window block table, state slots)
-                (logits, cache2), _ = apply(
+                (logits, cache2, *expert_rows), _ = apply(
                     param_datas, buffer_datas, key, ids, cache, tables,
                     bt, cu, ctx, nseq)
                 packed, finite = pack_sampled(
                     logits[:, None, :], sdraft, sndraft, skeys, stemp,
                     stopk, stopp)
+                if expert_rows:
+                    # a model with expert layers: what its router decided
+                    # rides the step's ONE fetched array, behind the rows
+                    packed = jnp.concatenate(
+                        [packed.reshape(-1),
+                         expert_rows[0].astype(jnp.int32).reshape(-1)])
                 return packed, finite, cache2
 
             self._jstep_ragged = jax.jit(
@@ -870,44 +885,86 @@ class LLMEngine:
         self.metrics = ServingMetrics(self)
 
     # -- a model that says what it caches ---------------------------------
+    # (what, is it on?, why by cache kind): a refusal gives the reasons
+    # that hold for the kinds the model's spec really has
     _SPEC_REFUSED = (
-        ("kv_tiers", lambda c: c.kv_tiers not in (None, False),
-         "a demoted block has no recurrent state to come back to"),
-        ("swap_mode='host'", lambda c: c.swap_mode == "host",
-         "the host pool holds K/V blocks, not state slots or a window "
-         "table; preemption is by recompute from zero state"),
-        ("draft_model", lambda c: c.draft_model is not None,
-         "a rejected draft token cannot be taken back out of a "
-         "recurrent state"),
-        ("tp_degree > 1", lambda c: c.tp_degree > 1,
-         "the state slots and the window pool have no TP layout yet"),
-        ("prefix_cache=True", lambda c: bool(c.prefix_cache),
-         "a shared K/V block does not carry the recurrent state at its "
-         "boundary (it needs snapshots: ROADMAP.md)"),
+        ("kv_tiers", lambda c: c.kv_tiers not in (None, False), {
+            "state": "a demoted block has no recurrent state to come "
+                     "back to",
+            "window": "a tier holds whole K/V frames, not a window table "
+                      "with released entries",
+            "latent": "the tiers and the host mirror hold (K, V) frames "
+                      "of kv-head rows; one latent array a layer has no "
+                      "tier format yet"}),
+        ("swap_mode='host'", lambda c: c.swap_mode == "host", {
+            "state": "the host pool holds K/V blocks, not state slots; "
+                     "preemption is by recompute from zero state",
+            "window": "the host pool holds K/V blocks, not a window "
+                      "table; preemption is by recompute",
+            "latent": "the host pool is a (K, V) pair of kv-head frames; "
+                      "a latent pool has no host format yet, preemption "
+                      "is by recompute"}),
+        ("draft_model", lambda c: c.draft_model is not None, {
+            "state": "a rejected draft token cannot be taken back out of "
+                     "a recurrent state",
+            "window": "a verify row's rejected tokens may already have "
+                      "released blocks behind the window",
+            "latent": "the step of a model with cache_spec() yields one "
+                      "logit row a slot: it has no multi-row verify yet"}),
+        ("tp_degree > 1", lambda c: c.tp_degree > 1, {
+            "state": "the state slots have no TP layout yet",
+            "window": "the window pool has no TP layout yet",
+            "latent": "a latent entry has no kv-head dim to split: it "
+                      "needs a TP layout of its own (entries replicated, "
+                      "query heads and experts sharded)"}),
+        ("prefix_cache=True", lambda c: bool(c.prefix_cache), {
+            "state": "a shared K/V block does not carry the recurrent "
+                     "state at its boundary (it needs snapshots: "
+                     "ROADMAP.md)",
+            "window": "a block released behind the window cannot be "
+                      "shared",
+            "latent": "copy-on-write and the trie move (K, V) frames; the "
+                      "latent pool has no block-copy path yet"}),
     )
+    _SPEC_METHOD_REFUSED = {
+        "state": "its K/V blocks mean nothing without the recurrent state "
+                 "that goes with them, which has no wire or host format "
+                 "yet",
+        "window": "the window table that goes with its K/V blocks has no "
+                  "wire or host format yet",
+        "latent": "the wire frame and the host format are a (K, V) pair "
+                  "of (L, n, BS, KH, D); one latent array a layer has "
+                  "neither yet",
+    }
 
     def _refuse_for_cache_spec(self, method: Optional[str] = None):
-        """A model with ``cache_spec`` (window pools, recurrent state)
-        cannot honour these; each is refused by name, the knobs at
-        construction and the methods when called."""
-        if method is not None:
-            if self._cache_spec is not None:
-                raise ValueError(
-                    f"{method} is refused for a model with cache_spec(): "
-                    f"its K/V blocks mean nothing without the recurrent "
-                    f"state and the window table that go with them, and "
-                    f"those have no wire or host format yet")
+        """A model with ``cache_spec`` (window pools, recurrent state, a
+        latent pool) cannot honour these; each is refused by name, the
+        knobs at construction and the methods when called, for the
+        reasons that hold for the kinds of cache it has."""
+        if self._cache_spec is None:
             return
-        for name, on, why in self._SPEC_REFUSED:
+
+        def reasons(by_kind):
+            return "; ".join(why for kind, why in by_kind.items()
+                             if kind in self._spec_kinds)
+
+        who = (f"a model with cache_spec() ({type(self.model).__name__}: "
+               f"{', '.join(sorted(self._spec_kinds))})")
+        if method is not None:
+            raise ValueError(f"{method} is refused for {who}: "
+                             f"{reasons(self._SPEC_METHOD_REFUSED)}")
+        for name, on, by_kind in self._SPEC_REFUSED:
             if on(self.cfg):
-                raise ValueError(
-                    f"{name} is refused for a model with cache_spec() "
-                    f"({type(self.model).__name__}): {why}")
+                raise ValueError(f"{name} is refused for {who}: "
+                                 f"{reasons(by_kind)}")
 
     def _build_cache(self, spec, dtype):
         """The cache a model's ``cache_spec()`` describes, one entry per
         layer: a (K, V) pair of pools for ``full`` (``num_blocks``) and
-        ``window`` (``num_window_blocks``) layers, a dict of
+        ``window`` (``num_window_blocks``) layers, ONE pool of
+        ``num_blocks`` for a ``latent`` layer (its entry is key and value;
+        indexed by the main block table, as a ``full`` layer's), a dict of
         ``(max_num_seqs + 1, *shape)`` state arrays (the last slot is
         scratch, for padding rows) for ``state`` layers, None for layers
         that cache nothing of their own."""
@@ -917,14 +974,18 @@ class LLMEngine:
 
         def pool(blocks):
             shape = (blocks, self.cfg.block_size, *spec["kv_shape"])
-            return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+            return jnp.zeros(shape, dtype)
 
         cache = []
         for lay in spec["layers"]:
             if lay["kind"] == "full":
+                cache.append((pool(self.cfg.num_blocks),
+                              pool(self.cfg.num_blocks)))
+            elif lay["kind"] == "latent":
                 cache.append(pool(self.cfg.num_blocks))
             elif lay["kind"] == "window":
-                cache.append(pool(self.cfg.num_window_blocks))
+                cache.append((pool(self.cfg.num_window_blocks),
+                              pool(self.cfg.num_window_blocks)))
             elif lay["kind"] == "state":
                 cache.append({
                     name: jnp.zeros((self.cfg.max_num_seqs + 1, *shape),
@@ -1662,7 +1723,7 @@ class LLMEngine:
                 (reqs, n_run, arrays, sampling_arrays, prompt_toks,
                  composition) = self._fill(batch)
             try:
-                out_np, finite_np = self._dispatch(
+                out_np, finite_np, expert_rows = self._dispatch(
                     reqs, arrays, sampling_arrays, composition)
             except EngineStepError as e:
                 # this step's already-produced structured outputs (flushed
@@ -1765,6 +1826,9 @@ class LLMEngine:
                     emitted=sum(1 for o in outputs[n_before:]
                                 if o.token is not None),
                     finished=sum(1 for o in outputs[n_before:] if o.finished))
+                if expert_rows is not None:
+                    post_span.set(
+                        **self.metrics.record_expert_rows(expert_rows))
                 if self.block_manager.window_blocks:
                     # blocks wholly behind a row's window go back to the
                     # window pool (a finished row's went with its table)
@@ -1858,7 +1922,10 @@ class LLMEngine:
             rows=len(reqs), q_tokens=int(sum(n_run)),
             ctx_tokens=int(ctx.sum()), prefill_rows=len(reqs) - decode_rows,
             decode_rows=decode_rows, sampled_rows=sampled_rows)
-        if self._cache is not None:
+        if self.block_manager.latent:
+            composition.update(
+                latent_blocks=self.block_manager.num_used_latent_blocks)
+        elif self._cache is not None:
             first = sum(1 for r in reqs if r.num_cached == 0)
             composition.update(
                 state_rows=len(reqs) - first, first_rows=first,
@@ -1959,10 +2026,13 @@ class LLMEngine:
         """Run the compiled step under the fault-isolation envelope:
         watchdog-armed dispatch (hung-step detection), bounded
         retry-with-backoff on transient failures, and the fetch of this
-        step's host-side views. Returns ``(out_np, finite_np)`` —
-        ``out_np`` is the packed (rows, R+3) int32 sampler output
-        ([tokens(R), n_emit, key_hi, key_lo] per row); ``finite_np`` is
-        the per-row nonfinite-guard bit (None with the guard off).
+        step's host-side views. Returns ``(out_np, finite_np,
+        expert_rows)`` — ``out_np`` is the packed (rows, R+3) int32
+        sampler output ([tokens(R), n_emit, key_hi, key_lo] per row);
+        ``finite_np`` is the per-row nonfinite-guard bit (None with the
+        guard off); ``expert_rows`` the (expert layers, E) rows-per-expert
+        histogram that rode the same fetch (None for a model without
+        expert layers).
 
         On a failure that exhausts the retry budget — or any failure
         with donated caches, whose buffers a failed dispatch may have
@@ -2020,7 +2090,15 @@ class LLMEngine:
                 # speculative verify) ran in-graph — the step's whole
                 # host boundary is this one packed int32 row per slot
                 with span("engine.fetch"):
-                    out_np = np.asarray(packed)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized int fetch IS the engine's host boundary — tokens, emit counts, and advanced RNG keys in one packed row)
+                    out_np = np.asarray(packed)  # tpulint: disable=host-sync-in-traced (B-sized int fetch IS the engine's host boundary — tokens, emit counts, and advanced RNG keys in one packed row)
+                    expert_rows = None
+                    if self._expert_rows_shape is not None:
+                        # one flat array: the rows, then the histogram
+                        rows = S * (self._spec_R + 3)
+                        expert_rows = out_np[rows:].reshape(
+                            self._expert_rows_shape)
+                        out_np = out_np[:rows].reshape(S, -1)
+                    out_np = out_np[:len(reqs)]
                     finite_np = None
                     if self.cfg.nonfinite_guard:
                         finite_np = np.asarray(finite)[:len(reqs)]  # tpulint: disable=host-sync-in-traced (B-sized bool fetch: the nonfinite guard's observable)
@@ -2070,7 +2148,7 @@ class LLMEngine:
                 f"{self.cfg.step_timeout_s}s watchdog deadline — "
                 f"engine drained, {len(outs)} request(s) aborted with "
                 f"structured outputs", outs)
-        return out_np, finite_np
+        return out_np, finite_np, expert_rows
 
     def _poisoned_rows(self, reqs, finite_np) -> set:
         """Row indices whose logits are non-finite (or deterministically

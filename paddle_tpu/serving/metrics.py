@@ -129,6 +129,11 @@ class ServingMetrics:
         self.prefill_steps = 0
         self.decode_steps = 0
         self.mixed_steps = 0
+        # expert layers (0 for a model without them): assignments of the
+        # steps' live rows, and experts given at least one row, both
+        # summed over layers and steps
+        self.moe_expert_rows = 0
+        self.moe_experts_hit = 0
         self.ttfts_s: List[float] = []
         self.tpots_s: List[float] = []
         # arrival -> first scheduled, appended by the engine where the
@@ -162,6 +167,18 @@ class ServingMetrics:
         if decode_rows:
             self._occupancy_sum += decode_rows / max_num_seqs
             self._occupancy_n += 1
+
+    def record_expert_rows(self, rows_per_expert) -> Dict[str, float]:
+        """One step's rows-per-expert histogram (expert layers, E), as
+        the step handed it back: counted, and reduced to the attributes
+        of the step's ``engine.post`` span."""
+        rows = int(rows_per_expert.sum())
+        hit = int((rows_per_expert > 0).sum())
+        self.moe_expert_rows += rows
+        self.moe_experts_hit += hit
+        return dict(expert_rows=rows, experts_hit=hit,
+                    expert_rows_max=int(rows_per_expert.max(axis=1).sum()),
+                    expert_rows_even=rows / rows_per_expert.shape[1])
 
     def estimated_ttft_ms(self, queue_depth: int,
                           queued_prefill_tokens: int = 0,
@@ -230,6 +247,8 @@ class ServingMetrics:
                 _percentile(self.queue_waits_s, 0.9) * 1e3, 3),
             "tpot_ms_avg": round(_mean(self.tpots_s) * 1e3, 3),
             "batch_occupancy": round(self.batch_occupancy, 4),
+            "moe_expert_rows": self.moe_expert_rows,
+            "moe_experts_hit": self.moe_experts_hit,
         }
         if eng is not None:
             out.update({
@@ -245,6 +264,8 @@ class ServingMetrics:
                 # slots held (the last three are 0 for a model without
                 # cache_spec)
                 "kv_blocks_full": eng.block_manager.num_used_blocks,
+                "kv_blocks_latent":
+                    eng.block_manager.num_used_latent_blocks,
                 "kv_blocks_window":
                     eng.block_manager.num_used_window_blocks,
                 "window_blocks_released":

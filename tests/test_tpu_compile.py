@@ -134,6 +134,58 @@ def test_ragged_kernel_compiles_folded_for_v5e(one_chip, t, window, write):
     assert _kernel_calls(compiled, "ragged_paged_attention") == 1
 
 
+def test_latent_call_compiles_for_v5e(one_chip):
+    """The attention call of the `kimi-vl-a3b-d8.vqa-c32` step: 16 query
+    heads of 640 lanes (512 latent + 64 rope + 64 zero: Mosaic refuses
+    to slice a 576-lane page) over ONE latent cache of 16,384 blocks,
+    values its first 512 lanes; the cache is updated in place."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    t, h, lanes, s, mb, nb = 512, 16, 640, 32, 512, 16384
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def call(q, new, cache, *rest):
+        out, cache, _ = ragged_paged_attention(
+            q, new, None, cache, None, *rest, impl="pallas", v_lanes=512,
+            scale=192 ** -0.5)
+        return out, cache
+
+    compiled = jax.jit(call, donate_argnums=2).lower(
+        sds((t, h, lanes), bf16), sds((t, lanes), bf16),
+        sds((nb, BS, lanes), bf16), sds((s, mb), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == nb * BS * lanes * 2
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+def test_expert_ffn_compiles_to_grouped_kernels_for_v5e(one_chip):
+    """The dropless expert FFN at the same cell's widths (3,072
+    assignments of 512 rows over 64 experts of width 1,408): the two
+    grouped products are the repo's Pallas kernel, each holding a whole
+    (K, 1408) / (K, 2048) weight slab of 5.8 MB twice over in VMEM (the
+    kernel raises the scoped limit itself)."""
+    import functools
+
+    from paddle_tpu.ops.moe import dropless_expert_ffn
+
+    bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    t, k, e, d, f = 512, 6, 64, 2048, 1408
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(dropless_expert_ffn,
+                                         impl="pallas")).lower(
+        sds((t, d), bf16), sds((t, k), i32), sds((t, k), f32),
+        sds((e, d, 2 * f), bf16), sds((e, f, d), bf16),
+        sds((t,), jnp.bool_)).compile()
+    assert _kernel_calls(compiled, "grouped_matmul") == 2
+    assert "ragged-dot" not in compiled.as_text()
+
+
 def test_ragged_kernel_compiles_head_sharded_over_four_chips(topo):
     """TP serving: GSPMD refuses to partition a Mosaic kernel, so under a
     declared kernel mesh the op runs per head-shard inside shard_map —
