@@ -18,6 +18,21 @@ Freed blocks whose content is still registered go to the COLD end of the
 free list, so cached prefixes survive until capacity actually needs
 them (LRU-ish eviction: claiming a cached-free block drops its key).
 
+Commit cursor: a request's tokens only ever grow, so ``commit_prefix``
+keeps, per block table, how far that request's chain has been walked:
+the block index reached, the nested key at that index and the chain hash
+there. A call resumes from the cursor, so every block is sliced, keyed
+and hashed once in the request's life, and a call with no newly covered
+block (every decode step) costs a dictionary look-up. The cursor lives
+and dies with the table: ``free`` drops it (finish, abort, preemption:
+a recomputed request walks from block 0 again), and so does ``swap_out``
+(the swapped-in table is new blocks, offered to the trie from 0 once).
+Copy-on-write, promotion and demotion change the entry AT an index, not
+the tokens of the chain, and leave it alone. A block below the cursor is
+never offered again: a request's own copy of a block whose key was held
+by another block at the time stays unregistered even if that block is
+evicted later (a possible hit lost, never a wrong one).
+
 Invariants (pinned by tests/test_serving.py randomized sequences):
   * a block id appears in tables exactly ``refcount`` times,
   * ``len(free) + len(distinct owned) == num_blocks`` always,
@@ -179,6 +194,10 @@ class BlockManager:
         self._key_hash: Dict[tuple, str] = {}
         self._hash_key: Dict[str, tuple] = {}
         self._hash_tokens: Dict[str, int] = {}
+        # commit cursor per block table (module docstring): (blocks
+        # walked, chain key there, chain hash there)
+        self._commit_cursor: Dict[
+            str, Tuple[int, Optional[tuple], Optional[str]]] = {}
         self._trie_rev = 0
         self._digest_cache: Optional[Tuple[tuple, dict]] = None
         self._cow_pairs: List[Tuple[int, int]] = []
@@ -187,6 +206,9 @@ class BlockManager:
         self.num_prefix_hit_tokens = 0
         self.num_cow_copies = 0
         self.last_hit_tokens = 0
+        # blocks commit_prefix walked / newly registered
+        self.num_commit_visited = 0
+        self.num_prefix_blocks_committed = 0
         # host swap pool (0 = swap disabled)
         self.num_host_blocks = num_host_blocks
         self._host_free: List[int] = list(range(num_host_blocks - 1, -1,
@@ -610,37 +632,41 @@ class BlockManager:
 
     def commit_prefix(self, request_id: str, tokens: Sequence[int],
                       covered: int):
-        """Register the request's prompt blocks whose content is fully
-        written (``covered`` tokens computed so far). Called AFTER the
-        step that wrote them — a block must never be discoverable before
-        its K/V bytes exist on device."""
+        """Register the request's blocks whose content is fully written
+        (``covered`` tokens computed so far). Called AFTER the step that
+        wrote them — a block must never be discoverable before its K/V
+        bytes exist on device. Resumes from the request's commit cursor
+        (module docstring): ``tokens`` may have grown since the last
+        call (the prompt, then prompt + generated at a session's
+        finish) but must agree with it below the cursor."""
         if not self.enable_prefix_cache:
+            return
+        start, key, chash = self._commit_cursor.get(request_id,
+                                                    (0, None, None))
+        bs = self.block_size
+        end = min(covered, len(tokens)) // bs
+        if end <= start:
             return
         table = self._tables.get(request_id)
         if table is None:
             return
-        bs = self.block_size
-        limit = min(covered, len(tokens))
-        key: Optional[tuple] = None
-        chash: Optional[str] = None
-        idx = 0
-        while (idx + 1) * bs <= limit:
+        self.num_commit_visited += end - start
+        for idx in range(start, end):
             part = tuple(tokens[idx * bs:(idx + 1) * bs])
             key = (key, part)
             chash = _fold_hash(chash, part)
             b = table[idx]
-            if key in self._prefix_index:
-                # someone committed this prefix first; keep their block
-                idx += 1
-                continue
-            if b not in self._block_key:
+            # a key someone committed first keeps their block; a block
+            # that carries another key keeps that one
+            if key not in self._prefix_index and b not in self._block_key:
                 self._prefix_index[key] = b
                 self._block_key[b] = key
                 self._key_hash[key] = chash
                 self._hash_key[chash] = key
                 self._hash_tokens[chash] = (idx + 1) * bs
                 self._trie_rev += 1
-            idx += 1
+                self.num_prefix_blocks_committed += 1
+        self._commit_cursor[request_id] = (end, key, chash)
 
     # -- fleet prefix advertisement ---------------------------------------
     @property
@@ -988,6 +1014,7 @@ class BlockManager:
         preempted before admission owns none)."""
         self.free_host(request_id)
         self._free_window_and_slot(request_id)
+        self._commit_cursor.pop(request_id, None)
         table = self._tables.pop(request_id, None)
         if table is None:
             return 0
@@ -1072,6 +1099,7 @@ class BlockManager:
         host = [self._claim_host() for _ in range(need)]
         self._host_tables[request_id] = host
         dev = self._tables.pop(request_id)
+        self._commit_cursor.pop(request_id, None)
         for b in dev:
             self._release(b)
         return dev, host
@@ -1179,6 +1207,12 @@ class BlockManager:
                 f"hash map drift: {h} does not map back to its key"
         assert set(self._hash_tokens) == set(self._hash_key), \
             "hash token-count map drifted from the hash map"
+        for rid, (idx, _, _) in self._commit_cursor.items():
+            assert rid in self._tables, \
+                f"commit cursor of {rid!r} outlived its block table"
+            assert 0 < idx <= len(self._tables[rid]), (
+                f"commit cursor of {rid!r} at block {idx}, table holds "
+                f"{len(self._tables[rid])}")
         assert not self._cow_pairs, \
             "pending COW pairs not drained before invariant check"
         assert not self._tier_moves, \

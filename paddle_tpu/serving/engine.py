@@ -1738,6 +1738,9 @@ class LLMEngine:
                 # logits are independent of the poisoned row)
                 poisoned = self._poisoned_rows(reqs, finite_np)
                 n_before = len(outputs)
+                bm = self.block_manager
+                visited0 = bm.num_commit_visited
+                committed0 = bm.num_prefix_blocks_committed
                 self.metrics.record_step(
                     batch.kind, self.cfg.max_num_seqs,
                     time.perf_counter() - t0, prompt_tokens=prompt_toks,
@@ -1825,7 +1828,10 @@ class LLMEngine:
                 post_span.set(
                     emitted=sum(1 for o in outputs[n_before:]
                                 if o.token is not None),
-                    finished=sum(1 for o in outputs[n_before:] if o.finished))
+                    finished=sum(1 for o in outputs[n_before:] if o.finished),
+                    commit_visited=bm.num_commit_visited - visited0,
+                    committed_blocks=(bm.num_prefix_blocks_committed
+                                      - committed0))
                 if expert_rows is not None:
                     post_span.set(
                         **self.metrics.record_expert_rows(expert_rows))

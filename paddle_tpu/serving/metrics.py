@@ -271,6 +271,12 @@ class ServingMetrics:
                 "window_blocks_released":
                     eng.block_manager.num_window_blocks_released,
                 "state_slots_in_use": eng.block_manager.state_slots_in_use,
+                # blocks commit_prefix walked, and those it newly
+                # registered in the prefix trie
+                "prefix_blocks_visited":
+                    eng.block_manager.num_commit_visited,
+                "prefix_blocks_committed":
+                    eng.block_manager.num_prefix_blocks_committed,
             })
             # resilience counters: swap traffic, TTL expiry, admission
             # rejects, step retries, poisoned-row aborts, drain lifecycle
