@@ -27,6 +27,14 @@ RoPE acts on the ``qk_rope_head_dim`` slice only, ONE rope key shared by
 all heads, in the half-split layout of ``models/llama.py`` (dim i pairs
 with dim i + d/2; whether the halves are interleaved is a storage layout).
 
+What this file does NOT build is refused by name in
+``MlaMoeConfig.__post_init__``: a scaled rope, group-limited routing,
+softmax router scores, dense layers between expert layers, a tied head;
+and query compression (``q_lora_rank``), which ``models/dots3.py`` builds
+beside what goes with it there (attention dims per layer, a head-wise
+gate, the latent rescale, a sparse indexer, windowed latent layers, a
+share of the experts). That file imports this one's norm, SwiGLU and head.
+
 ``cache_spec()`` says ``latent`` per layer; the serving engine builds one
 pool a layer for it. Each kind of layer is a ``jax.jit`` of its own, so
 the layers share two traces (PERF.md section 6: the set-up trap).
@@ -91,7 +99,9 @@ class MlaMoeConfig:
     def __post_init__(self):
         refused = [
             ("q_lora_rank", self.q_lora_rank is not None,
-             "query compression"),
+             "query compression: models/dots3.py builds it, with the "
+             "per-layer dims, gate, rescale, indexer and window of the "
+             "models that have it"),
             ("rope_scaling", self.rope_scaling is not None,
              "a scaled rope (and its mscale)"),
             ("n_group/topk_group", (self.n_group, self.topk_group) != (1, 1),

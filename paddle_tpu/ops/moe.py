@@ -13,6 +13,17 @@ The grouped product is ``ops/pallas/grouped_matmul.py``: the repo's own
 Pallas kernel on a TPU, ``jax.lax.ragged_dot`` (plain XLA) elsewhere;
 ``impl=`` is the only selector, as the attention op has one.
 
+A layer that holds a SHARE of the model's experts (``first_expert`` and
+the matrices of the ``held`` experts from there; the others live on the
+chips that share the layer with this one) is routed over all of them:
+scores, bias, top-k and the normalising sum run over the router's whole
+width, and only the assignments to held experts are sorted, multiplied
+and summed back. What an absent expert would add is left out, and nothing
+stands in for it or for the exchange that would carry it;
+``rows_per_expert`` is ``(held,)``. A layer that holds every expert
+(``first_expert`` 0, as many matrices as the router has columns) is the
+program it was before the share existed.
+
 Router (DeepSeek-V3's ``noaux_tc`` with one group): scores
 ``s = sigmoid(W_g u)`` in float32; SELECTION by ``s + bias``; WEIGHTS from
 the scores without the bias, normalised over the chosen set and scaled.
@@ -44,21 +55,39 @@ def route_sigmoid_topk(u, w_router, select_bias, *, top_k, scale,
 
 
 def dropless_expert_ffn(u, chosen, weights, gate_up, down, live, *,
-                        impl=None):
-    """``sum_k weights[t, k] * E_{chosen[t, k]}(u[t])`` with
-    ``E_e(u) = down[e](SiLU(g) * v)``, ``[g | v] = gate_up[e] u``.
+                        impl=None, first_expert=None):
+    """``sum_k weights[t, k] * E_{chosen[t, k]}(u[t])`` over the held
+    experts, with ``E_e(u) = down[e](SiLU(g) * v)``, ``[g | v] =
+    gate_up[e] u``.
 
     ``u`` (T, d); ``chosen``/``weights`` (T, K); ``gate_up`` (E, d, 2f);
     ``down`` (E, f, d); ``live`` (T,) bool, false on the stream's padding
-    rows. Returns (out (T, d) in ``u``'s dtype, rows_per_expert (E,)
-    int32: the live rows' assignments by expert). ``impl``: the grouped
-    product's (None: Pallas on a TPU, XLA elsewhere)."""
+    rows. ``first_expert`` None: the E matrices are all the experts
+    ``chosen`` names. An int: they are experts ``first_expert ..
+    first_expert + E`` of a wider router, and an assignment to any other
+    is dropped from the sort (it adds nothing). Returns (out (T, d) in
+    ``u``'s dtype, rows_per_expert (E,) int32: the live rows' assignments
+    by held expert). ``impl``: the grouped product's (None: Pallas on a
+    TPU, XLA elsewhere)."""
     t, k = chosen.shape
     e = gate_up.shape[0]
     f32 = jnp.float32
     with jax.named_scope("moe_dispatch"):
-        # a padding row's assignments sort behind every expert's
-        flat = jnp.where(live[:, None], chosen, e).reshape(-1)
+        held = None
+        if first_expert is not None:
+            chosen = chosen - first_expert
+            held = (chosen >= 0) & (chosen < e)
+
+        def counts(trailing=()):
+            """Which assignments count: the live rows', and of a share
+            those to held experts; (T, 1 or K) + ``trailing`` axes."""
+            if held is None:
+                return live[(slice(None), None) + trailing]
+            return (live[:, None] & held)[(...,) + trailing]
+
+        # a padding row's assignments (and those to experts not held)
+        # sort behind every expert's
+        flat = jnp.where(counts(), chosen, e).reshape(-1)
         order = jnp.argsort(flat, stable=True)
         rows_per_expert = jnp.zeros((e,), jnp.int32).at[flat].add(
             1, mode="drop")
@@ -72,8 +101,8 @@ def dropless_expert_ffn(u, chosen, weights, gate_up, down, live, *,
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=order.dtype))
         y = y[inverse].reshape(t, k, -1)
-        w = jnp.where(live[:, None], weights.astype(f32), 0.0)
+        w = jnp.where(counts(), weights.astype(f32), 0.0)
         # where, not multiply: a padding row's y was never computed
-        y = jnp.where(live[:, None, None], y, 0.0)
+        y = jnp.where(counts((None,)), y, 0.0)
         out = jnp.einsum("tk,tkd->td", w, y)
     return out.astype(u.dtype), rows_per_expert

@@ -35,7 +35,13 @@ whose W-lane entry is every query head's key and whose first ``n`` lanes
 are its value. ``q`` is (T, H, W), ``k_new`` (T, W) and ``v_new`` None;
 one K/V head is read by all H query heads, each page group is fetched
 once and serves as keys (all lanes) and values (the first ``n``), and the
-output is (T, H, n). Compiled, W % 128 == 0 and n % 128 == 0.
+output is (T, H, n). Compiled, W % 128 == 0 and n % 128 == 0. The latent
+call also takes ``window=`` (a latent pool of window layers, walked from
+the first live page like a K/V window pool), or ``selected=``, a per-row
+mask of the keys a learned indexer chose (``ops/sparse_index.py``), and
+``head_block=`` for models whose heads do not fit one q tile. In the
+device trace the three are ``ragged_paged_attention``,
+``ragged_window_latent_attention`` and ``ragged_sparse_latent_attention``.
 
 Returns ``(out (T, H, D), key_cache', value_cache')``: new K/V scattered
 into their paged slots (functional update — in-place on TPU is buffer
@@ -145,7 +151,7 @@ def _write_kv(cache, new, block_tables, seg, pos):
 # reference implementation (semantics oracle; the non-TPU path)
 # ---------------------------------------------------------------------------
 def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
-                       window=None, v_lanes=None):
+                       window=None, v_lanes=None, selected=None):
     t_total, h, d = q.shape
     if v_lanes is not None:           # latent: the entry is key and value
         kc = kc[:, :, None, :]
@@ -170,6 +176,8 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
            & valid[:, None])                             # (T, L)
     if window is not None:
         att = att & (lpos > pos[:, None] - window)
+    if selected is not None:
+        att = att & (selected != 0)
     neg = jnp.asarray(jnp.finfo(jnp.float32).min, logits.dtype)
     logits = jnp.where(att[:, None, :], logits, neg)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -209,9 +217,14 @@ def _head_reader(buf, d=None):
 
 def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
-                   kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
+                   kbuf, vbuf, sem, m_scr, l_scr, acc_scr,
+                   sel_ref=None, sel_scr=None, *,
                    scale, block_q, slab, block_size, pages, n_heads,
                    kv_heads, head_dim, window=None, v_lanes=None):
+    # ``sel_ref`` (the selected call): the q tile's (block_q, MB * BS)
+    # int8 selection mask; ``sel_scr`` a page group's columns of it as
+    # 32-bit rows (an int8 tile is 32 rows; a slab is 8 or 16)
+    selected = sel_ref is not None
     # latent mode (``v_lanes``): no value cache; a fetched page group is
     # the keys (all ``d`` lanes) and the values (its first ``dv`` lanes)
     latent = v_lanes is not None
@@ -326,6 +339,8 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             mask = (local >= 0) & (local < nq) & (col <= qpos)
             if window is not None:
                 mask = mask & (col > qpos - window)
+            if selected:
+                mask = mask & (sel_scr[rows, :] != 0)
             mask = jnp.concatenate([mask] * rep, axis=0)
             if latent:                   # the value is a slice of the key
                 k_head = _head_reader(kbuf.at[b], d)
@@ -402,6 +417,9 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                       1 - b)
 
             wait(n_pg - grp * pages, b)
+            if selected:
+                cols = pl.ds(pl.multiple_of(grp * width, width), width)
+                sel_scr[...] = sel_ref[:, cols].astype(jnp.int32)
             on_rows(functools.partial(attend, grp, b))
             return 1 - b
 
@@ -419,19 +437,41 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         (s0, jnp.int32(0), jnp.int32(0)))
 
 
+def _ragged_kernel_selected(cu_ref, ctx_ref, ns_ref, bt_ref, q_ref, kc_ref,
+                            vc_ref, sel_ref, o_ref, kbuf, vbuf, sem, m_scr,
+                            l_scr, acc_scr, sel_scr, **static):
+    """``_ragged_kernel`` in the argument order of a call with one more
+    input (the mask) and one more scratch."""
+    _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref, q_ref, kc_ref, vc_ref,
+                   o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, sel_ref,
+                   sel_scr, **static)
+
+
 # page groups of this many KV tokens: one lane width of scores
 _GROUP_TOKENS = 128
+# what a call with a selection mask or head groups may take of VMEM: a
+# (64, 32k) int8 mask tile is 2 MB, twice for the pipeline, beside the q,
+# output and accumulator tiles of 16 heads
+_VMEM_LIMIT_WIDE = 64 * 1024 * 1024
 
 
 # jitted on its own so that the layers of a model, which call it with one
 # set of shapes, share one trace and one lowering of the kernel body: the
 # body is the slow part of tracing a serving step (PERF.md, PR 28)
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
-                                             "v_lanes"))
+                                             "v_lanes", "head_block"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
-                          interpret, window=None, v_lanes=None):
+                          interpret, window=None, v_lanes=None,
+                          selected=None, head_block=None):
     t_total, h, d = q.shape
     latent = v_lanes is not None
+    # the latent call's two new modes carry names of their own in the
+    # device trace (the metrics tell them apart by these)
+    name = "ragged_paged_attention"
+    if selected is not None:
+        name = "ragged_sparse_latent_attention"
+    elif latent and window is not None:
+        name = "ragged_window_latent_attention"
     dv = v_lanes if latent else d
     folded = kc.ndim == 3
     if folded:
@@ -463,47 +503,75 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
     q2 = q.reshape(t_total, h * d)
     if t_pad != t_total:
         q2 = jnp.pad(q2, ((0, t_pad - t_total), (0, 0)))
+    # ``head_block`` (latent mode): the heads in groups of that many on a
+    # second, inner grid axis; a q tile then holds one group's lanes (128
+    # heads x 640 lanes would be 10 MB a 64-row tile) and the kernel body
+    # runs as it does for a model of that many heads
+    hb = h if head_block is None else head_block
+    grid = (n_qb,) if head_block is None else (n_qb, h // hb)
 
-    def q_map(qb, cu_r, ctx_r, ns_r, bt_r):
-        return (qb, 0)
+    def q_map(qb, *rest):
+        return (qb, 0) if head_block is None else (qb, rest[0])
 
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, block_q=block_q,
+    static = dict(
+        scale=scale, block_q=block_q,
         slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
-        n_heads=h, kv_heads=kh, head_dim=d,
+        n_heads=hb, kv_heads=kh, head_dim=d,
         **({} if window is None else {"window": window}),
         **({"v_lanes": v_lanes} if latent else {}))
+    kernel = functools.partial(_ragged_kernel, **static)
+    masked = functools.partial(_ragged_kernel_selected, **static)
     page_buf = _VMEM((2, pages) + page, kc.dtype)
     # latent: the kernel never touches its value operands; the one
     # cache and a token scratch stand in their places
     vc, v_buf = (kc, _VMEM((8, 128), kc.dtype)) if latent else (vc,
                                                                 page_buf)
+    in_specs = [
+        pl.BlockSpec((block_q, hb * d), q_map, memory_space=_VMEM),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    scratch = [
+        page_buf,
+        v_buf,
+        pltpu.SemaphoreType.DMA((2, 2)),
+        _VMEM((hb, block_q, 128), jnp.float32),
+        _VMEM((hb, block_q, 128), jnp.float32),
+        _VMEM((block_q, hb * dv), jnp.float32),
+    ]
+    operands = [q2, kc, vc]
+    if selected is not None:
+        # the mask's columns in whole page groups, its rows in whole tiles
+        width = pages * bs
+        sel_w = -(-mb // pages) * width
+        selected = jnp.pad(selected.astype(jnp.int8), (
+            (0, t_pad - t_total), (0, sel_w - selected.shape[1])))
+        in_specs.append(pl.BlockSpec((block_q, sel_w),
+                                     lambda qb, *rest: (qb, 0),
+                                     memory_space=_VMEM))
+        scratch.append(_VMEM((block_q, width), jnp.int32))
+        operands.append(selected)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_qb,),
-        in_specs=[
-            pl.BlockSpec((block_q, h * d), q_map, memory_space=_VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((block_q, h * dv), q_map,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_q, hb * dv), q_map,
                                memory_space=_VMEM),
-        scratch_shapes=[
-            page_buf,
-            v_buf,
-            pltpu.SemaphoreType.DMA((2, 2)),
-            _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((block_q, h * dv), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
-    out = pl.pallas_call(
-        kernel,
+    call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, h * dv), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
-    )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
+        name=name,
+        **({} if selected is None and head_block is None else {
+            "compiler_params": pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_WIDE)}))
+    scalars = (cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat)
+    if selected is None:
+        out = pl.pallas_call(kernel, **call)(*scalars, *operands)
+    else:
+        out = pl.pallas_call(masked, **call)(*scalars, *operands)
     return out[:t_total].reshape(t_total, h, dv)
 
 
@@ -519,7 +587,8 @@ def _resolve_impl(impl):
     return impl
 
 
-def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes):
+def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes,
+                      window=None, selected=None, head_block=None):
     """The latent call: one cache, written and read as the entry it
     holds (module docstring). Returns (out (T, H, v_lanes), cache',
     None)."""
@@ -527,45 +596,64 @@ def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes):
     if decl is not None and decl[1] is not None:
         raise NotImplementedError(
             "a latent cache has no head axis to shard over a mesh")
-    t_total, _, width = q.shape
+    t_total, heads, width = q.shape
     if cache.shape[-1] != width or not 0 < v_lanes <= width:
         raise ValueError(
             f"latent mode: q is {width} lanes wide, the cache's entry "
             f"{cache.shape[-1]}, the value its first {v_lanes}")
+    if head_block is not None and heads % head_block:
+        raise ValueError(f"{heads} heads in groups of {head_block}")
     seg, pos, valid = _token_layout(t_total, bt.shape[0], cu, ctx, ns)
     if new is not None:
         with jax.named_scope("kv_update"):          # the cache scatter
             cache = _write_kv(cache, jnp.asarray(new), bt, seg, pos)
+    # a call that passes none of the three has the trace it always had
+    more = {k: v for k, v in (("window", window), ("selected", selected))
+            if v is not None}
     with jax.named_scope("attention"):
         if impl == "ref":
             out = _ragged_attend_ref(q, cache, None, bt, ctx, seg, pos,
-                                     valid, scale, v_lanes=v_lanes)
+                                     valid, scale, v_lanes=v_lanes, **more)
         else:
+            if head_block is not None:
+                more["head_block"] = head_block
             out = _ragged_attend_pallas(
                 q, cache, None, bt, cu, ctx, ns, scale,
-                interpret=(impl == "interpret"), v_lanes=v_lanes)
+                interpret=(impl == "interpret"), v_lanes=v_lanes, **more)
     return out, cache, None
 
 
 def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
                            block_tables, cu_seqlens, context_lens,
                            num_seqs, *, scale=None, impl=None, window=None,
-                           v_lanes=None):
+                           v_lanes=None, selected=None, head_block=None):
     """See module docstring for the contract. Returns (out, kc', vc').
     ``v_lanes`` n with ``value_cache`` None is the latent call: one
     cache whose entry is the key and, in its first n lanes, the value.
     ``window`` w (None = full): a query at position p attends keys
     p-w+1..p, and the page walk starts at the page of the first row's
     oldest visible key, so the cost does not grow with the context and
-    block-table entries behind the window may be gone (-1).
+    block-table entries behind the window may be gone (-1); the K/V call
+    and the latent call take it alike.
+    ``selected`` (latent call only): a (T, MB * block_size) mask, by the
+    slot's logical position, of the keys each row attends to (what
+    ``ops/sparse_index.select_topk`` yields); a row attends to the
+    selected keys it causally sees and no others. Every live page is
+    still walked: the mask cuts the softmax, not the reads.
+    ``head_block`` (latent call only): the compiled kernel takes the
+    heads in groups of that many (a model of 128 heads x 640 lanes has
+    no 64-row q tile that fits VMEM whole).
     ``k_new``/``v_new`` None is the read-only call: nothing is written
     and the caches come back as they were (a layer that attends another
     layer's pages)."""
     q = jnp.asarray(q)
     if v_lanes is not None:
-        if value_cache is not None or v_new is not None or window:
-            raise ValueError("the latent call takes one cache, one new "
-                             "entry a row and no window")
+        if value_cache is not None or v_new is not None:
+            raise ValueError("the latent call takes one cache and one new "
+                             "entry a row (value_cache and v_new None)")
+        if window and selected is not None:
+            raise ValueError("the latent call takes a window or a "
+                             "selection, not both")
         return _latent_attention(
             q, k_new, jnp.asarray(key_cache),
             jnp.asarray(block_tables).astype(jnp.int32),
@@ -573,7 +661,12 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
             jnp.asarray(context_lens).astype(jnp.int32),
             jnp.asarray(num_seqs).astype(jnp.int32),
             1.0 / (q.shape[-1] ** 0.5) if scale is None else scale,
-            _resolve_impl(impl), int(v_lanes))
+            _resolve_impl(impl), int(v_lanes),
+            window=int(window) if window else None, selected=selected,
+            head_block=head_block)
+    if selected is not None or head_block is not None:
+        raise ValueError("selected= and head_block= belong to the latent "
+                         "call (v_lanes=)")
     read_only = k_new is None
     if read_only:
         k_new = v_new = jnp.zeros((0,), q.dtype)    # placeholders, unread

@@ -486,6 +486,10 @@ class LLMEngine:
         self._expert_rows_shape = (tuple(spec["expert_rows"])
                                    if spec and spec.get("expert_rows")
                                    else None)
+        # names of the int32 counters the step hands back behind the
+        # histogram (a model with a sparse indexer: what it selected)
+        self._step_counters = tuple(spec.get("step_counters", ())
+                                    if spec else ())
         if spec is not None:
             self._refuse_for_cache_spec()
 
@@ -567,7 +571,7 @@ class LLMEngine:
         if spec is not None:
             kinds = [lay["kind"] for lay in spec["layers"]]
             windows = {lay["window"] for lay in spec["layers"]
-                       if lay["kind"] == "window"}
+                       if lay["kind"] in ("window", "latent_window")}
             if len(windows) > 1:
                 raise ValueError(f"one window pool, one window: the "
                                  f"model's layers state {sorted(windows)}")
@@ -581,7 +585,9 @@ class LLMEngine:
                              window=w)
             if "state" in kinds:
                 pools.update(state_slots=self.cfg.max_num_seqs)
-            if "latent" in kinds:
+            if any(k in ("latent", "latent_indexed") for k in kinds):
+                # latent entries in the MAIN pool (``latent_window``
+                # layers keep theirs in the window pool)
                 pools.update(latent=True)
         self.block_manager = BlockManager(
             self.cfg.num_blocks, self.cfg.block_size,
@@ -732,18 +738,19 @@ class LLMEngine:
                 # the Llama step's argument positions (ids 3, bt 6, cu 7,
                 # ctx 8, nseq 9); 4 is the whole cache, donated, 5 the
                 # step's other tables (window block table, state slots)
-                (logits, cache2, *expert_rows), _ = apply(
+                (logits, cache2, *counted), _ = apply(
                     param_datas, buffer_datas, key, ids, cache, tables,
                     bt, cu, ctx, nseq)
                 packed, finite = pack_sampled(
                     logits[:, None, :], sdraft, sndraft, skeys, stemp,
                     stopk, stopp)
-                if expert_rows:
+                if counted:
                     # a model with expert layers: what its router decided
                     # rides the step's ONE fetched array, behind the rows
+                    # (and behind that, a sparse indexer's counters)
                     packed = jnp.concatenate(
-                        [packed.reshape(-1),
-                         expert_rows[0].astype(jnp.int32).reshape(-1)])
+                        [packed.reshape(-1)]
+                        + [c.astype(jnp.int32).reshape(-1) for c in counted])
                 return packed, finite, cache2
 
             self._jstep_ragged = jax.jit(
@@ -895,7 +902,13 @@ class LLMEngine:
                       "with released entries",
             "latent": "the tiers and the host mirror hold (K, V) frames "
                       "of kv-head rows; one latent array a layer has no "
-                      "tier format yet"}),
+                      "tier format yet",
+            "latent_indexed": "a demoted latent block would have to take "
+                              "its index keys with it: the tiers hold "
+                              "one (K, V) frame format",
+            "latent_window": "a tier holds whole frames, not a window "
+                             "table of latent entries with released "
+                             "blocks"}),
         ("swap_mode='host'", lambda c: c.swap_mode == "host", {
             "state": "the host pool holds K/V blocks, not state slots; "
                      "preemption is by recompute from zero state",
@@ -903,20 +916,36 @@ class LLMEngine:
                       "table; preemption is by recompute",
             "latent": "the host pool is a (K, V) pair of kv-head frames; "
                       "a latent pool has no host format yet, preemption "
-                      "is by recompute"}),
+                      "is by recompute",
+            "latent_indexed": "the host pool has no frame for a latent "
+                              "entry and its index key; preemption is by "
+                              "recompute",
+            "latent_window": "the host pool holds no window table of "
+                             "latent entries; preemption is by "
+                             "recompute"}),
         ("draft_model", lambda c: c.draft_model is not None, {
             "state": "a rejected draft token cannot be taken back out of "
                      "a recurrent state",
             "window": "a verify row's rejected tokens may already have "
                       "released blocks behind the window",
             "latent": "the step of a model with cache_spec() yields one "
-                      "logit row a slot: it has no multi-row verify yet"}),
+                      "logit row a slot: it has no multi-row verify yet",
+            "latent_indexed": "a rejected draft token's index key would "
+                              "stay selectable in the cache",
+            "latent_window": "a verify row's rejected tokens may already "
+                             "have released latent blocks behind the "
+                             "window"}),
         ("tp_degree > 1", lambda c: c.tp_degree > 1, {
             "state": "the state slots have no TP layout yet",
             "window": "the window pool has no TP layout yet",
             "latent": "a latent entry has no kv-head dim to split: it "
                       "needs a TP layout of its own (entries replicated, "
-                      "query heads and experts sharded)"}),
+                      "query heads and experts sharded)",
+            "latent_indexed": "the indexer's one key a token has no head "
+                              "dim to split either, and its selection "
+                              "would have to be agreed across shards",
+            "latent_window": "the window pool of latent entries has no "
+                             "TP layout yet"}),
         ("prefix_cache=True", lambda c: bool(c.prefix_cache), {
             "state": "a shared K/V block does not carry the recurrent "
                      "state at its boundary (it needs snapshots: "
@@ -924,7 +953,12 @@ class LLMEngine:
             "window": "a block released behind the window cannot be "
                       "shared",
             "latent": "copy-on-write and the trie move (K, V) frames; the "
-                      "latent pool has no block-copy path yet"}),
+                      "latent pool has no block-copy path yet",
+            "latent_indexed": "a shared block would have to carry the "
+                              "latent entries AND their index keys; the "
+                              "trie has no such pair yet",
+            "latent_window": "a latent block released behind the window "
+                             "cannot be shared"}),
     )
     _SPEC_METHOD_REFUSED = {
         "state": "its K/V blocks mean nothing without the recurrent state "
@@ -935,11 +969,17 @@ class LLMEngine:
         "latent": "the wire frame and the host format are a (K, V) pair "
                   "of (L, n, BS, KH, D); one latent array a layer has "
                   "neither yet",
+        "latent_indexed": "the wire frame has no place for a latent entry "
+                          "with its index key (two arrays of two widths a "
+                          "layer)",
+        "latent_window": "the window table that goes with its latent "
+                         "blocks has no wire or host format yet",
     }
 
     def _refuse_for_cache_spec(self, method: Optional[str] = None):
-        """A model with ``cache_spec`` (window pools, recurrent state, a
-        latent pool) cannot honour these; each is refused by name, the
+        """A model with ``cache_spec`` (window pools, recurrent state,
+        latent pools with or without index keys) cannot honour these;
+        each is refused by name, the
         knobs at construction and the methods when called, for the
         reasons that hold for the kinds of cache it has."""
         if self._cache_spec is None:
@@ -964,7 +1004,11 @@ class LLMEngine:
         layer: a (K, V) pair of pools for ``full`` (``num_blocks``) and
         ``window`` (``num_window_blocks``) layers, ONE pool of
         ``num_blocks`` for a ``latent`` layer (its entry is key and value;
-        indexed by the main block table, as a ``full`` layer's), a dict of
+        indexed by the main block table, as a ``full`` layer's), a
+        (latent pool, index-key pool) pair of ``num_blocks`` under that
+        same table for ``latent_indexed``, ONE pool of
+        ``num_window_blocks`` for ``latent_window`` (released behind the
+        window as a ``window`` layer's pair is), a dict of
         ``(max_num_seqs + 1, *shape)`` state arrays (the last slot is
         scratch, for padding rows) for ``state`` layers, None for layers
         that cache nothing of their own."""
@@ -972,8 +1016,11 @@ class LLMEngine:
 
         from paddle_tpu.core.dtype import to_jax
 
-        def pool(blocks):
-            shape = (blocks, self.cfg.block_size, *spec["kv_shape"])
+        def pool(blocks, lanes=None):
+            # a layer that states its own entry width (``lanes``) gets it;
+            # else the model's one ``kv_shape``
+            shape = (blocks, self.cfg.block_size,
+                     *(spec["kv_shape"] if lanes is None else (lanes,)))
             return jnp.zeros(shape, dtype)
 
         cache = []
@@ -982,7 +1029,14 @@ class LLMEngine:
                 cache.append((pool(self.cfg.num_blocks),
                               pool(self.cfg.num_blocks)))
             elif lay["kind"] == "latent":
-                cache.append(pool(self.cfg.num_blocks))
+                cache.append(pool(self.cfg.num_blocks, lay.get("lanes")))
+            elif lay["kind"] == "latent_indexed":
+                cache.append((pool(self.cfg.num_blocks, lay["lanes"]),
+                              pool(self.cfg.num_blocks,
+                                   lay["index_lanes"])))
+            elif lay["kind"] == "latent_window":
+                cache.append(pool(self.cfg.num_window_blocks,
+                                  lay["lanes"]))
             elif lay["kind"] == "window":
                 cache.append((pool(self.cfg.num_window_blocks),
                               pool(self.cfg.num_window_blocks)))
@@ -1723,7 +1777,7 @@ class LLMEngine:
                 (reqs, n_run, arrays, sampling_arrays, prompt_toks,
                  composition) = self._fill(batch)
             try:
-                out_np, finite_np, expert_rows = self._dispatch(
+                out_np, finite_np, expert_rows, counters = self._dispatch(
                     reqs, arrays, sampling_arrays, composition)
             except EngineStepError as e:
                 # this step's already-produced structured outputs (flushed
@@ -1835,6 +1889,9 @@ class LLMEngine:
                 if expert_rows is not None:
                     post_span.set(
                         **self.metrics.record_expert_rows(expert_rows))
+                if counters:
+                    post_span.set(
+                        **self.metrics.record_step_counters(counters))
                 if self.block_manager.window_blocks:
                     # blocks wholly behind a row's window go back to the
                     # window pool (a finished row's went with its table)
@@ -1931,6 +1988,9 @@ class LLMEngine:
         if self.block_manager.latent:
             composition.update(
                 latent_blocks=self.block_manager.num_used_latent_blocks)
+            if self.block_manager.window_blocks:
+                composition.update(
+                    win_blocks=self.block_manager.num_used_window_blocks)
         elif self._cache is not None:
             first = sum(1 for r in reqs if r.num_cached == 0)
             composition.update(
@@ -2033,12 +2093,14 @@ class LLMEngine:
         watchdog-armed dispatch (hung-step detection), bounded
         retry-with-backoff on transient failures, and the fetch of this
         step's host-side views. Returns ``(out_np, finite_np,
-        expert_rows)`` — ``out_np`` is the packed (rows, R+3) int32
+        expert_rows, counters)`` — ``out_np`` is the packed (rows, R+3)
+        int32
         sampler output ([tokens(R), n_emit, key_hi, key_lo] per row);
         ``finite_np`` is the per-row nonfinite-guard bit (None with the
         guard off); ``expert_rows`` the (expert layers, E) rows-per-expert
         histogram that rode the same fetch (None for a model without
-        expert layers).
+        expert layers); ``counters`` the step's named int counters behind
+        it (``cache_spec()["step_counters"]``; None or empty without).
 
         On a failure that exhausts the retry budget — or any failure
         with donated caches, whose buffers a failed dispatch may have
@@ -2097,12 +2159,17 @@ class LLMEngine:
                 # host boundary is this one packed int32 row per slot
                 with span("engine.fetch"):
                     out_np = np.asarray(packed)  # tpulint: disable=host-sync-in-traced (B-sized int fetch IS the engine's host boundary — tokens, emit counts, and advanced RNG keys in one packed row)
-                    expert_rows = None
+                    expert_rows = counters = None
                     if self._expert_rows_shape is not None:
-                        # one flat array: the rows, then the histogram
+                        # one flat array: the rows, the histogram, then
+                        # the step's counters
                         rows = S * (self._spec_R + 3)
-                        expert_rows = out_np[rows:].reshape(
+                        n_hist = int(np.prod(self._expert_rows_shape))
+                        expert_rows = out_np[rows:rows + n_hist].reshape(
                             self._expert_rows_shape)
+                        counters = dict(zip(
+                            self._step_counters,
+                            (int(v) for v in out_np[rows + n_hist:])))
                         out_np = out_np[:rows].reshape(S, -1)
                     out_np = out_np[:len(reqs)]
                     finite_np = None
@@ -2154,7 +2221,7 @@ class LLMEngine:
                 f"{self.cfg.step_timeout_s}s watchdog deadline — "
                 f"engine drained, {len(outs)} request(s) aborted with "
                 f"structured outputs", outs)
-        return out_np, finite_np, expert_rows
+        return out_np, finite_np, expert_rows, counters
 
     def _poisoned_rows(self, reqs, finite_np) -> set:
         """Row indices whose logits are non-finite (or deterministically

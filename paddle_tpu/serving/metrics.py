@@ -134,6 +134,8 @@ class ServingMetrics:
         # summed over layers and steps
         self.moe_expert_rows = 0
         self.moe_experts_hit = 0
+        # a sparse indexer's step counters, summed (0 without one)
+        self.step_counters: Dict[str, int] = {}
         self.ttfts_s: List[float] = []
         self.tpots_s: List[float] = []
         # arrival -> first scheduled, appended by the engine where the
@@ -179,6 +181,15 @@ class ServingMetrics:
         return dict(expert_rows=rows, experts_hit=hit,
                     expert_rows_max=int(rows_per_expert.max(axis=1).sum()),
                     expert_rows_even=rows / rows_per_expert.shape[1])
+
+    def record_step_counters(self, counters: Dict[str, int]):
+        """One step's named int counters, as the step handed them back
+        behind the histogram (a sparse indexer: ``index_visible``,
+        ``index_selected``, ``index_union``): summed, and returned as the
+        attributes of the step's ``engine.post`` span."""
+        for name, value in counters.items():
+            self.step_counters[name] = self.step_counters.get(name, 0) + value
+        return counters
 
     def estimated_ttft_ms(self, queue_depth: int,
                           queued_prefill_tokens: int = 0,
@@ -249,6 +260,8 @@ class ServingMetrics:
             "batch_occupancy": round(self.batch_occupancy, 4),
             "moe_expert_rows": self.moe_expert_rows,
             "moe_experts_hit": self.moe_experts_hit,
+            "index_visible": self.step_counters.get("index_visible", 0),
+            "index_selected": self.step_counters.get("index_selected", 0),
         }
         if eng is not None:
             out.update({

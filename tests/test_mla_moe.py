@@ -149,6 +149,13 @@ def test_config_reads_the_public_keys_and_refuses_what_is_not_built():
                 dict(tie_word_embeddings=True)):
         with pytest.raises(ValueError, match="does not implement"):
             MlaMoeConfig(**bad)
+    # query compression is built now, by the sibling that also has what
+    # goes with it in the models that use it; the refusal says where
+    with pytest.raises(ValueError, match="models/dots3.py"):
+        MlaMoeConfig(q_lora_rank=1536)
+    from paddle_tpu.models.dots3 import Dots3Config
+
+    assert Dots3Config.tiny(q_lora_rank=40).attn_dims("full")[4] == 40
 
 
 # -- (a) the whole-sequence forward ---------------------------------------
@@ -393,9 +400,18 @@ def test_latent_call_refuses_a_second_cache_and_a_window():
     with pytest.raises(ValueError, match="one cache"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"],
                                k["cache"], *args, v_lanes=128)
-    with pytest.raises(ValueError, match="one cache"):
+    # a window is taken now (a latent pool of window layers): the one
+    # row at position 4 under a window of 1 attends to its own entry
+    out, cache, _ = ragged_paged_attention(
+        k["q"], k["new"], None, k["cache"], None, *args, v_lanes=128,
+        window=1, impl="ref")
+    np.testing.assert_allclose(
+        np.asarray(out[0]), np.broadcast_to(np.asarray(k["new"][0, :128]),
+                                            (4, 128)), rtol=1e-6)
+    with pytest.raises(ValueError, match="not both"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
-                               *args, v_lanes=128, window=4)
+                               *args, v_lanes=128, window=4,
+                               selected=jnp.ones((32, 6 * BS), jnp.int8))
     with pytest.raises(ValueError, match="lanes"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
                                *args, v_lanes=512)

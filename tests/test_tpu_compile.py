@@ -14,6 +14,7 @@ file. All such tests live in this one file for the same reason.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +160,87 @@ def test_latent_call_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == nb * BS * lanes * 2
     assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+# the `dots3-note-prev-d5.longdoc-c16` step: 512 rows, 16 slots of 32,768
+# tokens, one block table of 2,048 entries a slot (128 KB of SMEM)
+_SPARSE = dict(t=512, s=16, mb=2048, nb=32768)
+
+
+def _sparse_sds(one_chip):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return sds
+
+
+@pytest.mark.parametrize("h,lanes,v_lanes,mode,name", [
+    (128, 640, 512, "selected", "ragged_sparse_latent_attention"),
+    (64, 1152, 1024, "window", "ragged_window_latent_attention"),
+])
+def test_latent_call_compiles_selected_and_windowed_for_v5e(
+        one_chip, h, lanes, v_lanes, mode, name):
+    """The two attention calls of the `dots3-note-prev-d5.longdoc-c16`
+    step at the published widths: 128 heads of 640 lanes over a per-row
+    selection mask (T, 32768) int8, and 64 heads of 1,152 lanes under a
+    513-key window over a window pool whose table may hold -1; the heads
+    in groups (a 64-row q tile of all of them does not fit VMEM), each
+    cache updated in place."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    t, s, mb = _SPARSE["t"], _SPARSE["s"], _SPARSE["mb"]
+    nb = _SPARSE["nb"] if mode == "selected" else 1088
+    sds = _sparse_sds(one_chip)
+    head_block = 10240 // lanes if mode == "window" else 16
+
+    def call(q, new, cache, sel, *rest):
+        more = ({"selected": sel} if mode == "selected"
+                else {"window": 513})
+        out, cache, _ = ragged_paged_attention(
+            q, new, None, cache, None, *rest, impl="pallas",
+            v_lanes=v_lanes, scale=0.07, head_block=head_block, **more)
+        return out, cache
+
+    compiled = jax.jit(call, donate_argnums=2).lower(
+        sds((t, h, lanes), bf16), sds((t, lanes), bf16),
+        sds((nb, BS, lanes), bf16), sds((t, mb * BS), jnp.int8),
+        sds((s, mb), i32), sds((s + 1,), i32), sds((s,), i32),
+        sds((), i32)).compile()
+    assert _kernel_calls(compiled, name) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == nb * BS * lanes * 2
+    # q and the output re-tiled to (T, H * lanes): 84 MB and 67 MB at 128
+    # heads, beside the mask's padded copy
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_index_scores_and_selection_compile_for_v5e(one_chip):
+    """The indexer of a full layer at the published widths: 64 index
+    heads of 128 against one 128-lane key a token, paged under the main
+    block table; the scores (512, 32768) float32 and the exact top-2,048
+    mask by threshold passes (no sort in the program)."""
+    from paddle_tpu.ops.sparse_index import index_scores, select_topk
+
+    bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    t, s, mb, nb = (_SPARSE[k] for k in ("t", "s", "mb", "nb"))
+    sds = _sparse_sds(one_chip)
+
+    def call(q, w, k_new, cache, *rest):
+        scores, cache = index_scores(q, w, k_new, cache, *rest,
+                                     impl="pallas")
+        return select_topk(scores, 2048), cache
+
+    compiled = jax.jit(call, donate_argnums=3).lower(
+        sds((t, 64, 128), bf16), sds((t, 64), f32), sds((t, 128), bf16),
+        sds((nb, BS, 128), bf16), sds((s, mb), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_index_scores") == 1
+    # no instruction of the program sorts or takes a top-k
+    ops = [line.split(" = ", 1)[1] for line in
+           compiled.as_text().splitlines() if " = " in line]
+    assert not [o for o in ops if re.match(r"\S+ (sort|topk)\(", o)]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == nb * BS * 128 * 2
+    # the scores, their integer image and the mask: 64 + 64 + 16 MB
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
 
 
 def test_expert_ffn_compiles_to_grouped_kernels_for_v5e(one_chip):
