@@ -8,7 +8,8 @@ file to.
 
 * A FULL layer: multi-head latent attention with query compression
   (``q_lora_rank``), over the keys a learned sparse indexer selects
-  (``index_topk`` of the visible ones: ``ops/sparse_index.py``). It caches
+  (``index_topk`` of the visible ones: ``ops/sparse_index.py``), through
+  a kernel of its own (``ops/pallas/sparse_latent_attention.py``). It caches
   TWO arrays under the request's main block table: the latent entry
   ``[c | k_r | zero lanes]`` (576 -> 640 lanes) and, beside it, the
   indexer's one key a token (128 lanes). ``cache_spec()`` kind
@@ -58,6 +59,9 @@ from paddle_tpu.nn import initializer as init
 from paddle_tpu.ops.moe import dropless_expert_ffn, route_sigmoid_topk
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     _token_layout, ragged_paged_attention,
+)
+from paddle_tpu.ops.pallas.sparse_latent_attention import (
+    sparse_latent_attention,
 )
 from paddle_tpu.ops.sparse_index import (
     index_scores, select_topk, selection_counts,
@@ -300,18 +304,12 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
     c = _latent_norm(ckr[:, :rank], p["kv_norm_w"], eps, a_kv)
     q_r, k_r = _rope_at(q[..., dn:], ckr[:, rank:], cos, sin)
     selected = counts = None
-    more = {}
     if index is not None:
         cache, index_cache = cache
         selected, index_cache, counts = _select(
             p, u, c_q, index_cache, bt, cu, ctx, ns, cos, sin, index=index,
             impl=impl)
-        more["selected"] = selected
-    else:
-        more["window"] = window
     lanes = cache.shape[-1]
-    if heads * lanes > _TILE_LANES:
-        more["head_block"] = max(1, math.gcd(heads, _TILE_LANES // lanes))
     w_kvb = p["kv_b"].reshape(rank, heads, dn + dv)
     with jax.named_scope("mla_absorb"):
         q_abs = jnp.einsum("thn,chn->thc", q[..., :dn], w_kvb[..., :dn])
@@ -319,11 +317,21 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
     q_lat = jnp.concatenate(
         [q_abs, q_r, jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
     entry = jnp.concatenate([c, k_r, jnp.zeros((t, pad), c.dtype)], axis=-1)
-    with jax.named_scope("sparse_attention" if index is not None
-                         else "window_latent_attention"):
-        o_lat, cache, _ = ragged_paged_attention(
-            q_lat, entry, None, cache, None, bt, cu, ctx, ns,
-            scale=1.0 / math.sqrt(dn + dr), impl=impl, v_lanes=rank, **more)
+    scale = 1.0 / math.sqrt(dn + dr)
+    if index is not None:
+        with jax.named_scope("sparse_attention"):
+            o_lat, cache = sparse_latent_attention(
+                q_lat, entry, cache, bt, cu, ctx, ns, selected, scale=scale,
+                impl=impl, v_lanes=rank)
+    else:
+        more = {}
+        if heads * lanes > _TILE_LANES:
+            more["head_block"] = max(1, math.gcd(heads,
+                                                 _TILE_LANES // lanes))
+        with jax.named_scope("window_latent_attention"):
+            o_lat, cache, _ = ragged_paged_attention(
+                q_lat, entry, None, cache, None, bt, cu, ctx, ns,
+                scale=scale, impl=impl, v_lanes=rank, window=window, **more)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("thc,chv->thv", o_lat, w_kvb[..., dn:])
     with jax.named_scope("attn_gate"):

@@ -37,11 +37,11 @@ one K/V head is read by all H query heads, each page group is fetched
 once and serves as keys (all lanes) and values (the first ``n``), and the
 output is (T, H, n). Compiled, W % 128 == 0 and n % 128 == 0. The latent
 call also takes ``window=`` (a latent pool of window layers, walked from
-the first live page like a K/V window pool), or ``selected=``, a per-row
-mask of the keys a learned indexer chose (``ops/sparse_index.py``), and
-``head_block=`` for models whose heads do not fit one q tile. In the
-device trace the three are ``ragged_paged_attention``,
-``ragged_window_latent_attention`` and ``ragged_sparse_latent_attention``.
+the first live page like a K/V window pool) and ``head_block=`` for models
+whose heads do not fit one q tile. In the device trace the two are
+``ragged_paged_attention`` and ``ragged_window_latent_attention``. (A
+layer whose rows each attend to a SELECTION of their keys has a kernel of
+its own, ``sparse_latent_attention.py``.)
 
 Returns ``(out (T, H, D), key_cache', value_cache')``: new K/V scattered
 into their paged slots (functional update — in-place on TPU is buffer
@@ -152,6 +152,10 @@ def _write_kv(cache, new, block_tables, seg, pos):
 # ---------------------------------------------------------------------------
 def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
                        window=None, v_lanes=None, selected=None):
+    # ``selected`` (T, MB * BS), a mask by logical position of the keys a
+    # row may attend to among those it causally sees: no call of this
+    # module passes it; it makes this the oracle and the CPU route of
+    # ``sparse_latent_attention.py``
     t_total, h, d = q.shape
     if v_lanes is not None:           # latent: the entry is key and value
         kc = kc[:, :, None, :]
@@ -217,14 +221,9 @@ def _head_reader(buf, d=None):
 
 def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
-                   kbuf, vbuf, sem, m_scr, l_scr, acc_scr,
-                   sel_ref=None, sel_scr=None, *,
+                   kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
                    scale, block_q, slab, block_size, pages, n_heads,
                    kv_heads, head_dim, window=None, v_lanes=None):
-    # ``sel_ref`` (the selected call): the q tile's (block_q, MB * BS)
-    # int8 selection mask; ``sel_scr`` a page group's columns of it as
-    # 32-bit rows (an int8 tile is 32 rows; a slab is 8 or 16)
-    selected = sel_ref is not None
     # latent mode (``v_lanes``): no value cache; a fetched page group is
     # the keys (all ``d`` lanes) and the values (its first ``dv`` lanes)
     latent = v_lanes is not None
@@ -339,8 +338,6 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             mask = (local >= 0) & (local < nq) & (col <= qpos)
             if window is not None:
                 mask = mask & (col > qpos - window)
-            if selected:
-                mask = mask & (sel_scr[rows, :] != 0)
             mask = jnp.concatenate([mask] * rep, axis=0)
             if latent:                   # the value is a slice of the key
                 k_head = _head_reader(kbuf.at[b], d)
@@ -417,9 +414,6 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                       1 - b)
 
             wait(n_pg - grp * pages, b)
-            if selected:
-                cols = pl.ds(pl.multiple_of(grp * width, width), width)
-                sel_scr[...] = sel_ref[:, cols].astype(jnp.int32)
             on_rows(functools.partial(attend, grp, b))
             return 1 - b
 
@@ -437,21 +431,10 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         (s0, jnp.int32(0), jnp.int32(0)))
 
 
-def _ragged_kernel_selected(cu_ref, ctx_ref, ns_ref, bt_ref, q_ref, kc_ref,
-                            vc_ref, sel_ref, o_ref, kbuf, vbuf, sem, m_scr,
-                            l_scr, acc_scr, sel_scr, **static):
-    """``_ragged_kernel`` in the argument order of a call with one more
-    input (the mask) and one more scratch."""
-    _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref, q_ref, kc_ref, vc_ref,
-                   o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, sel_ref,
-                   sel_scr, **static)
-
-
 # page groups of this many KV tokens: one lane width of scores
 _GROUP_TOKENS = 128
-# what a call with a selection mask or head groups may take of VMEM: a
-# (64, 32k) int8 mask tile is 2 MB, twice for the pipeline, beside the q,
-# output and accumulator tiles of 16 heads
+# what a call with head groups may take of VMEM: the q, output and
+# accumulator tiles of a group of heads beside the page buffers
 _VMEM_LIMIT_WIDE = 64 * 1024 * 1024
 
 
@@ -462,15 +445,13 @@ _VMEM_LIMIT_WIDE = 64 * 1024 * 1024
                                              "v_lanes", "head_block"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
                           interpret, window=None, v_lanes=None,
-                          selected=None, head_block=None):
+                          head_block=None):
     t_total, h, d = q.shape
     latent = v_lanes is not None
-    # the latent call's two new modes carry names of their own in the
-    # device trace (the metrics tell them apart by these)
+    # the latent call under a window carries a name of its own in the
+    # device trace (the metrics tell the calls apart by these)
     name = "ragged_paged_attention"
-    if selected is not None:
-        name = "ragged_sparse_latent_attention"
-    elif latent and window is not None:
+    if latent and window is not None:
         name = "ragged_window_latent_attention"
     dv = v_lanes if latent else d
     folded = kc.ndim == 3
@@ -520,58 +501,40 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
         **({} if window is None else {"window": window}),
         **({"v_lanes": v_lanes} if latent else {}))
     kernel = functools.partial(_ragged_kernel, **static)
-    masked = functools.partial(_ragged_kernel_selected, **static)
     page_buf = _VMEM((2, pages) + page, kc.dtype)
     # latent: the kernel never touches its value operands; the one
     # cache and a token scratch stand in their places
     vc, v_buf = (kc, _VMEM((8, 128), kc.dtype)) if latent else (vc,
                                                                 page_buf)
-    in_specs = [
-        pl.BlockSpec((block_q, hb * d), q_map, memory_space=_VMEM),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    scratch = [
-        page_buf,
-        v_buf,
-        pltpu.SemaphoreType.DMA((2, 2)),
-        _VMEM((hb, block_q, 128), jnp.float32),
-        _VMEM((hb, block_q, 128), jnp.float32),
-        _VMEM((block_q, hb * dv), jnp.float32),
-    ]
-    operands = [q2, kc, vc]
-    if selected is not None:
-        # the mask's columns in whole page groups, its rows in whole tiles
-        width = pages * bs
-        sel_w = -(-mb // pages) * width
-        selected = jnp.pad(selected.astype(jnp.int8), (
-            (0, t_pad - t_total), (0, sel_w - selected.shape[1])))
-        in_specs.append(pl.BlockSpec((block_q, sel_w),
-                                     lambda qb, *rest: (qb, 0),
-                                     memory_space=_VMEM))
-        scratch.append(_VMEM((block_q, width), jnp.int32))
-        operands.append(selected)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((block_q, hb * d), q_map, memory_space=_VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
         out_specs=pl.BlockSpec((block_q, hb * dv), q_map,
                                memory_space=_VMEM),
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            page_buf,
+            v_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            _VMEM((hb, block_q, 128), jnp.float32),
+            _VMEM((hb, block_q, 128), jnp.float32),
+            _VMEM((block_q, hb * dv), jnp.float32),
+        ],
     )
-    call = dict(
+    out = pl.pallas_call(
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, h * dv), q.dtype),
         interpret=interpret,
         name=name,
-        **({} if selected is None and head_block is None else {
+        **({} if head_block is None else {
             "compiler_params": pltpu.CompilerParams(
-                vmem_limit_bytes=_VMEM_LIMIT_WIDE)}))
-    scalars = (cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat)
-    if selected is None:
-        out = pl.pallas_call(kernel, **call)(*scalars, *operands)
-    else:
-        out = pl.pallas_call(masked, **call)(*scalars, *operands)
+                vmem_limit_bytes=_VMEM_LIMIT_WIDE)}),
+    )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
     return out[:t_total].reshape(t_total, h, dv)
 
 
@@ -588,7 +551,7 @@ def _resolve_impl(impl):
 
 
 def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes,
-                      window=None, selected=None, head_block=None):
+                      window=None, head_block=None):
     """The latent call: one cache, written and read as the entry it
     holds (module docstring). Returns (out (T, H, v_lanes), cache',
     None)."""
@@ -607,9 +570,8 @@ def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes,
     if new is not None:
         with jax.named_scope("kv_update"):          # the cache scatter
             cache = _write_kv(cache, jnp.asarray(new), bt, seg, pos)
-    # a call that passes none of the three has the trace it always had
-    more = {k: v for k, v in (("window", window), ("selected", selected))
-            if v is not None}
+    # a call that passes neither has the trace it always had
+    more = {} if window is None else {"window": window}
     with jax.named_scope("attention"):
         if impl == "ref":
             out = _ragged_attend_ref(q, cache, None, bt, ctx, seg, pos,
@@ -626,7 +588,7 @@ def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes,
 def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
                            block_tables, cu_seqlens, context_lens,
                            num_seqs, *, scale=None, impl=None, window=None,
-                           v_lanes=None, selected=None, head_block=None):
+                           v_lanes=None, head_block=None):
     """See module docstring for the contract. Returns (out, kc', vc').
     ``v_lanes`` n with ``value_cache`` None is the latent call: one
     cache whose entry is the key and, in its first n lanes, the value.
@@ -635,11 +597,6 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
     oldest visible key, so the cost does not grow with the context and
     block-table entries behind the window may be gone (-1); the K/V call
     and the latent call take it alike.
-    ``selected`` (latent call only): a (T, MB * block_size) mask, by the
-    slot's logical position, of the keys each row attends to (what
-    ``ops/sparse_index.select_topk`` yields); a row attends to the
-    selected keys it causally sees and no others. Every live page is
-    still walked: the mask cuts the softmax, not the reads.
     ``head_block`` (latent call only): the compiled kernel takes the
     heads in groups of that many (a model of 128 heads x 640 lanes has
     no 64-row q tile that fits VMEM whole).
@@ -651,9 +608,6 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
         if value_cache is not None or v_new is not None:
             raise ValueError("the latent call takes one cache and one new "
                              "entry a row (value_cache and v_new None)")
-        if window and selected is not None:
-            raise ValueError("the latent call takes a window or a "
-                             "selection, not both")
         return _latent_attention(
             q, k_new, jnp.asarray(key_cache),
             jnp.asarray(block_tables).astype(jnp.int32),
@@ -662,11 +616,10 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
             jnp.asarray(num_seqs).astype(jnp.int32),
             1.0 / (q.shape[-1] ** 0.5) if scale is None else scale,
             _resolve_impl(impl), int(v_lanes),
-            window=int(window) if window else None, selected=selected,
-            head_block=head_block)
-    if selected is not None or head_block is not None:
-        raise ValueError("selected= and head_block= belong to the latent "
-                         "call (v_lanes=)")
+            window=int(window) if window else None, head_block=head_block)
+    if head_block is not None:
+        raise ValueError("head_block= belongs to the latent call "
+                         "(v_lanes=)")
     read_only = k_new is None
     if read_only:
         k_new = v_new = jnp.zeros((0,), q.dtype)    # placeholders, unread

@@ -13,7 +13,7 @@ Two ops over the engine's ragged token stream (the contract of
   where the row sees nothing (the future, another slot, padding). The
   rows' own keys are written first, by the scatter the latent entry uses.
 * :func:`select_topk` — the exact ``k`` largest scores of each row as a
-  ``(T, MB * BS)`` int8 mask (what the attention call's ``selected=``
+  ``(T, MB * BS)`` int8 mask (what ``ops/pallas/sparse_latent_attention.py``
   consumes): every visible position where at most ``k`` are visible; of
   equal scores at the boundary the LOWEST position first
   (``jax.lax.top_k``'s rule). The boundary is found by ``ops/sampling.py``'s

@@ -4,9 +4,10 @@ matrix cut by ``jax.lax.top_k``, a masked loop over the held experts):
 the whole-sequence forward; chunked prefill and decode through the paged
 caches (latent + index keys under the main table, latent entries in the
 window pool, released behind the window); faults that have to show; ties
-in the index scores; the attention op's new latent modes and the index
-kernel against their ``jnp`` forms; the eight shares of an expert layer;
-the serving engine end to end. Tiny widths with the published ratios:
+in the index scores; the latent call under a selection (a kernel of its
+own) and under a window and the index kernel against their ``jnp``
+forms; the eight shares of an expert layer; the serving engine end to
+end. Tiny widths with the published ratios:
 5 layers (a dense full one, then full, sliding x 3), ``index_topk`` 8 and
 a window of 5 against contexts of 40+, 8 experts of which 2-4 are held.
 Logits, not tokens, wherever the inputs can be replayed."""
@@ -25,6 +26,9 @@ from paddle_tpu.models.dots3 import Dots3Config, Dots3ForCausalLM
 from paddle_tpu.ops import moe, sparse_index
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_attention,
+)
+from paddle_tpu.ops.pallas.sparse_latent_attention import (
+    sparse_latent_attention,
 )
 from paddle_tpu.serving import EngineConfig, LLMEngine, SamplingParams
 from refs import dots3_ref as ref
@@ -419,26 +423,31 @@ def test_latent_call_under_a_selection_and_under_a_window(name, mode, impl,
     rng = np.random.default_rng(1)
     window = 6
     bt = k["bt"].copy()
-    more = {}
+    stream = (np.asarray(cu, np.int32), np.asarray(ctx, np.int32),
+              np.int32(ns))
     if mode == "selected":
         # any mask will do, the future included: a row attends to the
         # selected keys it causally sees (its own among them, so that no
-        # set is empty)
+        # set is empty). The call takes no head groups: ``head_block``'s
+        # two cases are 4 heads and 16 (the compiled kernel's granule)
         sel = rng.random((32, 12 * BS)) < 0.4
         for i in range(ns):
             n = cu[i + 1] - cu[i]
             sel[np.arange(cu[i], cu[i + 1]), ctx[i] - n + np.arange(n)] = 1
-        more["selected"] = jnp.asarray(sel, jnp.int8)
+        if head_block is not None:
+            k = latent_inputs(heads=16)
+        out, cache = sparse_latent_attention(
+            k["q"], k["new"], k["cache"], bt, *stream,
+            jnp.asarray(sel, jnp.int8), scale=0.1, impl=impl, v_lanes=128)
     else:
-        more["window"] = window
         for i in range(ns):
             # blocks wholly behind the first row's window are gone
             first = ctx[i] - (cu[i + 1] - cu[i])
             bt[i, :max(first - window + 1, 0) // BS] = -1
-    out, cache, _ = ragged_paged_attention(
-        k["q"], k["new"], None, k["cache"], None, bt,
-        np.asarray(cu, np.int32), np.asarray(ctx, np.int32), np.int32(ns),
-        scale=0.1, impl=impl, v_lanes=128, head_block=head_block, **more)
+        out, cache, _ = ragged_paged_attention(
+            k["q"], k["new"], None, k["cache"], None, bt, *stream,
+            scale=0.1, impl=impl, v_lanes=128, head_block=head_block,
+            window=window)
     out, cache = np.asarray(out), np.asarray(cache)
     for i in range(ns):
         r0, n = cu[i], cu[i + 1] - cu[i]
@@ -447,7 +456,7 @@ def test_latent_call_under_a_selection_and_under_a_window(name, mode, impl,
         at = np.arange(ctx[i])[None, :]
         seen = at <= pos
         if mode == "selected":
-            seen &= np.asarray(more["selected"])[r0:r0 + n, :ctx[i]] != 0
+            seen &= sel[r0:r0 + n, :ctx[i]]
         else:
             seen &= at > pos - window
         want = plain_latent_attention(np.asarray(k["q"][r0:r0 + n]),
@@ -462,15 +471,100 @@ def test_latent_call_states_its_contract():
     args = (k["bt"], np.asarray([0, 1, 1, 1, 1], np.int32),
             np.asarray([5, 0, 0, 0], np.int32), np.int32(1))
     sel = jnp.ones((32, 6 * BS), jnp.int8)
-    with pytest.raises(ValueError, match="not both"):
+    # the paged call takes no selection any more: a kernel of its own
+    with pytest.raises(TypeError, match="selected"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
-                               *args, v_lanes=128, window=4, selected=sel)
-    with pytest.raises(ValueError, match="belong to the latent call"):
+                               *args, v_lanes=128, selected=sel)
+    with pytest.raises(ValueError, match="belongs to the latent call"):
         ragged_paged_attention(k["q"], k["new"], k["new"], k["cache"],
-                               k["cache"], *args, selected=sel)
+                               k["cache"], *args, head_block=2)
     with pytest.raises(ValueError, match="in groups of"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
                                *args, v_lanes=128, head_block=3)
+    # the selected call: a mask a row of q by logical position, an entry
+    # as wide as q, the value inside it; compiled, lanes in 128s
+    with pytest.raises(ValueError, match="a mask a row of q"):
+        sparse_latent_attention(k["q"], k["new"], k["cache"], *args,
+                                sel[:16], v_lanes=128)
+    with pytest.raises(ValueError, match="a mask a row of q"):
+        sparse_latent_attention(k["q"], k["new"], k["cache"], *args,
+                                sel[:, :5 * BS], v_lanes=128)
+    with pytest.raises(ValueError, match="the value its first"):
+        sparse_latent_attention(k["q"], k["new"], k["cache"], *args, sel,
+                                v_lanes=512)
+    with pytest.raises(ValueError, match="the cache's entry"):
+        sparse_latent_attention(k["q"][..., :128], k["new"], k["cache"],
+                                *args, sel, v_lanes=128)
+    with pytest.raises(NotImplementedError, match="heads in 16s"):
+        sparse_latent_attention(k["q"], k["new"], k["cache"], *args, sel,
+                                v_lanes=128, impl="pallas")
+    with pytest.raises(ValueError, match="unknown ragged attention impl"):
+        sparse_latent_attention(k["q"], k["new"], k["cache"], *args, sel,
+                                v_lanes=128, impl="masked")
+
+
+# rows of the stream, as (cu_seqlens, context_lens, live slots), that put
+# the selected call's tiles (of 8 rows here) to work: a chunk that starts and
+# ends inside tiles, slots of one row between tiles, three slots in one
+# tile, a chunk of exactly one tile, and a stream with no live row at all
+TILE_ROWS = {
+    "ragged_chunk": ([0, 13, 14, 15, 32], [40, 9, 22, 47], 4),
+    "three_slots_in_a_tile": ([0, 3, 5, 8, 9], [30, 2, 48, 17], 4),
+    "one_whole_tile": ([0, 8, 8, 8, 8], [48, 0, 0, 0], 1),
+    "single_rows_only": ([0, 1, 2, 3, 4], [48, 1, 13, 30], 4),
+    "nothing_live": ([0, 0, 0, 0, 0], [0, 0, 0, 0], 0),
+}
+
+
+@pytest.mark.parametrize("dense", [False, True], ids=["sparse", "all"])
+@pytest.mark.parametrize("name", sorted(TILE_ROWS))
+def test_selected_call_over_tiles_slots_and_page_groups(name, dense,
+                                                        monkeypatch):
+    """The interpreted kernel against the plain form where a slot's pages
+    take several groups (groups of 16 tokens here) and its rows several
+    tiles; with every key selected it is causal attention; rows that select
+    nothing they can see, and padding rows, read zeros."""
+    from paddle_tpu.ops.pallas import sparse_latent_attention as sla
+
+    monkeypatch.setattr(sla, "_GROUP_TOKENS", 16)
+    monkeypatch.setattr(sla, "_TILE_ROWS", 8)
+    cu, ctx, ns = TILE_ROWS[name]
+    k = latent_inputs(seed=2)
+    rng = np.random.default_rng(3)
+    sel = np.ones((32, 12 * BS), bool) if dense else (
+        rng.random((32, 12 * BS)) < 0.3)
+    blind = 0
+    for i in range(ns):
+        n = cu[i + 1] - cu[i]
+        if not dense and n > 2:
+            # one row of the slot selects only keys of its future
+            sel[cu[i] + 1, :ctx[i] - n + 2] = 0
+            blind += 1
+    stream = (np.asarray(cu, np.int32), np.asarray(ctx, np.int32),
+              np.int32(ns))
+    out, cache = sla.sparse_latent_attention(
+        k["q"], k["new"], k["cache"], k["bt"], *stream,
+        jnp.asarray(sel, jnp.int8), scale=0.1, impl="interpret", v_lanes=128)
+    out, cache = np.asarray(out), np.asarray(cache)
+    for i in range(ns):
+        r0, n = cu[i], cu[i + 1] - cu[i]
+        entries = cache[k["bt"][i]].reshape(-1, 256)[:ctx[i]]
+        np.testing.assert_array_equal(entries[ctx[i] - n:],
+                                      np.asarray(k["new"][r0:r0 + n]))
+        pos = ctx[i] - n + np.arange(n)[:, None]
+        seen = (np.arange(ctx[i])[None, :] <= pos) & sel[r0:r0 + n, :ctx[i]]
+        for j in range(n):
+            if not seen[j].any():
+                assert not out[r0 + j].any()
+                blind -= 1
+                continue
+            want = plain_latent_attention(
+                np.asarray(k["q"][r0 + j:r0 + j + 1]), entries, 128, 0.1,
+                seen[j:j + 1])
+            np.testing.assert_allclose(out[r0 + j:r0 + j + 1], want,
+                                       rtol=2e-4, atol=2e-5)
+    assert blind <= 0
+    assert not out[cu[ns]:].any()            # padding rows read nothing
 
 
 # -- (e) the chip's share of an expert layer ------------------------------
@@ -687,9 +781,9 @@ def test_engine_spans_carry_the_cache_and_index_counters(model):
 
 
 def test_engine_through_the_interpreted_kernels(model):
-    """The same streams with the three Pallas kernels interpreted (the
-    selection mask, the window walk over released entries, the index
-    kernel) and the grouped product's."""
+    """The same streams with the four Pallas kernels interpreted (the
+    selected latent call, the window walk over released entries, the
+    index kernel, the grouped product)."""
     prompts = prompts_of((41, 7, 30))
     want, _, _ = serve(model, prompts, 4)
     kernels = with_config(model, ragged_attn_impl="interpret",

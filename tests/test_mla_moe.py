@@ -408,7 +408,9 @@ def test_latent_call_refuses_a_second_cache_and_a_window():
     np.testing.assert_allclose(
         np.asarray(out[0]), np.broadcast_to(np.asarray(k["new"][0, :128]),
                                             (4, 128)), rtol=1e-6)
-    with pytest.raises(ValueError, match="not both"):
+    # a selection is no mode of this call any more (PR 36: it walked
+    # every page under the mask); ops/pallas/sparse_latent_attention.py
+    with pytest.raises(TypeError, match="selected"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
                                *args, v_lanes=128, window=4,
                                selected=jnp.ones((32, 6 * BS), jnp.int8))

@@ -180,23 +180,32 @@ def _sparse_sds(one_chip):
 def test_latent_call_compiles_selected_and_windowed_for_v5e(
         one_chip, h, lanes, v_lanes, mode, name):
     """The two attention calls of the `dots3-note-prev-d5.longdoc-c16`
-    step at the published widths: 128 heads of 640 lanes over a per-row
-    selection mask (T, 32768) int8, and 64 heads of 1,152 lanes under a
-    513-key window over a window pool whose table may hold -1; the heads
-    in groups (a 64-row q tile of all of them does not fit VMEM), each
-    cache updated in place."""
+    step at the published widths, as ``models/dots3.py: _attention``
+    issues them. Selected: the kernel of ``sparse_latent_attention.py``,
+    128 heads of 640 lanes side by side on the row axis (tiles of 16 stream
+    rows = 2,048 query rows) under a per-row selection mask (T, 32768)
+    int8, pages in groups of 512 tokens. Window: 64 heads of 1,152 lanes
+    under a 513-key window over a window pool whose table may hold -1, the
+    heads in groups (a 64-row q tile of all of them does not fit VMEM).
+    Each cache is updated in place."""
+    from paddle_tpu.ops.pallas.sparse_latent_attention import (
+        sparse_latent_attention,
+    )
+
     bf16, i32 = jnp.bfloat16, jnp.int32
     t, s, mb = _SPARSE["t"], _SPARSE["s"], _SPARSE["mb"]
     nb = _SPARSE["nb"] if mode == "selected" else 1088
     sds = _sparse_sds(one_chip)
-    head_block = 10240 // lanes if mode == "window" else 16
 
     def call(q, new, cache, sel, *rest):
-        more = ({"selected": sel} if mode == "selected"
-                else {"window": 513})
+        if mode == "selected":
+            return sparse_latent_attention(
+                q, new, cache, *rest, sel, impl="pallas", v_lanes=v_lanes,
+                scale=0.07)
         out, cache, _ = ragged_paged_attention(
             q, new, None, cache, None, *rest, impl="pallas",
-            v_lanes=v_lanes, scale=0.07, head_block=head_block, **more)
+            v_lanes=v_lanes, scale=0.07, head_block=10240 // lanes,
+            window=513)
         return out, cache
 
     compiled = jax.jit(call, donate_argnums=2).lower(
@@ -207,9 +216,11 @@ def test_latent_call_compiles_selected_and_windowed_for_v5e(
     assert _kernel_calls(compiled, name) == 1
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == nb * BS * lanes * 2
-    # q and the output re-tiled to (T, H * lanes): 84 MB and 67 MB at 128
-    # heads, beside the mask's padded copy
-    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+    # window: q and the output re-tiled to (T, H * lanes), 75 MB and 67 MB;
+    # selected: q and the output stay where they are, the mask's 32-bit
+    # copy is 64 MB
+    assert mem.temp_size_in_bytes < (96 if mode == "selected"
+                                     else 256) * 2 ** 20
 
 
 def test_index_scores_and_selection_compile_for_v5e(one_chip):
