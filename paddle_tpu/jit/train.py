@@ -31,7 +31,7 @@ from paddle_tpu.core import generator as gen
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.jit.trace import functionalize
 from paddle_tpu.nn.clip import ClipGradByGlobalNorm
-from paddle_tpu.profiler import span
+from paddle_tpu.profiler import StepProgram, span
 
 __all__ = ["TrainStep"]
 
@@ -138,8 +138,9 @@ class TrainStep:
                 # (step, key, nonfinite-skip count) live on device: no
                 # per-step host transfer
                 step, chain, nskip = carry
-                step = step + 1.0
-                chain, key = jax.random.split(chain)
+                with jax.named_scope("optimizer"):  # the step's carry
+                    step = step + 1.0
+                    chain, key = jax.random.split(chain)
                 scaling = scaler_state is not None
 
                 def loss_of(trainable_params):
@@ -188,7 +189,8 @@ class TrainStep:
                 clip = optimizer._grad_clip
                 clip_fn = getattr(clip, "clip_fn", None)
                 if clip_fn is not None:
-                    grads = clip_fn(list(grads))
+                    with jax.named_scope("optimizer"):
+                        grads = clip_fn(list(grads))
 
                 skip = None
                 if found_inf is not None:
@@ -265,6 +267,8 @@ class TrainStep:
 
         self._make_jitted = make_jitted
         self._jitted = make_jitted(None)  # optimistic whole-graph path
+        # the step as profiler.program_regions() knows it
+        self._step_program = StepProgram("train.step", self._jitted)
         self._multi_jitted = {}  # (k, stacked) -> scanned executable
         from paddle_tpu.jit.sot import PathCache
 
@@ -543,12 +547,17 @@ class TrainStep:
         wd_id = arm_step(f"TrainStep#{self._opt._step_count}",
                          cold=not warm)
         try:
+            args = (n_inputs, self._carry, param_datas, self._slots,
+                    buffer_datas, self._lr_arr, self._scaler_state, *datas)
+            if jitted is self._jitted:
+                # one is_enabled() with no profiler session live; under
+                # one, the step stays readable after the run
+                if not warm:
+                    self._step_program.note(args)
+                self._step_program.dispatched(self)
             with span("train.dispatch", cold=int(not warm)):
                 loss, self._carry, new_params, new_slots, new_buffers, \
-                    new_scaler_state, valid = jitted(
-                        n_inputs, self._carry, param_datas, self._slots,
-                        buffer_datas, self._lr_arr, self._scaler_state,
-                        *datas)
+                    new_scaler_state, valid = jitted(*args)
         except BaseException:
             # failed dispatch must not leave an armed deadline behind
             default_watchdog().disarm(wd_id)

@@ -270,21 +270,26 @@ def _select(p, u, c_q, index_cache, bt, cu, ctx, ns, cos, sin, *, index,
     t = u.shape[0]
     dr = cos.shape[-1]
     pad = index_cache.shape[-1] - width
-    q = (c_q @ p["idx_q"]).reshape(t, heads, width)
-    k = _layer_norm(u @ p["idx_k"], p["idx_k_norm_w"], p["idx_k_norm_b"],
-                    INDEX_NORM_EPS)
-    q_r, k_r = _rope_at(q[..., :dr], k[:, :dr], cos, sin)
-    q = jnp.concatenate(
-        [q_r, q[..., dr:], jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
-    k = jnp.concatenate([k_r, k[:, dr:], jnp.zeros((t, pad), k.dtype)],
-                        axis=-1)
-    w = (u @ p["idx_w"]).astype(jnp.float32) * (
-        1.0 / math.sqrt(heads) / math.sqrt(width))
+    # the indexer's own projections; the scores and the selection name
+    # themselves (ops/sparse_index.py)
+    with jax.named_scope("index_proj"):
+        q = (c_q @ p["idx_q"]).reshape(t, heads, width)
+        k = _layer_norm(u @ p["idx_k"], p["idx_k_norm_w"],
+                        p["idx_k_norm_b"], INDEX_NORM_EPS)
+        q_r, k_r = _rope_at(q[..., :dr], k[:, :dr], cos, sin)
+        q = jnp.concatenate(
+            [q_r, q[..., dr:], jnp.zeros((t, heads, pad), q.dtype)],
+            axis=-1)
+        k = jnp.concatenate([k_r, k[:, dr:], jnp.zeros((t, pad), k.dtype)],
+                            axis=-1)
+        w = (u @ p["idx_w"]).astype(jnp.float32) * (
+            1.0 / math.sqrt(heads) / math.sqrt(width))
     scores, index_cache = index_scores(q, w, k, index_cache, bt, cu, ctx,
                                        ns, impl=impl)
     selected = select_topk(scores, top_k)
-    return selected, index_cache, selection_counts(scores, selected, bt, cu,
-                                                   ctx, ns)
+    with jax.named_scope("index_counts"):     # the step's counters
+        counts = selection_counts(scores, selected, bt, cu, ctx, ns)
+    return selected, index_cache, counts
 
 
 def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
@@ -298,11 +303,12 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
     t, hidden = u.shape
     a_q = math.sqrt(hidden / rq) if rescale else 1.0
     a_kv = math.sqrt(hidden / rank) if rescale else 1.0
-    c_q = _latent_norm(u @ p["q_a"], p["q_norm_w"], eps, a_q)
-    q = (c_q @ p["q_b"]).reshape(t, heads, dn + dr)
-    ckr = u @ p["kv_a"]
-    c = _latent_norm(ckr[:, :rank], p["kv_norm_w"], eps, a_kv)
-    q_r, k_r = _rope_at(q[..., dn:], ckr[:, rank:], cos, sin)
+    with jax.named_scope("attn_proj"):
+        c_q = _latent_norm(u @ p["q_a"], p["q_norm_w"], eps, a_q)
+        q = (c_q @ p["q_b"]).reshape(t, heads, dn + dr)
+        ckr = u @ p["kv_a"]
+        c = _latent_norm(ckr[:, :rank], p["kv_norm_w"], eps, a_kv)
+        q_r, k_r = _rope_at(q[..., dn:], ckr[:, rank:], cos, sin)
     selected = counts = None
     if index is not None:
         cache, index_cache = cache
@@ -313,10 +319,12 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
     w_kvb = p["kv_b"].reshape(rank, heads, dn + dv)
     with jax.named_scope("mla_absorb"):
         q_abs = jnp.einsum("thn,chn->thc", q[..., :dn], w_kvb[..., :dn])
-    pad = lanes - rank - dr
-    q_lat = jnp.concatenate(
-        [q_abs, q_r, jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
-    entry = jnp.concatenate([c, k_r, jnp.zeros((t, pad), c.dtype)], axis=-1)
+    with jax.named_scope("attn_proj"):
+        pad = lanes - rank - dr
+        q_lat = jnp.concatenate(
+            [q_abs, q_r, jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
+        entry = jnp.concatenate([c, k_r, jnp.zeros((t, pad), c.dtype)],
+                                axis=-1)
     scale = 1.0 / math.sqrt(dn + dr)
     if index is not None:
         with jax.named_scope("sparse_attention"):
@@ -339,7 +347,9 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
         o = (o * gate[:, :, None]).astype(o.dtype)
     if index is not None:
         cache = (cache, index_cache)
-    return o.reshape(t, heads * dv) @ p["o_proj"], cache, selected, counts
+    with jax.named_scope("attn_proj"):
+        return (o.reshape(t, heads * dv) @ p["o_proj"], cache, selected,
+                counts)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -351,17 +361,23 @@ def _layer(p, x, cache, bt, cu, ctx, ns, cos, sin, live, *, dims, eps,
     """One layer. Returns (output, cache', {"rows": rows per held expert,
     "chosen": the sets (T, top_k), "selected": the index mask, "counts":
     the three index counters}, each None where the layer has none)."""
+    # the norms and the residual adds sit inside their neighbours'
+    # regions (XLA fuses them there)
+    with jax.named_scope("attn_proj"):
+        u = _rms_norm(x, p["norm1_w"], eps)
     mix, cache, selected, counts = _attention(
-        p, _rms_norm(x, p["norm1_w"], eps), cache, bt, cu, ctx, ns, cos,
-        sin, dims=dims, eps=eps, rescale=rescale, impl=impl, window=window,
-        index=index)
-    h = x + mix
-    u = _rms_norm(h, p["norm2_w"], eps)
+        p, u, cache, bt, cu, ctx, ns, cos, sin, dims=dims, eps=eps,
+        rescale=rescale, impl=impl, window=window, index=index)
+    with jax.named_scope("attn_proj"):
+        h = x + mix
     rows = chosen = None
     if ffn == "dense":
-        y = _swiglu(u, p["gate_up"], p["down"])
+        with jax.named_scope("mlp"):
+            u = _rms_norm(h, p["norm2_w"], eps)
+            out = h + _swiglu(u, p["gate_up"], p["down"])
     else:
         with jax.named_scope("moe_router"):
+            u = _rms_norm(h, p["norm2_w"], eps)
             chosen, w, _ = route_sigmoid_topk(
                 u, p["router"], p["router_bias"], top_k=top_k, scale=scale,
                 normalize=normalize)
@@ -369,9 +385,10 @@ def _layer(p, x, cache, bt, cu, ctx, ns, cos, sin, live, *, dims, eps,
             u, chosen, w, p["experts_gate_up"], p["experts_down"], live,
             impl=expert_impl, first_expert=first_expert)
         with jax.named_scope("moe_shared"):
-            y = routed + _swiglu(u, p["shared_gate_up"], p["shared_down"])
-    return h + y, cache, {"rows": rows, "chosen": chosen,
-                          "selected": selected, "counts": counts}
+            out = h + (routed + _swiglu(u, p["shared_gate_up"],
+                                        p["shared_down"]))
+    return out, cache, {"rows": rows, "chosen": chosen,
+                        "selected": selected, "counts": counts}
 
 
 # ---------------------------------------------------------------------------
@@ -482,14 +499,16 @@ class Dots3ForCausalLM(nn.Layer):
     # -- the step --------------------------------------------------------
     def _run(self, ids, cache, bt, wbt, cu, ctx, ns):
         c = self.config
-        # each row's absolute position; padding rows (-1) are not live
-        _, pos, live = _token_layout(ids.shape[0], ctx.shape[0], cu, ctx,
-                                     ns)
-        rope = {}
-        for kind, (cos, sin) in self._rope.items():
-            at = jnp.clip(pos, 0, cos.shape[0] - 1)
-            rope[kind] = (cos._data[at], sin._data[at])
-        x = self.embed_tokens.weight._data[ids - c.vocab_held[0]]
+        # the token gather and the stream's position arithmetic: each
+        # row's absolute position; padding rows (-1) are not live
+        with jax.named_scope("embed"):
+            _, pos, live = _token_layout(ids.shape[0], ctx.shape[0], cu,
+                                         ctx, ns)
+            rope = {}
+            for kind, (cos, sin) in self._rope.items():
+                at = jnp.clip(pos, 0, cos.shape[0] - 1)
+                rope[kind] = (cos._data[at], sin._data[at])
+            x = self.embed_tokens.weight._data[ids - c.vocab_held[0]]
         cache = list(cache)
         hist, counts, routing, selections = [], [], [], []
         for l, layer in enumerate(self.layers):
@@ -542,8 +561,10 @@ class Dots3ForCausalLM(nn.Layer):
             _raw(tables["window"]).astype(jnp.int32), cu,
             _raw(context_lens).astype(jnp.int32),
             _raw(num_seqs).astype(jnp.int32))
-        last = jnp.clip(cu[1:] - 1, 0, x.shape[0] - 1)
-        logits = _head(x[last], self.lm_head._data,
+        with jax.named_scope("lm_head"):
+            last = jnp.clip(cu[1:] - 1, 0, x.shape[0] - 1)
+            x_last = x[last]
+        logits = _head(x_last, self.lm_head._data,
                        self.final_norm.weight._data,
                        eps=self.config.rms_norm_eps)
         if return_routing:

@@ -168,35 +168,42 @@ class LlamaAttention(nn.Layer):
 
     def forward(self, x, cos, sin, attn_mask=None):
         b, s, h = x.shape
-        q = ops.reshape(self.q_proj(x), [b, s, self.n_heads, self.head_dim])
-        k = ops.reshape(self.k_proj(x), [b, s, self.n_kv, self.head_dim])
-        v = ops.reshape(self.v_proj(x), [b, s, self.n_kv, self.head_dim])
-        q, k = _registry.API["rope_apply"](q, k, cos, sin)
+        with jax.named_scope("attn_proj"):
+            q = ops.reshape(self.q_proj(x),
+                            [b, s, self.n_heads, self.head_dim])
+            k = ops.reshape(self.k_proj(x), [b, s, self.n_kv, self.head_dim])
+            v = ops.reshape(self.v_proj(x), [b, s, self.n_kv, self.head_dim])
+            q, k = _registry.API["rope_apply"](q, k, cos, sin)
         if self.config.context_parallel and attn_mask is None:
             # ring attention handles GQA internally so only compact
             # [B,S,n_kv,D] chunks travel the ring (no repeat here)
             from paddle_tpu.ops.ring_attention import ring_attention
 
-            out = ring_attention(q, k, v, axis_name=self.config.cp_axis,
-                                 causal=True,
-                                 batch_axis=self.config.cp_batch_axis)
+            with jax.named_scope("attention"):
+                out = ring_attention(
+                    q, k, v, axis_name=self.config.cp_axis, causal=True,
+                    batch_axis=self.config.cp_batch_axis)
+            with jax.named_scope("attn_proj"):
+                out = ops.reshape(out, [b, s, self.n_heads * self.head_dim])
+                return self.o_proj(out)
+        with jax.named_scope("attention"):
+            if self.n_kv != self.n_heads:
+                rep = self.n_heads // self.n_kv
+                k = ops.repeat_interleave(k, rep, axis=2)
+                v = ops.repeat_interleave(v, rep, axis=2)
+            fa = self.config.use_flash_attention
+            if fa and attn_mask is None:
+                from paddle_tpu.ops import pallas_attention
+
+                out = pallas_attention.flash_attention(
+                    q, k, v, causal=True, impl=None if fa is True else fa)
+            else:
+                out = ops.scaled_dot_product_attention(
+                    q, k, v, attn_mask=attn_mask,
+                    is_causal=attn_mask is None)
+        with jax.named_scope("attn_proj"):
             out = ops.reshape(out, [b, s, self.n_heads * self.head_dim])
             return self.o_proj(out)
-        if self.n_kv != self.n_heads:
-            rep = self.n_heads // self.n_kv
-            k = ops.repeat_interleave(k, rep, axis=2)
-            v = ops.repeat_interleave(v, rep, axis=2)
-        fa = self.config.use_flash_attention
-        if fa and attn_mask is None:
-            from paddle_tpu.ops import pallas_attention
-
-            out = pallas_attention.flash_attention(
-                q, k, v, causal=True, impl=None if fa is True else fa)
-        else:
-            out = ops.scaled_dot_product_attention(
-                q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None)
-        out = ops.reshape(out, [b, s, self.n_heads * self.head_dim])
-        return self.o_proj(out)
 
     def forward_ragged(self, x, cos, sin, key_cache, value_cache,
                        block_tables, cu_seqlens, context_lens, num_seqs):
@@ -208,19 +215,24 @@ class LlamaAttention(nn.Layer):
         from paddle_tpu.incubate.nn import functional as F
 
         b, t, _ = x.shape
-        q = ops.reshape(self.q_proj(x),
-                        [b, t, self.n_heads, self.head_dim])._data
-        k = ops.reshape(self.k_proj(x),
-                        [b, t, self.n_kv, self.head_dim])._data
-        v = ops.reshape(self.v_proj(x),
-                        [b, t, self.n_kv, self.head_dim])._data
-        q, k = _rope_apply_at(q, k, cos, sin)
-        out, kc, vc = F.ragged_paged_attention(
-            q[0], k[0], v[0], key_cache, value_cache,
-            block_tables=block_tables, cu_seqlens=cu_seqlens,
-            context_lens=context_lens, num_seqs=num_seqs)
-        out = ops.reshape(out, [1, t, self.n_heads * self.head_dim])
-        return self.o_proj(out), kc, vc
+        with jax.named_scope("attn_proj"):
+            q = ops.reshape(self.q_proj(x),
+                            [b, t, self.n_heads, self.head_dim])._data
+            k = ops.reshape(self.k_proj(x),
+                            [b, t, self.n_kv, self.head_dim])._data
+            v = ops.reshape(self.v_proj(x),
+                            [b, t, self.n_kv, self.head_dim])._data
+            q, k = _rope_apply_at(q, k, cos, sin)
+        # the op names its own halves (kv_update, attention); its row
+        # layout arithmetic reads as attention
+        with jax.named_scope("attention"):
+            out, kc, vc = F.ragged_paged_attention(
+                q[0], k[0], v[0], key_cache, value_cache,
+                block_tables=block_tables, cu_seqlens=cu_seqlens,
+                context_lens=context_lens, num_seqs=num_seqs)
+        with jax.named_scope("attn_proj"):
+            out = ops.reshape(out, [1, t, self.n_heads * self.head_dim])
+            return self.o_proj(out), kc, vc
 
 
 class LlamaMLP(nn.Layer):
@@ -262,8 +274,15 @@ class LlamaDecoderLayer(nn.Layer):
         sin = self.rope_sin[:s]
         if self.config.sequence_parallel:
             x = sharding_constraint(x, {1: "mp"})
-        h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        out = h + self.mlp(self.post_attention_layernorm(h))
+        # the norms and the residual adds sit inside their neighbours'
+        # regions (XLA fuses them there)
+        with jax.named_scope("attn_proj"):
+            u = self.input_layernorm(x)
+        mix = self.self_attn(u, cos, sin, attn_mask)
+        with jax.named_scope("attn_proj"):
+            h = x + mix
+        with jax.named_scope("mlp"):
+            out = h + self.mlp(self.post_attention_layernorm(h))
         if self.config.sequence_parallel:
             out = sharding_constraint(out, {1: "mp"})
         return out
@@ -273,14 +292,18 @@ class LlamaDecoderLayer(nn.Layer):
         """One decoder block over the ragged stream. ``positions`` (T,)
         absolute token positions (pad rows hold any in-range value — the
         attention op zeroes their outputs)."""
-        pos = jnp.clip(positions, 0, self.rope_cos.shape[0] - 1)
-        cos = self.rope_cos._data[pos][None]   # (1, T, D)
-        sin = self.rope_sin._data[pos][None]
+        with jax.named_scope("attn_proj"):
+            pos = jnp.clip(positions, 0, self.rope_cos.shape[0] - 1)
+            cos = self.rope_cos._data[pos][None]   # (1, T, D)
+            sin = self.rope_sin._data[pos][None]
+            u = self.input_layernorm(x)
         attn_out, kc, vc = self.self_attn.forward_ragged(
-            self.input_layernorm(x), cos, sin, key_cache, value_cache,
+            u, cos, sin, key_cache, value_cache,
             block_tables, cu_seqlens, context_lens, num_seqs)
-        h = x + attn_out
-        out = h + self.mlp(self.post_attention_layernorm(h))
+        with jax.named_scope("attn_proj"):
+            h = x + attn_out
+        with jax.named_scope("mlp"):
+            out = h + self.mlp(self.post_attention_layernorm(h))
         return out, kc, vc
 
 
@@ -296,7 +319,8 @@ class LlamaModel(nn.Layer):
         self.norm = LlamaRMSNorm(config)
 
     def forward(self, input_ids, attn_mask=None):
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         for layer in self.layers:
             if self.config.recompute and not self.training:
                 x = layer(x, attn_mask)
@@ -305,7 +329,8 @@ class LlamaModel(nn.Layer):
                 x = recompute(layer, x, attn_mask)
             else:
                 x = layer(x, attn_mask)
-        return self.norm(x)
+        with jax.named_scope("lm_head"):
+            return self.norm(x)
 
     def forward_ragged(self, input_ids, key_caches, value_caches,
                        block_tables, cu_seqlens, context_lens, num_seqs):
@@ -331,22 +356,25 @@ class LlamaModel(nn.Layer):
         ids2 = ops.reshape(input_ids, [1, -1])
         t = ids2.shape[1]
         s_slots = ctx.shape[0]
+        # the token gather and the stream's position arithmetic.
         # absolute position of token row r of slot i:
         # ctx[i] - (cu[i+1]-cu[i]) + r — pad rows clamp into range and
         # are masked downstream by cu_seqlens/num_seqs
-        tok = jnp.arange(t, dtype=jnp.int32)
-        seg = jnp.clip(jnp.searchsorted(cu, tok, side="right") - 1,
-                       0, s_slots - 1).astype(jnp.int32)
-        positions = jnp.maximum(
-            ctx[seg] - (cu[seg + 1] - cu[seg]) + (tok - cu[seg]), 0)
-        x = self.embed_tokens(ids2)
+        with jax.named_scope("embed"):
+            tok = jnp.arange(t, dtype=jnp.int32)
+            seg = jnp.clip(jnp.searchsorted(cu, tok, side="right") - 1,
+                           0, s_slots - 1).astype(jnp.int32)
+            positions = jnp.maximum(
+                ctx[seg] - (cu[seg + 1] - cu[seg]) + (tok - cu[seg]), 0)
+            x = self.embed_tokens(ids2)
         new_k, new_v = [], []
         for layer, kc, vc in zip(self.layers, key_caches, value_caches):
             x, kc, vc = layer.forward_ragged(
                 x, positions, kc, vc, block_tables, cu, ctx, num_seqs)
             new_k.append(kc._data if isinstance(kc, Tensor) else kc)
             new_v.append(vc._data if isinstance(vc, Tensor) else vc)
-        return self.norm(x), tuple(new_k), tuple(new_v)
+        with jax.named_scope("lm_head"):
+            return self.norm(x), tuple(new_k), tuple(new_v)
 
 
 class LlamaPretrainingCriterion(nn.Layer):
@@ -375,7 +403,7 @@ class LlamaForCausalLM(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None):
         h = self.llama(input_ids, attn_mask)
-        with jax.named_scope("lm_head_loss"):
+        with jax.named_scope("lm_head"):
             return self.lm_head(h)
 
     @staticmethod
@@ -401,9 +429,10 @@ class LlamaForCausalLM(nn.Layer):
         t = hd.shape[1]
         # pad slots point at cu[num_seqs]-1 (a real row) — harmless, the
         # engine never samples them
-        last = jnp.clip(cu[1:] - 1, 0, t - 1)
-        h_last = hd[0, last]                           # (S, hidden)
-        logits = self.lm_head(Tensor._from_data(h_last))
+        with jax.named_scope("lm_head"):
+            last = jnp.clip(cu[1:] - 1, 0, t - 1)
+            h_last = hd[0, last]                           # (S, hidden)
+            logits = self.lm_head(Tensor._from_data(h_last))
         return logits, kcs, vcs
 
     def forward_ragged_multi(self, input_ids, key_caches, value_caches,
@@ -427,11 +456,12 @@ class LlamaForCausalLM(nn.Layer):
         r = off.shape[0]
         hd = h._data if isinstance(h, Tensor) else h
         t = hd.shape[1]
-        idx = cu[1:, None] - r + off[None, :]          # (S, R)
-        idx = jnp.maximum(idx, cu[:-1, None])
-        idx = jnp.clip(idx, 0, t - 1)
-        h_g = hd[0, idx.reshape(-1)]                   # (S*R, hidden)
-        logits = self.lm_head(Tensor._from_data(h_g))
+        with jax.named_scope("lm_head"):
+            idx = cu[1:, None] - r + off[None, :]          # (S, R)
+            idx = jnp.maximum(idx, cu[:-1, None])
+            idx = jnp.clip(idx, 0, t - 1)
+            h_g = hd[0, idx.reshape(-1)]                   # (S*R, hidden)
+            logits = self.lm_head(Tensor._from_data(h_g))
         lg = logits._data if isinstance(logits, Tensor) else logits
         s = cu.shape[0] - 1
         return Tensor._from_data(lg.reshape(s, r, -1)), kcs, vcs

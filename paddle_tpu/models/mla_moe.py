@@ -172,38 +172,49 @@ def _mla(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps, impl):
     heads, dn, dr, dv, rank = dims
     t = u.shape[0]
     lanes = cache.shape[-1]
-    q = (u @ p["q_proj"]).reshape(t, heads, dn + dr)
-    ckr = u @ p["kv_a"]
-    c = _rms_norm(ckr[:, :rank], p["kv_norm_w"], eps)
-    # the llama block's rope at per-row positions, on the rope slices
-    # only: one key head, shared by all query heads
-    q_r, k_r = _rope_apply_at(q[None, ..., dn:], ckr[None, :, None, rank:],
-                              cos[None], sin[None])
-    q_r, k_r = q_r[0], k_r[0, :, 0]
-    w_kvb = p["kv_b"].reshape(rank, heads, dn + dv)
+    with jax.named_scope("attn_proj"):
+        q = (u @ p["q_proj"]).reshape(t, heads, dn + dr)
+        ckr = u @ p["kv_a"]
+        c = _rms_norm(ckr[:, :rank], p["kv_norm_w"], eps)
+        # the llama block's rope at per-row positions, on the rope slices
+        # only: one key head, shared by all query heads
+        q_r, k_r = _rope_apply_at(q[None, ..., dn:],
+                                  ckr[None, :, None, rank:],
+                                  cos[None], sin[None])
+        q_r, k_r = q_r[0], k_r[0, :, 0]
+        w_kvb = p["kv_b"].reshape(rank, heads, dn + dv)
     with jax.named_scope("mla_absorb"):
         q_abs = jnp.einsum("thn,chn->thc", q[..., :dn], w_kvb[..., :dn])
-    pad = lanes - rank - dr
-    q_lat = jnp.concatenate(
-        [q_abs, q_r, jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
-    entry = jnp.concatenate([c, k_r, jnp.zeros((t, pad), c.dtype)], axis=-1)
+    with jax.named_scope("attn_proj"):
+        pad = lanes - rank - dr
+        q_lat = jnp.concatenate(
+            [q_abs, q_r, jnp.zeros((t, heads, pad), q.dtype)], axis=-1)
+        entry = jnp.concatenate([c, k_r, jnp.zeros((t, pad), c.dtype)],
+                                axis=-1)
     with jax.named_scope("latent_attention"):
         o_lat, cache, _ = ragged_paged_attention(
             q_lat, entry, None, cache, None, bt, cu, ctx, ns,
             scale=1.0 / math.sqrt(dn + dr), impl=impl, v_lanes=rank)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("thc,chv->thv", o_lat, w_kvb[..., dn:])
-    return o.reshape(t, heads * dv) @ p["o_proj"], cache
+    with jax.named_scope("attn_proj"):
+        return o.reshape(t, heads * dv) @ p["o_proj"], cache
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "eps", "impl"))
 def _dense_layer(p, x, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
                  impl):
-    mix, cache = _mla(p, _rms_norm(x, p["norm1_w"], eps), cache, bt, cu,
-                      ctx, ns, cos, sin, dims=dims, eps=eps, impl=impl)
-    h = x + mix
-    u = _rms_norm(h, p["norm2_w"], eps)
-    return h + _swiglu(u, p["gate_up"], p["down"]), cache
+    # the norms and the residual adds sit inside their neighbours'
+    # regions (XLA fuses them there)
+    with jax.named_scope("attn_proj"):
+        u = _rms_norm(x, p["norm1_w"], eps)
+    mix, cache = _mla(p, u, cache, bt, cu, ctx, ns, cos, sin, dims=dims,
+                      eps=eps, impl=impl)
+    with jax.named_scope("attn_proj"):
+        h = x + mix
+    with jax.named_scope("mlp"):
+        u = _rms_norm(h, p["norm2_w"], eps)
+        return h + _swiglu(u, p["gate_up"], p["down"]), cache
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "eps", "impl", "top_k",
@@ -213,11 +224,14 @@ def _moe_layer(p, x, cache, bt, cu, ctx, ns, cos, sin, live, *, dims, eps,
                impl, top_k, scale, normalize, expert_impl):
     """Returns (layer output, cache', rows_per_expert (E,), chosen sets
     (T, top_k))."""
-    mix, cache = _mla(p, _rms_norm(x, p["norm1_w"], eps), cache, bt, cu,
-                      ctx, ns, cos, sin, dims=dims, eps=eps, impl=impl)
-    h = x + mix
-    u = _rms_norm(h, p["norm2_w"], eps)
+    with jax.named_scope("attn_proj"):
+        u = _rms_norm(x, p["norm1_w"], eps)
+    mix, cache = _mla(p, u, cache, bt, cu, ctx, ns, cos, sin, dims=dims,
+                      eps=eps, impl=impl)
+    with jax.named_scope("attn_proj"):
+        h = x + mix
     with jax.named_scope("moe_router"):
+        u = _rms_norm(h, p["norm2_w"], eps)
         chosen, w, _ = route_sigmoid_topk(
             u, p["router"], p["router_bias"], top_k=top_k, scale=scale,
             normalize=normalize)
@@ -226,13 +240,14 @@ def _moe_layer(p, x, cache, bt, cu, ctx, ns, cos, sin, live, *, dims, eps,
         impl=expert_impl)
     with jax.named_scope("moe_shared"):
         shared = _swiglu(u, p["shared_gate_up"], p["shared_down"])
-    return h + routed + shared, cache, rows_per_expert, chosen
+        return h + routed + shared, cache, rows_per_expert, chosen
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def _head(x, lm_head, norm_w, *, eps):
-    return jnp.dot(_rms_norm(x, norm_w, eps), lm_head,
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        return jnp.dot(_rms_norm(x, norm_w, eps), lm_head,
+                       preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -330,12 +345,14 @@ class MlaMoeForCausalLM(nn.Layer):
                 c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank)
         common = dict(dims=dims, eps=c.rms_norm_eps,
                       impl=c.ragged_attn_impl)
-        # each row's absolute position; padding rows (-1) are not live
-        _, pos, live = _token_layout(ids.shape[0], ctx.shape[0], cu, ctx,
-                                     ns)
-        pos = jnp.clip(pos, 0, self.rope_cos.shape[0] - 1)
-        cos, sin = self.rope_cos._data[pos], self.rope_sin._data[pos]
-        x = self.embed_tokens.weight._data[ids]
+        # the token gather and the stream's position arithmetic: each
+        # row's absolute position; padding rows (-1) are not live
+        with jax.named_scope("embed"):
+            _, pos, live = _token_layout(ids.shape[0], ctx.shape[0], cu,
+                                         ctx, ns)
+            pos = jnp.clip(pos, 0, self.rope_cos.shape[0] - 1)
+            cos, sin = self.rope_cos._data[pos], self.rope_sin._data[pos]
+            x = self.embed_tokens.weight._data[ids]
         cache = list(cache)
         hist, routing = [], []
         for l, layer in enumerate(self.layers):
@@ -371,8 +388,10 @@ class MlaMoeForCausalLM(nn.Layer):
             _raw(block_tables).astype(jnp.int32), cu,
             _raw(context_lens).astype(jnp.int32),
             _raw(num_seqs).astype(jnp.int32))
-        last = jnp.clip(cu[1:] - 1, 0, x.shape[0] - 1)
-        logits = _head(x[last], self.lm_head._data,
+        with jax.named_scope("lm_head"):
+            last = jnp.clip(cu[1:] - 1, 0, x.shape[0] - 1)
+            x_last = x[last]
+        logits = _head(x_last, self.lm_head._data,
                        self.final_norm.weight._data,
                        eps=self.config.rms_norm_eps)
         if return_routing:

@@ -151,34 +151,44 @@ def _silu(x):
 
 
 def _mlp_residual(p, h, eps):
-    u = _layer_norm(h, p["norm2_w"], p["norm2_b"], eps)
-    g, v = jnp.split(u @ p["gate_up"], 2, axis=-1)
-    return h + (_silu(g) * v) @ p["down"]
+    with jax.named_scope("mlp"):
+        u = _layer_norm(h, p["norm2_w"], p["norm2_b"], eps)
+        g, v = jnp.split(u @ p["gate_up"], 2, axis=-1)
+        return h + (_silu(g) * v) @ p["down"]
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "scan_impl"))
 def _mamba_layer(p, x, state, slots, cu, ctx, ns, *, eps, scan_impl):
     """Returns (layer output (T, d), state', memory (T, E))."""
     f32 = jnp.float32
-    u = _layer_norm(x, p["norm1_w"], p["norm1_b"], eps)
-    xi, z = jnp.split(u @ p["in_proj"], 2, axis=-1)
+    # the layer's own products and gates are ssm_proj; the convolution
+    # and the scan name themselves (ops/selective_scan.py)
+    with jax.named_scope("ssm_proj"):
+        u = _layer_norm(x, p["norm1_w"], p["norm1_b"], eps)
+        xi, z = jnp.split(u @ p["in_proj"], 2, axis=-1)
+        conv_in = (xi.astype(f32), p["conv_w"].astype(f32),
+                   p["conv_b"].astype(f32))
     conv, conv_state = ragged_causal_conv(
-        xi.astype(f32), p["conv_w"].astype(f32), p["conv_b"].astype(f32),
-        state["conv"], slots, cu, ctx, ns)
-    xc = _silu(conv).astype(x.dtype)
-    n, rank = p["A_log"].shape[1], p["dt_w"].shape[0]
-    rbc = xc @ p["x_proj"]
-    r, b, c = rbc[:, :rank], rbc[:, rank:rank + n], rbc[:, rank + n:]
-    dt = jax.nn.softplus(
-        jnp.dot(r, p["dt_w"], preferred_element_type=f32)
-        + p["dt_b"].astype(f32))
+        *conv_in, state["conv"], slots, cu, ctx, ns)
+    with jax.named_scope("ssm_proj"):
+        xc = _silu(conv).astype(x.dtype)
+        n, rank = p["A_log"].shape[1], p["dt_w"].shape[0]
+        rbc = xc @ p["x_proj"]
+        r, b, c = rbc[:, :rank], rbc[:, rank:rank + n], rbc[:, rank + n:]
+        dt = jax.nn.softplus(
+            jnp.dot(r, p["dt_w"], preferred_element_type=f32)
+            + p["dt_b"].astype(f32))
+        a = -jnp.exp(p["A_log"].astype(f32))
     y, ssm_state = ragged_selective_scan(
-        xc, dt, -jnp.exp(p["A_log"].astype(f32)), b, c, state["ssm"],
-        slots, cu, ctx, ns, impl=scan_impl)
-    y = y + p["D"].astype(f32) * xc.astype(f32)
-    mix = (y * _silu(z.astype(f32))).astype(x.dtype) @ p["out_proj"]
-    return (_mlp_residual(p, x + mix, eps),
-            {"ssm": ssm_state, "conv": conv_state}, y.astype(x.dtype))
+        xc, dt, a, b, c, state["ssm"], slots, cu, ctx, ns, impl=scan_impl)
+    with jax.named_scope("ssm_proj"):
+        y = y + p["D"].astype(f32) * xc.astype(f32)
+        mix = (y * _silu(z.astype(f32))).astype(x.dtype) @ p["out_proj"]
+        h = x + mix
+    out = _mlp_residual(p, h, eps)
+    with jax.named_scope("ssm_proj"):
+        memory = y.astype(x.dtype)
+    return out, {"ssm": ssm_state, "conv": conv_state}, memory
 
 
 def _diff_lambda(p, lam_init):
@@ -211,18 +221,20 @@ def _diff_attend(p, u, kc, vc, bt, cu, ctx, ns, lam_init, *, heads,
                  kv_heads, window, eps, impl, write):
     t, hidden = u.shape
     d = hidden // heads
-    q = _padded_queries((u @ p["q_proj"]).reshape(t, heads, d))
-    k = v = None
-    if write:
-        # adjacent K/V heads are adjacent in the projection's output: a
-        # pair [k1 | k2] is one reshape away
-        k = (u @ p["k_proj"]).reshape(t, kv_heads // 2, 2 * d)
-        v = (u @ p["v_proj"]).reshape(t, kv_heads // 2, 2 * d)
+    with jax.named_scope("attn_proj"):
+        q = _padded_queries((u @ p["q_proj"]).reshape(t, heads, d))
+        k = v = None
+        if write:
+            # adjacent K/V heads are adjacent in the projection's output:
+            # a pair [k1 | k2] is one reshape away
+            k = (u @ p["k_proj"]).reshape(t, kv_heads // 2, 2 * d)
+            v = (u @ p["v_proj"]).reshape(t, kv_heads // 2, 2 * d)
     out, kc, vc = ragged_paged_attention(
         q, k, v, kc, vc, bt, cu, ctx, ns, scale=1.0 / math.sqrt(d),
         impl=impl, window=window)
     o = _diff_combine(out, _diff_lambda(p, lam_init), lam_init, eps)
-    return o @ p["o_proj"], kc, vc
+    with jax.named_scope("attn_proj"):
+        return o @ p["o_proj"], kc, vc
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "window",
@@ -237,7 +249,8 @@ def _attn_layer(p, x, kc, vc, bt, cu, ctx, ns, lam_init, *, heads,
             p, u, kc, vc, bt, cu, ctx, ns, lam_init, heads=heads,
             kv_heads=kv_heads, window=window, eps=eps, impl=impl,
             write=True)
-    return _mlp_residual(p, x + mix, eps), kc, vc
+        h = x + mix
+    return _mlp_residual(p, h, eps), kc, vc
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "eps",
@@ -252,7 +265,8 @@ def _cross_layer(p, x, kc, vc, bt, cu, ctx, ns, lam_init, *, heads,
             p, u, kc, vc, bt, cu, ctx, ns, lam_init, heads=heads,
             kv_heads=kv_heads, window=None, eps=eps, impl=impl,
             write=False)
-    return _mlp_residual(p, x + mix, eps)
+        h = x + mix
+    return _mlp_residual(p, h, eps)
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
@@ -260,13 +274,15 @@ def _gmu_layer(p, x, memory, *, eps):
     with jax.named_scope("gmu"):
         u = _layer_norm(x, p["norm1_w"], p["norm1_b"], eps)
         mix = (memory * _silu(u @ p["in_proj"])) @ p["out_proj"]
-    return _mlp_residual(p, x + mix, eps)
+        h = x + mix
+    return _mlp_residual(p, h, eps)
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def _head(x, embed, norm_w, norm_b, *, eps):
-    return jnp.dot(_layer_norm(x, norm_w, norm_b, eps), embed.T,
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        return jnp.dot(_layer_norm(x, norm_w, norm_b, eps), embed.T,
+                       preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +396,8 @@ class Phi4FlashForCausalLM(nn.Layer):
         attn = dict(heads=c.num_attention_heads,
                     kv_heads=c.num_key_value_heads, eps=eps,
                     impl=c.ragged_attn_impl)
-        x = self.embed_tokens.weight._data[ids]
+        with jax.named_scope("embed"):
+            x = self.embed_tokens.weight._data[ids]
         cache = list(cache)
         memory = None
         full = c.split - 1

@@ -15,6 +15,14 @@ trace capture (xplane), the TPU's native tracer. ``Profiler.summary()``
 aggregates host scopes; ``benchmark()`` is the hapi throughput timer;
 ``estimate_mfu`` turns step flops + step time into the north-star MFU
 number.
+
+Two views of the device's time, both read from the trace's ``XLA Ops``:
+REGIONS (``regions.py``: ``DEVICE_REGIONS``, ``region_map``,
+``program_regions``, ``Profiler.device_summary(by="region")``) say where
+a compiled step's time goes, by the part of the program each operation
+belongs to; PHASES (``classify_phase``, ``phase_summary``,
+``device_phases``) say what kind of work it was: compute, collective or
+copy.
 """
 from __future__ import annotations
 
@@ -26,13 +34,17 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from paddle_tpu.profiler.regions import (  # noqa: F401
+    DEVICE_REGIONS, StepProgram, program_regions, region_map, self_times,
+)
 from paddle_tpu.profiler.timer import Benchmark, benchmark  # noqa: F401
 
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
            "span", "make_scheduler", "export_chrome_tracing",
            "load_profiler_result", "benchmark", "estimate_mfu",
            "device_phases", "register_counter_provider",
-           "unregister_counter_provider", "counters"]
+           "unregister_counter_provider", "counters", "DEVICE_REGIONS",
+           "region_map", "program_regions"]
 
 
 class ProfilerState:
@@ -416,15 +428,28 @@ class Profiler:
         return _latest_trace(self._trace_dir,
                              min_mtime=self._trace_token - 1.0)
 
-    def device_summary(self, top: int = 40, print_table: bool = True):
-        """Per-op DEVICE time table from the captured xplane trace — the
-        device half of the reference's profiler_statistic.py report
-        (kernel stats aggregated from CUPTI there, from the TPU/XLA
-        xplane here). Requires the profiler to have run with device
-        tracing (the default when jax.profiler capture is available)."""
+    def device_summary(self, top: int = 40, print_table: bool = True,
+                       by: str = "op"):
+        """DEVICE time table from the captured xplane trace — the device
+        half of the reference's profiler_statistic.py report (kernel
+        stats aggregated from CUPTI there, from the TPU/XLA xplane here).
+        Requires the profiler to have run with device tracing (the
+        default when jax.profiler capture is available).
+
+        ``by="op"``: one row an op as the trace names it (calls, total
+        and average ms). ``by="region"``: where the compiled steps' time
+        goes — one row a member of ``DEVICE_REGIONS`` (plus ``unscoped``
+        and, for a train step, ``<region>.backward``): calls, total ms,
+        SELF ms (a ``while`` no longer counts its body twice) and the
+        share of the device's busy time, read through
+        :func:`program_regions` (which lowers the steps dispatched under
+        this session once, after it). ``phase_summary`` is the other
+        view: compute / collective / copy."""
         pd = self._load_trace()
         if pd is None:
             return {}
+        if by == "region":
+            return _regions_from_trace(pd, top, print_table)
         agg: Dict[str, List[float]] = {}
         for name, dur_ms in _iter_device_ops(pd):
             agg.setdefault(name, []).append(dur_ms)
@@ -441,7 +466,6 @@ class Profiler:
         return {r[0]: {"calls": r[1], "total_ms": r[2], "avg_ms": r[3]}
                 for r in rows}
 
-
     _PHASE_COLLECTIVE = ("all-reduce", "all-gather", "all-to-all",
                          "reduce-scatter", "collective-permute",
                          "collective-broadcast", "psum", "ppermute")
@@ -457,8 +481,7 @@ class Profiler:
         fusion(.. %copy.3), kind=kLoop``), so a substring rule files
         every fusion that reads a copy under copies (the July-2026 chip
         run's ``copy_frac`` 0.545)."""
-        family = re.sub(r"[.\d]+$", "",
-                        op_name.split(" = ")[0].strip().lstrip("%")).lower()
+        family = re.sub(r"[.\d]+$", "", _instruction(op_name)).lower()
 
         def among(tokens):
             return any(family == t or family.startswith(t + "-")
@@ -487,6 +510,12 @@ class Profiler:
 # trace loading + device-op iteration (shared by Profiler and the public
 # device_phases API)
 # ---------------------------------------------------------------------------
+def _instruction(op_name: str) -> str:
+    """The HLO instruction's own name of a trace event: the chip names
+    an op by its whole instruction (``%fusion.6 = f32[..] fusion(..)``)."""
+    return op_name.split(" = ")[0].strip().lstrip("%")
+
+
 def _read_xspace(path: str):
     """One parsed trace file, through jax's own reader."""
     from jax.profiler import ProfileData
@@ -523,32 +552,75 @@ def _device_planes(pd):
             or "device" in p.name.lower()]
 
 
-def _iter_device_ops(pd):
-    """Yield (op_name, duration_ms) for every XLA op execution in a
-    parsed trace. TPU/GPU traces put ops on a device plane's 'XLA Ops'
-    line; XLA:CPU has no device plane — its ops run on '/host:CPU'
-    threadpool lines named 'tf_XLA*' (used only when no device plane
-    exists, so a TPU trace never double-counts host-side helpers)."""
+def _device_op_lines(pd):
+    """Yield, for every trace line that holds XLA op executions, its
+    events as ``[(op_name, start_ns, end_ns)]``. TPU/GPU traces put ops
+    on a device plane's 'XLA Ops' line; XLA:CPU has no device plane — its
+    ops run on '/host:CPU' threadpool lines named 'tf_XLA*' (used only
+    when no device plane exists, so a TPU trace never double-counts
+    host-side helpers)."""
     device_planes = _device_planes(pd)
     if any(line.name == "XLA Ops" for p in device_planes
            for line in p.lines):
         for plane in device_planes:
             for line in plane.lines:
-                if line.name != "XLA Ops":
-                    continue
-                for ev in line.events:
-                    yield ev.name, ev.duration_ns / 1e6
+                if line.name == "XLA Ops":
+                    yield [(ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns)
+                           for ev in line.events]
         return
     for plane in pd.planes:
         if "host:CPU" not in plane.name:
             continue
         for line in plane.lines:
-            if not line.name.startswith("tf_XLA"):
-                continue
-            for ev in line.events:
-                if any(t in ev.name for t in _CPU_INFRA_EVENTS):
-                    continue
-                yield ev.name, ev.duration_ns / 1e6
+            if line.name.startswith("tf_XLA"):
+                yield [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                       for ev in line.events
+                       if not any(t in ev.name for t in _CPU_INFRA_EVENTS)]
+
+
+def _iter_device_ops(pd):
+    """Yield (op_name, duration_ms) for every XLA op execution in a
+    parsed trace."""
+    for events in _device_op_lines(pd):
+        for name, a, b in events:
+            yield name, (b - a) / 1e6
+
+
+def _regions_from_trace(pd, top: int, print_table: bool) -> dict:
+    """Device SELF time by region (``device_summary(by="region")``). The
+    trace names an op by its HLO instruction (``%fusion.6 = ...`` on the
+    chip); the steps' maps say which region each belongs to. Steps of
+    several programs in one trace share instruction names: the first
+    program that knows a name files it."""
+    maps = [m for m in program_regions().values() if m]
+    if not maps:
+        return {}
+    rows: Dict[str, List[float]] = {}       # region -> [calls, total, self]
+    for events in _device_op_lines(pd):
+        for name, duration, own in self_times(events):
+            bare = _instruction(name)
+            at = next((m[bare] for m in maps if bare in m), None)
+            region = (at and at["region"]) or "unscoped"
+            if at and at["backward"]:
+                region += ".backward"
+            row = rows.setdefault(region, [0, 0.0, 0.0])
+            row[0] += 1
+            row[1] += duration / 1e6
+            row[2] += own / 1e6
+    busy = sum(r[2] for r in rows.values())
+    ranked = sorted(rows.items(), key=lambda kv: -kv[1][2])
+    if print_table and ranked:
+        hdr = (f"{'Region':<36}{'Calls':>8}{'Total(ms)':>12}"
+               f"{'Self(ms)':>12}{'Share':>8}")
+        print(hdr)
+        print("-" * len(hdr))
+        for nm, (c, tot, own) in ranked[:top]:
+            print(f"{nm:<36}{c:>8}{tot:>12.3f}{own:>12.3f}"
+                  f"{own / busy if busy else 0.0:>8.3f}")
+    return {nm: {"calls": c, "total_ms": tot, "self_ms": own,
+                 "share": own / busy if busy else 0.0}
+            for nm, (c, tot, own) in ranked}
 
 
 def _phases_from_trace(pd, print_table: bool = False) -> dict:

@@ -87,7 +87,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from paddle_tpu.profiler import span
+from paddle_tpu.profiler import StepProgram, span
 from paddle_tpu.serving.block_manager import (
     BlockManager, NoFreeBlocksError, cdiv,
 )
@@ -748,9 +748,11 @@ class LLMEngine:
                     # a model with expert layers: what its router decided
                     # rides the step's ONE fetched array, behind the rows
                     # (and behind that, a sparse indexer's counters)
-                    packed = jnp.concatenate(
-                        [packed.reshape(-1)]
-                        + [c.astype(jnp.int32).reshape(-1) for c in counted])
+                    with jax.named_scope("sampler"):
+                        packed = jnp.concatenate(
+                            [packed.reshape(-1)]
+                            + [c.astype(jnp.int32).reshape(-1)
+                               for c in counted])
                 return packed, finite, cache2
 
             self._jstep_ragged = jax.jit(
@@ -817,6 +819,11 @@ class LLMEngine:
                 else raw_step_ragged,
                 donate_argnums=(4, 5) if donate else (),
                 out_shardings=step_outs)
+        # the step as profiler.program_regions() knows it (the kind is
+        # named where it is not the plain ragged step)
+        self._step_program = StepProgram(
+            "serve.step" + (".tiered" if self._tiered else "")
+            + (".spec" if spec_r > 1 else ""), self._jstep_ragged)
         self._key = jax.random.key(0)
 
         self._requests: Dict[str, Request] = {}
@@ -2131,27 +2138,23 @@ class LLMEngine:
                     eid = self._watchdog.arm(
                         tag, factor=COMPILE_ALLOWANCE if cold else 1.0)
                 faults.fire(faults.SERVING_STEP)  # slow/raise/sigterm point
+                # one is_enabled() with no profiler session live; under
+                # one, the engine stays readable after the run
+                self._step_program.dispatched(self)
                 with span("engine.dispatch", cold=int(cold),
                           attempt=attempt, **composition):
                     if spec_cache:
-                        packed, finite, cache = self._jstep_ragged(
-                            [p._data for p in self._params],
-                            [b._data for b in self._buffers],
-                            self._key, ids, self._cache, *tables, bt, cu,
-                            ctx, nseq, *sampling_arrays)
+                        held = (self._cache, *tables)
                     elif self._kvtier is not None:
-                        packed, finite, kcs, vcs = self._jstep_ragged(
-                            [p._data for p in self._params],
-                            [b._data for b in self._buffers],
-                            self._key, ids, self._kcs, self._vcs,
-                            self._htk, self._htv, bt, cu, ctx, nseq,
-                            *sampling_arrays)
+                        held = (self._kcs, self._vcs, self._htk, self._htv)
                     else:
-                        packed, finite, kcs, vcs = self._jstep_ragged(
-                            [p._data for p in self._params],
-                            [b._data for b in self._buffers],
-                            self._key, ids, self._kcs, self._vcs, bt, cu,
-                            ctx, nseq, *sampling_arrays)
+                        held = (self._kcs, self._vcs)
+                    args = ([p._data for p in self._params],
+                            [b._data for b in self._buffers], self._key,
+                            ids, *held, bt, cu, ctx, nseq, *sampling_arrays)
+                    if cold:
+                        self._step_program.note(args)
+                    packed, finite, *caches = self._jstep_ragged(*args)
                 if self._watchdog is not None:
                     self._watchdog.attach(eid, (packed,))
                 # sampling (greedy AND temperature/top-k/top-p, plus
@@ -2203,9 +2206,9 @@ class LLMEngine:
         # commit only after a fully-successful dispatch+fetch, so a
         # retried attempt re-reads the PRE-failure cache state
         if spec_cache:
-            self._cache = cache
+            self._cache, = caches
         else:
-            self._kcs, self._vcs = kcs, vcs
+            self._kcs, self._vcs = caches
         self._seen_shapes.add(shape_key)
         with self._hung_lock:
             tags, self._hung_tags = self._hung_tags, None
