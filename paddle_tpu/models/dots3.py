@@ -9,7 +9,8 @@ file to.
 * A FULL layer: multi-head latent attention with query compression
   (``q_lora_rank``), over the keys a learned sparse indexer selects
   (``index_topk`` of the visible ones: ``ops/sparse_index.py``), through
-  a kernel of its own (``ops/pallas/sparse_latent_attention.py``). It caches
+  the latent kernel (``ops/pallas/sparse_latent_attention.py``, which
+  serves the sliding layers' call too, and Kimi's). It caches
   TWO arrays under the request's main block table: the latent entry
   ``[c | k_r | zero lanes]`` (576 -> 640 lanes) and, beside it, the
   indexer's one key a token (128 lanes). ``cache_spec()`` kind
@@ -71,9 +72,6 @@ __all__ = ["Dots3Config", "Dots3ForCausalLM"]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 INDEX_NORM_EPS = 1e-6
-# lanes of q (and of the accumulator) the compiled latent kernel takes in
-# one q tile: 16 heads x 640, as models/mla_moe.py's 16 heads fill it
-_TILE_LANES = 10240
 
 
 @dataclass
@@ -332,14 +330,10 @@ def _attention(p, u, cache, bt, cu, ctx, ns, cos, sin, *, dims, eps,
                 q_lat, entry, cache, bt, cu, ctx, ns, selected, scale=scale,
                 impl=impl, v_lanes=rank)
     else:
-        more = {}
-        if heads * lanes > _TILE_LANES:
-            more["head_block"] = max(1, math.gcd(heads,
-                                                 _TILE_LANES // lanes))
         with jax.named_scope("window_latent_attention"):
             o_lat, cache, _ = ragged_paged_attention(
                 q_lat, entry, None, cache, None, bt, cu, ctx, ns,
-                scale=scale, impl=impl, v_lanes=rank, window=window, **more)
+                scale=scale, impl=impl, v_lanes=rank, window=window)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("thc,chv->thv", o_lat, w_kvb[..., dn:])
     with jax.named_scope("attn_gate"):

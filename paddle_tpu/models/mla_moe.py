@@ -18,7 +18,9 @@ sum_s P_h(p, s) c(s)``, ``o_h = W_UV,h o'_h``: the same mathematics as
 per-head keys and values, but what a token leaves in the cache is one
 entry ``[c | k_r]`` a layer (after the norm, after the rotation) that all
 heads read as key and, in its first ``kv_lora_rank`` lanes, as value
-(``ops/pallas/ragged_paged_attention.py``'s latent mode). The entry is
+(``ragged_paged_attention(..., v_lanes=)``, whose compiled body is the
+latent kernel of ``ops/pallas/sparse_latent_attention.py``: the 16 heads
+of a stream row side by side on the row axis). The entry is
 padded with zero lanes to a multiple of 128 (576 -> 640): Mosaic cannot
 slice a 576-lane page, and the TPU's tiled HBM layout pads the minor
 dimension to 128 lanes anyway, so the padding costs no memory.

@@ -29,19 +29,17 @@ Contract (all data-level jnp arrays):
                     step's new tokens are written (prefix + new).
 * ``num_seqs``:     int32 scalar — live slots; trailing slots are padding.
 
-Latent mode (``value_cache=None, v_lanes=n``; multi-head latent
+The latent call (``value_cache=None, v_lanes=n``; multi-head latent
 attention in its absorbed form): ONE cache ``(num_blocks, block_size, W)``
 whose W-lane entry is every query head's key and whose first ``n`` lanes
-are its value. ``q`` is (T, H, W), ``k_new`` (T, W) and ``v_new`` None;
-one K/V head is read by all H query heads, each page group is fetched
-once and serves as keys (all lanes) and values (the first ``n``), and the
-output is (T, H, n). Compiled, W % 128 == 0 and n % 128 == 0. The latent
-call also takes ``window=`` (a latent pool of window layers, walked from
-the first live page like a K/V window pool) and ``head_block=`` for models
-whose heads do not fit one q tile. In the device trace the two are
-``ragged_paged_attention`` and ``ragged_window_latent_attention``. (A
-layer whose rows each attend to a SELECTION of their keys has a kernel of
-its own, ``sparse_latent_attention.py``.)
+are its value. ``q`` is (T, H, W), ``k_new`` (T, W) and ``v_new`` None,
+the output (T, H, n); ``window=`` as for a K/V window pool. This entry
+point hands it on: the latent calls have a kernel of their own
+(``sparse_latent_attention.py``: a row's heads side by side on the row
+axis, page groups of 512 tokens), and ``_ragged_kernel`` below is the K/V
+kernel only. In the device trace the latent call is
+``ragged_paged_attention`` too, under a window
+``ragged_window_latent_attention``.
 
 Returns ``(out (T, H, D), key_cache', value_cache')``: new K/V scattered
 into their paged slots (functional update — in-place on TPU is buffer
@@ -223,12 +221,8 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
                    kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
                    scale, block_q, slab, block_size, pages, n_heads,
-                   kv_heads, head_dim, window=None, v_lanes=None):
-    # latent mode (``v_lanes``): no value cache; a fetched page group is
-    # the keys (all ``d`` lanes) and the values (its first ``dv`` lanes)
-    latent = v_lanes is not None
+                   kv_heads, head_dim, window=None):
     d = head_dim
-    dv = v_lanes if latent else d
     rep = n_heads // kv_heads
     width = pages * block_size            # KV tokens per page group
     s_slots = ctx_ref.shape[0]
@@ -241,10 +235,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
     # unfetched tail is multiplied by exact-zero probabilities, so what
     # the V buffer starts with must be finite
     o_ref[...] = jnp.zeros_like(o_ref)
-    if latent:
-        kbuf[...] = jnp.zeros_like(kbuf)
-    else:
-        vbuf[...] = jnp.zeros_like(vbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
 
     def span(s):
         """Slot ``s`` in this tile: its stream rows [r0, r1), the first
@@ -268,11 +259,8 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         return lo, nq, ctx_ref[c], r0, r1, pg0, live, n_pg - pg0
 
     def copies(p, b, page):
-        k_copy = pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
-                                       sem.at[0, b])
-        if latent:
-            return (k_copy,)
-        return (k_copy,
+        return (pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
+                                      sem.at[0, b]),
                 pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
                                       sem.at[1, b]))
 
@@ -324,7 +312,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             m_scr[:, rows, :] = jnp.full((n_heads, n, 128), _NEG_INF,
                                          jnp.float32)
             l_scr[:, rows, :] = jnp.zeros((n_heads, n, 128), jnp.float32)
-            acc_scr[rows, :] = jnp.zeros((n, n_heads * dv), jnp.float32)
+            acc_scr[rows, :] = jnp.zeros((n, n_heads * d), jnp.float32)
 
         def attend(grp, b, row0, n):
             rows = pl.ds(row0, n)
@@ -339,9 +327,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
             if window is not None:
                 mask = mask & (col > qpos - window)
             mask = jnp.concatenate([mask] * rep, axis=0)
-            if latent:                   # the value is a slice of the key
-                k_head = _head_reader(kbuf.at[b], d)
-            elif len(kbuf.shape) == 4:                   # folded pages
+            if len(kbuf.shape) == 4:                     # folded pages
                 k_head, v_head = (_head_reader(kbuf.at[b], d),
                                   _head_reader(vbuf.at[b], d))
             else:
@@ -355,9 +341,9 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                 heads = range(g * rep, (g + 1) * rep)
                 qg = jnp.concatenate(
                     [q_ref[rows, h * d:(h + 1) * d] for h in heads], axis=0)
-                hs = [slice(h * dv, (h + 1) * dv) for h in heads]
+                hs = [slice(h * d, (h + 1) * d) for h in heads]
                 kg = k_head(g)
-                vg = kg[:, :dv] if latent else v_head(g)
+                vg = v_head(g)
                 sc = mxu_dot(
                     qg, kg, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -391,7 +377,7 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                      + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0))
             ok = (local >= 0) & (local < nq)
             for h in range(n_heads):
-                hd = slice(h * dv, (h + 1) * dv)
+                hd = slice(h * d, (h + 1) * d)
                 l = l_scr[h, rows, :1]
                 val = (acc_scr[rows, hd]
                        / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
@@ -433,27 +419,16 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
 
 # page groups of this many KV tokens: one lane width of scores
 _GROUP_TOKENS = 128
-# what a call with head groups may take of VMEM: the q, output and
-# accumulator tiles of a group of heads beside the page buffers
-_VMEM_LIMIT_WIDE = 64 * 1024 * 1024
 
 
 # jitted on its own so that the layers of a model, which call it with one
 # set of shapes, share one trace and one lowering of the kernel body: the
 # body is the slow part of tracing a serving step (PERF.md, PR 28)
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
-                                             "v_lanes", "head_block"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
-                          interpret, window=None, v_lanes=None,
-                          head_block=None):
+                          interpret, window=None):
     t_total, h, d = q.shape
-    latent = v_lanes is not None
-    # the latent call under a window carries a name of its own in the
-    # device trace (the metrics tell the calls apart by these)
-    name = "ragged_paged_attention"
-    if latent and window is not None:
-        name = "ragged_window_latent_attention"
-    dv = v_lanes if latent else d
     folded = kc.ndim == 3
     if folded:
         _, bs, lanes = kc.shape
@@ -471,11 +446,6 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
             f"{kc.dtype} cache with {kh} KV head(s) per shard: keep "
             f"kv_heads * itemsize >= 4 (fewer head shards)")
     block_q = _pick_block_q(t_total)
-    if latent:
-        # a row's q tile is h * d lanes and its float32 accumulator
-        # h * dv: 128 rows of 16 x 640 / 16 x 512 would take ~14 MB of
-        # VMEM beside the page buffers
-        block_q = min(block_q, 64)
     n_qb = -(-t_total // block_q)
     t_pad = n_qb * block_q
     pages = max(1, min(mb, _GROUP_TOKENS // bs))
@@ -484,58 +454,42 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
     q2 = q.reshape(t_total, h * d)
     if t_pad != t_total:
         q2 = jnp.pad(q2, ((0, t_pad - t_total), (0, 0)))
-    # ``head_block`` (latent mode): the heads in groups of that many on a
-    # second, inner grid axis; a q tile then holds one group's lanes (128
-    # heads x 640 lanes would be 10 MB a 64-row tile) and the kernel body
-    # runs as it does for a model of that many heads
-    hb = h if head_block is None else head_block
-    grid = (n_qb,) if head_block is None else (n_qb, h // hb)
 
-    def q_map(qb, *rest):
-        return (qb, 0) if head_block is None else (qb, rest[0])
+    def q_map(qb, cu_r, ctx_r, ns_r, bt_r):
+        return (qb, 0)
 
-    static = dict(
-        scale=scale, block_q=block_q,
+    kernel = functools.partial(
+        _ragged_kernel, scale=scale, block_q=block_q,
         slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
-        n_heads=hb, kv_heads=kh, head_dim=d,
-        **({} if window is None else {"window": window}),
-        **({"v_lanes": v_lanes} if latent else {}))
-    kernel = functools.partial(_ragged_kernel, **static)
-    page_buf = _VMEM((2, pages) + page, kc.dtype)
-    # latent: the kernel never touches its value operands; the one
-    # cache and a token scratch stand in their places
-    vc, v_buf = (kc, _VMEM((8, 128), kc.dtype)) if latent else (vc,
-                                                                page_buf)
+        n_heads=h, kv_heads=kh, head_dim=d,
+        **({} if window is None else {"window": window}))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=grid,
+        grid=(n_qb,),
         in_specs=[
-            pl.BlockSpec((block_q, hb * d), q_map, memory_space=_VMEM),
+            pl.BlockSpec((block_q, h * d), q_map, memory_space=_VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_q, hb * dv), q_map,
+        out_specs=pl.BlockSpec((block_q, h * d), q_map,
                                memory_space=_VMEM),
         scratch_shapes=[
-            page_buf,
-            v_buf,
+            _VMEM((2, pages) + page, kc.dtype),
+            _VMEM((2, pages) + page, vc.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            _VMEM((hb, block_q, 128), jnp.float32),
-            _VMEM((hb, block_q, 128), jnp.float32),
-            _VMEM((block_q, hb * dv), jnp.float32),
+            _VMEM((h, block_q, 128), jnp.float32),
+            _VMEM((h, block_q, 128), jnp.float32),
+            _VMEM((block_q, h * d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_pad, h * dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, h * d), q.dtype),
         interpret=interpret,
-        name=name,
-        **({} if head_block is None else {
-            "compiler_params": pltpu.CompilerParams(
-                vmem_limit_bytes=_VMEM_LIMIT_WIDE)}),
+        name="ragged_paged_attention",
     )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
-    return out[:t_total].reshape(t_total, h, dv)
+    return out[:t_total].reshape(t_total, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -550,56 +504,19 @@ def _resolve_impl(impl):
     return impl
 
 
-def _latent_attention(q, new, cache, bt, cu, ctx, ns, scale, impl, v_lanes,
-                      window=None, head_block=None):
-    """The latent call: one cache, written and read as the entry it
-    holds (module docstring). Returns (out (T, H, v_lanes), cache',
-    None)."""
-    decl = declared()
-    if decl is not None and decl[1] is not None:
-        raise NotImplementedError(
-            "a latent cache has no head axis to shard over a mesh")
-    t_total, heads, width = q.shape
-    if cache.shape[-1] != width or not 0 < v_lanes <= width:
-        raise ValueError(
-            f"latent mode: q is {width} lanes wide, the cache's entry "
-            f"{cache.shape[-1]}, the value its first {v_lanes}")
-    if head_block is not None and heads % head_block:
-        raise ValueError(f"{heads} heads in groups of {head_block}")
-    seg, pos, valid = _token_layout(t_total, bt.shape[0], cu, ctx, ns)
-    if new is not None:
-        with jax.named_scope("kv_update"):          # the cache scatter
-            cache = _write_kv(cache, jnp.asarray(new), bt, seg, pos)
-    # a call that passes neither has the trace it always had
-    more = {} if window is None else {"window": window}
-    with jax.named_scope("attention"):
-        if impl == "ref":
-            out = _ragged_attend_ref(q, cache, None, bt, ctx, seg, pos,
-                                     valid, scale, v_lanes=v_lanes, **more)
-        else:
-            if head_block is not None:
-                more["head_block"] = head_block
-            out = _ragged_attend_pallas(
-                q, cache, None, bt, cu, ctx, ns, scale,
-                interpret=(impl == "interpret"), v_lanes=v_lanes, **more)
-    return out, cache, None
-
-
 def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
                            block_tables, cu_seqlens, context_lens,
                            num_seqs, *, scale=None, impl=None, window=None,
-                           v_lanes=None, head_block=None):
+                           v_lanes=None):
     """See module docstring for the contract. Returns (out, kc', vc').
     ``v_lanes`` n with ``value_cache`` None is the latent call: one
-    cache whose entry is the key and, in its first n lanes, the value.
+    cache whose entry is the key and, in its first n lanes, the value
+    (``sparse_latent_attention.py: latent_attention``).
     ``window`` w (None = full): a query at position p attends keys
     p-w+1..p, and the page walk starts at the page of the first row's
     oldest visible key, so the cost does not grow with the context and
     block-table entries behind the window may be gone (-1); the K/V call
     and the latent call take it alike.
-    ``head_block`` (latent call only): the compiled kernel takes the
-    heads in groups of that many (a model of 128 heads x 640 lanes has
-    no 64-row q tile that fits VMEM whole).
     ``k_new``/``v_new`` None is the read-only call: nothing is written
     and the caches come back as they were (a layer that attends another
     layer's pages)."""
@@ -608,18 +525,16 @@ def ragged_paged_attention(q, k_new, v_new, key_cache, value_cache,
         if value_cache is not None or v_new is not None:
             raise ValueError("the latent call takes one cache and one new "
                              "entry a row (value_cache and v_new None)")
-        return _latent_attention(
-            q, k_new, jnp.asarray(key_cache),
-            jnp.asarray(block_tables).astype(jnp.int32),
-            jnp.asarray(cu_seqlens).astype(jnp.int32),
-            jnp.asarray(context_lens).astype(jnp.int32),
-            jnp.asarray(num_seqs).astype(jnp.int32),
-            1.0 / (q.shape[-1] ** 0.5) if scale is None else scale,
-            _resolve_impl(impl), int(v_lanes),
-            window=int(window) if window else None, head_block=head_block)
-    if head_block is not None:
-        raise ValueError("head_block= belongs to the latent call "
-                         "(v_lanes=)")
+        # imported here: that module imports this one's stream layout,
+        # scatter and reference, and a K/V call never needs it
+        from paddle_tpu.ops.pallas.sparse_latent_attention import (
+            latent_attention,
+        )
+        out, cache = latent_attention(
+            q, k_new, key_cache, block_tables, cu_seqlens, context_lens,
+            num_seqs, v_lanes=int(v_lanes), window=window, scale=scale,
+            impl=impl)
+        return out, cache, None
     read_only = k_new is None
     if read_only:
         k_new = v_new = jnp.zeros((0,), q.dtype)    # placeholders, unread

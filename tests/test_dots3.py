@@ -412,14 +412,16 @@ def plain_latent_attention(q, entries, v_lanes, scale, seen):
     return np.stack(out)
 
 
-@pytest.mark.parametrize("head_block", [None, 2])
+@pytest.mark.parametrize("heads", [4, 16])
 @pytest.mark.parametrize("impl", ["ref", "interpret"])
 @pytest.mark.parametrize("mode", ["selected", "window"])
 @pytest.mark.parametrize("name", sorted(INDEX_ROWS))
 def test_latent_call_under_a_selection_and_under_a_window(name, mode, impl,
-                                                          head_block):
+                                                          heads):
+    # 16 heads: the compiled kernel's granule (neither call takes its
+    # heads in groups)
     cu, ctx, ns = INDEX_ROWS[name]
-    k = latent_inputs()
+    k = latent_inputs(heads=heads)
     rng = np.random.default_rng(1)
     window = 6
     bt = k["bt"].copy()
@@ -428,14 +430,11 @@ def test_latent_call_under_a_selection_and_under_a_window(name, mode, impl,
     if mode == "selected":
         # any mask will do, the future included: a row attends to the
         # selected keys it causally sees (its own among them, so that no
-        # set is empty). The call takes no head groups: ``head_block``'s
-        # two cases are 4 heads and 16 (the compiled kernel's granule)
+        # set is empty)
         sel = rng.random((32, 12 * BS)) < 0.4
         for i in range(ns):
             n = cu[i + 1] - cu[i]
             sel[np.arange(cu[i], cu[i + 1]), ctx[i] - n + np.arange(n)] = 1
-        if head_block is not None:
-            k = latent_inputs(heads=16)
         out, cache = sparse_latent_attention(
             k["q"], k["new"], k["cache"], bt, *stream,
             jnp.asarray(sel, jnp.int8), scale=0.1, impl=impl, v_lanes=128)
@@ -446,8 +445,7 @@ def test_latent_call_under_a_selection_and_under_a_window(name, mode, impl,
             bt[i, :max(first - window + 1, 0) // BS] = -1
         out, cache, _ = ragged_paged_attention(
             k["q"], k["new"], None, k["cache"], None, bt, *stream,
-            scale=0.1, impl=impl, v_lanes=128, head_block=head_block,
-            window=window)
+            scale=0.1, impl=impl, v_lanes=128, window=window)
     out, cache = np.asarray(out), np.asarray(cache)
     for i in range(ns):
         r0, n = cu[i], cu[i + 1] - cu[i]
@@ -475,12 +473,11 @@ def test_latent_call_states_its_contract():
     with pytest.raises(TypeError, match="selected"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
                                *args, v_lanes=128, selected=sel)
-    with pytest.raises(ValueError, match="belongs to the latent call"):
-        ragged_paged_attention(k["q"], k["new"], k["new"], k["cache"],
-                               k["cache"], *args, head_block=2)
-    with pytest.raises(ValueError, match="in groups of"):
+    # nor its heads in groups (PR 38: a row's heads sit side by side on
+    # the row axis, whatever their number)
+    with pytest.raises(TypeError, match="head_block"):
         ragged_paged_attention(k["q"], k["new"], None, k["cache"], None,
-                               *args, v_lanes=128, head_block=3)
+                               *args, v_lanes=128, head_block=2)
     # the selected call: a mask a row of q by logical position, an entry
     # as wide as q, the value inside it; compiled, lanes in 128s
     with pytest.raises(ValueError, match="a mask a row of q"):
@@ -527,7 +524,10 @@ def test_selected_call_over_tiles_slots_and_page_groups(name, dense,
     from paddle_tpu.ops.pallas import sparse_latent_attention as sla
 
     monkeypatch.setattr(sla, "_GROUP_TOKENS", 16)
-    monkeypatch.setattr(sla, "_TILE_ROWS", 8)
+    monkeypatch.setattr(sla, "_PRODUCT_ROWS", 8 * 4)
+    # unjitted: the two are read at trace, and no other test's trace of
+    # these shapes may serve this one
+    monkeypatch.setattr(sla, "_attend_pallas", sla._attend_pallas.__wrapped__)
     cu, ctx, ns = TILE_ROWS[name]
     k = latent_inputs(seed=2)
     rng = np.random.default_rng(3)

@@ -419,6 +419,80 @@ def test_latent_call_refuses_a_second_cache_and_a_window():
                                *args, v_lanes=512)
 
 
+# rows of the stream, as (stream rows a tile, cu_seqlens, context_lens),
+# that put the latent kernel's tiles and page groups (two pages = 16 tokens
+# here) to work at 16 heads, the stream's padding behind them
+def _one_row_slots(n, seed=7):
+    ctx = np.random.default_rng(seed).integers(1, 64, n)
+    return list(range(n + 1)), [int(c) for c in ctx]
+
+
+STREAM_ROWS = {
+    "decode_row_alone_in_a_tile": (8, [0, 8, 9], [40, 29]),
+    "thirty_one_row_slots_in_one_tile": (32, *_one_row_slots(30)),
+    "chunk_crossing_two_tiles": (8, [0, 1, 14], [33, 50]),
+    "chunk_tail_beside_decode_rows": (8, [0, 11, 12, 13, 14, 15],
+                                      [43, 64, 1, 17, 32]),
+    "context_ends_inside_a_group_and_a_page": (8, [0, 5, 6], [37, 21]),
+    "one_slot_a_tile_to_the_last_row": (8, [0, 8, 16, 64], [8, 64, 48]),
+    "nothing_live": (8, [0], []),
+}
+
+
+@pytest.mark.parametrize("window", [None, 10, 16],
+                         ids=["causal", "window10", "window16"])
+@pytest.mark.parametrize("name", sorted(STREAM_ROWS))
+def test_latent_kernel_over_tiles_slots_and_page_groups(name, window,
+                                                        monkeypatch):
+    """The interpreted kernel against the plain float32 form, 16 heads of
+    a stream row side by side: slots of one row computed on their own 16
+    queries, chunks over whole tiles with the other slots' rows masked,
+    contexts that end inside a page group and inside a page. Under a
+    window the table's entries behind it are -1 (released) and the window
+    of a row crosses a group's boundary wherever 16 does not divide its
+    first key. Padding rows read zeros."""
+    from paddle_tpu.ops.pallas import sparse_latent_attention as sla
+
+    tile, cu, ctx = STREAM_ROWS[name]
+    ns, t, s, mb, heads = len(ctx), 64, 32, 8, 16
+    monkeypatch.setattr(sla, "_PRODUCT_ROWS", tile * heads)
+    monkeypatch.setattr(sla, "_group_tokens", lambda window: 16)
+    # the jitted body reads the two at trace: unjitted, no trace of another
+    # tiling serves this case, nor this one's a later test
+    monkeypatch.setattr(sla, "_attend_pallas", sla._attend_pallas.__wrapped__)
+    rng = np.random.default_rng(4)
+    q = rng.standard_normal((t, heads, 256)).astype(np.float32)
+    new = rng.standard_normal((t, 256)).astype(np.float32)
+    pool = rng.standard_normal((s * mb, BS, 256)).astype(np.float32)
+    bt = rng.permutation(s * mb).astype(np.int32).reshape(s, mb)
+    cu = np.asarray(cu + [cu[-1]] * (s + 1 - len(cu)), np.int32)
+    ctx = np.asarray(ctx + [0] * (s - ns), np.int32)
+    table = bt.copy()
+    if window is not None:
+        for i in range(ns):
+            # blocks wholly behind the first row's window are gone
+            first = ctx[i] - (cu[i + 1] - cu[i])
+            table[i, :max(first - window + 1, 0) // BS] = -1
+    out, cache, _ = ragged_paged_attention(
+        q, new, None, pool, None, table, cu, ctx, np.int32(ns), scale=0.1,
+        impl="interpret", v_lanes=128, window=window)
+    out, cache = np.asarray(out), np.asarray(cache)
+    for i in range(ns):
+        r0, n = cu[i], cu[i + 1] - cu[i]
+        entries = cache[bt[i]].reshape(-1, 256)[:ctx[i]]
+        np.testing.assert_array_equal(entries[ctx[i] - n:], new[r0:r0 + n])
+        for j in range(n):
+            pos = ctx[i] - n + j
+            keys = entries[0 if window is None
+                           else max(pos - window + 1, 0):pos + 1]
+            sc = np.einsum("hd,ld->hl", q[r0 + j], keys) * 0.1
+            sc = np.exp(sc - sc.max(-1, keepdims=True))
+            np.testing.assert_allclose(
+                out[r0 + j], (sc / sc.sum(-1, keepdims=True)) @ keys[:, :128],
+                rtol=2e-4, atol=2e-5)
+    assert not out[cu[ns]:].any()            # padding rows read nothing
+
+
 # -- (e) the engine: one latent pool a layer -------------------------------
 @pytest.mark.parametrize("lengths", [(5, 3), (37, 20), (5, 30, 17, 9, 26)],
                          ids=["short", "chunked", "mixed"])

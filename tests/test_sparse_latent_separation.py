@@ -1,10 +1,11 @@
-"""The selected latent call lives in a file of its own
-(``ops/pallas/sparse_latent_attention.py``) so that the calls every other
-cell runs (K/V, K/V under a window, Kimi's latent call, the windowed
-latent call) stay what they were. Held here, not by a builder's diff: each
-of them lowers to the same text in a process that never imports that
-module and in one that does (kernels interpreted; lowered text carries no
-line numbers)."""
+"""The latent calls have a kernel and a file of their own
+(``ops/pallas/sparse_latent_attention.py``: Kimi's call, the windowed
+call and the selected call, one body) so that the K/V calls every other
+cell runs stay what they were: a K/V call never pulls that module in, and
+every call lowers to the same text in a process that imported it first
+and in one that did not (kernels interpreted; lowered text carries no line
+numbers). The same script, run against a checkout of a PR's parent, says
+which calls that PR left as they were."""
 import json
 import os
 import subprocess
@@ -13,7 +14,7 @@ import sys
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-CALLS = ["kv", "kv_window_folded", "latent", "latent_window_head_groups"]
+CALLS = ["kv", "kv_window_folded", "latent", "latent_window", "selected"]
 
 
 def _hashes(*flags):
@@ -40,19 +41,24 @@ def test_shared_calls_lower_the_same_beside_the_selected_call(lowered,
     assert without[call] == with_sparse[call]
 
 
-def test_the_shared_kernel_takes_no_selection():
-    """``selected=`` is gone from the paged call and its kernel: a
-    selection is the other file's business (the ``jnp`` reference keeps
-    the mask: it is that file's oracle and CPU route)."""
+def test_the_kv_kernel_has_no_latent_mode():
+    """``_ragged_kernel`` is the K/V kernel it was before PR 33: no
+    ``v_lanes``, head groups or selection in it or in its jitted caller
+    (the ``jnp`` reference keeps all three: it is the latent kernel's
+    oracle and CPU route); the paged call hands a latent call on."""
     import inspect
 
     from paddle_tpu.ops.pallas import ragged_paged_attention as paged
 
-    for fn in (paged.ragged_paged_attention, paged._ragged_kernel,
-               paged._latent_attention,
+    for fn in (paged._ragged_kernel,
                paged._ragged_attend_pallas.__wrapped__):
-        assert "selected" not in inspect.signature(fn).parameters
-    assert "selected" in inspect.signature(
-        paged._ragged_attend_ref).parameters
-    assert "sel_ref" not in inspect.getsource(paged)
-    assert not hasattr(paged, "_ragged_kernel_selected")
+        for gone in ("selected", "v_lanes", "head_block"):
+            assert gone not in inspect.signature(fn).parameters
+    for kept in ("selected", "v_lanes", "window"):
+        assert kept in inspect.signature(paged._ragged_attend_ref).parameters
+    assert "selected" not in inspect.signature(
+        paged.ragged_paged_attention).parameters
+    assert "head_block" not in inspect.signature(
+        paged.ragged_paged_attention).parameters
+    source = inspect.getsource(paged._ragged_kernel)
+    assert "latent" not in source and "sel_ref" not in source

@@ -139,7 +139,10 @@ def test_latent_call_compiles_for_v5e(one_chip):
     """The attention call of the `kimi-vl-a3b-d8.vqa-c32` step: 16 query
     heads of 640 lanes (512 latent + 64 rope + 64 zero: Mosaic refuses
     to slice a 576-lane page) over ONE latent cache of 16,384 blocks,
-    values its first 512 lanes; the cache is updated in place."""
+    values its first 512 lanes; the cache is updated in place. The
+    kernel of ``sparse_latent_attention.py`` without a selection: the
+    stream as (512 x 16, 640), tiles of 128 stream rows, q and the output
+    where they are (no re-tile: the temporaries are the row layout's)."""
     bf16, i32 = jnp.bfloat16, jnp.int32
     t, h, lanes, s, mb, nb = 512, 16, 640, 32, 512, 16384
 
@@ -181,13 +184,13 @@ def test_latent_call_compiles_selected_and_windowed_for_v5e(
         one_chip, h, lanes, v_lanes, mode, name):
     """The two attention calls of the `dots3-note-prev-d5.longdoc-c16`
     step at the published widths, as ``models/dots3.py: _attention``
-    issues them. Selected: the kernel of ``sparse_latent_attention.py``,
-    128 heads of 640 lanes side by side on the row axis (tiles of 16 stream
-    rows = 2,048 query rows) under a per-row selection mask (T, 32768)
-    int8, pages in groups of 512 tokens. Window: 64 heads of 1,152 lanes
-    under a 513-key window over a window pool whose table may hold -1, the
-    heads in groups (a 64-row q tile of all of them does not fit VMEM).
-    Each cache is updated in place."""
+    issues them, both the kernel of ``sparse_latent_attention.py``, a
+    row's heads side by side on the row axis. Selected: 128 heads of 640
+    lanes (tiles of 16 stream rows = 2,048 query rows) under a per-row
+    selection mask (T, 32768) int8, pages in groups of 512 tokens. Window:
+    64 heads of 1,152 lanes (tiles of 16 rows = 1,024 query rows) under a
+    513-key window over a window pool whose table may hold -1, pages in
+    groups of 256 tokens. Each cache is updated in place."""
     from paddle_tpu.ops.pallas.sparse_latent_attention import (
         sparse_latent_attention,
     )
@@ -204,8 +207,7 @@ def test_latent_call_compiles_selected_and_windowed_for_v5e(
                 scale=0.07)
         out, cache, _ = ragged_paged_attention(
             q, new, None, cache, None, *rest, impl="pallas",
-            v_lanes=v_lanes, scale=0.07, head_block=10240 // lanes,
-            window=513)
+            v_lanes=v_lanes, scale=0.07, window=513)
         return out, cache
 
     compiled = jax.jit(call, donate_argnums=2).lower(
@@ -216,11 +218,11 @@ def test_latent_call_compiles_selected_and_windowed_for_v5e(
     assert _kernel_calls(compiled, name) == 1
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == nb * BS * lanes * 2
-    # window: q and the output re-tiled to (T, H * lanes), 75 MB and 67 MB;
-    # selected: q and the output stay where they are, the mask's 32-bit
-    # copy is 64 MB
+    # q and the output stay where they are (window: 1.2 MB of row layout;
+    # until PR 38 their re-tiles to (T, H * lanes) were 75 MB and 67 MB);
+    # selected: the mask's 32-bit copy is 64 MB
     assert mem.temp_size_in_bytes < (96 if mode == "selected"
-                                     else 256) * 2 ** 20
+                                     else 16) * 2 ** 20
 
 
 def test_index_scores_and_selection_compile_for_v5e(one_chip):
