@@ -10,6 +10,10 @@ differential attention at L/2 + 1 (the model's only full K/V), then gated
 memory units (even) and differential cross-attention onto layer L/2 + 1's
 pages (odd). No positional encoding. The equations are written out in
 ``benchmark/reference_phi4flash.py``, which the tests hold this file to.
+The Mamba-1 mixer itself (projections, convolution, scan, gate) is
+``ops/selective_scan.py: mamba_mixer``, which owns it since
+``models/jamba.py`` runs the same mixer with norms inside; this file keeps
+the layer around it (its LayerNorm, residual, MLP and the memory output).
 
 What the model needs cached is not one stack of K/V: ``cache_spec()``
 says, per layer, ``full``, ``window`` (only the last ``sliding_window``
@@ -52,9 +56,7 @@ from paddle_tpu.nn import initializer as init
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_attention,
 )
-from paddle_tpu.ops.selective_scan import (
-    ragged_causal_conv, ragged_selective_scan,
-)
+from paddle_tpu.ops.selective_scan import mamba_mixer
 
 __all__ = ["Phi4FlashConfig", "Phi4FlashForCausalLM"]
 
@@ -159,36 +161,19 @@ def _mlp_residual(p, h, eps):
 
 @functools.partial(jax.jit, static_argnames=("eps", "scan_impl"))
 def _mamba_layer(p, x, state, slots, cu, ctx, ns, *, eps, scan_impl):
-    """Returns (layer output (T, d), state', memory (T, E))."""
-    f32 = jnp.float32
-    # the layer's own products and gates are ssm_proj; the convolution
-    # and the scan name themselves (ops/selective_scan.py)
+    """Returns (layer output (T, d), state', memory (T, E)). The mixer is
+    ``ops/selective_scan.py: mamba_mixer``, which ``models/jamba.py``
+    calls too."""
     with jax.named_scope("ssm_proj"):
         u = _layer_norm(x, p["norm1_w"], p["norm1_b"], eps)
-        xi, z = jnp.split(u @ p["in_proj"], 2, axis=-1)
-        conv_in = (xi.astype(f32), p["conv_w"].astype(f32),
-                   p["conv_b"].astype(f32))
-    conv, conv_state = ragged_causal_conv(
-        *conv_in, state["conv"], slots, cu, ctx, ns)
+    mix, y, state = mamba_mixer(p, u, state, slots, cu, ctx, ns,
+                                scan_impl=scan_impl)
     with jax.named_scope("ssm_proj"):
-        xc = _silu(conv).astype(x.dtype)
-        n, rank = p["A_log"].shape[1], p["dt_w"].shape[0]
-        rbc = xc @ p["x_proj"]
-        r, b, c = rbc[:, :rank], rbc[:, rank:rank + n], rbc[:, rank + n:]
-        dt = jax.nn.softplus(
-            jnp.dot(r, p["dt_w"], preferred_element_type=f32)
-            + p["dt_b"].astype(f32))
-        a = -jnp.exp(p["A_log"].astype(f32))
-    y, ssm_state = ragged_selective_scan(
-        xc, dt, a, b, c, state["ssm"], slots, cu, ctx, ns, impl=scan_impl)
-    with jax.named_scope("ssm_proj"):
-        y = y + p["D"].astype(f32) * xc.astype(f32)
-        mix = (y * _silu(z.astype(f32))).astype(x.dtype) @ p["out_proj"]
         h = x + mix
     out = _mlp_residual(p, h, eps)
     with jax.named_scope("ssm_proj"):
         memory = y.astype(x.dtype)
-    return out, {"ssm": ssm_state, "conv": conv_state}, memory
+    return out, state, memory
 
 
 def _diff_lambda(p, lam_init):

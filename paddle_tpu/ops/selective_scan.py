@@ -23,6 +23,13 @@ tokens in blocks of ``unroll``, both with dynamic trip counts, so a
 decode-only step costs S short iterations and not T. A Pallas kernel
 (``ragged_selective_scan``) is queued in ROADMAP.md; asking for
 ``impl="pallas"`` raises until it exists.
+
+``mamba_mixer`` is the Mamba-1 mixer itself, from a layer's normed input
+to its output projection, over that stream and those slots. This file
+owns it: ``models/phi4flash.py`` (plain Mamba-1, which also reads the
+scan's output before the gate) and ``models/jamba.py`` (RMSNorms on the
+time-step input, B and C: ``inner_norm_eps``) both call it, so the two
+models' state-space layers cannot drift apart.
 """
 from __future__ import annotations
 
@@ -30,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["selective_scan_step", "ragged_selective_scan",
-           "ragged_causal_conv"]
+           "ragged_causal_conv", "mamba_mixer"]
 
 
 def selective_scan_step(h, x_t, dt_t, a_t, b_t, c_t):
@@ -151,3 +158,57 @@ def ragged_causal_conv(x, w, bias, conv_state, state_slots, cu_seqlens,
         new = jnp.where((idx < keep)[:, :, None], from_old,
                         from_new.astype(conv_state.dtype))
         return out, conv_state.at[slot].set(new)
+
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+def _rms(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                            + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def mamba_mixer(p, u, state, state_slots, cu_seqlens, context_lens,
+                num_seqs, *, scan_impl=None, inner_norm_eps=None):
+    """The Mamba-1 mixer over the ragged stream. ``u`` (T, d) is the
+    layer's normed input; ``p`` its weights as ``[in, out]`` matrices
+    (``in_proj`` (d, 2E), ``conv_w`` (taps, E), ``conv_b``, ``x_proj``
+    (E, R + 2N), ``dt_w`` (R, E), ``dt_b``, ``A_log`` (E, N), ``D``,
+    ``out_proj`` (E, d)); ``state`` ``{"ssm": (slots, N, E) float32,
+    "conv": (slots, taps - 1, E)}``. ``inner_norm_eps``: Jamba's RMSNorms
+    on the time-step input, B and C (weights ``dt_norm``, ``b_norm``,
+    ``c_norm``); None is plain Mamba-1. Returns (mixer output (T, d), the
+    scan's output with the skip, before the gate (T, E) float32,
+    state'). The products and gates are ``ssm_proj``; the convolution and
+    the scan name themselves."""
+    f32 = jnp.float32
+    with jax.named_scope("ssm_proj"):
+        xi, z = jnp.split(u @ p["in_proj"], 2, axis=-1)
+        conv_in = (xi.astype(f32), p["conv_w"].astype(f32),
+                   p["conv_b"].astype(f32))
+    conv, conv_state = ragged_causal_conv(
+        *conv_in, state["conv"], state_slots, cu_seqlens, context_lens,
+        num_seqs)
+    with jax.named_scope("ssm_proj"):
+        xc = _silu(conv).astype(u.dtype)
+        n, rank = p["A_log"].shape[1], p["dt_w"].shape[0]
+        rbc = xc @ p["x_proj"]
+        r, b, c = rbc[:, :rank], rbc[:, rank:rank + n], rbc[:, rank + n:]
+        if inner_norm_eps is not None:
+            r = _rms(r, p["dt_norm"], inner_norm_eps)
+            b = _rms(b, p["b_norm"], inner_norm_eps)
+            c = _rms(c, p["c_norm"], inner_norm_eps)
+        dt = jax.nn.softplus(
+            jnp.dot(r, p["dt_w"], preferred_element_type=f32)
+            + p["dt_b"].astype(f32))
+        a = -jnp.exp(p["A_log"].astype(f32))
+    y, ssm_state = ragged_selective_scan(
+        xc, dt, a, b, c, state["ssm"], state_slots, cu_seqlens,
+        context_lens, num_seqs, impl=scan_impl)
+    with jax.named_scope("ssm_proj"):
+        y = y + p["D"].astype(f32) * xc.astype(f32)
+        mix = (y * _silu(z.astype(f32))).astype(u.dtype) @ p["out_proj"]
+    return mix, y, {"ssm": ssm_state, "conv": conv_state}

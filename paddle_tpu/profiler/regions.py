@@ -40,6 +40,8 @@ DEVICE_REGIONS = (
     "window_latent_attention", "mla_absorb", "attn_gate",
     # state-space layers
     "ssm_proj", "ssm_scan", "ssm_conv", "gmu",
+    # recurrent state to and from its snapshots (the prefix cache)
+    "state_snapshot", "state_restore",
     # expert layers
     "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
     # the sparse indexer

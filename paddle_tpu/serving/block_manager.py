@@ -83,12 +83,31 @@ reaches it), so a sequence's live window blocks stay bounded however long
 it grows. A state slot is the index of the request's recurrent state in
 the engine's per-layer state arrays: taken at ``allocate``, returned by
 ``free`` (finish, abort, preemption), never shared. Neither pool is
-prefix-cached, swapped or tiered (the engine refuses those for such a
-model)."""
+swapped or tiered, and the window pool is never prefix-cached (the engine
+refuses those for such a model).
+
+State snapshots (``state_snapshots > 0``, with state slots and the prefix
+cache): a shared K/V block does not carry the recurrent state at its
+boundary, so the trie alone cannot serve a model with state. A SNAPSHOT is
+one entry of a device pool (the engine's; here only its index) holding
+every state layer's arrays as they were after exactly ``k * block_size``
+tokens, keyed by the chain hash of the block that ends there. One is
+planned (``plan_snapshots``) before a step in which a PROMPT row ends on a
+block boundary and bound to its hash by the ``commit_prefix`` after that
+step (the commit cursor stands at that block). ``match_prefix`` and
+``allocate`` walk the trie as ever and then CUT the hit back to the
+deepest matched block that has a snapshot: blocks are shared up to there,
+the rest claimed fresh, and the request's slot is loaded from the snapshot
+before its first step (``take_state_copies``). A hit therefore always ends
+on a block boundary below the first token written, so no shared block is
+ever written and copy-on-write never happens for such a model. Snapshots
+are evicted least recently hit first, never one a request admitted this
+round is about to load; a block that leaves the trie takes its snapshot
+with it; a chain with no snapshot is a miss from zero state."""
 from __future__ import annotations
 
 import hashlib
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from paddle_tpu.testing import faults
@@ -140,15 +159,21 @@ class BlockManager:
                  enable_prefix_cache: bool = False,
                  kv_layout=None, tiered: bool = False,
                  window_blocks: int = 0, window: int = 0,
-                 state_slots: int = 0, latent: bool = False):
+                 state_slots: int = 0, latent: bool = False,
+                 state_snapshots: int = 0):
         if num_blocks < 1 or block_size < 1:
             raise ValueError("num_blocks and block_size must be >= 1")
         if window_blocks < 0 or state_slots < 0:
             raise ValueError("window_blocks and state_slots must be >= 0")
         if window_blocks and window < 1:
             raise ValueError("a window pool needs its window (tokens)")
+        if state_snapshots < 0 or (state_snapshots and not (
+                state_slots and enable_prefix_cache)):
+            raise ValueError("state_snapshots must be >= 0 and go with "
+                             "state slots and the prefix cache")
         if (window_blocks or state_slots) and (
-                enable_prefix_cache or tiered or num_host_blocks):
+                tiered or num_host_blocks or (enable_prefix_cache and (
+                    window_blocks or not state_snapshots))):
             raise ValueError(
                 "a window pool or state slots cannot be combined with "
                 "prefix caching, a host pool or tiers: a shared or "
@@ -199,6 +224,9 @@ class BlockManager:
         self._commit_cursor: Dict[
             str, Tuple[int, Optional[tuple], Optional[str]]] = {}
         self._trie_rev = 0
+        # _matched_chain's last walk: (trie revision, tokens, blocks
+        # asked for, blocks matched, their chain hashes)
+        self._last_walk: tuple = (-1, None, 0, [], [])
         self._digest_cache: Optional[Tuple[tuple, dict]] = None
         self._cow_pairs: List[Tuple[int, int]] = []
         # observability (engine surfaces these through ServingMetrics)
@@ -231,6 +259,22 @@ class BlockManager:
         self.state_slots = state_slots
         self._slot_free: List[int] = list(range(state_slots - 1, -1, -1))
         self._slots: Dict[str, int] = {}
+        # state snapshots (module docstring): free entries; chain hash
+        # <-> entry, least recently hit first; entries planned for the
+        # step in flight (request -> entry); the copies the engine owes
+        # the device before and after that step
+        self.state_snapshots = state_snapshots
+        self._snap_free: List[int] = list(range(state_snapshots - 1, -1,
+                                                -1))
+        self._snap_index: "OrderedDict[str, int]" = OrderedDict()
+        self._snap_hit: set = set()     # hashes some admission loaded
+        self._snap_planned: Dict[str, int] = {}
+        self._restores: List[Tuple[int, int]] = []     # (entry, slot)
+        self._captures: List[Tuple[int, int]] = []     # (slot, entry)
+        self.num_snapshot_hits = 0
+        self.num_snapshot_evictions = 0
+        # tokens the trie matched and a missing snapshot made recompute
+        self.num_prefix_recomputed_tokens = 0
         # the main pool holds latent entries (one array a layer, not a K
         # and a V): accounted exactly as a ``full`` layer's pool is, the
         # same table and the same block ids across layers
@@ -296,6 +340,73 @@ class BlockManager:
     def state_slot(self, request_id: str) -> int:
         return self._slots[request_id]
 
+    # -- state snapshots ---------------------------------------------------
+    @property
+    def state_snapshots_in_use(self) -> int:
+        return len(self._snap_index)
+
+    def _snapshot_depth(self, hashes: Sequence[Optional[str]],
+                        limit: int) -> int:
+        """The deepest ``k <= limit`` whose block ``k - 1`` (chain hash
+        ``hashes[k - 1]``) ends on a snapshot; 0 if none does."""
+        for k in range(min(len(hashes), limit), 0, -1):
+            if hashes[k - 1] in self._snap_index:
+                return k
+        return 0
+
+    def _claim_snapshot(self) -> Optional[int]:
+        """A free snapshot entry, or the least recently hit one that no
+        admitted request is about to load (one never hit before any that
+        was, oldest first); None if every entry is about to be loaded."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        loading = {e for e, _ in self._restores}
+        idle = [h for h, e in self._snap_index.items() if e not in loading]
+        victim = next((h for h in idle if h not in self._snap_hit),
+                      idle[0] if idle else None)
+        if victim is None:
+            return None
+        self._snap_hit.discard(victim)
+        self.num_snapshot_evictions += 1
+        return self._snap_index.pop(victim)
+
+    def plan_snapshots(self, rows: Sequence[Tuple[str, int, int]]):
+        """Before a step. ``rows``: (request id, tokens covered after the
+        step, prompt length) of the step's rows. A row that is still
+        inside its prompt and ends on a block boundary gets a snapshot
+        entry, written from its slot after the step (``take_state_copies``)
+        and bound to its chain hash by the ``commit_prefix`` that
+        follows."""
+        if not self.state_snapshots:
+            return
+        for rid, covered, prompt_len in rows:
+            if (covered % self.block_size or not 0 < covered <= prompt_len
+                    or rid in self._snap_planned):
+                continue
+            entry = self._claim_snapshot()
+            if entry is None:
+                return
+            self._snap_planned[rid] = entry
+            self._captures.append((self._slots[rid], entry))
+
+    def take_state_copies(self):
+        """Drain the device copies the engine owes: ``restores`` ((entry,
+        slot): snapshot -> state slot, BEFORE the step's rows run) and
+        ``captures`` ((slot, entry): state slot -> snapshot, AFTER)."""
+        out = self._restores, self._captures
+        self._restores, self._captures = [], []
+        return out
+
+    def _bind_snapshot(self, request_id: str, key, chash):
+        """After the step: the planned entry now holds the state where
+        the commit cursor stands (chain ``key``, hash ``chash``). It is
+        kept if the trie knows that chain and has no snapshot of it."""
+        entry = self._snap_planned.pop(request_id)
+        if key in self._prefix_index and chash not in self._snap_index:
+            self._snap_index[chash] = entry
+        else:
+            self._snap_free.append(entry)
+
     def _window_need(self, request_id: Optional[str],
                      num_tokens: int) -> int:
         """Window-pool blocks a table must gain to cover
@@ -339,6 +450,14 @@ class BlockManager:
         slot = self._slots.pop(request_id, None)
         if slot is not None:
             self._slot_free.append(slot)
+            planned = self._snap_planned.pop(request_id, None)
+            if planned is not None:
+                # aborted between the plan and the commit: the entry
+                # goes back, and neither copy is owed any more
+                self._snap_free.append(planned)
+                self._captures = [c for c in self._captures
+                                  if c[1] != planned]
+            self._restores = [r for r in self._restores if r[1] != slot]
 
     def has_table(self, request_id: str) -> bool:
         return request_id in self._tables
@@ -358,15 +477,41 @@ class BlockManager:
         whole prefix chain matches. Read-only (no refcount changes)."""
         if not self.enable_prefix_cache:
             return 0
+        blocks, hashes = self._matched_chain(tokens, len(tokens))
+        if self.state_snapshots:
+            # a model with state resumes only where a snapshot stands,
+            # and below its last token (one row is always computed)
+            return self.block_size * self._snapshot_depth(
+                hashes, (len(tokens) - 1) // self.block_size)
+        return len(blocks) * self.block_size
+
+    def _matched_chain(self, tokens: Sequence[int], upto: int):
+        """The registered blocks whose whole chain matches the leading
+        full blocks of ``tokens[:upto]``, and (where snapshots are kept)
+        each one's chain hash. The last walk is kept while the trie
+        stands as it was: an admission asks twice (``match_prefix``, then
+        ``allocate``), and a walk hashes every nested key from its root
+        (7.6 ms for 8k tokens in blocks of 64)."""
         bs = self.block_size
-        key: Optional[tuple] = None
-        hit = 0
-        while hit + bs <= len(tokens):
-            key = (key, tuple(tokens[hit:hit + bs]))
-            if key not in self._prefix_index:
-                break
-            hit += bs
-        return hit
+        n = upto // bs
+        if not isinstance(tokens, list):
+            tokens = list(tokens)
+        rev, seen, walked, blocks, hashes = self._last_walk
+        if rev != self._trie_rev or n > walked or seen != tokens:
+            key: Optional[tuple] = None
+            blocks, hashes = [], []
+            while len(blocks) < n:
+                at = len(blocks) * bs
+                key = (key, tuple(tokens[at:at + bs]))
+                b = self._prefix_index.get(key)
+                if b is None:
+                    break
+                blocks.append(b)
+                if self.state_snapshots:
+                    hashes.append(self._key_hash[key])
+            self._last_walk = (self._trie_rev, list(tokens), n, blocks,
+                               hashes)
+        return blocks[:n], hashes[:n]
 
     def _drop_registration(self, entry: int):
         """Forget the trie registration of a (device or virtual) id —
@@ -378,6 +523,12 @@ class BlockManager:
             if h is not None and self._hash_key.get(h) == key:
                 self._hash_key.pop(h)
                 self._hash_tokens.pop(h, None)
+                snap = self._snap_index.pop(h, None)
+                if snap is not None:
+                    # the block leaves the trie: its snapshot with it
+                    self._snap_free.append(snap)
+                    self._snap_hit.discard(h)
+                    self.num_snapshot_evictions += 1
             self._trie_rev += 1
 
     def _move_registration(self, src_entry: int, dst_entry: int):
@@ -667,6 +818,8 @@ class BlockManager:
                 self._trie_rev += 1
                 self.num_prefix_blocks_committed += 1
         self._commit_cursor[request_id] = (end, key, chash)
+        if request_id in self._snap_planned:
+            self._bind_snapshot(request_id, key, chash)
 
     # -- fleet prefix advertisement ---------------------------------------
     @property
@@ -745,17 +898,17 @@ class BlockManager:
         bs = self.block_size
         need_total = self.blocks_needed(num_tokens)
         shared: List[int] = []
+        uncovered = 0
         if self.enable_prefix_cache and tokens is not None:
-            key: Optional[tuple] = None
-            hit = 0
-            while (hit + bs <= min(len(tokens), num_tokens)
-                   and len(shared) < need_total):
-                key = (key, tuple(tokens[hit:hit + bs]))
-                b = self._prefix_index.get(key)
-                if b is None:
-                    break
-                shared.append(b)
-                hit += bs
+            shared, hashes = self._matched_chain(
+                tokens, len(tokens) if self.state_snapshots
+                else min(len(tokens), num_tokens))
+            if self.state_snapshots:
+                # cut the hit back to the deepest matched block with a
+                # snapshot, below the first row this request computes
+                keep = self._snapshot_depth(hashes, (num_tokens - 1) // bs)
+                uncovered = (len(shared) - keep) * bs
+                del shared[keep:], hashes[keep:]
         hit_tok = len(shared) * bs
         eff = min(hit_tok, max(num_tokens - 1, 0))
         fresh_need = need_total - len(shared)
@@ -788,6 +941,15 @@ class BlockManager:
                     f"all {self.state_slots} state slots are in use "
                     f"(one per running sequence, max_num_seqs)")
             self._slots[request_id] = self._slot_free.pop()
+            if self.state_snapshots and shared:
+                assert cow_idx is None and eff == hit_tok, \
+                    "a snapshot hit ends below the first row computed"
+                self._snap_index.move_to_end(hashes[-1])
+                self._snap_hit.add(hashes[-1])
+                self._restores.append((self._snap_index[hashes[-1]],
+                                       self._slots[request_id]))
+                self.num_snapshot_hits += 1
+            self.num_prefix_recomputed_tokens += uncovered
         if self.window_blocks:
             self._grow_window(request_id, wneed)
         table: List[int] = []
@@ -1166,6 +1328,17 @@ class BlockManager:
             range(self.window_blocks)), "window-pool block leak or double"
         assert sorted(list(self._slots.values()) + self._slot_free) == \
             list(range(self.state_slots)), "state slot leak or double"
+        planned = list(self._snap_planned.values())
+        assert sorted(self._snap_free + list(self._snap_index.values())
+                      + planned) == list(range(self.state_snapshots)), \
+            "state snapshot leak or double"
+        assert self._snap_hit <= set(self._snap_index) <= \
+            set(self._hash_key), \
+            "a state snapshot outlived its block's place in the trie"
+        assert set(self._snap_planned) <= set(self._slots), \
+            "a planned state snapshot without its request's state slot"
+        assert not self._restores and not self._captures, \
+            "pending state copies not drained before invariant check"
         assert set(self._slots) <= set(self._tables) and (
             not self.window_blocks
             or set(self._wtables) == set(self._tables)), \

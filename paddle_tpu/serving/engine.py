@@ -166,9 +166,16 @@ class EngineConfig:
     # re-prefill. Needs prefix caching (the trie is what spans tiers).
     kv_tiers: Optional[object] = None
     # prefix caching (COW block sharing over the content-keyed trie).
-    # None resolves to on, except for a model with ``cache_spec()``,
-    # which cannot share blocks (see ``_SPEC_REFUSED``).
+    # None resolves to on, except for a model with ``cache_spec()``:
+    # there it is off unless asked for by name, and True is taken only
+    # where every layer is ``state``, ``full`` or ``none`` (recurrent
+    # state comes back from snapshots kept at block boundaries; the
+    # other kinds cannot share blocks: ``_SPEC_REFUSED``).
     prefix_cache: Optional[bool] = None
+    # entries of the state-snapshot pool of a model with ``state`` layers
+    # and the prefix cache on (each holds every state layer's arrays at
+    # one block boundary). None: two a sequence slot.
+    num_state_snapshots: Optional[int] = None
     # admission control: reject (first-class 'rejected' output) when the
     # waiting queue is this deep, or when the estimated TTFT for a new
     # arrival exceeds the SLO (None = unbounded / no SLO)
@@ -218,6 +225,9 @@ class EngineConfig:
             raise ValueError("max_step_retries must be >= 0")
         if self.num_spec_tokens < 0:
             raise ValueError("num_spec_tokens must be >= 0")
+        if self.num_state_snapshots is not None \
+                and self.num_state_snapshots < 1:
+            raise ValueError("num_state_snapshots must be >= 1")
         if (self.draft_model is None) != (self.num_spec_tokens == 0):
             raise ValueError(
                 "speculative decoding takes BOTH draft_model and "
@@ -585,6 +595,12 @@ class LLMEngine:
                              window=w)
             if "state" in kinds:
                 pools.update(state_slots=self.cfg.max_num_seqs)
+                if self.cfg.prefix_cache:
+                    if self.cfg.num_state_snapshots is None:
+                        self.cfg.num_state_snapshots = \
+                            2 * self.cfg.max_num_seqs
+                    pools.update(
+                        state_snapshots=self.cfg.num_state_snapshots)
             if any(k in ("latent", "latent_indexed") for k in kinds):
                 # latent entries in the MAIN pool (``latent_window``
                 # layers keep theirs in the window pool)
@@ -641,6 +657,13 @@ class LLMEngine:
         else:
             self._kcs = self._vcs = None
             self._cache = self._build_cache(spec, cache_dtype)
+        # the state-snapshot pool: per state layer the slot arrays' shapes
+        # with ``num_state_snapshots`` entries (None: no snapshots)
+        n_snap = self.block_manager.state_snapshots
+        self._snaps = [
+            {k: jnp.zeros((n_snap, *a.shape[1:]), a.dtype)
+             for k, a in c.items()}
+            for c in self._cache if isinstance(c, dict)] if n_snap else None
         # host swap pool: plain numpy per-shard frames, the
         # restore-on-readmit side of swap-based preemption. Leading
         # axis = TP shard (size 1 when unsharded), so a spilled block
@@ -731,16 +754,52 @@ class LLMEngine:
             step_outs = None
 
         if spec is not None:
+            state_layers = [l for l, lay in enumerate(spec["layers"])
+                            if lay["kind"] == "state"]
+
+            def copy_entries(dst, src, pairs):
+                # rows 1..n of ``pairs`` (n = pairs[0, 0]) are (entry of
+                # src, entry of dst): one walk over the live pairs, every
+                # state layer's arrays updated in place
+                def one(i, dst):
+                    a, b = pairs[i, 0], pairs[i, 1]
+                    return [{k: d[k].at[b].set(s[k][a]) for k in d}
+                            for d, s in zip(dst, src)]
+                return jax.lax.fori_loop(1, pairs[0, 0] + 1, one, dst)
+
             def raw_step_ragged_spec(param_datas, buffer_datas, key, ids,
                                      cache, tables, bt, cu, ctx, nseq,
                                      skeys, stemp, stopk, stopp, sdraft,
                                      sndraft):
                 # the Llama step's argument positions (ids 3, bt 6, cu 7,
-                # ctx 8, nseq 9); 4 is the whole cache, donated, 5 the
+                # ctx 8, nseq 9); 4 is the whole cache, donated (with the
+                # snapshot pool beside it where there is one), 5 the
                 # step's other tables (window block table, state slots)
+                snaps = None
+                if n_snap:
+                    # a request admitted on a prefix hit: its slot is
+                    # loaded from the snapshot before its first row runs
+                    cache, snaps = cache
+                    tables = dict(tables)
+                    copies = tables.pop("state_copies")
+                    cache = list(cache)
+                    with jax.named_scope("state_restore"):
+                        loaded = copy_entries(
+                            [cache[l] for l in state_layers], snaps,
+                            copies[0])
+                    for l, st in zip(state_layers, loaded):
+                        cache[l] = st
                 (logits, cache2, *counted), _ = apply(
                     param_datas, buffer_datas, key, ids, cache, tables,
                     bt, cu, ctx, nseq)
+                if n_snap:
+                    # a prompt row that ended on a block boundary: its
+                    # slot as the step left it becomes a snapshot
+                    with jax.named_scope("state_snapshot"):
+                        snaps = copy_entries(
+                            snaps, [cache2[l] for l in state_layers],
+                            copies[1])
+                    cache2 = (cache2, snaps)
                 packed, finite = pack_sampled(
                     logits[:, None, :], sdraft, sndraft, skeys, stemp,
                     stopk, stopp)
@@ -899,10 +958,11 @@ class LLMEngine:
         self.metrics = ServingMetrics(self)
 
     # -- a model that says what it caches ---------------------------------
-    # (what, is it on?, why by cache kind): a refusal gives the reasons
-    # that hold for the kinds the model's spec really has
+    # (what, is it on? (config, the spec's kinds), why by cache kind): a
+    # refusal gives the reasons that hold for the kinds the model's spec
+    # really has
     _SPEC_REFUSED = (
-        ("kv_tiers", lambda c: c.kv_tiers not in (None, False), {
+        ("kv_tiers", lambda c, kinds: c.kv_tiers not in (None, False), {
             "state": "a demoted block has no recurrent state to come "
                      "back to",
             "window": "a tier holds whole K/V frames, not a window table "
@@ -916,7 +976,7 @@ class LLMEngine:
             "latent_window": "a tier holds whole frames, not a window "
                              "table of latent entries with released "
                              "blocks"}),
-        ("swap_mode='host'", lambda c: c.swap_mode == "host", {
+        ("swap_mode='host'", lambda c, kinds: c.swap_mode == "host", {
             "state": "the host pool holds K/V blocks, not state slots; "
                      "preemption is by recompute from zero state",
             "window": "the host pool holds K/V blocks, not a window "
@@ -930,7 +990,7 @@ class LLMEngine:
             "latent_window": "the host pool holds no window table of "
                              "latent entries; preemption is by "
                              "recompute"}),
-        ("draft_model", lambda c: c.draft_model is not None, {
+        ("draft_model", lambda c, kinds: c.draft_model is not None, {
             "state": "a rejected draft token cannot be taken back out of "
                      "a recurrent state",
             "window": "a verify row's rejected tokens may already have "
@@ -942,7 +1002,7 @@ class LLMEngine:
             "latent_window": "a verify row's rejected tokens may already "
                              "have released latent blocks behind the "
                              "window"}),
-        ("tp_degree > 1", lambda c: c.tp_degree > 1, {
+        ("tp_degree > 1", lambda c, kinds: c.tp_degree > 1, {
             "state": "the state slots have no TP layout yet",
             "window": "the window pool has no TP layout yet",
             "latent": "a latent entry has no kv-head dim to split: it "
@@ -953,10 +1013,16 @@ class LLMEngine:
                               "would have to be agreed across shards",
             "latent_window": "the window pool of latent entries has no "
                              "TP layout yet"}),
-        ("prefix_cache=True", lambda c: bool(c.prefix_cache), {
+        # taken where every layer is ``state``, ``full`` or ``none``:
+        # blocks are shared up to the deepest state snapshot
+        # (block_manager.py); any other kind beside them refuses it
+        ("prefix_cache=True", lambda c, kinds: bool(c.prefix_cache)
+         and not kinds <= {"state", "full", "none"}, {
             "state": "a shared K/V block does not carry the recurrent "
                      "state at its boundary (it needs snapshots: "
                      "ROADMAP.md)",
+            "reads": "a layer that reads another layer's pages has no "
+                     "snapshot of what it read at a block boundary",
             "window": "a block released behind the window cannot be "
                       "shared",
             "latent": "copy-on-write and the trie move (K, V) frames; the "
@@ -1002,7 +1068,7 @@ class LLMEngine:
             raise ValueError(f"{method} is refused for {who}: "
                              f"{reasons(self._SPEC_METHOD_REFUSED)}")
         for name, on, by_kind in self._SPEC_REFUSED:
-            if on(self.cfg):
+            if on(self.cfg, self._spec_kinds):
                 raise ValueError(f"{name} is refused for {who}: "
                                  f"{reasons(by_kind)}")
 
@@ -1766,8 +1832,22 @@ class LLMEngine:
                 # scheduler sees the post-demotion free list
                 self._kvtier.balance()
             t0 = time.perf_counter()
-            with span("engine.schedule"):
+            with span("engine.schedule") as sched_span:
+                bm = self.block_manager
+                hit0, cut0, admitted0 = (
+                    bm.num_prefix_hit_tokens,
+                    bm.num_prefix_recomputed_tokens,
+                    self.scheduler.num_admitted_prompt_tokens)
                 batch = self.scheduler.schedule()
+                if self.scheduler.num_admitted_prompt_tokens > admitted0:
+                    # what this round's admissions found in the trie
+                    sched_span.set(
+                        prompt_tokens=(
+                            self.scheduler.num_admitted_prompt_tokens
+                            - admitted0),
+                        prefix_hit_tokens=bm.num_prefix_hit_tokens - hit0,
+                        prefix_recomputed_tokens=(
+                            bm.num_prefix_recomputed_tokens - cut0))
                 outputs.extend(self._terminal_output(r) for r in batch.expired)
                 self.num_expired += len(batch.expired)
                 self._note_first_scheduled(batch.requests)
@@ -1945,8 +2025,24 @@ class LLMEngine:
             bt[i, :len(table)] = table
         cu[len(reqs) + 1:] = off
         arrays = (ids, bt, cu, ctx, np.int32(len(reqs)))
+        restored = captured = 0
         if self._cache is not None:
-            arrays += (self._spec_tables(reqs, S),)
+            tables = self._spec_tables(reqs, S)
+            if self._snaps is not None:
+                bm = self.block_manager
+                bm.plan_snapshots([(r.request_id, r.num_cached + n,
+                                    len(r.prompt_ids))
+                                   for r, n in zip(reqs, n_run)])
+                # [0] snapshot -> slot before the rows run, [1] slot ->
+                # snapshot after; row 0 of each holds its count
+                copies = np.zeros((2, S + 1, 2), np.int32)
+                for side, pairs in zip(copies, bm.take_state_copies()):
+                    if pairs:
+                        side[0, 0] = len(pairs)
+                        side[1:len(pairs) + 1] = pairs
+                tables["state_copies"] = copies
+                restored, captured = copies[:, 0, 0]
+            arrays += (tables,)
         # the mixed batch's split: prompt tokens prefilled this step vs
         # decode rows (feeds occupancy + prompt throughput; a verify row
         # costs 1 + its draft count but is still one decode row)
@@ -2005,6 +2101,11 @@ class LLMEngine:
                 win_blocks=self.block_manager.num_used_window_blocks,
                 full_blocks=self.block_manager.num_used_blocks,
                 cross_rows=len(reqs))
+            if self._snaps is not None:
+                composition.update(
+                    state_slots=self.block_manager.state_slots_in_use,
+                    snapshots_taken=int(captured),
+                    snapshots_restored=int(restored))
         return (reqs, n_run, arrays, sampling_arrays, prompt_toks,
                 composition)
 
@@ -2143,7 +2244,9 @@ class LLMEngine:
                 self._step_program.dispatched(self)
                 with span("engine.dispatch", cold=int(cold),
                           attempt=attempt, **composition):
-                    if spec_cache:
+                    if self._snaps is not None:
+                        held = ((self._cache, self._snaps), *tables)
+                    elif spec_cache:
                         held = (self._cache, *tables)
                     elif self._kvtier is not None:
                         held = (self._kcs, self._vcs, self._htk, self._htv)
@@ -2205,7 +2308,9 @@ class LLMEngine:
             break
         # commit only after a fully-successful dispatch+fetch, so a
         # retried attempt re-reads the PRE-failure cache state
-        if spec_cache:
+        if self._snaps is not None:
+            (self._cache, self._snaps), = caches
+        elif spec_cache:
             self._cache, = caches
         else:
             self._kcs, self._vcs = caches

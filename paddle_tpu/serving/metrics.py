@@ -50,7 +50,8 @@ class ServingMetrics:
               # ragged hot path (ISSUE 9): prefix-cache, copy-on-write,
               # and chunked-prefill traffic
               "prefix_cache_hits",
-              "prefix_cache_hit_tokens", "cow_copies", "prefill_chunks",
+              "prefix_cache_hit_tokens", "admitted_prompt_tokens",
+              "prefix_recomputed_tokens", "cow_copies", "prefill_chunks",
               # in-graph sampling + speculative decoding (ISSUE 11):
               # draft proposal/acceptance traffic and sampled-step count
               "spec_proposed", "spec_accepted", "spec_acceptance_rate",
@@ -94,6 +95,14 @@ class ServingMetrics:
         "prefix_cache_hits": lambda eng: eng.block_manager.num_prefix_hits,
         "prefix_cache_hit_tokens":
             lambda eng: eng.block_manager.num_prefix_hit_tokens,
+        # prompt tokens of the requests admitted, and of those the
+        # tokens the trie matched but a model with state recomputed for
+        # want of a snapshot (beside prefix_cache_hit_tokens: the hit
+        # share's numerator, denominator and what was cut from it)
+        "admitted_prompt_tokens":
+            lambda eng: eng.scheduler.num_admitted_prompt_tokens,
+        "prefix_recomputed_tokens":
+            lambda eng: eng.block_manager.num_prefix_recomputed_tokens,
         "cow_copies": lambda eng: eng.block_manager.num_cow_copies,
         "prefill_chunks": lambda eng: eng.scheduler.num_prefill_chunks,
         "spec_proposed": lambda eng: eng.num_spec_proposed,
@@ -284,6 +293,15 @@ class ServingMetrics:
                 "window_blocks_released":
                     eng.block_manager.num_window_blocks_released,
                 "state_slots_in_use": eng.block_manager.state_slots_in_use,
+                # state snapshots kept at block boundaries (a model with
+                # state under the prefix cache; 0 otherwise): entries
+                # bound to a chain, admissions that loaded one, entries
+                # evicted (least recently hit, or with their block)
+                "state_snapshots_in_use":
+                    eng.block_manager.state_snapshots_in_use,
+                "state_snapshot_hits": eng.block_manager.num_snapshot_hits,
+                "state_snapshot_evictions":
+                    eng.block_manager.num_snapshot_evictions,
                 # blocks commit_prefix walked, and those it newly
                 # registered in the prefix trie
                 "prefix_blocks_visited":

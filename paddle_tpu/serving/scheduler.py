@@ -122,6 +122,9 @@ class Scheduler:
         # cross-TP-degree reshard — scheduling is layout-agnostic, so
         # this counter is the only place the scheduler sees them)
         self.num_continuation_resumes = 0
+        # prompt tokens of the requests admitted from the waiting queue
+        # (the denominator of the prefix cache's hit share)
+        self.num_admitted_prompt_tokens = 0
         # tiered-KV relief hook (engine-installed): called with the
         # OOM'ing request before any preemption; True means >= 1 device
         # block was freed by demoting cold content to the host tier, so
@@ -336,6 +339,18 @@ class Scheduler:
         any_prefill = False
         any_decode = False
 
+        # a model with recurrent state under the prefix cache: a prompt
+        # chunk that does not reach the prompt's end is cut to end on a
+        # block boundary, where the engine snapshots the state (at most
+        # block_size - 1 rows of budget given up; a chunk shorter than
+        # the way to the next boundary runs as it is)
+        align = bm.block_size if bm.state_snapshots else 0
+
+        def cut(req: Request, start: int, n: int) -> int:
+            if align and start + n < len(req.prompt_ids):
+                return max((start + n) // align * align - start, 0) or n
+            return n
+
         def drop_row(victim: Request):
             nonlocal used
             if victim in rows:
@@ -401,7 +416,7 @@ class Scheduler:
                 break
             total = len(req.tokens)
             remaining = total - req.num_cached
-            n = min(remaining, left)
+            n = cut(req, req.num_cached, min(remaining, left))
             if claim_slots(req, req.num_cached + n, req.num_cached):
                 rows.append(req)
                 nsched.append(n)
@@ -451,12 +466,13 @@ class Scheduler:
             hit = bm.match_prefix(req.tokens)
             eff = min(hit, total - 1)
             n = self._admit_with_relief(
-                req, min(total - eff, left),
+                req, cut(req, eff, min(total - eff, left)),
                 lambda k: bm.allocate(req.request_id, eff + k,
                                       tokens=req.tokens))
             if n is None:
                 break  # blocks free up as running requests finish
             req.num_cached = bm.last_hit_tokens
+            self.num_admitted_prompt_tokens += len(req.prompt_ids)
             req.status = RequestStatus.RUNNING
             admitted.append(req)
             rows.append(req)

@@ -135,6 +135,31 @@ def test_ragged_kernel_compiles_folded_for_v5e(one_chip, t, window, write):
     assert _kernel_calls(compiled, "ragged_paged_attention") == 1
 
 
+def test_mqa_call_compiles_for_v5e(one_chip):
+    """The attention call of the `ai21-jamba2-3b.agent-prefix-c64` step:
+    20 query heads on ONE K/V head of 128, so the cache is FOLDED
+    (12,288 blocks of 64 tokens x 128 lanes: a 4-D cache with one bfloat16
+    head is refused) and the 20 heads stack on the row axis of one product
+    (2,560 x 128 rows a q tile); 64 slots of 192 table entries; the caches
+    are updated in place."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    t, h, s, bs, mb, nb = 512, 20, 64, 64, 192, 12288
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn = jax.jit(functools.partial(ragged_paged_attention, impl="pallas"),
+                 donate_argnums=(3, 4))
+    compiled = fn.lower(
+        sds((t, h, D), bf16), sds((t, 1, D), bf16), sds((t, 1, D), bf16),
+        sds((nb, bs, D), bf16), sds((nb, bs, D), bf16), sds((s, mb), i32),
+        sds((s + 1,), i32), sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * nb * bs * D * 2
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
 def test_latent_call_compiles_for_v5e(one_chip):
     """The attention call of the `kimi-vl-a3b-d8.vqa-c32` step: 16 query
     heads of 640 lanes (512 latent + 64 rope + 64 zero: Mosaic refuses
