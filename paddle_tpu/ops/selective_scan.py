@@ -18,11 +18,16 @@ padding rows are sent there.
 ``y_t = h_t C_t`` (the skip ``D * x_t`` is the caller's). The state is
 kept ``(N, E)``, state dim on sublanes and channels on lanes, in float32.
 
-``impl``: ``"xla"`` (default) walks the live rows and, per row, its
-tokens in blocks of ``unroll``, both with dynamic trip counts, so a
-decode-only step costs S short iterations and not T. A Pallas kernel
-(``ragged_selective_scan``) is queued in ROADMAP.md; asking for
-``impl="pallas"`` raises until it exists.
+``impl``: ``None`` picks ``"pallas"`` on a TPU backend and ``"xla"``
+elsewhere. ``"pallas"`` is the kernel of ``ops/pallas/selective_scan.py``
+(``ragged_selective_scan`` in a compiled step's text): a row's state
+stays in VMEM across its tokens, a decode row costs one micro-step and
+two state copies. ``"interpret"`` runs that kernel through the Pallas
+interpreter (slow, tests only). ``"xla"`` is the CPU's route and the
+oracle the kernel is tested against: it walks the live rows and, per row,
+its tokens in blocks of ``unroll`` (its own argument, no other route
+reads it), both with dynamic trip counts. Anything else raises
+``ValueError`` by name.
 
 ``mamba_mixer`` is the Mamba-1 mixer itself, from a layer's normed input
 to its output projection, over that stream and those slots. This file
@@ -35,6 +40,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas.selective_scan import selective_scan_pallas
 
 __all__ = ["selective_scan_step", "ragged_selective_scan",
            "ragged_causal_conv", "mamba_mixer"]
@@ -102,18 +109,19 @@ def ragged_selective_scan(x, dt, a, b, c, state, state_slots, cu_seqlens,
     (y (T, E) float32, state'). Rows past ``cu_seqlens[num_seqs]`` are
     padding: their y is zero or finite and no slot but the scratch one is
     touched for them."""
-    if impl not in (None, "xla"):
-        if impl == "pallas":
-            raise NotImplementedError(
-                "the ragged_selective_scan Pallas kernel is queued "
-                "(ROADMAP.md Queue 1); impl='xla' is the only one")
+    if impl is None:
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in ("xla", "pallas", "interpret"):
         raise ValueError(f"unknown selective scan impl: {impl!r}")
     with jax.named_scope("ssm_scan"):
-        return _scan_xla(
-            x, dt, jnp.transpose(a).astype(jnp.float32), b, c, state,
-            state_slots.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
-            context_lens.astype(jnp.int32),
-            jnp.asarray(num_seqs, jnp.int32), unroll)
+        args = (x, dt, jnp.transpose(a).astype(jnp.float32), b, c, state,
+                state_slots.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
+                context_lens.astype(jnp.int32),
+                jnp.asarray(num_seqs, jnp.int32))
+        if impl == "xla":
+            return _scan_xla(*args, unroll)
+        return selective_scan_pallas(*args,
+                                     interpret=(impl == "interpret"))
 
 
 def ragged_causal_conv(x, w, bias, conv_state, state_slots, cu_seqlens,

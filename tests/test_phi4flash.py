@@ -192,10 +192,10 @@ def test_forward_logits_match_reference(model, length):
 
 
 # -- (j) the ragged scan and its convolution ------------------------------
-def _scan_case(seed, rows, e=24, n=4):
+def _scan_case(seed, rows, e=24, n=4, spare=1):
     """rows: [(new tokens, context length after the step)]."""
     rng = np.random.default_rng(seed)
-    s = len(rows) + 1                      # one padding slot
+    s = len(rows) + spare                  # padding slots
     t = sum(r[0] for r in rows) + 3        # padding rows at the end
     cu = np.zeros(s + 1, np.int32)
     ctx = np.zeros(s, np.int32)
@@ -221,15 +221,26 @@ SCAN_ROWS = {
     "decode_only": [(1, 9), (1, 2), (1, 30)],
     "fresh_prefill": [(11, 11)],
     "mixed": [(1, 9), (10, 10), (9, 25), (1, 1), (17, 40)],
+    # what the kernel's walk has to get right: a chunk that crosses its
+    # 128-token tiles beside one-token rows, a row with no token, a row
+    # from position 0 over a slot that holds another request's state
+    "decodes_beside_a_long_chunk": [(1, 5), (1, 1), (150, 190), (1, 7)],
+    "a_row_with_no_token": [(1, 9), (0, 5), (6, 6), (1, 3)],
+    "from_zero_over_a_stale_slot": [(7, 7), (1, 1), (3, 9)],
 }
+SCAN_IMPLS = ("xla", "interpret")
 
 
+def _scan_args(k):
+    return [jnp.asarray(k[n]) for n in ("x", "dt", "a", "b", "c", "state",
+                                        "slots", "cu", "ctx")]
+
+
+@pytest.mark.parametrize("impl", SCAN_IMPLS)
 @pytest.mark.parametrize("name", sorted(SCAN_ROWS))
-def test_ragged_scan_matches_per_token_loop(name):
+def test_ragged_scan_matches_per_token_loop(name, impl):
     k = _scan_case(1, SCAN_ROWS[name])
-    y, st = ragged_selective_scan(
-        *(jnp.asarray(k[n]) for n in ("x", "dt", "a", "b", "c", "state",
-                                      "slots", "cu", "ctx")), k["ns"])
+    y, st = ragged_selective_scan(*_scan_args(k), k["ns"], impl=impl)
     y, st = np.asarray(y), np.asarray(st)
     want_st = k["state"].copy()
     for i in range(int(k["ns"])):
@@ -252,6 +263,63 @@ def test_ragged_scan_matches_per_token_loop(name):
     assert np.isfinite(y).all()
 
 
+@pytest.mark.parametrize("name,shape", [
+    ("decode_only", dict(spare=4)),             # num_seqs < slots
+    ("mixed", dict()),                          # T = 41: no tile's multiple
+    ("mixed", dict(e=256, n=8, spare=2)),       # two lane tiles of 128
+    ("decodes_beside_a_long_chunk", dict()),    # T = 156: two token tiles
+    ("a_row_with_no_token", dict(spare=3)),
+    ("from_zero_over_a_stale_slot", dict()),
+], ids=["decode_only", "mixed", "mixed_e256", "long_chunk", "no_token",
+        "stale_slot"])
+def test_scan_kernel_equals_the_xla_route(name, shape):
+    """The Pallas kernel through the interpreter against ``_scan_xla``,
+    its oracle: equal to float32 rounding on the live rows and slots, and
+    every other slot bit for bit what it was."""
+    k = _scan_case(5, SCAN_ROWS[name], **shape)
+    want_y, want_st = ragged_selective_scan(*_scan_args(k), k["ns"],
+                                            impl="xla")
+    y, st = ragged_selective_scan(*_scan_args(k), k["ns"],
+                                  impl="interpret")
+    tokens = int(k["cu"][int(k["ns"])])
+    np.testing.assert_allclose(np.asarray(y)[:tokens],
+                               np.asarray(want_y)[:tokens], rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(y)[tokens:], 0.0)
+    rows = [i for i, r in enumerate(SCAN_ROWS[name]) if r[0]]
+    live = [int(k["slots"][i]) for i in rows]
+    np.testing.assert_allclose(np.asarray(st)[live],
+                               np.asarray(want_st)[live], rtol=1e-6,
+                               atol=1e-6)
+    rest = [s for s in range(st.shape[0]) if s not in live]
+    np.testing.assert_array_equal(np.asarray(st)[rest], k["state"][rest])
+
+
+@pytest.mark.parametrize("impl", SCAN_IMPLS)
+def test_scan_row_does_not_depend_on_its_neighbours(impl):
+    """A row's y and state are bitwise the same alone in the stream and
+    beside other rows, wherever in the stream (and in which of the
+    kernel's token tiles and state buffers) it stands."""
+    rows = SCAN_ROWS["decodes_beside_a_long_chunk"] + [(9, 20)]
+    k = _scan_case(6, rows)
+    y, st = ragged_selective_scan(*_scan_args(k), k["ns"], impl=impl)
+    for i, (nq, ctx) in enumerate(rows):
+        lo = int(k["cu"][i])
+        alone = dict(k, cu=np.array([0, nq] + [nq] * (len(rows) - 1),
+                                    np.int32),
+                     ctx=np.array([ctx] + [0] * (len(rows) - 1), np.int32),
+                     slots=np.roll(k["slots"], -i), ns=np.int32(1))
+        for name in ("x", "dt", "b", "c"):
+            alone[name] = np.roll(k[name], -lo, axis=0)
+        y1, st1 = ragged_selective_scan(*_scan_args(alone), alone["ns"],
+                                        impl=impl)
+        np.testing.assert_array_equal(np.asarray(y1)[:nq],
+                                      np.asarray(y)[lo:lo + nq])
+        slot = int(k["slots"][i])
+        np.testing.assert_array_equal(np.asarray(st1)[slot],
+                                      np.asarray(st)[slot])
+
+
 def test_scan_step_is_the_decode_form():
     k = _scan_case(2, [(1, 4)] * 3)
     h = jnp.asarray(k["state"][:3])
@@ -268,14 +336,10 @@ def test_scan_step_is_the_decode_form():
         np.testing.assert_allclose(y[i], yi, rtol=1e-6)
 
 
-def test_scan_refuses_an_unwritten_kernel_by_name():
+def test_scan_refuses_an_unknown_impl_by_name():
     k = _scan_case(3, [(1, 4)])
-    args = [jnp.asarray(k[n]) for n in ("x", "dt", "a", "b", "c", "state",
-                                        "slots", "cu", "ctx")]
-    with pytest.raises(NotImplementedError, match="ragged_selective_scan"):
-        ragged_selective_scan(*args, k["ns"], impl="pallas")
-    with pytest.raises(ValueError, match="unknown"):
-        ragged_selective_scan(*args, k["ns"], impl="cuda")
+    with pytest.raises(ValueError, match="unknown selective scan impl"):
+        ragged_selective_scan(*_scan_args(k), k["ns"], impl="cuda")
 
 
 @pytest.mark.parametrize("name", sorted(SCAN_ROWS))

@@ -306,6 +306,79 @@ def test_expert_ffn_compiles_to_grouped_kernels_for_v5e(one_chip):
     assert "ragged-dot" not in compiled.as_text()
 
 
+def _no_scan_loop_left(text):
+    """No ``while`` instruction of the program belongs to the scan."""
+    return not [line for line in text.splitlines()
+                if re.search(r"\swhile\(", line)
+                and re.search(r'op_name="[^"]*ssm_scan', line)]
+
+
+@pytest.mark.parametrize("s", [64, 32],
+                         ids=["agent-prefix-c64", "reason-c32"])
+def test_selective_scan_compiles_for_v5e(one_chip, s):
+    """The scan of a Mamba layer of the `ai21-jamba2-3b.agent-prefix-c64`
+    and `phi4-mini-flash.reason-c32` steps: 512 rows of 5,120 channels,
+    state dim 16, 64 + 1 / 32 + 1 state slots. One Mosaic call in place
+    of the row and token loops, and the state updated in place."""
+    from paddle_tpu.ops.selective_scan import ragged_selective_scan
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    t, e, n = 512, 5120, 16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        functools.partial(ragged_selective_scan, impl="pallas"),
+        donate_argnums=5).lower(
+        sds((t, e), bf16), sds((t, e), f32), sds((e, n), f32),
+        sds((t, n), bf16), sds((t, n), bf16), sds((s + 1, n, e), f32),
+        sds((s,), i32), sds((s + 1,), i32), sds((s,), i32),
+        sds((), i32)).compile()
+    text = compiled.as_text()
+    assert kernel_calls(text, "ragged_selective_scan") == 1
+    assert kernel_calls(text, "ragged_paged_attention") == 0
+    assert _no_scan_loop_left(text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (s + 1) * n * e * 4
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_jamba_mamba_layer_compiles_to_one_scan_call_for_v5e(one_chip):
+    """``models/jamba.py: _mamba_layer`` whole at the published widths
+    (hidden 2,560, 5,120 channels, state 16, dt rank 160, conv 4, MLP
+    8,192; 64 + 1 slots): one ``ragged_selective_scan`` call, no loop
+    under ``ssm_scan``, the scan's state updated in place."""
+    from paddle_tpu.models.jamba import _mamba_layer
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    t, d, e, n, r, f, s = 512, 2560, 5120, 16, 160, 8192, 64
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    p = dict(
+        norm1_w=sds((d,), bf16), norm2_w=sds((d,), bf16),
+        in_proj=sds((d, 2 * e), bf16), conv_w=sds((4, e), bf16),
+        conv_b=sds((e,), bf16), x_proj=sds((e, r + 2 * n), bf16),
+        dt_norm=sds((r,), bf16), b_norm=sds((n,), bf16),
+        c_norm=sds((n,), bf16), dt_w=sds((r, e), bf16),
+        dt_b=sds((e,), f32), A_log=sds((e, n), f32), D=sds((e,), f32),
+        out_proj=sds((e, d), bf16), gate_up=sds((d, 2 * f), bf16),
+        down=sds((f, d), bf16))
+    state = dict(ssm=sds((s + 1, n, e), f32), conv=sds((s + 1, 3, e), bf16))
+    compiled = jax.jit(
+        functools.partial(_mamba_layer, eps=1e-6, scan_impl="pallas"),
+        donate_argnums=2).lower(
+        p, sds((t, d), bf16), state, sds((s,), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    assert kernel_calls(text, "ragged_selective_scan") == 1
+    assert _no_scan_loop_left(text)
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        (s + 1) * n * e * 4)
+
+
 def test_ragged_kernel_compiles_head_sharded_over_four_chips(topo):
     """TP serving: GSPMD refuses to partition a Mosaic kernel, so under a
     declared kernel mesh the op runs per head-shard inside shard_map —
