@@ -474,3 +474,58 @@ def test_llama_ragged_step_updates_its_cache_in_place_on_v5e(
     cache_bytes = 2 * layers * nb * BS * kh * D * 2
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
+def test_longcat_double_layer_compiles_for_v5e(one_chip):
+    """``models/longcat.py: _layer`` whole at the
+    `longcat-flash-chat-d4.chat-zipf-c64` widths (hidden 6,144, 64 heads,
+    q rank 1,536, latent 512 + 64 in 640 lanes, two 12,288-wide FFNs, a
+    float32 router over 512 + 256 experts, top-12, 16 of 512 experts held;
+    512 rows, 64 slots of 4,096 tokens, 16,384 blocks): the latent call
+    once a sublayer, the grouped product twice, both latent pools updated
+    in place. The temporaries (0.39 GB) are the dispatch's: it gathers
+    all 512 x 12 assignments' rows, hidden wide, and their float32 results
+    (ROADMAP S-item: ~1/48 of them reach a held expert)."""
+    from paddle_tpu.models.longcat import LongCatConfig, _layer
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    c = LongCatConfig(n_routed_experts=512, experts_held=(0, 16),
+                      num_layers=4, max_position_embeddings=4096)
+    heads, dn, dr, dv, rq, rank = c.attn_dims
+    d, f, fe, held = (c.hidden_size, c.ffn_hidden_size,
+                      c.expert_ffn_hidden_size, 16)
+    t, s, mb, nb, lanes = 512, 64, 256, 16384, c.latent_lanes
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    p = {name: sds((d,), bf16)
+         for name in ("in0_w", "post0_w", "in1_w", "post1_w")}
+    for j in (0, 1):
+        p.update({f"attn{j}_q_a": sds((d, rq), bf16),
+                  f"attn{j}_q_norm_w": sds((rq,), bf16),
+                  f"attn{j}_q_b": sds((rq, heads * (dn + dr)), bf16),
+                  f"attn{j}_kv_a": sds((d, rank + dr), bf16),
+                  f"attn{j}_kv_norm_w": sds((rank,), bf16),
+                  f"attn{j}_kv_b": sds((rank, heads * (dn + dv)), bf16),
+                  f"attn{j}_o_proj": sds((heads * dv, d), bf16),
+                  f"mlp{j}_gate_up": sds((d, 2 * f), bf16),
+                  f"mlp{j}_down": sds((f, d), bf16)})
+    p.update(router=sds((d, c.router_width), f32),
+             router_bias=sds((c.router_width,), f32),
+             experts_gate_up=sds((held, d, 2 * fe), bf16),
+             experts_down=sds((held, fe, d), bf16))
+    pool = sds((nb, BS, lanes), bf16)
+    compiled = jax.jit(functools.partial(
+        _layer, dims=c.attn_dims, eps=c.rms_norm_eps, rescale=(True, True),
+        impl="pallas", top_k=c.moe_topk, scale=6.0, expert_impl="pallas",
+        first_expert=0, zero_experts=512), donate_argnums=2).lower(
+        p, sds((t, d), bf16), (pool, pool), sds((s, mb), i32),
+        sds((s + 1,), i32), sds((s,), i32), sds((), i32),
+        sds((t, dr // 2), f32), sds((t, dr // 2), f32),
+        sds((t,), jnp.bool_)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 2
+    assert _kernel_calls(compiled, "grouped_matmul") == 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * nb * BS * lanes * 2
+    assert mem.temp_size_in_bytes < 512 * 2 ** 20
