@@ -55,23 +55,32 @@ Two implementations, shape-identical:
   and the path every non-TPU backend takes. It materializes each token's
   whole (MB*BS, KH, D) context, so it is for small shapes only.
 * ``_ragged_attend_pallas`` — Pallas TPU kernel whose work follows the
-  live (q tile, slot, page) triples, not the batch's capacity. The grid
-  is the q tiles of the packed stream alone: q and out move through
-  (block_q, H*D) BlockSpec tiles, the caches stay in HBM. Inside a tile
-  the kernel walks, from the scalar-prefetched cu_seqlens/context_lens/
+  live (q tile, slot, page group) triples, not the batch's capacity. q
+  and out are the stream ``(T * H, D)``: a row's H query heads side by
+  side on the row axis (free reshapes), one product row a (row, head).
+  The grid is the stream's tiles of ``_tile_rows`` rows (128, or fewer so
+  that a K/V head's product stays within ``_PRODUCT_ROWS``: 64 at 20
+  query heads a K/V head); the caches stay in HBM. Inside a tile the
+  kernel walks, from the scalar-prefetched cu_seqlens/context_lens/
   block_tables, the contiguous range of slots that have rows in it and,
-  per slot, its pages up to the causal bound of its last row there, in
-  groups of ``pages`` pages (128 tokens): each group is ``pages`` async
-  copies into a double-buffered VMEM scratch, in the cache's own
-  (BS, KH, D) layout, the next group (or the next slot's first) in
-  flight while the present one is computed. Per group and KV head one
-  matmul of the head's ``rep`` query heads, stacked on the row axis,
-  against the group's keys, online-softmax state in VMEM scratch. A slot
-  whose rows fit one aligned slab of 8/16 rows (a decode row, a chunk's
-  tail) computes on that slab only; any other on the whole tile, the
-  other slots' rows masked. Tiles past the last token do nothing but
-  zero their output. Compiled, it needs head_dim % 128 == 0 and
-  kv_heads * itemsize >= 4 per shard.
+  per slot, its pages (from page 0, or under a window the page of the
+  first row's oldest visible key) up to the causal bound of its last row
+  there, in page groups of ``_GROUP_TOKENS`` (512) tokens, under a window
+  about half of it (``_group_tokens``). A group is always fetched whole:
+  ``pages`` async copies of K and of V (past the slot's last live page
+  the table's clamped entries, which the causal bound masks) into a
+  double-buffered VMEM scratch in the cache's own layout, ONE wait for
+  each buffer's bytes, the next group (or the next slot's first) in
+  flight while the present one is computed. A slot whose product rows
+  fit one aligned window of a row's heads (a decode row, a chunk's last
+  row) computes on that window: one product a K/V head, each product row
+  masked to its own head's keys; any other slot on the whole tile, of one
+  K/V head in one product, of more in one product a K/V head over its
+  ``rep`` query heads' rows (strided reads of the stream), the other
+  slots' rows masked. Online-softmax state a product row in VMEM
+  scratch. Tiles past the last token do nothing but zero their output.
+  Compiled, it needs head_dim % 128 == 0 and kv_heads * itemsize >= 4
+  per shard.
 
 Selection: ``impl=None`` reads ``PADDLE_RAGGED_ATTN_IMPL``, else picks
 ``"pallas"`` on a TPU backend and ``"ref"`` elsewhere. ``"pallas"``
@@ -86,6 +95,7 @@ Under a device mesh the whole op runs per head-shard inside
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -101,13 +111,6 @@ _VMEM = pltpu.VMEM
 _NEG_INF = -1e30
 
 __all__ = ["ragged_paged_attention"]
-
-
-def _pick_block_q(t):
-    for b in (128, 64, 32, 16, 8):
-        if b <= t:
-            return b
-    return t
 
 
 # ---------------------------------------------------------------------------
@@ -191,58 +194,92 @@ def _ragged_attend_ref(q, kc, vc, bt, ctx, seg, pos, valid, scale,
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _head_reader(buf, d=None):
+# cached tokens a page group (under a window about half of it: _group_tokens),
+# and the most rows a K/V head's product of a q tile takes (a tile's stream
+# rows x its ``rep`` query heads): the latent kernel's sizes
+_GROUP_TOKENS = 512
+_PRODUCT_ROWS = 2048
+
+
+def _group_tokens(window):
+    """Cached tokens a page group. Under a window a tile of n rows sees
+    ``window + n - 1`` keys wherever it stands: groups of half the window
+    waste less of each product than 512 would (the latent kernel's rule)."""
+    if window is None:
+        return _GROUP_TOKENS
+    return max(128, min(_GROUP_TOKENS, 1 << ((window // 2).bit_length() - 1)))
+
+
+def _tile_rows(t, rep):
+    """Stream rows a q tile: a power of two from 16 to 128, at most ``t``
+    (unless that is under 16) and at most ``_PRODUCT_ROWS`` product rows a
+    K/V head (20 query heads on one K/V head: 64 rows)."""
+    tile = 128
+    while tile > 16 and (tile > t or tile * rep > _PRODUCT_ROWS):
+        tile //= 2
+    return tile
+
+
+def _rows_reader(rows, k):
+    """``read(h)``: rows ``h, h + k, h + 2k, ...`` of the 2-D ref ``rows``
+    ``(n * k, d)`` as an ``(n, d)`` matrix, one strided load. Rows of 16
+    bits share a 32-bit sublane word with the next row: there row ``h`` is
+    one half of every ``k / 2``-th word of the ``(n * k / 2, d)`` view."""
+    nk, d = rows.shape
+    n = nk // k
+    if rows.dtype.itemsize != 2 or k % 2:
+        return lambda h: rows[pl.ds(h, n, stride=k), :]
+    words = rows.bitcast(jnp.uint32)
+
+    def read(h):
+        w = words[pl.ds(h // 2, n, stride=k // 2), :]
+        w = w & jnp.uint32(0xFFFF0000) if h % 2 else w << 16
+        return pltpu.bitcast(w, jnp.float32).astype(rows.dtype)
+    return read
+
+
+def _head_reader(buf, d):
     """``read(g)``: KV head ``g`` of a fetched page group ``buf`` as a
-    (P*BS, D) matrix. A folded group (P, BS, KH*D) keeps its heads side
+    (P*BS, d) matrix. A folded group (P, BS, KH*D) keeps its heads side
     by side on lanes: a head is a static ``d``-wide lane slice. Else
     ``buf`` is (P, BS, KH, D). The pages keep the cache's own
     layout (folding heads onto lanes would re-tile the whole cache every
-    call), so a head is a static index on the sublane axis. Rows of 16
-    bits share a 32-bit sublane word with the next head's: there the
-    head is one half of every KH/2-th word of the (P*BS*KH/2, D) view,
-    one strided load instead of a row-by-row gather."""
+    call), so a head is a static index on the sublane axis, and of a
+    16-bit cache a strided read of the (P*BS*KH, D) view
+    (``_rows_reader``)."""
     if len(buf.shape) == 3:
         p, bs, _ = buf.shape
         return lambda g: buf[:, :, g * d:(g + 1) * d].reshape(p * bs, d)
     p, bs, kh, d = buf.shape
-    n = p * bs
     if buf.dtype.itemsize != 2 or kh % 2:
-        return lambda g: buf[:, :, g, :].reshape(n, d)
-    words = buf.reshape(n * kh, d).bitcast(jnp.uint32)
-
-    def read(g):
-        w = words[pl.ds(g // 2, n, stride=kh // 2), :]
-        w = w & jnp.uint32(0xFFFF0000) if g % 2 else w << 16
-        return pltpu.bitcast(w, jnp.float32).astype(buf.dtype)
-    return read
+        return lambda g: buf[:, :, g, :].reshape(p * bs, d)
+    return _rows_reader(buf.reshape(p * bs * kh, d), kh)
 
 
 def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
                    q_ref, kc_ref, vc_ref, o_ref,
                    kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
-                   scale, block_q, slab, block_size, pages, n_heads,
-                   kv_heads, head_dim, window=None):
-    d = head_dim
-    rep = n_heads // kv_heads
-    width = pages * block_size            # KV tokens per page group
+                   scale, tile, heads, kv_heads, block_size, pages, align,
+                   small, window=None):
+    d = q_ref.shape[-1]
+    rep = heads // kv_heads
+    width = pages * block_size            # cached tokens per page group
     s_slots = ctx_ref.shape[0]
     mb = bt_ref.shape[0] // s_slots
-    t_lo = pl.program_id(0) * block_q     # this q tile's stream rows
-    t_hi = t_lo + block_q
+    t_lo = pl.program_id(0) * tile        # this tile's stream rows
+    t_hi = t_lo + tile
     ns = ns_ref[0]
+    f32, i32 = jnp.float32, jnp.int32
 
-    # rows no slot owns (stream padding) keep these zeros; a page group's
-    # unfetched tail is multiplied by exact-zero probabilities, so what
-    # the V buffer starts with must be finite
+    # rows no slot owns (stream padding) keep these zeros
     o_ref[...] = jnp.zeros_like(o_ref)
-    vbuf[...] = jnp.zeros_like(vbuf)
 
     def span(s):
-        """Slot ``s`` in this tile: its stream rows [r0, r1), the first
-        KV page they may attend to (0 without a window; under one, the
-        page of the first row's oldest visible key: the pages before it
-        may be gone) and how many pages from there on (up to the causal
-        bound of the last row; 0 if the slot has no row here)."""
+        """Slot ``s`` in this tile: its stream rows [r0, r1), whether it
+        has any, how many pages they may attend to (to the causal bound of
+        the last row; 0 if the slot has no row here) and the first of
+        them (0 without a window; under one, the page of the first row's
+        oldest visible key: the pages before it may be gone)."""
         c = jnp.minimum(s, s_slots - 1)
         lo = cu_ref[c]
         nq = cu_ref[c + 1] - lo
@@ -252,160 +289,191 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
         hi = ctx_ref[c] - nq + (r1 - lo) - 1   # absolute pos of row r1-1
         n_pg = jnp.where(live, jnp.clip(hi // block_size + 1, 1, mb), 0)
         if window is None:
-            return lo, nq, ctx_ref[c], r0, r1, 0, live, n_pg
+            return lo, nq, ctx_ref[c], r0, r1, live, n_pg, 0
         first = ctx_ref[c] - nq + (r0 - lo)    # absolute pos of row r0
         pg0 = jnp.where(
             live, jnp.maximum(first - window + 1, 0) // block_size, 0)
-        return lo, nq, ctx_ref[c], r0, r1, pg0, live, n_pg - pg0
+        return lo, nq, ctx_ref[c], r0, r1, live, n_pg - pg0, pg0
 
-    def copies(p, b, page):
-        return (pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
-                                      sem.at[0, b]),
-                pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
-                                      sem.at[1, b]))
-
-    def fetch(s, pg0, grp, n_pg, b):
+    def fetch(s, pg0, grp, b):
         """Start the copies of slot ``s``'s page group ``grp`` counted
-        from page ``pg0`` (the first ``pages`` of its remaining ``n_pg``
-        pages) into buffer ``b``."""
-        def one(p, _):
-            entry = s * mb + grp * pages + p
-            if window is not None:
-                entry = entry + pg0
-            for c in copies(p, b, bt_ref[entry]):
-                c.start()
-            return 0
-        jax.lax.fori_loop(0, jnp.minimum(n_pg, pages), one, 0)
+        from page ``pg0`` into buffer ``b``: always ``pages`` of them, so
+        that one wait serves the group. Past the slot's last live page the
+        table's clamped entries name blocks of the pool, whose keys the
+        causal bound masks."""
+        base = s * mb + pg0 + grp * pages
+        for p in range(pages):
+            page = bt_ref[jnp.minimum(base + p, s * mb + mb - 1)]
+            pltpu.make_async_copy(kc_ref.at[page], kbuf.at[b, p],
+                                  sem.at[0, b]).start()
+            pltpu.make_async_copy(vc_ref.at[page], vbuf.at[b, p],
+                                  sem.at[1, b]).start()
 
-    def wait(n_pg, b):
-        def one(p, _):
-            for c in copies(p, b, 0):
-                c.wait()
-            return 0
-        jax.lax.fori_loop(0, jnp.minimum(n_pg, pages), one, 0)
+    def wait(b):
+        for c, (ref, buf) in enumerate(((kc_ref, kbuf), (vc_ref, vbuf))):
+            pltpu.make_async_copy(ref.at[pl.ds(0, pages)], buf.at[b],
+                                  sem.at[c, b]).wait()
+
+    def heads_of(b):
+        return _head_reader(kbuf.at[b], d), _head_reader(vbuf.at[b], d)
+
+    def online(q, keep, kg, vg, m_prev, l_prev, acc):
+        """One page group of the online softmax for the product rows of
+        ``q``; a row with no key kept is left exactly as it was."""
+        sc = mxu_dot(q, kg, (((1,), (1,)), ((), ())),
+                     preferred_element_type=f32) * scale
+        sc = jnp.where(keep, sc, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # where, not only the shift: while a row has met no key of its own
+        # m is the mask value and every masked score would weigh 1
+        p = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        pv = mxu_dot(p.astype(vg.dtype), vg, (((1,), (0,)), ((), ())),
+                     preferred_element_type=f32)
+        return m_new, l_new, acc * alpha + pv
 
     def slot_body(carry):
         s, b, fetched = carry
-        lo, nq, ctx, r0, r1, pg0, live, n_pg = span(s)
+        lo, nq, ctx, r0, r1, live, n_pg, pg0 = span(s)
         n_grp = (n_pg + pages - 1) // pages
-        nxt_pg0, nxt_live, nxt_pg = span(s + 1)[-3:]
+        nxt_live, _, nxt_pg0 = span(s + 1)[-3:]
 
         @pl.when(live & (fetched == 0))
         def _():
-            fetch(s, pg0, 0, n_pg, b)
+            fetch(s, pg0, 0, b)
 
-        # the slot's rows in the tile: one aligned slab of ``slab`` rows
-        # when they fit in one (a decode row, a chunk's tail), else the
-        # whole tile with the other slots' rows masked
-        first = (r0 - t_lo) // slab * slab
-        small = r1 - t_lo <= first + slab
+        def walk(attend):
+            def group_body(grp, b):
+                # next in flight while this one is computed: the slot's
+                # next group, or after its last the next slot's first
+                last = grp + 1 == n_grp
 
-        def on_rows(fn):
-            if slab < block_q:
-                pl.when(live & small)(
-                    lambda: fn(pl.multiple_of(first, slab), slab))
-            pl.when(live & ~small if slab < block_q else live)(
-                lambda: fn(0, block_q))
+                @pl.when(~last | nxt_live)
+                def _():
+                    fetch(jnp.where(last, s + 1, s),
+                          jnp.where(last, nxt_pg0, pg0),
+                          jnp.where(last, 0, grp + 1), 1 - b)
+
+                wait(b)
+                attend(grp, b)
+                return 1 - b
+            jax.lax.fori_loop(0, n_grp, group_body, b)
+
+        def columns(grp, qpos):
+            """Which of the group's keys a product row at absolute
+            position ``qpos`` (n, 1) sees."""
+            col = pg0 * block_size + grp * width + jax.lax.broadcasted_iota(
+                i32, (1, width), 1)
+            seen = col <= qpos
+            if window is not None:
+                seen = seen & (col > qpos - window)
+            return seen
+
+        def product_rows(row0, n):
+            """Of the tile's product rows [row0, row0 + n) (stream row x
+            query head): which are the slot's, the slot's row each is
+            counted from its first, and its query head."""
+            rel = (row0 - (lo - t_lo) * heads
+                   + jax.lax.broadcasted_iota(i32, (n, 1), 0))
+            own = (rel >= 0) & (rel < nq * heads)
+            row = jnp.floor((rel.astype(f32) + 0.5) * (1.0 / heads)).astype(
+                i32)
+            return own, row, rel - row * heads
+
+        def stream(row0, n):
+            return pl.ds(pl.multiple_of(row0, align), n)
 
         def init(row0, n):
-            rows = pl.ds(row0, n)
-            m_scr[:, rows, :] = jnp.full((n_heads, n, 128), _NEG_INF,
-                                         jnp.float32)
-            l_scr[:, rows, :] = jnp.zeros((n_heads, n, 128), jnp.float32)
-            acc_scr[rows, :] = jnp.zeros((n, n_heads * d), jnp.float32)
-
-        def attend(grp, b, row0, n):
-            rows = pl.ds(row0, n)
-            row = row0 + jax.lax.broadcasted_iota(jnp.int32, (n, width), 0)
-            col = grp * width + jax.lax.broadcasted_iota(
-                jnp.int32, (n, width), 1)
-            if window is not None:
-                col = col + pg0 * block_size
-            local = t_lo + row - lo                      # seq-local q index
-            qpos = ctx - nq + local                      # absolute position
-            mask = (local >= 0) & (local < nq) & (col <= qpos)
-            if window is not None:
-                mask = mask & (col > qpos - window)
-            mask = jnp.concatenate([mask] * rep, axis=0)
-            if len(kbuf.shape) == 4:                     # folded pages
-                k_head, v_head = (_head_reader(kbuf.at[b], d),
-                                  _head_reader(vbuf.at[b], d))
-            else:
-                k_head, v_head = _head_reader(kbuf.at[b]), _head_reader(
-                    vbuf.at[b])
-            for g in range(kv_heads):
-                # q/out heads live on the lane axis (the (T, H*D) view),
-                # so a head is a static 128-aligned lane slice, and the
-                # ``rep`` query heads of a KV head stack on the row axis:
-                # one matmul per KV head over the whole page group
-                heads = range(g * rep, (g + 1) * rep)
-                qg = jnp.concatenate(
-                    [q_ref[rows, h * d:(h + 1) * d] for h in heads], axis=0)
-                hs = [slice(h * d, (h + 1) * d) for h in heads]
-                kg = k_head(g)
-                vg = v_head(g)
-                sc = mxu_dot(
-                    qg, kg, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(mask, sc, _NEG_INF)
-                m_prev = jnp.concatenate(
-                    [m_scr[g * rep + r, rows, :1] for r in range(rep)],
-                    axis=0)
-                l_prev = jnp.concatenate(
-                    [l_scr[g * rep + r, rows, :1] for r in range(rep)],
-                    axis=0)
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(sc, axis=1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(sc - m_new)
-                l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-                pv = mxu_dot(
-                    p.astype(vg.dtype), vg, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                for r, h in enumerate(hs):
-                    part = slice(r * n, (r + 1) * n)
-                    acc_scr[rows, h] = (acc_scr[rows, h] * alpha[part]
-                                        + pv[part])
-                    m_scr[g * rep + r, rows, :] = jnp.broadcast_to(
-                        m_new[part], (n, 128))
-                    l_scr[g * rep + r, rows, :] = jnp.broadcast_to(
-                        l_new[part], (n, 128))
+            rows = stream(row0, n)
+            m_scr[rows, :] = jnp.full((n, 128), _NEG_INF, f32)
+            l_scr[rows, :] = jnp.zeros((n, 128), f32)
+            acc_scr[rows, :] = jnp.zeros((n, d), f32)
 
         def store(row0, n):
-            rows = pl.ds(row0, n)
-            local = (t_lo + row0 - lo
-                     + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0))
-            ok = (local >= 0) & (local < nq)
-            for h in range(n_heads):
-                hd = slice(h * d, (h + 1) * d)
-                l = l_scr[h, rows, :1]
-                val = (acc_scr[rows, hd]
-                       / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-                # rows of other slots keep what their own sweep stored
-                o_ref[rows, hd] = jnp.where(ok, val, o_ref[rows, hd])
+            rows = stream(row0, n)
+            own = product_rows(row0, n)[0]
+            l = l_scr[rows, :1]
+            val = (acc_scr[rows, :]
+                   / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            # rows of other slots keep what their own sweep stored
+            o_ref[rows, :] = jnp.where(own, val, o_ref[rows, :])
 
-        on_rows(init)
+        def contiguous(row0, n):
+            """The product rows [row0, row0 + n) in the stream's own order,
+            one product a K/V head: of more than one K/V head a product
+            row keeps only its own head's keys."""
+            own, row, head = product_rows(row0, n)
+            qpos = ctx - nq + row
+            rows = stream(row0, n)
 
-        def group_body(grp, b):
-            # next in flight while this one is computed: the slot's next
-            # group, or after its last the next slot's first
-            last = grp + 1 == n_grp
+            def attend(grp, b):
+                seen = own & columns(grp, qpos)
+                q = q_ref[rows, :]
+                k_head, v_head = heads_of(b)
+                for g in range(kv_heads):
+                    keep = seen
+                    if kv_heads > 1:
+                        keep = keep & (head >= g * rep) & (
+                            head < (g + 1) * rep)
+                    m, l, acc = online(q, keep, k_head(g), v_head(g),
+                                       m_scr[rows, :1], l_scr[rows, :1],
+                                       acc_scr[rows, :])
+                    m_scr[rows, :] = jnp.broadcast_to(m, (n, 128))
+                    l_scr[rows, :] = jnp.broadcast_to(l, (n, 128))
+                    acc_scr[rows, :] = acc
 
-            @pl.when(~last | nxt_live)
-            def _():
-                fetch(jnp.where(last, s + 1, s),
-                      jnp.where(last, nxt_pg0, pg0) if window else 0,
-                      jnp.where(last, 0, grp + 1),
-                      jnp.where(last, nxt_pg, n_pg - (grp + 1) * pages),
-                      1 - b)
+            init(row0, n)
+            walk(attend)
+            store(row0, n)
 
-            wait(n_pg - grp * pages, b)
-            on_rows(functools.partial(attend, grp, b))
-            return 1 - b
+        def by_head():
+            """The whole tile, one product a K/V head of its ``rep`` query
+            heads' rows (``rep`` blocks of ``tile`` rows, each a strided
+            read of the stream)."""
+            local = t_lo - lo + jax.lax.broadcasted_iota(i32, (tile, 1), 0)
+            own = jnp.concatenate([(local >= 0) & (local < nq)] * rep,
+                                  axis=0)
+            qpos = jnp.concatenate([ctx - nq + local] * rep, axis=0)
+            read_q = _rows_reader(q_ref, heads)
 
-        b = jax.lax.fori_loop(0, n_grp, group_body, b)
-        on_rows(store)
-        return s + 1, b, (live & nxt_live).astype(jnp.int32)
+            def attend(grp, b):
+                keep = own & columns(grp, qpos)
+                k_head, v_head = heads_of(b)
+                for g in range(kv_heads):
+                    hs = range(g * rep, (g + 1) * rep)
+                    rows = [pl.ds(h, tile, stride=heads) for h in hs]
+
+                    def stacked(ref):
+                        return jnp.concatenate([ref[r, :] for r in rows],
+                                               axis=0)
+                    m, l, acc = online(
+                        jnp.concatenate([read_q(h) for h in hs], axis=0),
+                        keep, k_head(g), v_head(g), stacked(m_scr)[:, :1],
+                        stacked(l_scr)[:, :1], stacked(acc_scr))
+                    for i, r in enumerate(rows):
+                        part = slice(i * tile, (i + 1) * tile)
+                        m_scr[r, :] = jnp.broadcast_to(m[part], (tile, 128))
+                        l_scr[r, :] = jnp.broadcast_to(l[part], (tile, 128))
+                        acc_scr[r, :] = acc[part]
+
+            init(0, tile * heads)
+            walk(attend)
+            store(0, tile * heads)
+
+        # the slot's product rows in the tile: one aligned window of
+        # ``small`` rows when they fit in one (a decode row, a chunk's
+        # tail), else the whole tile; of one K/V head either is one
+        # product, of more the whole tile goes by K/V head
+        first = (r0 - t_lo) * heads
+        w0 = jnp.minimum(first // align * align, tile * heads - small)
+        fits = (r1 - t_lo) * heads - w0 <= small
+        pl.when(live & fits)(lambda: contiguous(w0, small))
+        if kv_heads == 1:
+            pl.when(live & ~fits)(lambda: contiguous(0, tile * heads))
+        else:
+            pl.when(live & ~fits)(by_head)
+        return s + 1, (b + n_grp) % 2, (live & nxt_live).astype(i32)
 
     # slots are contiguous in the stream, so a tile holds a contiguous
     # slot range: find its first, walk until one starts past the tile
@@ -415,10 +483,6 @@ def _ragged_kernel(cu_ref, ctx_ref, ns_ref, bt_ref,   # scalar prefetch
     jax.lax.while_loop(
         lambda c: (c[0] < ns) & (cu_ref[c[0]] < t_hi), slot_body,
         (s0, jnp.int32(0), jnp.int32(0)))
-
-
-# page groups of this many KV tokens: one lane width of scores
-_GROUP_TOKENS = 128
 
 
 # jitted on its own so that the layers of a model, which call it with one
@@ -445,51 +509,65 @@ def _ragged_attend_pallas(q, kc, vc, bt, cu, ctx, num_seqs, scale,
             f"the compiled ragged kernel cannot fetch pages of a "
             f"{kc.dtype} cache with {kh} KV head(s) per shard: keep "
             f"kv_heads * itemsize >= 4 (fewer head shards)")
-    block_q = _pick_block_q(t_total)
-    n_qb = -(-t_total // block_q)
-    t_pad = n_qb * block_q
-    pages = max(1, min(mb, _GROUP_TOKENS // bs))
-    ns = jnp.reshape(num_seqs.astype(jnp.int32), (1,))
-    bt_flat = jnp.maximum(bt, 0).reshape(-1).astype(jnp.int32)
-    q2 = q.reshape(t_total, h * d)
-    if t_pad != t_total:
-        q2 = jnp.pad(q2, ((0, t_pad - t_total), (0, 0)))
+    rep = h // kh
+    tile = _tile_rows(t_total, rep)
+    n_qb = -(-t_total // tile)
+    t_pad = n_qb * tile
+    pages = max(1, min(mb, _group_tokens(window) // bs))
+    # product rows: a tile's start at a whole sublane tile of q, and the
+    # window that holds one stream row's heads wherever it starts
+    align = max(8, 32 // q.dtype.itemsize)
+    small = -(-(h + align - math.gcd(h, align)) // align) * align
+    # q and out as the stream (T * H, D): a row's heads side by side on
+    # the row axis (free reshapes)
+    q2 = jnp.pad(q, ((0, t_pad - t_total), (0, 0), (0, 0))).reshape(
+        t_pad * h, d)
 
-    def q_map(qb, cu_r, ctx_r, ns_r, bt_r):
+    def tile_of(qb, *_):
         return (qb, 0)
 
     kernel = functools.partial(
-        _ragged_kernel, scale=scale, block_q=block_q,
-        slab=max(8, 32 // q.dtype.itemsize), block_size=bs, pages=pages,
-        n_heads=h, kv_heads=kh, head_dim=d,
+        _ragged_kernel, scale=scale, tile=tile, heads=h, kv_heads=kh,
+        block_size=bs, pages=pages, align=align, small=small,
         **({} if window is None else {"window": window}))
+    # what the call holds in VMEM: K and V groups (two buffers each), q and
+    # out tiles (double-buffered), the state, and a group's scores,
+    # probabilities and mask at the widest product, with room to spare
+    width = pages * bs
+    need = (4 * pages * math.prod(page) * kc.dtype.itemsize
+            + 4 * tile * h * d * q.dtype.itemsize
+            + tile * h * (256 + d) * 4
+            + 6 * tile * rep * width * 4)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_qb,),
         in_specs=[
-            pl.BlockSpec((block_q, h * d), q_map, memory_space=_VMEM),
+            pl.BlockSpec((tile * h, d), tile_of, memory_space=_VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_q, h * d), q_map,
-                               memory_space=_VMEM),
+        out_specs=pl.BlockSpec((tile * h, d), tile_of, memory_space=_VMEM),
         scratch_shapes=[
             _VMEM((2, pages) + page, kc.dtype),
             _VMEM((2, pages) + page, vc.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((h, block_q, 128), jnp.float32),
-            _VMEM((block_q, h * d), jnp.float32),
+            _VMEM((tile * h, 128), jnp.float32),
+            _VMEM((tile * h, 128), jnp.float32),
+            _VMEM((tile * h, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_pad, h * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad * h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=need + 16 * 2 ** 20),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(cu.astype(jnp.int32), ctx.astype(jnp.int32), ns, bt_flat, q2, kc, vc)
-    return out[:t_total].reshape(t_total, h, d)
+    )(cu.astype(jnp.int32), ctx.astype(jnp.int32),
+      jnp.reshape(num_seqs.astype(jnp.int32), (1,)),
+      jnp.maximum(bt, 0).reshape(-1).astype(jnp.int32), q2, kc, vc)
+    return out.reshape(t_pad, h, d)[:t_total]
 
 
 # ---------------------------------------------------------------------------

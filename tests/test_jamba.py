@@ -138,20 +138,28 @@ def test_published_layout_and_parameter_count():
 
 
 # -- rep 20 on one K/V head through the kernel ---------------------------------
-def test_twenty_query_heads_on_one_folded_kv_head_interpreted():
+@pytest.mark.parametrize("new,ctx_live,mb,t", [
+    ([17, 1, 6], [25, 30, 6], 4, 24),
+    # 40 decode rows: one 64-row q tile holds 40 slots, each computed on
+    # its own 20 product rows; then 39 of them beside a chunk
+    ([1] * 40, [3 + 2 * i for i in range(40)], 12, 64),
+    ([1] * 39 + [9], [5 + 2 * i for i in range(39)] + [40], 12, 64),
+], ids=["chunk-decode-chunk", "forty-decode-rows", "forty-rows-and-chunk"])
+def test_twenty_query_heads_on_one_folded_kv_head_interpreted(new, ctx_live,
+                                                              mb, t):
     rng = np.random.default_rng(0)
-    t, s, h, d, bs, mb = 24, 3, 20, 128, 8, 4
+    s, h, d, bs = len(new), 20, 128, 8
     f32 = jnp.float32
     q = jnp.asarray(rng.standard_normal((t, h, d)), f32)
     k = jnp.asarray(rng.standard_normal((t, 1, d)), f32)
     v = jnp.asarray(rng.standard_normal((t, 1, d)), f32)
     kc = jnp.asarray(rng.standard_normal((s * mb, bs, d)), f32)
     vc = jnp.asarray(rng.standard_normal((s * mb, bs, d)), f32)
-    bt = jnp.arange(s * mb, dtype=jnp.int32).reshape(s, mb)
-    cu = jnp.asarray([0, 17, 18, 24], jnp.int32)     # chunk, decode, chunk
-    ctx = jnp.asarray([25, 30, 6], jnp.int32)
+    bt = jnp.asarray(rng.permutation(s * mb).reshape(s, mb), jnp.int32)
+    cu = jnp.asarray(np.concatenate([[0], np.cumsum(new)]), jnp.int32)
+    ctx = jnp.asarray(ctx_live, jnp.int32)
     outs = [ragged_paged_attention(q, k, v, kc, vc, bt, cu, ctx,
-                                   jnp.int32(3), impl=impl)[0]
+                                   jnp.int32(s), impl=impl)[0]
             for impl in ("ref", "interpret")]
     np.testing.assert_allclose(np.asarray(outs[1]), np.asarray(outs[0]),
                                atol=2e-5, rtol=0)

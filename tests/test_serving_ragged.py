@@ -269,10 +269,11 @@ def test_fleet_handoff_parity_ragged(tiny_model):
 # the Pallas kernel itself (interpret mode, asked for by name)
 # ---------------------------------------------------------------------------
 def _ragged_batch(heads, kv_heads, new, ctx_live, t, s, mb, bs, d=16,
-                  seed=7, dtype=np.float32):
+                  seed=7, dtype=np.float32, folded=False):
     """One packed step for the op: ``new[i]`` query rows of live slot i
     end a context of ``ctx_live[i]`` tokens; slots past them, rows past
-    them and every block-table tail are padding."""
+    them and every block-table tail are padding. ``folded``: the caches
+    as (blocks, block size, KV heads x d)."""
     n = len(new)
     nb = s * mb
     rng = np.random.RandomState(seed)
@@ -286,10 +287,11 @@ def _ragged_batch(heads, kv_heads, new, ctx_live, t, s, mb, bs, d=16,
     for i, c in enumerate(ctx_live):
         n_blocks = -(-c // bs)
         bt[i, :n_blocks] = [free.pop() for _ in range(n_blocks)]
+    cache = (nb, bs, kv_heads * d) if folded else (nb, bs, kv_heads, d)
     return tuple(
         jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
         for shape in [(t, heads, d), (t, kv_heads, d), (t, kv_heads, d),
-                      (nb, bs, kv_heads, d), (nb, bs, kv_heads, d)]
+                      cache, cache]
     ) + (bt, cu, ctx, np.int32(n))
 
 
@@ -310,6 +312,25 @@ _LONG_CHUNK = dict(new=[1, 390, 1], ctx_live=[40, 540, 9],
 # 2 of 6 slots live, 71 of 384 rows: the last two q tiles hold nothing
 _TRAILING_PADDING = dict(new=[70, 1], ctx_live=[131, 17],
                          t=384, s=6, mb=64, bs=8)
+# the Jamba call cut down: 20 query heads on ONE folded K/V head of 128,
+# blocks of 64 (page groups of 8 pages, 512 tokens), decode rows whose
+# contexts end just before, on and just after a group boundary, one over
+# three groups, one inside the first page
+_MQA = dict(new=[1] * 5, ctx_live=[511, 512, 513, 1100, 40], t=16, s=6,
+            mb=20, bs=64, d=128, folded=True, dtype=jnp.bfloat16)
+# q tiles of 64 rows: a chunk that straddles the two, then two decode rows
+# alone in the second tile beside the chunk's tail
+_STRADDLE = dict(new=[40, 30, 1, 1], ctx_live=[40, 95, 17, 300], t=80,
+                 s=5, mb=80, bs=4)
+# blocks of 16 (groups of 32 pages): slots of 33 pages, no multiple of the
+# group, so the second group is one live page and 31 clamped entries
+_PAGES_33 = dict(new=[1, 20, 1], ctx_live=[528, 530, 513], t=32, s=4,
+                 mb=40, bs=16)
+# a 200-key window over a folded cache of two 64-lane heads (the hybrid's
+# fold): groups of 128 tokens counted from the page of each slot's oldest
+# visible key, which starts mid-page
+_WINDOW_FOLDED = dict(new=[1, 30, 1], ctx_live=[350, 301, 60], t=48, s=4,
+                      mb=100, bs=4, d=64, folded=True, window=200)
 
 
 @pytest.mark.parametrize("heads,kv_heads,batch,strict", [
@@ -321,9 +342,19 @@ _TRAILING_PADDING = dict(new=[70, 1], ctx_live=[131, 17],
     # 16-bit caches: a KV head is half of a sublane word (_head_reader)
     (4, 2, dict(_MIXED, dtype=jnp.bfloat16), False),
     (8, 4, dict(_DECODE, dtype=jnp.bfloat16), False),
+    # the stream layout: a decode row on its own query heads, page groups
+    # of 512 tokens fetched whole (the strict cases count the one wait's
+    # bytes, clamped tail pages included)
+    (20, 1, _MQA, False), (20, 1, _MQA, True),
+    (4, 2, _STRADDLE, False), (4, 2, _STRADDLE, True),
+    (8, 2, _PAGES_33, False), (8, 2, _PAGES_33, True),
+    (4, 2, _WINDOW_FOLDED, False), (4, 2, _WINDOW_FOLDED, True),
 ], ids=["mha", "gqa", "rep2", "decode-rep2", "decode-rep4", "chunk-rep2",
         "chunk-mha", "padding-rep2", "strict-mixed", "strict-decode",
-        "bf16-mixed", "bf16-decode"])
+        "bf16-mixed", "bf16-decode", "mqa-group-edges",
+        "strict-mqa-group-edges", "decode-beside-straddling-chunk",
+        "strict-decode-beside-straddling-chunk", "bs16-33-pages",
+        "strict-bs16-33-pages", "window-folded", "strict-window-folded"])
 def test_ragged_kernel_interpret_matches_ref_on_mixed_batch(
         heads, kv_heads, batch, strict):
     """impl="interpret" vs impl="ref" on one step — the coverage whose
@@ -336,15 +367,17 @@ def test_ragged_kernel_interpret_matches_ref_on_mixed_batch(
 
     from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
 
+    batch = dict(batch)
+    win = {"window": batch.pop("window")} if "window" in batch else {}
     args = _ragged_batch(heads, kv_heads, **batch)
-    ref = rpa.ragged_paged_attention(*args, impl="ref")
+    ref = rpa.ragged_paged_attention(*args, impl="ref", **win)
     if strict:
         q = args[0]
         got = (rpa._ragged_attend_pallas(
             q, ref[1], ref[2], *args[5:], 1.0 / q.shape[-1] ** 0.5,
-            pltpu.InterpretParams()),)
+            pltpu.InterpretParams(), **win),)
     else:
-        got = rpa.ragged_paged_attention(*args, impl="interpret")
+        got = rpa.ragged_paged_attention(*args, impl="interpret", **win)
     tol = 1e-5 if args[0].dtype == np.float32 else 3e-2
     for r, i in zip(ref, got):
         np.testing.assert_allclose(np.asarray(i, np.float32),
