@@ -8,8 +8,9 @@ kernel consume it).
 
 Prefix caching (``enable_prefix_cache=True``): device blocks are
 refcounted and FULL prompt blocks are registered in a prefix trie keyed
-by the token-content chain (block i's key folds block i-1's key, so a
-block is only shared when the ENTIRE prefix up to it matches). A request
+by the token-content chain (block i's key holds block i-1's key, so a
+block is only shared when the ENTIRE prefix up to it matches; a key hashes
+and compares one block's tokens, ``_ChainKey``). A request
 admitted with a matching prompt prefix shares those device blocks
 instead of recomputing them; the first write into a block another
 request still holds triggers copy-on-write (``take_cow_pairs`` hands the
@@ -20,7 +21,7 @@ them (LRU-ish eviction: claiming a cached-free block drops its key).
 
 Commit cursor: a request's tokens only ever grow, so ``commit_prefix``
 keeps, per block table, how far that request's chain has been walked:
-the block index reached, the nested key at that index and the chain hash
+the block index reached, the chain key at that index and the chain hash
 there. A call resumes from the cursor, so every block is sliced, keyed
 and hashed once in the request's life, and a call with no newly covered
 block (every decode step) costs a dictionary look-up. The cursor lives
@@ -106,6 +107,7 @@ round is about to load; a block that leaves the trie takes its snapshot
 with it; a chain with no snapshot is a miss from zero state."""
 from __future__ import annotations
 
+import bisect
 import hashlib
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -122,6 +124,37 @@ class NoFreeBlocksError(RuntimeError):
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+class _ChainKey:
+    """The prefix trie's key of one full block: the key of the chain
+    before it (None at the root) and the block's tokens. Two keys are
+    equal exactly when their whole token chains are, as nested
+    ``(parent, tokens)`` tuples would be; but the hash is taken once, from
+    this block's tokens and the parent's hash, and equality compares the
+    parents by identity before content. A probe built on the key the trie
+    stores for its parent (``_walk`` and ``commit_prefix`` build them so)
+    therefore hashes and compares one block's tokens at any depth, where a
+    nested tuple was hashed from the root at every look-up."""
+
+    __slots__ = ("parent", "tokens", "_hash")
+
+    def __init__(self, parent: Optional["_ChainKey"], tokens: tuple):
+        self.parent = parent
+        self.tokens = tokens
+        self._hash = hash((parent, tokens))
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        a, b = self, other
+        while a is not b:
+            if not (isinstance(a, _ChainKey) and isinstance(b, _ChainKey)
+                    and a._hash == b._hash and a.tokens == b.tokens):
+                return False
+            a, b = a.parent, b.parent
+        return True
 
 
 def _fold_hash(parent_hex: Optional[str],
@@ -204,30 +237,35 @@ class BlockManager:
         # device refcounts for owned blocks (block -> #table occurrences)
         self._refs: Dict[int, int] = {}
         # prefix trie: chain-key -> block id, and its inverse. The key for
-        # prompt block i is (key_{i-1}, tuple(block_i_tokens)), so equal
-        # keys imply the whole prefix matches. Keys outlive free(): a
-        # cached-free block keeps its registration until reclaimed.
-        self._prefix_index: Dict[tuple, int] = {}
-        self._block_key: Dict[int, tuple] = {}
+        # prompt block i is _ChainKey(key_{i-1}, tuple(block_i_tokens)),
+        # so equal keys imply the whole prefix matches. Keys outlive
+        # free(): a cached-free block keeps its registration until
+        # reclaimed.
+        self._prefix_index: Dict[_ChainKey, int] = {}
+        self._block_key: Dict[int, _ChainKey] = {}
         # fleet advertisement layer: every registered chain key also
         # carries a content chain HASH (stable across processes, unlike
-        # the tuple key which is only meaningful locally). `_hash_key`
-        # is the inverse used to resolve an incoming ship/export request
-        # by hash; `_hash_tokens` caches covered-token counts for the
-        # digest. `_trie_rev` bumps on any registration change so the
-        # heartbeat-rate digest is computed at most once per change.
-        self._key_hash: Dict[tuple, str] = {}
-        self._hash_key: Dict[str, tuple] = {}
+        # the key which is only meaningful locally). `_hash_key` is the
+        # inverse used to resolve an incoming ship/export request by
+        # hash; `_hash_tokens` caches covered-token counts for the digest.
+        # `_trie_rev` bumps on any registration change.
+        self._key_hash: Dict[_ChainKey, str] = {}
+        self._hash_key: Dict[str, _ChainKey] = {}
         self._hash_tokens: Dict[str, int] = {}
+        # the digest's order, kept as entries come and go: every
+        # (covered tokens, chain hash), sorted; the lowest position that
+        # changed since the digest was last built; that digest
+        self._digest_order: List[Tuple[int, str]] = []
+        self._digest_dirty = 0
+        self._digest_cache: Optional[Tuple[int, dict]] = None
         # commit cursor per block table (module docstring): (blocks
         # walked, chain key there, chain hash there)
         self._commit_cursor: Dict[
-            str, Tuple[int, Optional[tuple], Optional[str]]] = {}
+            str, Tuple[int, Optional[_ChainKey], Optional[str]]] = {}
         self._trie_rev = 0
         # _matched_chain's last walk: (trie revision, tokens, blocks
         # asked for, blocks matched, their chain hashes)
         self._last_walk: tuple = (-1, None, 0, [], [])
-        self._digest_cache: Optional[Tuple[tuple, dict]] = None
         self._cow_pairs: List[Tuple[int, int]] = []
         # observability (engine surfaces these through ServingMetrics)
         self.num_prefix_hits = 0
@@ -237,6 +275,8 @@ class BlockManager:
         # blocks commit_prefix walked / newly registered
         self.num_commit_visited = 0
         self.num_prefix_blocks_committed = 0
+        # prefix_digest calls that found its kept entries changed
+        self.num_digest_rebuilds = 0
         # host swap pool (0 = swap disabled)
         self.num_host_blocks = num_host_blocks
         self._host_free: List[int] = list(range(num_host_blocks - 1, -1,
@@ -490,28 +530,59 @@ class BlockManager:
         full blocks of ``tokens[:upto]``, and (where snapshots are kept)
         each one's chain hash. The last walk is kept while the trie
         stands as it was: an admission asks twice (``match_prefix``, then
-        ``allocate``), and a walk hashes every nested key from its root
-        (7.6 ms for 8k tokens in blocks of 64)."""
-        bs = self.block_size
-        n = upto // bs
+        ``allocate``)."""
+        n = upto // self.block_size
         if not isinstance(tokens, list):
             tokens = list(tokens)
         rev, seen, walked, blocks, hashes = self._last_walk
         if rev != self._trie_rev or n > walked or seen != tokens:
-            key: Optional[tuple] = None
             blocks, hashes = [], []
-            while len(blocks) < n:
-                at = len(blocks) * bs
-                key = (key, tuple(tokens[at:at + bs]))
-                b = self._prefix_index.get(key)
-                if b is None:
-                    break
+            for key, b in self._walk(tokens, n):
                 blocks.append(b)
                 if self.state_snapshots:
                     hashes.append(self._key_hash[key])
             self._last_walk = (self._trie_rev, list(tokens), n, blocks,
                                hashes)
         return blocks[:n], hashes[:n]
+
+    def _walk(self, tokens: Sequence[int], n: int):
+        """Yield ``(key, entry)`` down the registered chain over the
+        leading ``n`` full blocks of ``tokens``, to the first block the
+        trie does not hold. Each probe is built on the stored key of the
+        block before it, so a look-up costs one block's tokens."""
+        bs = self.block_size
+        key: Optional[_ChainKey] = None
+        for i in range(n):
+            b = self._prefix_index.get(
+                _ChainKey(key, tuple(tokens[i * bs:(i + 1) * bs])))
+            if b is None:
+                return
+            key = self._block_key[b]
+            yield key, b
+
+    def _register(self, key: _ChainKey, entry: int, chash: str,
+                  covered: int):
+        """Make ``entry`` the trie's block for ``key``, advertised under
+        ``chash`` as covering ``covered`` tokens."""
+        self._prefix_index[key] = entry
+        self._block_key[entry] = key
+        self._key_hash[key] = chash
+        if chash in self._hash_tokens:
+            self._unadvertise(chash)
+        self._hash_key[chash] = key
+        self._hash_tokens[chash] = covered
+        item = (covered, chash)
+        at = bisect.bisect_left(self._digest_order, item)
+        self._digest_order.insert(at, item)
+        self._digest_dirty = min(self._digest_dirty, at)
+        self._trie_rev += 1
+
+    def _unadvertise(self, chash: str):
+        """Take ``chash`` out of the digest's entries and its order."""
+        at = bisect.bisect_left(self._digest_order,
+                                (self._hash_tokens.pop(chash), chash))
+        del self._digest_order[at]
+        self._digest_dirty = min(self._digest_dirty, at)
 
     def _drop_registration(self, entry: int):
         """Forget the trie registration of a (device or virtual) id —
@@ -522,7 +593,7 @@ class BlockManager:
             h = self._key_hash.pop(key, None)
             if h is not None and self._hash_key.get(h) == key:
                 self._hash_key.pop(h)
-                self._hash_tokens.pop(h, None)
+                self._unadvertise(h)
                 snap = self._snap_index.pop(h, None)
                 if snap is not None:
                     # the block leaves the trie: its snapshot with it
@@ -722,17 +793,9 @@ class BlockManager:
         anyway). Returns blocks demoted."""
         if not self.tiered:
             return 0
-        bs = self.block_size
-        full = (min(covered, len(tokens)) // bs) * bs
-        key: Optional[tuple] = None
         done = 0
-        hit = 0
-        while hit + bs <= full:
-            key = (key, tuple(tokens[hit:hit + bs]))
-            b = self._prefix_index.get(key)
-            if b is None:
-                break
-            hit += bs
+        for _, b in self._walk(tokens,
+                               min(covered, len(tokens)) // self.block_size):
             if self.is_host_entry(b) or self._refs.get(b, 0) != 0 \
                     or not self._host_free:
                 continue
@@ -756,18 +819,8 @@ class BlockManager:
         drop on either tier; blocks a running request still references
         merely become unregistered-owned. Returns registrations
         dropped."""
-        bs = self.block_size
-        full = (min(covered, len(tokens)) // bs) * bs
-        key: Optional[tuple] = None
-        entries: List[int] = []
-        hit = 0
-        while hit + bs <= full:
-            key = (key, tuple(tokens[hit:hit + bs]))
-            b = self._prefix_index.get(key)
-            if b is None:
-                break
-            entries.append(b)
-            hit += bs
+        entries = [b for _, b in self._walk(
+            tokens, min(covered, len(tokens)) // self.block_size)]
         for b in entries:
             self._drop_registration(b)
             if self.is_host_entry(b):
@@ -804,18 +857,20 @@ class BlockManager:
         self.num_commit_visited += end - start
         for idx in range(start, end):
             part = tuple(tokens[idx * bs:(idx + 1) * bs])
-            key = (key, part)
-            chash = _fold_hash(chash, part)
+            key = _ChainKey(key, part)
             b = table[idx]
-            # a key someone committed first keeps their block; a block
-            # that carries another key keeps that one
-            if key not in self._prefix_index and b not in self._block_key:
-                self._prefix_index[key] = b
-                self._block_key[b] = key
-                self._key_hash[key] = chash
-                self._hash_key[chash] = key
-                self._hash_tokens[chash] = (idx + 1) * bs
-                self._trie_rev += 1
+            # a key someone committed first keeps their block (and the
+            # walk goes on from their key object and its chain hash, a
+            # function of the same tokens); a block that carries another
+            # key keeps that one
+            held = self._prefix_index.get(key)
+            if held is not None:
+                key = self._block_key[held]
+                chash = self._key_hash[key]
+                continue
+            chash = _fold_hash(chash, part)
+            if b not in self._block_key:
+                self._register(key, b, chash, (idx + 1) * bs)
                 self.num_prefix_blocks_committed += 1
         self._commit_cursor[request_id] = (end, key, chash)
         if request_id in self._snap_planned:
@@ -837,17 +892,24 @@ class BlockManager:
         chains (shared system prompts) are the broadly useful ones, and
         keeping every ancestor of a kept entry means a router walking
         the chain front-to-back never breaks early on a capped-out
-        middle link. Cached per trie revision, so heartbeat-rate calls
-        are O(1) between registration changes."""
-        ck = (self._trie_rev, int(max_entries))
-        if self._digest_cache is not None \
-                and self._digest_cache[0] == ck:
-            return self._digest_cache[1]
-        items = sorted(self._hash_tokens.items(),
-                       key=lambda kv: (kv[1], kv[0]))
-        digest = {"bs": self.block_size, "n": len(items),
-                  "h": dict(items[:max_entries])}
-        self._digest_cache = (ck, digest)
+        middle link. Ties in depth go by hash. The order is kept as
+        entries come and go, so a call builds ``"h"`` anew (O(max
+        entries)) only when an entry entered or left the kept part, and
+        otherwise returns the last digest, with ``"n"`` brought up to
+        date: heartbeat-rate calls cost nothing however large the trie."""
+        m = int(max_entries)
+        n = len(self._digest_order)
+        cached = self._digest_cache
+        if cached is None or cached[0] != m or self._digest_dirty < m:
+            self.num_digest_rebuilds += 1
+            self._digest_dirty = m
+            digest = {"bs": self.block_size, "n": n,
+                      "h": {h: c for c, h in self._digest_order[:m]}}
+        elif cached[1]["n"] != n:
+            digest = dict(cached[1], n=n)
+        else:
+            return cached[1]
+        self._digest_cache = (m, digest)
         return digest
 
     def prefix_blocks_by_hash(
@@ -862,22 +924,18 @@ class BlockManager:
         key = self._hash_key.get(chain_hash)
         if key is None:
             return None
-        parts: List[tuple] = []
-        k: Optional[tuple] = key
-        while k is not None:
-            k, part = k
-            parts.append(part)
-        parts.reverse()
+        chain: List[_ChainKey] = []
+        while key is not None:
+            chain.append(key)
+            key = key.parent
         tokens: List[int] = []
         blocks: List[int] = []
-        k = None
-        for part in parts:
-            k = (k, part)
+        for k in reversed(chain):
             b = self._prefix_index.get(k)
             if b is None:
                 return None   # ancestor evicted since registration
             blocks.append(b)
-            tokens.extend(part)
+            tokens.extend(k.tokens)
         return tokens, blocks
 
     # -- allocation ------------------------------------------------------
@@ -1006,16 +1064,8 @@ class BlockManager:
                 f"free() it before resuming")
         bs = self.block_size
         full = (covered // bs) * bs
-        shared: List[int] = []
-        key: Optional[tuple] = None
-        hit = 0
-        while hit + bs <= full:
-            key = (key, tuple(tokens[hit:hit + bs]))
-            b = self._prefix_index.get(key)
-            if b is None:
-                break
-            shared.append(b)
-            hit += bs
+        shared = [b for _, b in self._walk(tokens, covered // bs)]
+        hit = len(shared) * bs
         table = [self._share_entry(b) for b in shared]
         tail_block: Optional[int] = None
         hit_tokens = hit
@@ -1380,6 +1430,9 @@ class BlockManager:
                 f"hash map drift: {h} does not map back to its key"
         assert set(self._hash_tokens) == set(self._hash_key), \
             "hash token-count map drifted from the hash map"
+        assert self._digest_order == sorted(
+            (c, h) for h, c in self._hash_tokens.items()), \
+            "the digest's order drifted from the hash token-count map"
         for rid, (idx, _, _) in self._commit_cursor.items():
             assert rid in self._tables, \
                 f"commit cursor of {rid!r} outlived its block table"

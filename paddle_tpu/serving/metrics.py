@@ -308,6 +308,9 @@ class ServingMetrics:
                     eng.block_manager.num_commit_visited,
                 "prefix_blocks_committed":
                     eng.block_manager.num_prefix_blocks_committed,
+                # prefix_digest calls that found its kept entries changed
+                "prefix_digest_rebuilds":
+                    eng.block_manager.num_digest_rebuilds,
             })
             # resilience counters: swap traffic, TTL expiry, admission
             # rejects, step retries, poisoned-row aborts, drain lifecycle

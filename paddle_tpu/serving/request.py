@@ -82,12 +82,16 @@ FINISH_REASONS = ("stop", "length", "expired", "rejected", "aborted:user",
                   "fenced")
 
 
-@dataclass
+@dataclass(eq=False)
 class Request:
     """One in-flight generation. ``tokens`` is prompt + generated so far;
     ``num_cached`` counts the leading tokens whose K/V live in the paged
     cache (0 after admission or preemption — preempted requests recompute
-    their whole prefix on re-admission)."""
+    their whole prefix on re-admission).
+
+    A request is a live object (its own RNG and device key): two requests
+    are equal only when they are the same object, and it hashes by
+    identity, so the scheduler's membership tests are set look-ups."""
 
     request_id: str
     prompt_ids: List[int]

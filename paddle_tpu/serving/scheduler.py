@@ -335,6 +335,7 @@ class Scheduler:
         rows: List[Request] = []
         nsched: List[int] = []
         preempted: List[Request] = []
+        evicted = set()   # ``preempted`` as a set: requests hash by identity
         used = 0
         any_prefill = False
         any_decode = False
@@ -375,8 +376,10 @@ class Scheduler:
                     if victim is None:
                         self._evict(req)
                         preempted.append(req)
+                        evicted.add(req)
                         return False
                     preempted.append(victim)
+                    evicted.add(victim)
                     drop_row(victim)
 
         # pass A — decode rows (fully caught-up requests; cost 1 each,
@@ -384,13 +387,13 @@ class Scheduler:
         # all-or-nothing: a verify that doesn't fit the budget sheds its
         # drafts and decodes plainly rather than verifying a partial
         # draft)
-        running = sorted(self.running, key=lambda r: r.sort_key)
-        decode_rows = [r for r in running
-                       if len(r.tokens) - r.num_cached == 1
-                       and r.num_generated > 0]
-        chunk_rows = [r for r in running if r not in decode_rows]
+        decode_rows: List[Request] = []
+        chunk_rows: List[Request] = []
+        for r in sorted(self.running, key=lambda r: r.sort_key):
+            (decode_rows if len(r.tokens) - r.num_cached == 1
+             and r.num_generated > 0 else chunk_rows).append(r)
         for req in decode_rows:
-            if req not in self.running:
+            if req in evicted:
                 continue  # evicted saving a more important row
             if used >= budget:
                 break
@@ -409,7 +412,7 @@ class Scheduler:
         # a preempted/recomputed request catching back up is the same
         # shape: everything in ``tokens`` past ``num_cached`` is prefill
         for req in chunk_rows:
-            if req not in self.running:
+            if req in evicted:
                 continue
             left = budget - used
             if left <= 0:
