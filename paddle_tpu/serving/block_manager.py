@@ -1298,8 +1298,8 @@ class BlockManager:
         first ``num_tokens`` tokens. Returns ``(device_table,
         host_table)`` — the caller must copy device->host before the
         freed device blocks are rewritten (synchronously, or async with
-        a fence ahead of the next step that could reuse them; the
-        engine's _KVSwapper does the latter). Each host slot starts at
+        a land ahead of the next read of the slots; the engine's
+        KVFrames does the latter). Each host slot starts at
         refcount 1."""
         if not self.can_swap_out(request_id, num_tokens):
             raise NoFreeBlocksError(

@@ -13,7 +13,7 @@ the TPU-native execution model:
   ``incubate.nn.functional.ragged_paged_attention``. No stacked
   ``(L, NB, BS, KH, D)`` array lives on the device: that shape is the
   FRAME of a handful of blocks in the host pool, the tiers and on the
-  wire, made and consumed by :mod:`paddle_tpu.serving.kv_blocks`;
+  wire, made and consumed by :mod:`paddle_tpu.serving.kv_frames`;
 * prefill and decode are the SAME compiled function: every iteration is
   ONE unpadded ragged step of the model's ``forward_ragged`` — a packed
   (T,) token stream over S sequence slots, so a mixed
@@ -22,7 +22,7 @@ the TPU-native execution model:
 * prompt prefixes are cached: full prompt blocks register in the
   BlockManager's content-keyed trie after the step that writes them,
   later requests share them by refcount, and the first divergent write
-  copy-on-writes (``_apply_cow`` lands the block copies pre-step);
+  copy-on-writes (``KVFrames.copy`` lands the block copies pre-step);
 * cache buffers are donated at the jit boundary on TPU: each layer
   scatters its new rows into its own array and the step returns the
   arrays as they are, so the update aliases in place and nothing is
@@ -81,7 +81,6 @@ import contextlib
 import itertools
 import threading
 import time
-import zlib
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -91,7 +90,7 @@ from paddle_tpu.profiler import StepProgram, span
 from paddle_tpu.serving.block_manager import (
     BlockManager, NoFreeBlocksError, cdiv,
 )
-from paddle_tpu.serving.kv_blocks import gather_blocks, scatter_blocks
+from paddle_tpu.serving.kv_frames import KVFrames
 from paddle_tpu.serving.metrics import ServingMetrics
 from paddle_tpu.serving.request import (
     Request, RequestOutput, RequestStatus, SamplingParams,
@@ -313,120 +312,6 @@ def _tp_param_layout(name: str, ndim: int, tp: int):
     return Layout((("tp", tp),), placements)
 
 
-class _KVSwapper:
-    """Engine-side block mover for swap-based preemption: copies
-    (L, nblocks, BS, KH, D) frames of the per-layer device caches
-    to/from the host pool, framed per TP shard (a single frame when
-    unsharded).
-
-    ``copy_out`` is ASYNC: it enqueues a device gather of the victim's
-    blocks (a fresh buffer, so the freed blocks may be rewritten by the
-    very next compiled step) and starts the device->host transfer
-    without blocking the scheduler; :meth:`fence` lands every pending
-    spill into the numpy host pool, and runs before any host slot is
-    read back (``copy_in``). Insertion order makes a reused host slot's
-    last writer win, so an abort-while-spilling needs no bookkeeping."""
-
-    def __init__(self, engine: "LLMEngine"):
-        self._eng = engine
-        # request_id -> (host slot ids, gathered K slice, gathered V
-        # slice); the device slices pin their buffers until fenced
-        self._pending: Dict[str, tuple] = {}
-
-    def copy_out(self, request: Request, dev_table: List[int],
-                 host_table: List[int]):
-        eng = self._eng
-        # the device table may hold one more block than was written
-        # (a decode-step slot claimed before the eviction); spill only
-        # the blocks the host table covers
-        dev = np.asarray(dev_table[:len(host_table)], np.int32)
-        host = np.asarray(host_table, np.int32)
-        k_slice, v_slice = eng._gather_blocks(dev)   # their own buffers
-        for buf in (k_slice, v_slice):
-            start = getattr(buf, "copy_to_host_async", None)
-            if start is not None:
-                start()             # overlap D2H with the next step
-        self._pending[request.request_id] = (host, k_slice, v_slice)
-
-    def fence(self):
-        """Land every in-flight spill in the host pool (blocking). Must
-        run before host slots are read or handed to a new victim whose
-        write should win — dict insertion order already serializes the
-        latter."""
-        if not self._pending:
-            return
-        eng = self._eng
-        for host, k_slice, v_slice in self._pending.values():
-            eng._host_k[:, :, host] = self._frames(np.asarray(k_slice))  # tpulint: disable=host-sync-in-traced (landing the async swap-out spill; a handful of KV blocks, off the step's critical path)
-            eng._host_v[:, :, host] = self._frames(np.asarray(v_slice))
-        self._pending.clear()
-
-    def _frames(self, arr: np.ndarray) -> np.ndarray:
-        """Global (L, n, BS, KH, D) gather -> stacked per-TP-shard
-        frames (tp, L, n, BS, KH/tp, D); a single frame unsharded."""
-        return self._eng.kv_layout.shard_frames(arr)
-
-    def copy_in(self, request: Request, host_table: List[int],
-                dev_table: List[int]):
-        self.fence()                # the spill may still be in flight
-        eng = self._eng
-        host = np.asarray(host_table, np.int32)
-        dev = np.asarray(dev_table, np.int32)
-        k_np = eng.kv_layout.unshard_frames(eng._host_k[:, :, host])
-        v_np = eng.kv_layout.unshard_frames(eng._host_v[:, :, host])
-        eng._scatter_blocks(dev, k_np, v_np)
-
-    def gather(self, dev_table: List[int]):
-        """Device->host gather of arbitrary blocks — the fleet KV-ship
-        export path. Same discipline as ``copy_out``/``fence`` (a
-        functional gather into a fresh buffer, async D2H start, then
-        land), except the bytes leave the process instead of landing in
-        a host-pool slot, so the land is immediate.
-
-        Tiered tables may hold VIRTUAL entries whose bytes live in the
-        host pool: any pending tier moves land first (their bytes may
-        still be device-side), then host-tier rows read straight from
-        the numpy pool — no promote, no device round-trip."""
-        eng = self._eng
-        bm = eng.block_manager
-        if eng._kvtier is not None:
-            eng._kvtier.apply_moves()
-        host_pos = [(i, bm.host_slot_of(b))
-                    for i, b in enumerate(dev_table)
-                    if bm.is_host_entry(b)]
-        if not host_pos:
-            k_slice, v_slice = eng._gather_blocks(dev_table)
-            for buf in (k_slice, v_slice):
-                start = getattr(buf, "copy_to_host_async", None)
-                if start is not None:
-                    start()         # overlap D2H across the two slices
-            return np.asarray(k_slice), np.asarray(v_slice)
-        self.fence()
-        shape = eng._frame_shape(len(dev_table))
-        dt = np.dtype(eng._kcs[0].dtype)
-        k_out, v_out = np.empty(shape, dt), np.empty(shape, dt)
-        dev_pos = [(i, b) for i, b in enumerate(dev_table)
-                   if not bm.is_host_entry(b)]
-        if dev_pos:
-            idxs = [i for i, _ in dev_pos]
-            k_dev, v_dev = eng._gather_blocks([b for _, b in dev_pos])
-            k_out[:, idxs] = np.asarray(k_dev)  # tpulint: disable=host-sync-in-traced (mixed-tier gather: the export path's one device read, off the step's critical path)
-            v_out[:, idxs] = np.asarray(v_dev)
-        idxs = [i for i, _ in host_pos]
-        slots = [s for _, s in host_pos]
-        k_out[:, idxs] = eng.kv_layout.unshard_frames(
-            eng._host_k[:, :, slots])
-        v_out[:, idxs] = eng.kv_layout.unshard_frames(
-            eng._host_v[:, :, slots])
-        return k_out, v_out
-
-    def scatter(self, dev_table: List[int], k_np, v_np):
-        """Write shipped KV bytes into freshly claimed device blocks
-        (fleet KV-ship import path) — the ``copy_in`` write, sourced
-        from wire bytes instead of the host pool."""
-        self._eng._scatter_blocks(dev_table, k_np, v_np)
-
-
 class LLMEngine:
     """Drive a :class:`~paddle_tpu.models.llama.LlamaForCausalLM` (or
     any model exposing the same ``forward_ragged`` contract) as a
@@ -610,18 +495,6 @@ class LLMEngine:
             num_host_blocks=self.cfg.num_host_blocks,
             enable_prefix_cache=self.cfg.prefix_cache,
             kv_layout=self.kv_layout, tiered=self._tiered, **pools)
-        self._swapper = _KVSwapper(self)
-        self._kvtier = (TieredKVStore(self, self._tiers_cfg)
-                        if self._tiered else None)
-        self.scheduler = Scheduler(
-            self.block_manager,
-            SchedulerConfig(max_num_seqs=self.cfg.max_num_seqs,
-                            max_batched_tokens=self._ragged_T),
-            swap_mode=self.cfg.swap_mode, kv_swapper=self._swapper)
-        if self._kvtier is not None:
-            # demote-before-preempt: every scheduler OOM path tries
-            # this before evicting a batch peer
-            self.scheduler.tier_relief = self._kvtier.relief
         self.admission = AdmissionController(
             max_queue_depth=self.cfg.max_queue_depth,
             ttft_slo_ms=self.cfg.ttft_slo_ms)
@@ -664,30 +537,25 @@ class LLMEngine:
             {k: jnp.zeros((n_snap, *a.shape[1:]), a.dtype)
              for k, a in c.items()}
             for c in self._cache if isinstance(c, dict)] if n_snap else None
-        # host swap pool: plain numpy per-shard frames, the
-        # restore-on-readmit side of swap-based preemption. Leading
-        # axis = TP shard (size 1 when unsharded), so a spilled block
-        # never interleaves bytes across shards and a future per-host
-        # pool can ship frames without re-slicing.
-        if self.cfg.num_host_blocks > 0:
-            hshape = (tp, mcfg.num_hidden_layers,
-                      self.cfg.num_host_blocks, self.cfg.block_size,
-                      kh // tp, hd)
-            self._host_k = np.zeros(hshape, np.dtype(cache_dtype))
-            self._host_v = np.zeros(hshape, np.dtype(cache_dtype))
-        else:
-            self._host_k = self._host_v = None
-        # tiered mode keeps a DEVICE mirror of the host tier — per layer
-        # (NHB, BS, KH, D), same sharding as the caches — updated
-        # incrementally at each demote, so the compiled step attends
-        # host-tier blocks through one in-graph concat per layer without
-        # a per-step full-pool upload. The numpy pool above stays the
-        # swap/wire source of truth.
-        if self._tiered:
-            self._htk = layer_pools(self.cfg.num_host_blocks)
-            self._htv = layer_pools(self.cfg.num_host_blocks)
-        else:
-            self._htk = self._htv = None
+        # the host pool (swap spills, the host tier) and, tiered, its
+        # device mirror: every block that leaves or enters the caches
+        # outside the step moves through this one object
+        self.kv_frames = KVFrames(
+            self, self.cfg.num_host_blocks, self._cache_sharding,
+            mirror=((layer_pools(self.cfg.num_host_blocks),
+                     layer_pools(self.cfg.num_host_blocks))
+                    if self._tiered else None))
+        self._kvtier = (TieredKVStore(self, self._tiers_cfg)
+                        if self._tiered else None)
+        self.scheduler = Scheduler(
+            self.block_manager,
+            SchedulerConfig(max_num_seqs=self.cfg.max_num_seqs,
+                            max_batched_tokens=self._ragged_T),
+            swap_mode=self.cfg.swap_mode, kv_swapper=self.kv_frames)
+        if self._kvtier is not None:
+            # demote-before-preempt: every scheduler OOM path tries
+            # this before evicting a batch peer
+            self.scheduler.tier_relief = self._kvtier.relief
 
         # -- the compiled step -------------------------------------------
         from paddle_tpu.jit.trace import functionalize
@@ -1224,47 +1092,6 @@ class LLMEngine:
                 for name, p in zip(self._pnames, self._params)}
 
     # -- fleet KV-ship ---------------------------------------------------
-    def _wire_src_layout(self, meta: dict, global_shape):
-        """The layout a shipped KV payload's frames are in. Absent
-        stanza = the pre-TP flat format (one replicated frame). A
-        malformed or non-fitting layout is a clean ``ValueError``
-        rejection, same as any geometry mismatch."""
-        from paddle_tpu.distributed.redistribute import Layout
-
-        lm = meta.get("layout")
-        if lm is None:
-            return Layout.tp_sharded(len(global_shape), 3, 1)
-        try:
-            src = Layout.from_meta(lm)
-            src.validate_shape(global_shape)
-        except (KeyError, TypeError, ValueError) as e:
-            raise ValueError(
-                f"shipped KV layout {lm!r} does not fit shape "
-                f"{list(global_shape)}: {e}") from e
-        return src
-
-    def _land_wire(self, payload: bytes, offset: int, src_layout,
-                   global_shape, dtype: np.dtype) -> np.ndarray:
-        """Parse per-shard wire frames and land them as one global
-        host array in THIS engine's cache orientation. A ship from a
-        replica of a different TP degree reshards through
-        ``redistribute`` — the single primitive both the SPMD step and
-        checkpoint restore use — instead of being rejected."""
-        local = src_layout.local_shape(global_shape)
-        n = int(np.prod(local))
-        frames = [np.frombuffer(payload, dtype=dtype,
-                                offset=offset + i * n * dtype.itemsize,
-                                count=n).reshape(local)
-                  for i in range(src_layout.size)]
-        if src_layout != self.kv_layout:
-            from paddle_tpu.distributed.redistribute import (
-                redistribute_host,
-            )
-
-            frames = redistribute_host(frames, src_layout,
-                                       self.kv_layout, global_shape)
-        return self.kv_layout.assemble(frames, global_shape)
-
     def export_kv(self, request_id: str):
         """Package the request's committed KV for a fleet KV-ship:
         ``(meta, payload)`` where ``payload`` is the K bytes followed by
@@ -1286,27 +1113,7 @@ class LLMEngine:
                 covered, table = parked
         if not table or covered <= 0:
             return None
-        k_np, v_np = self._swapper.gather(table)
-        # per-shard framing: K shard frames then V shard frames, in
-        # mesh order — byte-identical to the flat legacy format when
-        # unsharded (one frame each). The layout stanza lets an
-        # importer of a different TP degree reshard through
-        # redistribute instead of rejecting.
-        k_bytes = b"".join(s.tobytes()
-                           for s in self.kv_layout.shards(k_np))
-        payload = k_bytes + b"".join(s.tobytes()
-                                     for s in self.kv_layout.shards(v_np))
-        meta = {
-            "tokens_covered": int(covered),
-            "blocks": len(table),
-            "block_size": int(self.cfg.block_size),
-            "shape": list(k_np.shape),
-            "dtype": str(k_np.dtype),
-            "k_bytes": len(k_bytes),
-            "layout": self.kv_layout.to_meta(),
-            "crc32": zlib.crc32(payload) & 0xFFFFFFFF,
-        }
-        return meta, payload
+        return self.kv_frames.pack(table, tokens_covered=int(covered))
 
     def import_kv(self, request_id: str, prompt_ids: Sequence[int],
                   sampling: Optional[SamplingParams] = None,
@@ -1341,58 +1148,14 @@ class LLMEngine:
                 f"request {request_id!r}: shipped coverage {covered} "
                 f"outside (0, {len(prompt_ids)}) — at least one prompt "
                 f"token must remain to compute")
-        if int(meta.get("block_size", -1)) != self.cfg.block_size:
-            raise ValueError(
-                f"request {request_id!r}: shipped block_size "
-                f"{meta.get('block_size')} != {self.cfg.block_size}")
-        nblocks = cdiv(covered, self.cfg.block_size)
-        want_shape = list(self._frame_shape(nblocks))
-        if list(meta.get("shape", ())) != want_shape or \
-                int(meta.get("blocks", -1)) != nblocks:
-            raise ValueError(
-                f"request {request_id!r}: shipped KV shape "
-                f"{meta.get('shape')} != expected {want_shape}")
-        if str(meta.get("dtype")) != str(self._kcs[0].dtype):
-            raise ValueError(
-                f"request {request_id!r}: shipped dtype "
-                f"{meta.get('dtype')} != cache dtype {self._kcs[0].dtype}")
-        dtype = np.dtype(str(meta["dtype"]))
-        k_bytes = int(meta.get("k_bytes", -1))
-        want_bytes = int(np.prod(want_shape)) * dtype.itemsize
-        if k_bytes != want_bytes or len(payload) != 2 * want_bytes:
-            raise ValueError(
-                f"request {request_id!r}: shipped payload "
-                f"{len(payload)}B (k={k_bytes}) != 2x{want_bytes}B")
-        if zlib.crc32(payload) & 0xFFFFFFFF != int(meta.get("crc32", -1)):
-            raise ValueError(
-                f"request {request_id!r}: shipped KV failed its "
-                f"checksum — payload corrupt, refusing the import")
-        src_layout = self._wire_src_layout(meta, want_shape)
+        frames = self.kv_frames.unpack(
+            meta, payload, cdiv(covered, self.cfg.block_size),
+            f"request {request_id!r}: shipped")
         req = Request(request_id=request_id, prompt_ids=prompt_ids,
                       sampling=sampling, callback=callback)
         self._apply_rng_state(req, rng_state)
-        try:
-            table = self.block_manager.import_blocks(
-                request_id, covered, src_layout=src_layout)
-        except NoFreeBlocksError as e:
-            raise ValueError(str(e)) from e
-        try:
-            # partial-failure cleanup: blocks are allocated but nothing
-            # is registered yet — a scatter fault must not leak them
-            # (the fault point stands in for a device OOM/transfer error)
-            faults.fire(faults.SERVING_KV_SCATTER)
-            k_np = self._land_wire(payload, 0, src_layout, want_shape,
-                                   dtype)
-            v_np = self._land_wire(payload, k_bytes, src_layout,
-                                   want_shape, dtype)
-            self._swapper.scatter(table, k_np, v_np)
-        except Exception as e:
-            self.block_manager.free(request_id)
-            raise ValueError(
-                f"request {request_id!r}: KV scatter failed after "
-                f"block allocation ({e}); blocks freed") from e
-        if src_layout != self.kv_layout:
-            self.num_kv_reshards += 1
+        self._import_blocks(request_id, covered, frames,
+                            f"request {request_id!r}: KV scatter")
         req.num_cached = covered
         self._requests[request_id] = req
         self._note_arrival(req)
@@ -1404,6 +1167,31 @@ class LLMEngine:
                                              covered)
         self.num_continuation_admits += 1
         return request_id
+
+    def _import_blocks(self, rid: str, covered: int, frames, what: str,
+                       tokens: Optional[List[int]] = None):
+        """Claim blocks for ``covered`` shipped tokens under ``rid`` and
+        write the landed ``(k, v, src_layout)`` frames into them; with
+        ``tokens``, commit them to the trie as well. A fault after the
+        claim frees it whole before the ``ValueError`` (the fault point
+        stands in for a device OOM/transfer error)."""
+        k, v, src_layout = frames
+        try:
+            table = self.block_manager.import_blocks(
+                rid, covered, src_layout=src_layout)
+        except NoFreeBlocksError as e:
+            raise ValueError(str(e)) from e
+        try:
+            faults.fire(faults.SERVING_KV_SCATTER)
+            self.kv_frames.scatter(table, k, v)
+            if tokens is not None:
+                self.block_manager.commit_prefix(rid, tokens, covered)
+        except Exception as e:
+            self.block_manager.free(rid)
+            raise ValueError(f"{what} failed after block allocation "
+                             f"({e}); blocks freed") from e
+        if src_layout != self.kv_layout:
+            self.num_kv_reshards += 1
 
     # -- fleet prefix cache ----------------------------------------------
     def prefix_digest(self) -> Optional[dict]:
@@ -1430,24 +1218,10 @@ class LLMEngine:
         if resolved is None:
             return None
         tokens, table = resolved
-        k_np, v_np = self._swapper.gather(table)
-        k_bytes = b"".join(s.tobytes()
-                           for s in self.kv_layout.shards(k_np))
-        payload = k_bytes + b"".join(s.tobytes()
-                                     for s in self.kv_layout.shards(v_np))
-        meta = {
-            "chain_hash": chain_hash,
-            "tokens": [int(t) for t in tokens],
-            "blocks": len(table),
-            "block_size": int(self.cfg.block_size),
-            "shape": list(k_np.shape),
-            "dtype": str(k_np.dtype),
-            "k_bytes": len(k_bytes),
-            "layout": self.kv_layout.to_meta(),
-            "crc32": zlib.crc32(payload) & 0xFFFFFFFF,
-        }
+        out = self.kv_frames.pack(table, chain_hash=chain_hash,
+                                  tokens=[int(t) for t in tokens])
         self.num_prefix_exports += 1
-        return meta, payload
+        return out
 
     def import_prefix(self, *, meta: dict, payload: bytes) -> int:
         """Commit a shipped prefix into the local trie with NO request
@@ -1469,37 +1243,13 @@ class LLMEngine:
         tokens = [int(t) for t in meta.get("tokens", ())]
         covered = len(tokens)
         bs = self.cfg.block_size
-        if int(meta.get("block_size", -1)) != bs:
-            raise ValueError(
-                f"shipped prefix block_size {meta.get('block_size')} "
-                f"!= {bs}")
         if covered <= 0 or covered % bs != 0:
             raise ValueError(
                 f"shipped prefix covers {covered} tokens — must be a "
                 f"positive multiple of block_size {bs}")
         nblocks = covered // bs
-        want_shape = list(self._frame_shape(nblocks))
-        if list(meta.get("shape", ())) != want_shape or \
-                int(meta.get("blocks", -1)) != nblocks:
-            raise ValueError(
-                f"shipped prefix KV shape {meta.get('shape')} != "
-                f"expected {want_shape}")
-        if str(meta.get("dtype")) != str(self._kcs[0].dtype):
-            raise ValueError(
-                f"shipped prefix dtype {meta.get('dtype')} != cache "
-                f"dtype {self._kcs[0].dtype}")
-        dtype = np.dtype(str(meta["dtype"]))
-        k_bytes = int(meta.get("k_bytes", -1))
-        want_bytes = int(np.prod(want_shape)) * dtype.itemsize
-        if k_bytes != want_bytes or len(payload) != 2 * want_bytes:
-            raise ValueError(
-                f"shipped prefix payload {len(payload)}B "
-                f"(k={k_bytes}) != 2x{want_bytes}B")
-        if zlib.crc32(payload) & 0xFFFFFFFF != int(meta.get("crc32", -1)):
-            raise ValueError(
-                "shipped prefix failed its checksum — payload corrupt, "
-                "refusing the import")
-        src_layout = self._wire_src_layout(meta, want_shape)
+        frames = self.kv_frames.unpack(meta, payload, nblocks,
+                                       "shipped prefix")
         if self.block_manager.match_prefix(tokens) >= covered:
             return 0
         if nblocks > self.block_manager.num_uncached_free_blocks:
@@ -1509,29 +1259,9 @@ class LLMEngine:
                 f"{self.block_manager.num_uncached_free_blocks} "
                 f"uncached-free — refusing to evict resident cache")
         rid = f"__prefix_import__{next(self._prefix_import_seq)}"
-        try:
-            table = self.block_manager.import_blocks(
-                rid, covered, src_layout=src_layout)
-        except NoFreeBlocksError as e:
-            raise ValueError(str(e)) from e
-        try:
-            # same partial-failure discipline as import_kv: a scatter
-            # fault after allocation frees the synthetic claim whole
-            faults.fire(faults.SERVING_KV_SCATTER)
-            k_np = self._land_wire(payload, 0, src_layout, want_shape,
-                                   dtype)
-            v_np = self._land_wire(payload, k_bytes, src_layout,
-                                   want_shape, dtype)
-            self._swapper.scatter(table, k_np, v_np)
-            self.block_manager.commit_prefix(rid, tokens, covered)
-        except Exception as e:
-            self.block_manager.free(rid)
-            raise ValueError(
-                f"prefix import scatter failed after block allocation "
-                f"({e}); blocks freed") from e
+        self._import_blocks(rid, covered, frames, "prefix import scatter",
+                            tokens=tokens)
         self.block_manager.free(rid)
-        if src_layout != self.kv_layout:
-            self.num_kv_reshards += 1
         self.num_prefix_imports += 1
         return covered
 
@@ -2058,7 +1788,7 @@ class LLMEngine:
         # both before the step writes the destination blocks
         if self._kvtier is not None:
             self._kvtier.apply_moves()
-        self._apply_cow()
+        self.kv_frames.copy(self.block_manager.take_cow_pairs())
         # per-slot sampling state for the in-graph sampler: RNG keys,
         # params, and the draft rows under verification
         skeys = np.zeros((S, 2), np.uint32)
@@ -2153,48 +1883,6 @@ class LLMEngine:
         for (r, d), row in zip(cand, rows):
             r.draft_tokens = [int(t) for t in row[:d]]
 
-    def _apply_cow(self):
-        """Apply pending copy-on-write block copies (prefix-cache
-        divergence) as one batched device gather/scatter, ahead of the
-        step that writes into the fresh destination blocks."""
-        pairs = self.block_manager.take_cow_pairs()
-        if not pairs:
-            return
-        self._scatter_blocks([dst for _, dst in pairs],
-                             *self._gather_blocks([src for src, _ in pairs]))
-
-    def _frame_shape(self, n: int) -> tuple:
-        """``(L, n, BS, KH, D)``: the frame of ``n`` blocks of every
-        layer, as the host pool, the tiers and the wire hold them."""
-        return (len(self._kcs), n, *self._kcs[0].shape[1:])
-
-    def _gather_blocks(self, ids):
-        """The K and V frames ``(L, n, BS, KH, D)`` of device blocks
-        ``ids`` (swap-out, export, tier demote, COW), each in a device
-        buffer of its own."""
-        ids = np.asarray(ids, np.int32)
-        return gather_blocks(self._kcs, ids), gather_blocks(self._vcs, ids)
-
-    def _scatter_blocks(self, ids, k_frame, v_frame):
-        """Write ``(L, n, BS, KH, D)`` frames into device blocks ``ids``
-        of every layer (swap-in, import, tier promote, COW): the caches
-        are donated to the write, so only the n blocks move."""
-        ids = np.asarray(ids, np.int32)
-        self._kcs = scatter_blocks(self._kcs, ids, k_frame)
-        self._vcs = scatter_blocks(self._vcs, ids, v_frame)
-        self._pin_caches()
-
-    def _pin_caches(self):
-        """Re-commit every layer's cache to the TP cache sharding after
-        an update outside the step: it may hand back a differently-
-        sharded result, and a drifted cache layout would silently
-        recompile the ONE step the engine promises. No-op unsharded."""
-        if self._cache_sharding is not None:
-            import jax
-
-            self._kcs, self._vcs = jax.device_put(
-                (self._kcs, self._vcs), self._cache_sharding)
-
     # -- the guarded compiled dispatch ----------------------------------
     def _dispatch(self, reqs, arrays, sampling_arrays, composition):
         """Run the compiled step under the fault-isolation envelope:
@@ -2249,7 +1937,8 @@ class LLMEngine:
                     elif spec_cache:
                         held = (self._cache, *tables)
                     elif self._kvtier is not None:
-                        held = (self._kcs, self._vcs, self._htk, self._htv)
+                        held = (self._kcs, self._vcs,
+                                *self.kv_frames.mirror)
                     else:
                         held = (self._kcs, self._vcs)
                     args = ([p._data for p in self._params],

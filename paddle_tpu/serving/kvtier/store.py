@@ -1,16 +1,17 @@
-"""TieredKVStore — policy + byte movement for the device/host/peer
-KV hierarchy.
+"""TieredKVStore — the policy of the device/host/peer KV hierarchy.
 
 The :class:`~paddle_tpu.serving.block_manager.BlockManager` owns the
 *mechanism*: virtual block ids, the ordered ``_tier_moves`` ledger,
-tier-blind trie registration. This module owns the *policy* and the
-actual bytes:
+tier-blind trie registration. The engine's
+:class:`~paddle_tpu.serving.kv_frames.KVFrames` owns the bytes: the
+numpy host pool and the device mirror the compiled step concatenates
+with the cache, with the ledger's moves and the swap spills in one
+event order (a reused host slot's last writer wins). This module owns
+the *policy*:
 
-* :meth:`TieredKVStore.apply_moves` drains the ledger once per engine
-  iteration and lands every demote/promote in record order — into the
-  numpy host pool (the swap/wire source of truth) AND the device-side
-  mirror the compiled step concatenates with the device cache, so a
-  host-tier block is attendable the same iteration it demotes;
+* :meth:`TieredKVStore.apply_moves` lands the ledger once per engine
+  iteration, so a host-tier block is attendable the same iteration it
+  demotes;
 * :meth:`balance` keeps an uncached-free device headroom by demoting
   cold registered blocks, and opportunistically promotes running
   requests' host-tier blocks back while the device pool has slack;
@@ -23,12 +24,6 @@ actual bytes:
   chain off-device between turns, and ``claim_resume`` re-shares it —
   walking the ladder down to plain recompute when the chain was partly
   or wholly evicted, never losing or duplicating a block.
-
-Ordering contract (why fence-then-in-order is sufficient): swap-out
-spills land via :meth:`_KVSwapper.fence` BEFORE any tier move applies,
-and within one schedule round a host slot freed by one move may be
-reclaimed by a later one — in-order application makes the last writer
-win, exactly matching the allocator's event order.
 """
 from __future__ import annotations
 
@@ -38,7 +33,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from paddle_tpu.serving.block_manager import prefix_chain_hashes
-from paddle_tpu.serving.kv_blocks import scatter_blocks
 
 __all__ = ["KVTiersConfig", "SessionRecord", "TieredKVStore"]
 
@@ -104,12 +98,12 @@ class KVTiersConfig:
 class SessionRecord:
     """One parked (or park-eligible) multi-turn session: the full token
     chain whose KV survives the request, plus the partial tail block's
-    bytes (per-TP-shard frames) that the trie cannot hold."""
+    K and V frames that the trie cannot hold."""
 
     session_id: str
     tokens: List[int]
     covered: int                       # tokens with cached KV at finish
-    tail_k: Optional[np.ndarray] = None   # (tp, L, 1, BS, KH/tp, D)
+    tail_k: Optional[np.ndarray] = None   # (L, 1, BS, KH, D)
     tail_v: Optional[np.ndarray] = None
     tenant: Optional[str] = None
     chain_hash: Optional[str] = None   # full-block chain id (offload)
@@ -143,59 +137,7 @@ class TieredKVStore:
         """Drain the BlockManager's ordered move ledger and land the
         bytes. Runs once per engine iteration, after scheduling and
         before COW pairs / the compiled step. Returns moves applied."""
-        eng = self._eng
-        moves = eng.block_manager.take_tier_moves()
-        if not moves:
-            return 0
-        # pending swap-out spills were recorded before any of these
-        # moves could reclaim their slots: land them first so a reused
-        # slot's last writer wins in true event order
-        eng._swapper.fence()
-        i = 0
-        while i < len(moves):
-            kind = moves[i][0]
-            j = i
-            while j < len(moves) and moves[j][0] == kind:
-                j += 1
-            run = moves[i:j]
-            if kind == "demote":
-                self._demote_bytes(run)
-            else:
-                self._promote_bytes(run)
-            i = j
-        return len(moves)
-
-    @staticmethod
-    def _dedupe_last(pairs: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
-        """Keep only the LAST write per destination (batched fancy
-        assignment with duplicate indices must match sequential
-        last-writer-wins semantics)."""
-        last = {dst: k for k, (_, dst) in enumerate(pairs)}
-        return [p for k, p in enumerate(pairs)
-                if last[p[1]] == k]
-
-    def _demote_bytes(self, run: List[tuple]) -> None:
-        eng = self._eng
-        pairs = self._dedupe_last([(dev, slot) for _, dev, slot in run])
-        slots = np.asarray([s for _, s in pairs], np.int32)
-        k_dev, v_dev = eng._gather_blocks([d for d, _ in pairs])
-        k_np = np.asarray(k_dev)  # tpulint: disable=host-sync-in-traced (tier demotion: a handful of cold blocks leave the device, off the step's critical path)
-        v_np = np.asarray(v_dev)
-        eng._host_k[:, :, slots] = eng.kv_layout.shard_frames(k_np)
-        eng._host_v[:, :, slots] = eng.kv_layout.shard_frames(v_np)
-        # the device-side mirror the tiered step concatenates with the
-        # cache — updated incrementally, never re-uploaded wholesale
-        eng._htk = scatter_blocks(eng._htk, slots, k_np)
-        eng._htv = scatter_blocks(eng._htv, slots, v_np)
-
-    def _promote_bytes(self, run: List[tuple]) -> None:
-        eng = self._eng
-        pairs = self._dedupe_last([(slot, dev) for _, slot, dev in run])
-        slots = [s for s, _ in pairs]
-        devs = [d for _, d in pairs]
-        k_np = eng.kv_layout.unshard_frames(eng._host_k[:, :, slots])
-        v_np = eng.kv_layout.unshard_frames(eng._host_v[:, :, slots])
-        eng._scatter_blocks(devs, k_np, v_np)
+        return self._eng.kv_frames.apply_tier_moves()
 
     # -- per-iteration policy ---------------------------------------------
     def balance(self) -> None:
@@ -255,9 +197,7 @@ class TieredKVStore:
             table = bm.block_table(rid)
             idx = covered // bs
             if idx < len(table):
-                k_np, v_np = eng._swapper.gather([table[idx]])
-                tail_k = eng.kv_layout.shard_frames(k_np)
-                tail_v = eng.kv_layout.shard_frames(v_np)
+                tail_k, tail_v = eng.kv_frames.fetch([table[idx]])
         full = (covered // bs) * bs
         chain_hash = (prefix_chain_hashes(tokens[:full], bs)[-1]
                       if full >= bs else None)
@@ -329,9 +269,8 @@ class TieredKVStore:
             self.num_resume_recomputes += 1
         elif tail_block is not None:
             try:
-                eng._scatter_blocks(
-                    [tail_block], eng.kv_layout.unshard_frames(rec.tail_k),
-                    eng.kv_layout.unshard_frames(rec.tail_v))
+                eng.kv_frames.scatter([tail_block], rec.tail_k,
+                                      rec.tail_v)
             except Exception:
                 # a failed tail restore must not strand the resumed
                 # claim: free the whole chain before the error

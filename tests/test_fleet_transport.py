@@ -747,12 +747,12 @@ class TestDisaggKVShip:
         meta, payload = eng_a.export_kv("src")
         src_table = eng_a.block_manager.export_blocks(
             "src", meta["tokens_covered"])
-        k_src, v_src = eng_a._swapper.gather(src_table)
+        k_src, v_src = eng_a.kv_frames.fetch(src_table)
         eng_b.import_kv("dst", list(req.tokens), sampling=sp,
                         meta=meta, payload=payload)
         dst_table = eng_b.block_manager.export_blocks(
             "dst", meta["tokens_covered"])
-        k_dst, v_dst = eng_b._swapper.gather(dst_table)
+        k_dst, v_dst = eng_b.kv_frames.fetch(dst_table)
         np.testing.assert_array_equal(k_src, k_dst)
         np.testing.assert_array_equal(v_src, v_dst)
         bm = eng_b.block_manager

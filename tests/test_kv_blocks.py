@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.distributed.redistribute import Layout
-from paddle_tpu.serving.kv_blocks import gather_blocks, scatter_blocks
+from paddle_tpu.serving.kv_frames import gather_blocks, scatter_blocks
 
 L, NB, BS, KH, D = 3, 12, 4, 4, 8
 

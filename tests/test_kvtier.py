@@ -391,12 +391,12 @@ class TestParkResume:
         prompt2 = prompt + turn1 + [1, 2, 3]
         def torn(*a):
             raise RuntimeError("torn tail copy")
-        eng._pin_caches = torn          # dies inside the tail restore
+        eng.kv_frames._pin_caches = torn  # dies inside the tail restore
         try:
             with pytest.raises(RuntimeError, match="torn tail copy"):
                 eng.resume_session("s2", "s", prompt2, sampling=GREEDY)
         finally:
-            del eng._pin_caches         # back to the class method
+            del eng.kv_frames._pin_caches  # back to the class method
         bm = eng.block_manager
         assert not bm.has_table("s2")     # the claim did not strand
         bm.check_invariants()

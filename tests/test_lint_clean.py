@@ -56,16 +56,13 @@ def test_suppression_inventory_is_intentional():
         # serving/engine.py: the engine's deliberate host boundaries —
         # ONE packed B-sized int fetch per step (tokens + emit counts +
         # advanced RNG keys; sampling is fully in-graph, so the old
-        # B×vocab sampled-decode fetch is GONE), the B-bool
-        # nonfinite-guard fetch, the swap-out KV spill (device->host
-        # is the POINT of swap-based preemption), and the swapper's
-        # tier-aware gather (reading host-tier frames back for
-        # export/park IS a host copy by definition)
-        "paddle_tpu/serving/engine.py": 4,
-        # serving/kvtier/store.py: the demote copy — moving cold KV
-        # blocks device->host is the tier boundary itself, off the
-        # step's critical path
-        "paddle_tpu/serving/kvtier/store.py": 1,
+        # B×vocab sampled-decode fetch is GONE) and the B-bool
+        # nonfinite-guard fetch
+        "paddle_tpu/serving/engine.py": 2,
+        # serving/kv_frames.py: the K/V frame's one device->host land,
+        # shared by the swap-out spill, the tier demote and the export
+        # gather (moving blocks to the host IS each of those paths)
+        "paddle_tpu/serving/kv_frames.py": 1,
         # serving/spec.py: the draft proposer's B×k int proposal fetch —
         # its whole host boundary, same O(B) order as the engine's
         # packed-token fetch

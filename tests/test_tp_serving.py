@@ -78,14 +78,14 @@ class TestEngineTPSurface:
         shape = (2, 3, 4, 2, 8)
         # absent stanza = the pre-TP flat wire format: one replicated
         # frame — old exporters keep working against a TP importer
-        lay = eng._wire_src_layout({}, shape)
+        lay = eng.kv_frames.wire_layout({}, shape)
         assert lay.size == 1 and lay.ndim == 5
         with pytest.raises(ValueError):
-            eng._wire_src_layout({"layout": {"bogus": True}}, shape)
+            eng.kv_frames.wire_layout({"layout": {"bogus": True}}, shape)
         # a layout that cannot tile the payload geometry is refused
         bad = Layout.tp_sharded(5, 3, 4).to_meta()
         with pytest.raises(ValueError):
-            eng._wire_src_layout({"layout": bad}, (2, 3, 4, 2, 8)[:4])
+            eng.kv_frames.wire_layout({"layout": bad}, (2, 3, 4, 2, 8)[:4])
 
     def test_tp_degree_must_divide_heads(self, tiny_model):
         with pytest.raises(ValueError, match="divide"):
