@@ -125,13 +125,13 @@ def ragged_selective_scan(x, dt, a, b, c, state, state_slots, cu_seqlens,
 
 
 def ragged_causal_conv(x, w, bias, conv_state, state_slots, cu_seqlens,
-                       context_lens, num_seqs):
-    """Causal depthwise convolution over the ragged stream. ``x`` (T, E);
-    ``w`` (taps, E), tap ``taps - 1`` on the current token; ``bias``
-    (E,); ``conv_state`` (slots, taps - 1, E): a sequence's last
-    ``taps - 1`` inputs, newest last. Returns (x' (T, E) before the
-    activation, conv_state')."""
-    with jax.named_scope("ssm_conv"):
+                       context_lens, num_seqs, delta_rule=False):
+    """Causal depthwise convolution over the ragged stream (region
+    ``gdn_conv`` in a ``delta_rule`` layer, else ``ssm_conv``). ``x`` (T,
+    E); ``w`` (taps, E), tap ``taps - 1`` on the current token; ``bias``
+    (E,); ``conv_state`` (slots, taps - 1, E): a sequence's last ``taps -
+    1`` inputs, newest last. Returns (x' before the activation, state')."""
+    with jax.named_scope("gdn_conv" if delta_rule else "ssm_conv"):
         t_total, e = x.shape
         taps = w.shape[0]
         keep = taps - 1

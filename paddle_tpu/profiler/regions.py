@@ -40,6 +40,8 @@ DEVICE_REGIONS = (
     "window_latent_attention", "mla_absorb", "attn_gate",
     # state-space layers
     "ssm_proj", "ssm_scan", "ssm_conv", "gmu",
+    # gated delta-rule (linear attention) layers
+    "gdn_proj", "gdn_conv", "gdn_delta", "gdn_out",
     # recurrent state to and from its snapshots (the prefix cache)
     "state_snapshot", "state_restore",
     # expert layers

@@ -72,7 +72,10 @@ class ServingMetrics:
               # (all 0 on a non-tiered engine)
               "kv_tier_demotes", "kv_tier_promotes",
               "kv_tier_host_blocks_used", "kv_tier_peer_blocks_used",
-              "kv_tier_park_resumes")
+              "kv_tier_park_resumes",
+              # bytes of recurrent state held in slots by live requests
+              # (0 for a model without state layers)
+              "state_bytes_held")
 
     # per-terminal-reason histogram (ISSUE 8): every request's end state
     # lands in exactly one bucket — `serving/finish/<reason>` counters,
@@ -126,6 +129,8 @@ class ServingMetrics:
         "kv_tier_park_resumes": lambda eng: (
             eng._kvtier.num_park_resumes
             if getattr(eng, "_kvtier", None) is not None else 0),
+        "state_bytes_held": lambda eng: (
+            eng.block_manager.state_slots_in_use * _slot_bytes(eng._cache)),
     }
 
     def __init__(self, engine):
@@ -369,6 +374,14 @@ class ServingMetrics:
             self._registered.append(cname)
         # an app that never reads counters() must not leak providers
         weakref.finalize(engine, _unregister_all, list(self._registered))
+
+
+def _slot_bytes(cache):
+    """Bytes of one sequence's state slots over every state layer of a
+    ``cache_spec`` cache (a dict of ``(slots + 1, ...)`` arrays a layer);
+    0 for a model without them, or once the cache is gone."""
+    return sum(a.nbytes // a.shape[0] for c in (cache or ())
+               if isinstance(c, dict) for a in c.values())
 
 
 def _unregister_all(names):

@@ -180,7 +180,7 @@ def test_self_times_nest_by_containment():
     assert sum(own for _, _, own in got) == 130     # the busy union
 
 
-# -- the tiny steps of the four block designs and the train step ---------------
+# -- the tiny steps of the five block designs and the train step ---------------
 def _llama(**kw):
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
@@ -204,6 +204,13 @@ def _sparse():
     from paddle_tpu.models.dots3 import Dots3Config, Dots3ForCausalLM
 
     return Dots3ForCausalLM(Dots3Config.tiny(experts_held=(2, 4)))
+
+
+def _olmo_hybrid():
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                               OlmoHybridForCausalLM)
+
+    return OlmoHybridForCausalLM(OlmoHybridConfig.tiny())
 
 
 def _engine(model, block_size=4):
@@ -248,6 +255,7 @@ STEPS = {"llama": lambda: _serve_step(_llama),
          "hybrid": lambda: _serve_step(_hybrid),
          "latent_moe": lambda: _serve_step(_moe, block_size=8),
          "sparse": lambda: _serve_step(_sparse),
+         "olmo_hybrid": lambda: _serve_step(_olmo_hybrid),
          "train": _train_step}
 HAS = {"llama": {"embed", "attn_proj", "attention", "kv_update", "mlp",
                  "lm_head", "sampler"},
@@ -260,6 +268,9 @@ HAS = {"llama": {"embed", "attn_proj", "attention", "kv_update", "mlp",
        "sparse": {"embed", "attn_proj", "mla_absorb", "attn_gate",
                   "index_proj", "index_scores", "index_select", "mlp",
                   "moe_router", "moe_shared", "lm_head", "sampler"},
+       "olmo_hybrid": {"embed", "gdn_proj", "gdn_conv", "gdn_delta",
+                       "gdn_out", "attn_proj", "attention", "kv_update",
+                       "mlp", "lm_head", "sampler"},
        "train": {"embed", "attn_proj", "attention", "mlp", "lm_head",
                  "lm_head_loss", "optimizer"}}
 

@@ -529,3 +529,76 @@ def test_longcat_double_layer_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * nb * BS * lanes * 2
     assert mem.temp_size_in_bytes < 512 * 2 ** 20
+
+
+def _olmo_hybrid_sds(one_chip):
+    from paddle_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    c = OlmoHybridConfig(num_hidden_layers=8,
+                         layer_types=OlmoHybridConfig().layer_types[:8])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    return c, sds
+
+
+def test_olmo_hybrid_linear_layer_compiles_for_v5e(one_chip):
+    """``models/olmo_hybrid.py: _linear_layer`` whole at the
+    `olmo-hybrid-7b-d8.chat-c128` widths (hidden 3,840, 30 heads of 96 /
+    192, conv 4, MLP 11,008; 512 rows, 128 + 1 state slots): one
+    ``ragged_gated_delta`` call for the decode rows, the (96, 5,760)
+    float32 state and the convolution's inputs updated in place."""
+    from paddle_tpu.models.olmo_hybrid import _linear_layer
+
+    c, sds = _olmo_hybrid_sds(one_chip)
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    t, s, d, f = 512, 128, c.hidden_size, c.intermediate_size
+    qk, vw = c.linear_widths
+    heads, dk, dv = 30, 96, 192
+    p = dict(attn_norm_w=sds((d,), bf16), ff_norm_w=sds((d,), bf16),
+             gate_up=sds((d, 2 * f), bf16), down=sds((f, d), bf16),
+             qkv_proj=sds((d, 2 * qk + vw), bf16),
+             conv_w=sds((4, 2 * qk + vw), bf16),
+             ab_proj=sds((d, 2 * heads), bf16), g_proj=sds((d, vw), bf16),
+             o_norm_w=sds((dv,), bf16), o_proj=sds((vw, d), bf16),
+             A_log=sds((heads,), f32), dt_bias=sds((heads,), f32))
+    state = dict(ssm=sds((s + 1, dk, vw), f32),
+                 conv=sds((s + 1, 3, 2 * qk + vw), bf16))
+    compiled = jax.jit(functools.partial(
+        _linear_layer, heads=heads, dk=dk, dv=dv, eps=1e-6,
+        neg_eigval=True, impl="pallas"), donate_argnums=2).lower(
+        p, sds((t, d), bf16), state, sds((s,), i32), sds((s + 1,), i32),
+        sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_gated_delta") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (s + 1) * dk * vw * 4
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_olmo_hybrid_full_layer_compiles_for_v5e(one_chip):
+    """``models/olmo_hybrid.py: _full_layer`` at the cell's widths: 30 K/V
+    heads of 128 with one query head each (``rep`` 1) in a FOLDED cache
+    (2,560 blocks of 64 x 3,840 lanes: a 4-D cache of 30 heads is
+    refused), 128 slots of 48 table entries; the ragged kernel once, both
+    pools updated in place."""
+    from paddle_tpu.models.olmo_hybrid import _full_layer
+
+    c, sds = _olmo_hybrid_sds(one_chip)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    t, s, d, f, mb, nb, bs = 512, 128, 3840, c.intermediate_size, 48, 2560, 64
+    p = {n: sds((d, d), bf16) for n in ("q_proj", "k_proj", "v_proj",
+                                        "o_proj")}
+    p.update({n: sds((d,), bf16) for n in ("q_norm_w", "k_norm_w",
+                                           "attn_norm_w", "ff_norm_w")})
+    p.update(gate_up=sds((d, 2 * f), bf16), down=sds((f, d), bf16))
+    pool = sds((nb, bs, d), bf16)
+    compiled = jax.jit(functools.partial(
+        _full_layer, heads=30, eps=1e-6, impl="pallas"),
+        donate_argnums=(2, 3)).lower(
+        p, sds((t, d), bf16), pool, pool, sds((s, mb), i32),
+        sds((s + 1,), i32), sds((s,), i32), sds((), i32)).compile()
+    assert _kernel_calls(compiled, "ragged_paged_attention") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * nb * bs * d * 2
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
